@@ -114,7 +114,11 @@ def main(argv=None) -> int:
                     help="eviction budget per reconcile tick")
     ap.add_argument("--deschedule-device", action="store_true",
                     help="dispatch the what-if matrix through the jitted "
-                         "mirror instead of the host walker")
+                         "mirror instead of the host walker. This makes the "
+                         "descheduler a JAX process of its own: a chip "
+                         "belongs to one process, so beside a scheduler "
+                         "that holds the chip run it with JAX_PLATFORMS=cpu "
+                         "(the harnesses do) or give it its own chip")
     args = ap.parse_args(argv)
 
     if args.mode == "deschedule":

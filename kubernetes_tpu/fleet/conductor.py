@@ -244,6 +244,9 @@ class FleetConductor:
                 others = [u for u in self.follower_urls if u != api_url] \
                     + [self.base]
                 extra = ["--api-fallbacks", ",".join(others)]
+            # N shard processes cannot share one chip (a chip belongs to
+            # one process), so the shard plane schedules on the CPU —
+            # by flag here and by JAX_PLATFORMS in shard/harness.py _env.
             cmd = pin + [sys.executable, "-m", "kubernetes_tpu",
                          "--api-url", api_url, "--platform", "cpu",
                          "--port", "0",

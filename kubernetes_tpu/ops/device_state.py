@@ -30,25 +30,29 @@ jax.config.update("jax_enable_x64", True)
 
 
 def enable_persistent_compilation_cache() -> None:
-    """Persistent XLA compilation cache: kernel compiles run 30-90s on TPU,
-    and the perf/bench harnesses start fresh processes per run — without this
-    every process pays every compile again. Called from TPUScheduler.__init__
-    (constructing the device-backed scheduler is the opt-in; merely importing
-    the library must not redirect an embedding application's JAX caching).
-    Opt out with KUBERNETES_TPU_NO_XLA_CACHE=1."""
-    if os.environ.get("KUBERNETES_TPU_NO_XLA_CACHE"):
-        return
+    """Persistent XLA compilation cache: a cold kernel compile is the
+    dominant start-up cost on an accelerator, and the perf/bench harnesses
+    start fresh processes per run — without this every process pays every
+    compile again. Called from TPUScheduler.__init__ (constructing the
+    device-backed scheduler is the opt-in; merely importing the library
+    must not redirect an embedding application's JAX caching). Placement
+    is compile_cache.cache_dir()'s one rule: JAX_COMPILATION_CACHE_DIR when
+    set (JAX configures itself from it — nothing is set here), else
+    <checkout>/.jax_cache."""
     if jax.config.jax_compilation_cache_dir:
-        return  # the application already configured a cache; respect it
-    cache_dir = os.environ.get(
-        "KUBERNETES_TPU_XLA_CACHE_DIR",
-        os.path.join(os.path.expanduser("~"), ".cache", "kubernetes_tpu_xla"))
+        return  # the environment or the application already placed it
+    from ..compile_cache import cache_dir
+    path = cache_dir()
     try:
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except (OSError, AttributeError):  # read-only FS or old jax: best-effort
-        pass
+        os.makedirs(path, exist_ok=True)
+    except OSError as e:  # read-only checkout: run uncached, and say so
+        import logging
+        logging.getLogger(__name__).warning(
+            "no persistent compile cache (%s); set JAX_COMPILATION_CACHE_DIR "
+            "to a writable directory", e)
+        return
+    jax.config.update("jax_compilation_cache_dir", path)
+
 
 import jax.numpy as jnp  # noqa: E402
 
@@ -396,12 +400,7 @@ class NodeStateMirror:
         that was later DONATED back to the kernel or a patch jit. adopt and
         the patch seam keep host staging in line, so a full upload from
         staging reproduces the exact device truth."""
-        if self._device is None:
-            return False
-        try:
-            return self._device.req_r.is_deleted()
-        except AttributeError:
-            return False
+        return self._device is not None and self._device.req_r.is_deleted()
 
     def _scatter_dirty(self, dirty) -> DeviceNodeState:
         """Scatter the given staging rows into the resident device state."""

@@ -15,8 +15,10 @@ Two implementations, bit-identical by construction:
   compile wait in a 250ms reconcile tick);
 - ``whatif_scores(batch, device=True)`` — a jax.jit mirror of the same
   int64 formulas, shape-padded so a steady descheduler tick reuses one
-  compiled executable (the SNIPPETS.md donation pattern keeps these
-  buffers resident beside the scheduler's own batch tensors).
+  compiled executable. The descheduler is its own OS process, so this
+  is a SECOND JAX process beside the scheduler: a chip belongs to one
+  process, hence CPU (JAX_PLATFORMS=cpu, what shard/harness.py _env gives
+  every spawned controller) or a chip of its own — never the scheduler's.
 
 Bit-parity is load-bearing, not cosmetic: a standby descheduler
 re-deriving a dead ACTIVE's plan — possibly on different hardware —

@@ -26,7 +26,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.device_state import DeviceNodeState
@@ -236,9 +235,11 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
     1. one ``all_gather`` of an i32 pair per shard — the shard's feasible
        count (global prefix-sum offsets + total_feas) and its contribution
        to F[start-1] (the rotation-rank origin);
-    2. one packed ``pmax`` over [2·LAP_MAX] lanes — the per-window
-       max-score-then-min-rotation selection keys and (negated) the
-       per-window evaluated boundaries.
+    2. one packed ``all_gather`` of [2·LAP_MAX] i64 lanes, max-reduced
+       locally — the per-window max-score-then-min-rotation selection keys
+       and (negated) the per-window evaluated boundaries. (Not ``pmax``:
+       XLA:TPU lowers a 64-bit integer all-reduce for sum only, and the
+       packed keys are i64 — established on the v5e, PR 21.)
 
     Everything else — fit/BA re-eval, window segmentation, the landed-row
     aggregate updates — touches only shard-local rows. Integer arithmetic
@@ -316,7 +317,7 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
         ev_w_l = jnp.min(jnp.where(in_b, rot[None, :] + 1, num), axis=1)
         # ---- collective 2: packed per-window reduction (mins negated) ----
         packed = jnp.concatenate([key_w_l, -ev_w_l.astype(jnp.int64)])
-        red = lax.pmax(packed, gather_axis)
+        red = lax.all_gather(packed, gather_axis).max(axis=0)
         key_w = red[:LAP_MAX]
         ev_w = (-red[LAP_MAX:]).astype(jnp.int32)
         has_w = (lanes < L) & (key_w >= 0)
@@ -390,14 +391,14 @@ class _ShardedLap:
         def chained(state, f, n_active, carry_in):
             return body(state, f, n_active, carry_in)
 
-        self.fresh = jax.jit(shard_map(
+        self.fresh = jax.jit(jax.shard_map(
             fresh, mesh=mesh,
             in_specs=(state_specs, feat_specs, P()),
-            out_specs=(P(), carry_specs), check_rep=False))
-        self.chained = jax.jit(shard_map(
+            out_specs=(P(), carry_specs), check_vma=False))
+        self.chained = jax.jit(jax.shard_map(
             chained, mesh=mesh,
             in_specs=(state_specs, feat_specs, P(), carry_specs),
-            out_specs=(P(), carry_specs), check_rep=False),
+            out_specs=(P(), carry_specs), check_vma=False),
             donate_argnums=3)
 
     def __call__(self, state, feats, n_active, carry_in=None):

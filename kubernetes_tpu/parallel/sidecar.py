@@ -355,16 +355,22 @@ class SidecarClient:
 
 def main(argv=None) -> int:
     """`python -m kubernetes_tpu.parallel.sidecar --socket /tmp/tpu.sock
-    [--platform cpu]` — the sidecar as its own OS process."""
+    [--platform cpu|tpu]` — the sidecar as its own OS process. It is a
+    JAX process of its own: beside a scheduler that holds the chip it runs
+    on the CPU (or on a chip of its own)."""
     import argparse
+    import sys
 
     ap = argparse.ArgumentParser(prog="kubernetes-tpu-sidecar")
     ap.add_argument("--socket", required=True)
-    ap.add_argument("--platform", default="auto", choices=("auto", "cpu"))
+    ap.add_argument("--platform", default="auto",
+                    choices=("auto", "cpu", "tpu"))
     args = ap.parse_args(argv)
-    if args.platform == "cpu":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+    from ..perf.device import pin_platform
+    refused = pin_platform(args.platform)
+    if refused:
+        print(refused, file=sys.stderr)
+        return 3
     SidecarServer(args.socket).serve_forever()
     return 0
 

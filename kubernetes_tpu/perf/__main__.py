@@ -20,6 +20,7 @@ import sys
 import time
 import traceback
 
+from .device import breaker_charges, measuring_device
 from .harness import load_config, run_workload
 
 DEFAULT_CONFIG = os.path.join(os.path.dirname(__file__), "configs",
@@ -50,10 +51,15 @@ def main(argv=None) -> int:
         wls = [w for w in wls if args.only in f"{w.testcase}/{w.name}"]
 
     results = []
+    # In-process rows run on this device (no TPU and no JAX_PLATFORMS=cpu
+    # by name: refused). Sharded/hollow rows start their schedulers as
+    # CPU child processes — N processes cannot share this process's chip.
+    device = measuring_device()
     meta = {
         "config": args.config,
         "scale": args.scale,
-        "platform": os.environ.get("JAX_PLATFORMS", "default"),
+        "platform": device["platform"],
+        "device": device,
         "started": time.strftime("%Y-%m-%dT%H:%M:%S"),
     }
     meta["runs"] = args.runs
@@ -82,6 +88,13 @@ def main(argv=None) -> int:
                             & set(wl.labels))
             try:
                 res = run_workload(wl)
+                charges = breaker_charges(
+                    res.detail.get("device_path_fallback") or {})
+                if charges:
+                    # Scheduling completed on the host path, which is the
+                    # breaker's guarantee — and exactly why the number is
+                    # not a device measurement.
+                    entry["breaker_charged"] = charges
                 tp = res.metrics.get("SchedulingThroughput", {})
                 avg = tp.get("Average", 0.0)
                 entry["runs"].append(round(avg, 1))
@@ -117,6 +130,7 @@ def main(argv=None) -> int:
             entry["vs_baseline"] = round(worst / thr, 2) if thr else None
             entry["meets_threshold"] = (
                 "error" not in entry
+                and "breaker_charged" not in entry
                 and (not asserted or not thr or worst >= thr)
                 and entry.get("other_thresholds_ok", True))
             print(json.dumps({"run": run_i + 1, "workload": key,

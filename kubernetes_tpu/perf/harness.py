@@ -38,6 +38,7 @@ import yaml
 from ..api.types import Namespace, PodGroup, Volume
 from ..core.scheduler import Scheduler
 from ..testing.wrappers import make_node, make_pod
+from .device import fallbacks_by_reason
 
 ZONE = "topology.kubernetes.io/zone"
 HOSTNAME = "kubernetes.io/hostname"
@@ -1011,6 +1012,9 @@ def run_workload(wl: Workload, sched: Optional[Scheduler] = None) -> PerfResult:
         v = getattr(sched, attr, None)
         if v is not None:
             result.detail[attr] = round(v, 3) if isinstance(v, float) else v
+    # Device→host fallbacks by reason: `python -m kubernetes_tpu.perf`
+    # fails a run whose breaker was charged (perf/device.py).
+    result.detail["device_path_fallback"] = fallbacks_by_reason(sched)
     # Mesh plane: compile-time per-step ici/dcn collective counts of the
     # workload's own dispatch path (the MULTICHIP collective budget).
     # Opt-in (one lower+compile per run) — the bench/dryrun mains set it.

@@ -949,12 +949,11 @@ class TestShardingDisciplineFixtures:
         """shard_map's in/out_specs ARE the placement pin."""
         good = textwrap.dedent("""
             import jax
-            from jax.experimental.shard_map import shard_map
 
             def build(mesh, in_specs, out_specs, out_shardings):
-                return jax.jit(shard_map(body, mesh=mesh,
-                                         in_specs=in_specs,
-                                         out_specs=out_specs))
+                return jax.jit(jax.shard_map(body, mesh=mesh,
+                                             in_specs=in_specs,
+                                             out_specs=out_specs))
         """)
         assert check_source(checker_by_id("sharding-discipline"),
                             good) == []
@@ -982,15 +981,15 @@ class TestShardingDisciplineFixtures:
         join the jit-purity scan scope')."""
         bad = textwrap.dedent("""
             import time
-            from jax.experimental.shard_map import shard_map
+            import jax
 
             def body(x):
                 time.sleep(1)
                 return x
 
             def build(mesh, specs):
-                return shard_map(body, mesh=mesh, in_specs=specs,
-                                 out_specs=specs)
+                return jax.shard_map(body, mesh=mesh, in_specs=specs,
+                                     out_specs=specs)
         """)
         fs = check_source(checker_by_id("jit-purity"), bad)
         assert any("time" in f.message or "impure" in f.message
