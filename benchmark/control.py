@@ -1,0 +1,170 @@
+#!/usr/bin/env python3
+"""The controls of `correct`: the reference put in the program's place with
+one thing a later PR could be tempted to do, which has to come out as NOT
+correct, and beside them the readings of what `correct` cannot see.
+
+The configurations state exact 64-bit integer arithmetic (memory in bytes,
+fractions in millionths) and two guarantees on the order of work. Controls
+(each differs from the reference on nearly every placement, on every seed):
+
+- `int32`: the score path with every quantity held and multiplied in 32 bits,
+  as a narrower kernel would: 256Gi in bytes wraps to 0 and the millionths
+  overflow;
+- `stale_batch`: a batch of 64 pods scored against the usage the batch started
+  with (the sequential carry left out: the guarantee "identical to sequential
+  scheduling in creation order" broken);
+- `last_maximum`: ties broken the other way (the guarantee "first maximum in
+  walk order" broken).
+
+Reading, NOT a control: `float32`, the score terms in float32 and floored
+where the reference floors. On these uniform clusters (equal nodes, equal
+pods, power-of-two sizes) it places every pod where int64 does, so exact
+placements do not detect float32 scoring here, and `correct` would pass it. A
+later PR that lowers the score path's precision must bring a configuration
+with unequal nodes or pods on which this reading differs (PERF.md).
+
+    python3 benchmark/control.py --config spread-5k --seeds 11 12 13
+
+schedules the init pods and one wave at the configuration's own size with the
+reference and with each control, and prints how many placements differ (the
+limit of the comparison is 0 differing; a control must differ). It needs no
+chip: both sides are numpy. `tests/benchmark/test_benchmark_reference.py`
+keeps it as a test at toy size.
+"""
+
+from __future__ import annotations
+
+import argparse
+import os
+import sys
+
+import numpy as np
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path.insert(0, HERE)
+
+import objects  # noqa: E402
+import reference  # noqa: E402
+from reference import FRACTION_SCALE, MAX_NODE_SCORE  # noqa: E402
+
+
+class Float32Scores(reference.Reference):
+    """The same score terms, floored where the reference floors them, with
+    every quantity and operation in float32."""
+
+    def scores(self, shape, rows):
+        f = np.float32
+        a_cpu, a_mem = self.alloc_cpu[rows].astype(f), self.alloc_mem[rows].astype(f)
+        u_cpu = (self.nz_cpu[rows] + shape.nz_cpu).astype(f)
+        u_mem = (self.nz_mem[rows] + shape.nz_memory).astype(f)
+        hundred, scale = f(MAX_NODE_SCORE), f(FRACTION_SCALE)
+        l_cpu = np.floor((a_cpu - u_cpu) * hundred / a_cpu)
+        l_mem = np.floor((a_mem - u_mem) * hundred / a_mem)
+        least = np.floor((l_cpu + l_mem) / f(2))
+        q_cpu = np.floor(u_cpu * scale / a_cpu)
+        q_mem = np.floor(u_mem * scale / a_mem)
+        balanced = np.floor((hundred * scale - f(50) * np.abs(q_cpu - q_mem))
+                            / scale)
+        return (least + balanced).astype(np.int64)
+
+
+class Int32Scores(reference.Reference):
+    """The same score terms with every quantity held, and every product
+    taken, in 32-bit integers (wrapping, as the hardware would); a division
+    by a capacity that wrapped to 0 gives 0."""
+
+    def scores(self, shape, rows):
+        i = np.int32
+        with np.errstate(all="ignore"):
+            a_cpu = self.alloc_cpu[rows].astype(i)
+            a_mem = self.alloc_mem[rows].astype(i)
+            u_cpu = (self.nz_cpu[rows] + shape.nz_cpu).astype(i)
+            u_mem = (self.nz_mem[rows] + shape.nz_memory).astype(i)
+            hundred, scale = i(MAX_NODE_SCORE), i(FRACTION_SCALE)
+
+            def div(x, y):
+                return np.where(y == 0, i(0), x // np.where(y == 0, i(1), y))
+
+            l_cpu = np.where(u_cpu > a_cpu, i(0),
+                             div((a_cpu - u_cpu) * hundred, a_cpu))
+            l_mem = np.where(u_mem > a_mem, i(0),
+                             div((a_mem - u_mem) * hundred, a_mem))
+            least = (l_cpu + l_mem) // i(2)
+            q_cpu = np.minimum(div(u_cpu * scale, a_cpu), scale)
+            q_mem = np.minimum(div(u_mem * scale, a_mem), scale)
+            balanced = ((hundred * scale - i(50) * np.abs(q_cpu - q_mem))
+                        // scale)
+        return (least + balanced).astype(np.int64)
+
+
+class StaleBatch(reference.Reference):
+    """Pods placed in groups of 64 against the usage the group started
+    with: what scheduling a batch in parallel, without the sequential
+    carry, would do (feasibility stays live, so no node overflows)."""
+
+    GROUP = 64
+
+    def scores(self, shape, rows):
+        if len(self.placed) % self.GROUP == 0 or not hasattr(self, "_frozen"):
+            self._frozen = (self.nz_cpu.copy(), self.nz_mem.copy())
+        live = self.nz_cpu, self.nz_mem
+        self.nz_cpu, self.nz_mem = self._frozen
+        try:
+            return super().scores(shape, rows)
+        finally:
+            self.nz_cpu, self.nz_mem = live
+
+
+class LastMaximum(reference.Reference):
+    """Ties broken the other way: the last maximum in walk order."""
+
+    def scores(self, shape, rows):
+        s = super().scores(shape, rows)
+        return s * len(s) + np.arange(len(s))
+
+
+# A control has to differ on every seed. The float32 reading is kept beside
+# them as found (see the module's docstring): an identical result, which a
+# guarantee on placements cannot refuse.
+CONTROLS = {"int32": Int32Scores, "stale_batch": StaleBatch,
+            "last_maximum": LastMaximum}
+READINGS = {"float32": Float32Scores}
+
+
+def differing(cfg: dict, seed: int, control: type) -> tuple:
+    """(pods compared, placements on which the control differs)."""
+    nodes = objects.cluster(cfg, seed)
+    sound, other = reference.Reference(nodes), control(nodes)
+    differ = total = 0
+    for group in ("initPods", "measurePods"):
+        tpl = cfg[group]["template"]
+        for i in range(int(cfg[group]["count"])):
+            name = f"{group}-{i}"
+            differ += sound.schedule(name, tpl) != other.schedule(name, tpl)
+            total += 1
+    return total, differ
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--config", required=True)
+    ap.add_argument("--seeds", type=int, nargs="+", required=True)
+    ap.add_argument("--rehearse", action="store_true")
+    args = ap.parse_args(argv)
+    cfg = objects.load_config(
+        os.path.join(HERE, "configs", args.config + ".json"), args.rehearse)
+    least = None
+    for seed in args.seeds:
+        for name, control in {**CONTROLS, **READINGS}.items():
+            total, differ = differing(cfg, seed, control)
+            if name in CONTROLS:
+                least = differ if least is None else min(least, differ)
+            print(f"{'control' if name in CONTROLS else 'reading'} {name} "
+                  f"config {args.config} seed {seed}: {differ} of {total} "
+                  f"placements differ from the reference (limit of the "
+                  f"comparison: 0)", flush=True)
+    return 0 if least else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())

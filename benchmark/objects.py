@@ -1,0 +1,77 @@
+"""From a configuration file and a seed to what the program is handed.
+
+The only module of the benchmark that builds the program's own object types
+(``kubernetes_tpu.testing`` builders, the apiserver's wire dicts). The plain
+descriptions it starts from are the same ones ``reference.py`` reads, so the
+two sides see the same cluster without sharing any code.
+
+``--seed`` changes the order in which the nodes are created (names keep their
+zone): the node tree, every tie-break and the rotating start index then see
+another cluster, while sizes and shapes stay the configuration's.
+"""
+
+from __future__ import annotations
+
+import json
+from typing import List
+
+import numpy as np
+
+import reference
+
+ZONE = reference.ZONE_KEY
+
+
+def load_config(path: str, rehearse: bool) -> dict:
+    with open(path) as f:
+        cfg = json.load(f)
+    if rehearse:
+        # Toy counts for the CPU rehearsal, stated in the file itself.
+        for group, count in cfg["rehearse"].items():
+            cfg[group]["count"] = int(count)
+    return cfg
+
+
+def node_order(count: int, seed: int) -> List[int]:
+    return [int(i) for i in
+            np.random.default_rng([int(seed), 1]).permutation(count)]
+
+
+def cluster(cfg: dict, seed: int) -> List[dict]:
+    """Plain node descriptions in creation order."""
+    n = int(cfg["nodes"]["count"])
+    return reference.node_descriptions(
+        cfg["nodes"]["template"], n, node_order(n, seed))
+
+
+def make_node(desc: dict):
+    from kubernetes_tpu.testing import make_node as builder
+    return (builder().name(desc["name"])
+            .capacity({"cpu": f"{desc['cpu']}m", "memory": desc["memory"],
+                       "pods": desc["pods"]})
+            .zone(desc["zone"]).obj())
+
+
+def make_pod_prototype(template: dict):
+    """One pod of the template; stamp the rest with
+    ``proto.clone_from_template(name)`` as the program's own perf harness
+    does, so that creating a wave costs the client what it costs there."""
+    from kubernetes_tpu.testing import make_pod as builder
+    b = builder().name("prototype").req(
+        {k: template[k] for k in ("cpu", "memory") if k in template})
+    for k, v in template.get("labels", {}).items():
+        b = b.label(k, v)
+    for c in template.get("topologySpreadConstraints", ()):
+        b = b.spread_constraint(
+            c.get("maxSkew", 1), c.get("topologyKey", ZONE),
+            c.get("whenUnsatisfiable", "DoNotSchedule"),
+            c.get("labelSelector", template.get("labels", {})))
+    return b.obj()
+
+
+def stamp(proto, name: str):
+    """A pod named ``name`` whose uid is its name, so that placements can be
+    compared by name on every path (the HTTP wire keys pods by uid)."""
+    pod = proto.clone_from_template(name)
+    pod.uid = name
+    return pod
