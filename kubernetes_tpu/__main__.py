@@ -186,6 +186,10 @@ def main(argv=None) -> int:
           f"device_kind={device['kind']!r} "
           f"devices={device['count']})", flush=True)
 
+    # Collector pauses stop the loop wherever it is: one gc.callbacks clock
+    # on this binary's /metrics (scheduler_gc_pause_seconds_total).
+    from .core.spans import GcClock
+    sched.gc_clock = GcClock().install()
     stop = {"flag": False}
 
     def _sig(_s, _f):
@@ -199,7 +203,8 @@ def main(argv=None) -> int:
             # Sharded runs also refresh ownership per CYCLE via the
             # scheduler's loop_hook; this outer tick covers idle stretches.
             if member is not None:
-                member.tick()
+                with sched.stages.stage("loop.idle"):
+                    member.tick()
             progressed = server.run_cycles()
             if args.once and not progressed:
                 active, backoff, _unsched = sched.queue.pending_counts()
@@ -208,8 +213,10 @@ def main(argv=None) -> int:
                     # they are reported in the failure count below).
                     break
             if not progressed:
-                time.sleep(0.02)
+                with sched.stages.stage("loop.idle"):
+                    time.sleep(0.02)
     finally:
+        sched.gc_clock.close()
         server.shutdown()
         if flight is not None:
             flight.dump("shutdown")

@@ -138,7 +138,9 @@ def _jit_reachable_uncached(tree: ast.AST) -> List[ast.FunctionDef]:
         nxt = set()
         for name in frontier:
             for fn in defs.get(name, ()):
-                for c in ast.walk(fn):
+                # the body only: a decorator runs once, where the function
+                # is defined, never under trace
+                for c in (n for stmt in fn.body for n in ast.walk(stmt)):
                     if isinstance(c, ast.Call):
                         chain = attr_chain(c.func)
                         if (len(chain) == 1 and chain[0] in defs

@@ -38,3 +38,27 @@ def entry_count(path: str = "") -> int:
                    if not n.endswith("-atime"))
     except OSError:
         return 0
+
+
+# Backend compile requests this process has made, loads from the persistent
+# cache included (a load deserializes, and JAX reports it as a compile). One
+# cell, so that a reader keeps the reference and pays an index per read.
+COMPILE_EVENTS = [0]
+_watching = False
+
+
+def watch_compiles() -> None:
+    """Count this process's compile requests into ``COMPILE_EVENTS`` from
+    JAX's own monitoring events (imports JAX: the device-backed scheduler
+    calls it). A slow stage says from it whether a compile ran inside."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    import jax.monitoring as mon
+
+    def on_duration(event: str, _secs: float, **_kw) -> None:
+        if event == "/jax/core/compile/backend_compile_duration":
+            COMPILE_EVENTS[0] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)

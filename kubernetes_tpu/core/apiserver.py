@@ -64,6 +64,7 @@ from . import spans as _spans
 from . import wire
 from .clientset import FakeClientset
 from .flowcontrol import FlowController
+from .metrics import Histogram
 from .watchcache import (
     ShardFilter,
     WatchCache,
@@ -637,7 +638,19 @@ class APIServer:
         # thread — so the slim BOUND event and the WAL record carry the
         # binder's trace id out to every watcher.
         self._bind_ctx = None
+        self._binding = False  # a bind is committing: its stages are timed
         self.tracer = _spans.default_tracer()
+        # The three stages a bind passes through here, for EVERY bind (the
+        # spans are for sampled pods): apiserver_stage_duration_seconds
+        # {stage} on /metrics. Observed under the write lock / event lock.
+        self.stage_duration = Histogram(
+            "apiserver_stage_duration_seconds",
+            "Latency of the apiserver's stages of a bind: api.bind "
+            "(binding subresource commit), wal.append (durable append of "
+            "the BOUND event), bound.fanout (fanout to the watch streams).",
+            ("stage",))
+        # Collector pauses: the binary's main installs a spans.GcClock.
+        self.gc_clock = None
 
     # -- durability (WAL + snapshot; core/wal.py) ---------------------------
 
@@ -1042,16 +1055,17 @@ class APIServer:
         tr = self.tracer
         ctx = (_spans.parse_ctx(tctx) if tctx else None) \
             or tr.context_for(uid)
-        if not tr.wants(ctx):
-            return self._bind_one_locked(uid, node)
         t0 = time.perf_counter()
-        self._bind_ctx = ctx
+        self._binding = True
+        self._bind_ctx = ctx if tr.wants(ctx) else None
         try:
             code, payload = self._bind_one_locked(uid, node)
         finally:
+            self._binding = False
             self._bind_ctx = None
-        tr.record("api.bind", ctx, time.perf_counter() - t0,
-                  node=node, code=code)
+        seconds = time.perf_counter() - t0
+        self.stage_duration.observe(seconds, "api.bind")
+        tr.record("api.bind", ctx, seconds, node=node, code=code)
         return code, payload
 
     def _bind_one_locked(self, uid: str, node: str):
@@ -1643,6 +1657,9 @@ class APIServer:
                    % (1 if self.role == "leader" else 0))
         out.append("# TYPE apiserver_replication_lag_records gauge")
         out.append("apiserver_replication_lag_records %d" % max(0, lag))
+        out.extend(self.stage_duration.expose())
+        if self.gc_clock is not None:
+            out.extend(self.gc_clock.expose("apiserver"))
         return "\n".join(out) + "\n"
 
     # -- event fanout to watch streams -------------------------------------
@@ -1655,6 +1672,7 @@ class APIServer:
             # event class): times the WAL append and the watcher fanout
             # into the binder's trace (stages wal.append / bound.fanout).
             ctx = self._bind_ctx
+            binding = self._binding
             # Mint the event's DELTA twin FIRST — before the WAL append
             # or the fanout installs the new object, while the watch
             # cache's snapshot still holds the exact base every attached
@@ -1667,14 +1685,16 @@ class APIServer:
             # (and the replication seq/epoch stamp), so recovery — and a
             # tailing follower — rebuilds both the store and the watch
             # backlog from one stream.
-            _tw = time.perf_counter() if ctx is not None else 0.0
+            _tw = time.perf_counter() if binding else 0.0
             self._repl_append(
                 {"kind": kind, **event},
                 delta=None if delta is None else {"kind": kind, **delta})
-            if ctx is not None:
-                self.tracer.record("wal.append", ctx,
-                                   time.perf_counter() - _tw,
-                                   rv=event["rv"])
+            if binding:
+                seconds = time.perf_counter() - _tw
+                self.stage_duration.observe(seconds, "wal.append")
+                if ctx is not None:
+                    self.tracer.record("wal.append", ctx, seconds,
+                                       rv=event["rv"])
             if (self.persistence is not None
                     and self.persistence.should_compact()):
                 try:
@@ -1689,13 +1709,15 @@ class APIServer:
                 except Exception:  # noqa: BLE001
                     self.compaction_failures += 1
             item = wire.WireItem(event, delta=delta)
-            _tf = time.perf_counter() if ctx is not None else 0.0
+            _tf = time.perf_counter() if binding else 0.0
             self._fan_event(kind, event, item)
-            if ctx is not None:
-                self.tracer.record("bound.fanout", ctx,
-                                   time.perf_counter() - _tf,
-                                   watchers=len(self._watchers[kind]),
-                                   rv=event["rv"])
+            if binding:
+                seconds = time.perf_counter() - _tf
+                self.stage_duration.observe(seconds, "bound.fanout")
+                if ctx is not None:
+                    self.tracer.record("bound.fanout", ctx, seconds,
+                                       watchers=len(self._watchers[kind]),
+                                       rv=event["rv"])
 
     def _fan_event(self, kind: str, event: dict, item) -> None:
         """The one commit→read-plane fanout both write paths share (the
@@ -4416,6 +4438,7 @@ def main(argv=None) -> int:
             at_exit=True,
             autodump_interval=float(
                 os.environ.get("TPU_SCHED_FLIGHTREC_INTERVAL", "5.0")))
+    api.gc_clock = _spans.GcClock().install()
     port = api.serve(args.port)
     lease = None
     if tail is not None:
@@ -4454,6 +4477,7 @@ def main(argv=None) -> int:
     if lease is not None:
         lease.stop()
     api.shutdown()
+    api.gc_clock.close()
     if flight is not None:
         flight.dump("shutdown")
         flight.close()

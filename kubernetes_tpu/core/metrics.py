@@ -34,6 +34,11 @@ class Counter(Metric):
         key = tuple(labels)
         self._values[key] = self._values.get(key, 0.0) + value
 
+    def set_total(self, total: float, *labels: str) -> None:
+        """Publish a total that is accumulated elsewhere (the loop's stage
+        table): written at scrape time, so the hot path pays nothing."""
+        self._values[tuple(labels)] = total
+
     def value(self, *labels: str) -> float:
         return self._values.get(tuple(labels), 0.0)
 
@@ -198,6 +203,21 @@ class SchedulerMetrics:
             "buckets: late pods in a large drain legitimately wait tens of "
             "seconds in the queue.",
             buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
+        self.pod_stage_duration = r(Histogram(
+            "scheduler_pod_stage_duration_seconds",
+            "The e2e latency split where it is made, for EVERY pod: "
+            "queue.wait (admission -> pop) and bind.post (the bind call's "
+            "round trip as the scheduler sees it).", ("stage",),
+            buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
+        self.loop_stage_seconds = r(Counter(
+            "scheduler_loop_stage_seconds_total",
+            "Self time of each stage of the scheduling loop "
+            "(core/spans.py StageLedger; docs/OBSERVABILITY.md).",
+            ("stage",)))
+        self.loop_stages = r(Counter(
+            "scheduler_loop_stages_total",
+            "Times each stage of the scheduling loop was entered.",
+            ("stage",)))
         self.framework_extension_point_duration = r(Histogram(
             "scheduler_framework_extension_point_duration_seconds",
             "Latency per extension point.", ("extension_point", "status", "profile")))

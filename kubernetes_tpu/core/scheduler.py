@@ -122,6 +122,10 @@ class Handle:
         return self._scheduler.metrics
 
     @property
+    def stages(self):
+        return self._scheduler.stages
+
+    @property
     def gates(self):
         return self._scheduler.gates
 
@@ -385,14 +389,19 @@ class Scheduler:
         from .podgroupstate import PodGroupState
         self.pod_group_state = PodGroupState()
         self.cache.pod_group_state = self.pod_group_state
-        # Event recorder + step tracing (schedule_one.go:1138, :574).
+        # Event recorder (schedule_one.go:1138).
         from .tracing import EventRecorder
         self.recorder = EventRecorder()
         # Pod-lifecycle spans (core/spans.py; docs/OBSERVABILITY.md): the
         # process-global tracer — head-sampled, ring-buffered; every stage
         # below checks `tracer.wants(ctx)` before building anything.
-        from .spans import default_tracer
+        from .spans import StageLedger, default_tracer
         self.tracer = default_tracer()
+        # The loop's own account (core/spans.py StageLedger): every boundary
+        # of the scheduling loop is one `with self.stages.stage(...)`.
+        self.stages = StageLedger(self.tracer, self.metrics)
+        # Collector pauses: the binary's main installs a spans.GcClock.
+        self.gc_clock = None
         # metrics
         self.attempts = 0
         self.scheduled = 0
@@ -527,14 +536,17 @@ class Scheduler:
 
     def drain_event_inbox(self) -> int:
         """Replay off-thread watch events on the scheduling loop."""
+        if not self._event_inbox:
+            return 0
         n = 0
-        while self._event_inbox:
-            try:
-                handler, args = self._event_inbox.popleft()
-            except IndexError:
-                break
-            handler(*args)
-            n += 1
+        with self.stages.stage("inbox.drain"):
+            while self._event_inbox:
+                try:
+                    handler, args = self._event_inbox.popleft()
+                except IndexError:
+                    break
+                handler(*args)
+                n += 1
         return n
 
     def _on_storage_event(self, kind: str, obj) -> None:
@@ -859,10 +871,18 @@ class Scheduler:
     # -- one cycle ---------------------------------------------------------
 
     def schedule_one(self) -> bool:
+        """One turn of the loop: the ledger's `cycle`, whose own self time
+        is what no stage below it has a name for."""
+        with self.stages.stage("cycle"):
+            return self._cycle()
+
+    def _cycle(self) -> bool:
         self.process_async_api_errors()
         if self.waiting_pods and self.now() >= self._next_wait_deadline:
             self.flush_expired_waiters()
+        t0 = time.perf_counter()
         qpi = self.queue.pop()
+        self.stages.leaf("queue.pop", time.perf_counter() - t0)
         if qpi is None:
             return False
         self.process_one(qpi)
@@ -896,7 +916,6 @@ class Scheduler:
             # would double-place it.
             self.queue.done(pod.uid)
             return
-        from .tracing import StepTrace
         fw = self.framework_for_pod(pod)
         self.attempts += 1
         t0 = time.perf_counter()
@@ -912,24 +931,24 @@ class Scheduler:
                               wait=round(self.now() - eq, 3),
                               namespace=pod.namespace)
         self.record_queue_wait(qpi, ctx)
-        trace = StepTrace("Scheduling", ctx=ctx,
-                          pod=f"{pod.namespace}/{pod.name}")
-        state = CycleState()
-        try:
-            self._process_one_traced(fw, state, qpi, trace, t0)
-        finally:
-            # utiltrace logs via defer: slow cycles are reported on EVERY
-            # outcome — bound, unschedulable, Permit WAIT, or error.
-            trace.log_if_long()
+        # The host path's whole cycle (algorithm + bind) is one host.commit
+        # stage per pod — table only, no profiler annotation per pod. The
+        # slow-stage rule reports it on EVERY outcome (utiltrace logs via
+        # defer: bound, unschedulable, Permit WAIT, or error); the span
+        # enters the pod's trace only when it bound.
+        with self.stages.stage(
+                "host.commit", (ctx,) if self.tracer.wants(ctx) else (),
+                annotate=False, pod=f"{pod.namespace}/{pod.name}",
+                path="host") as stage:
+            stage.span = False
+            self._process_one_staged(fw, CycleState(), qpi, stage, t0)
 
-    def _process_one_traced(self, fw, state, qpi, trace, t0) -> None:
+    def _process_one_staged(self, fw, state, qpi, stage, t0) -> None:
         pod = qpi.pod
         try:
             result = self.scheduling_cycle(fw, state, qpi)
-            trace.step("scheduling cycle done")
         except FitError as fe:
             self.handle_fit_error(fw, state, qpi, fe, t0)
-            trace.step("unschedulable")
             return
         except Exception as e:  # noqa: BLE001
             self.error_log.append(f"{pod.namespace}/{pod.name}: {e!r}")
@@ -946,13 +965,12 @@ class Scheduler:
             return
         bound = self.run_binding_cycle(fw, state, qpi, result)
         self.queue.done(pod.uid)
-        trace.step("binding cycle done")
         elapsed = time.perf_counter() - t0
         if bound:
             # Host-path commit span: the whole cycle (algorithm + bind
             # enqueue) — the device path records finer-grained stages.
-            self.tracer.record("host.commit", trace.ctx, elapsed,
-                               node=result.suggested_host, path="host")
+            stage.attrs["node"] = result.suggested_host
+            stage.span = True
         self.metrics.schedule_attempts.inc("scheduled" if bound else "error", fw.profile_name)
         self.metrics.scheduling_attempt_duration.observe(
             elapsed, "scheduled" if bound else "error", fw.profile_name)
@@ -1663,15 +1681,20 @@ class Scheduler:
     # -- span helpers (core/spans.py; docs/OBSERVABILITY.md) ----------------
 
     def record_queue_wait(self, qpi, ctx) -> None:
-        """Retroactive queue.admission event + queue.wait span, recorded at
-        pop time (no hot add-path cost). Guarded against double recording
-        when a device-popped pod falls back to the host cycle."""
-        tr = self.tracer
-        if not tr.wants(ctx) or getattr(qpi, "_qwait_recorded", False):
+        """Queue wait, recorded at pop time (no hot add-path cost): EVERY
+        pod feeds scheduler_pod_stage_duration_seconds{stage="queue.wait"};
+        a sampled pod also gets its retroactive queue.admission event +
+        queue.wait span. Guarded against double recording when a
+        device-popped pod falls back to the host cycle."""
+        if getattr(qpi, "_qwait_recorded", False):
             return
         qpi._qwait_recorded = True
         start = getattr(qpi, "enqueued_at", None)
         wait = max(0.0, self.now() - start) if start is not None else 0.0
+        self.metrics.pod_stage_duration.observe(wait, "queue.wait")
+        tr = self.tracer
+        if not tr.wants(ctx):
+            return
         wall_pop = time.time()
         tr.record("queue.admission", ctx, start=wall_pop - wait)
         tr.record("queue.wait", ctx, wait, start=wall_pop - wait,
@@ -1798,6 +1821,7 @@ class Scheduler:
     def expose_metrics(self) -> str:
         """/metrics (app/server.go:376)."""
         self.update_pending_metrics()
+        self.stages.publish()
         out = self.metrics.expose()
         # Step-accounting counters (plan/device/host split, device-vs-host
         # path mix, conflict/unwind tallies): in-process harnesses read
@@ -1825,6 +1849,8 @@ class Scheduler:
                 ("scheduler_attempts_total", self.attempts)):
             extra.append(f"# TYPE {name} counter")
             extra.append(f"{name} {float(val)}")
+        if self.gc_clock is not None:
+            extra.extend(self.gc_clock.expose("scheduler"))
         return out + "\n".join(extra) + "\n"
 
     def handle_scheduling_failure(

@@ -144,6 +144,9 @@ class SchedulerServer:
     def run_cycles(self, max_cycles: int = 1_000_000) -> int:
         """Drive scheduling while holding leadership (or unconditionally when
         leader election is off)."""
-        if self.elector is not None and not self.elector.tick():
-            return 0
+        if self.elector is not None:
+            with self.scheduler.stages.stage("loop.idle"):
+                leading = self.elector.tick()
+            if not leading:
+                return 0
         return self.scheduler.run_until_idle(max_cycles)

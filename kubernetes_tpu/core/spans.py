@@ -31,9 +31,16 @@ Design constraints (this rides paths benchmarked at >10k pods/s):
   ended on all paths, and neither spans nor metrics may appear inside
   jit-reachable code).
 
+The scheduling LOOP accounts for its own time through the same recorder's
+``StageLedger`` (one per scheduler): ``with stages.stage("plan.build")`` at
+every boundary of the loop adds the stage's self time to a fixed table
+(always on), lies in any profiler session as ``sched.<stage>`` on the
+device's clock, feeds ``/metrics``, copies itself into the sampled pods'
+traces, and applies the one slow-stage rule.
+
 The flight recorder dumps the span ring plus the last-K events/errors per
-process to ``<dir>/flightrec-<pid>.jsonl`` on SIGUSR2, on a StepTrace
-slow-step breach, on unhandled crash (excepthook + atexit, with
+process to ``<dir>/flightrec-<pid>.jsonl`` on SIGUSR2, on a slow-stage
+breach, on unhandled crash (excepthook + atexit, with
 ``faulthandler`` covering native faults), and optionally on a periodic
 timer — so a chaos ``kill -9`` (which no handler can observe) still leaves
 a recent forensic artifact on disk instead of nothing.
@@ -44,14 +51,21 @@ in ``STAGES``/``CORE_CHAIN``; docs/OBSERVABILITY.md is the prose spec.
 
 from __future__ import annotations
 
+import gc
 import hashlib
 import itertools
 import json
+import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
-from typing import Dict, Iterable, List, Optional
+from typing import Dict, Iterable, List, Optional, Sequence
+
+from ..compile_cache import COMPILE_EVENTS
+
+logger = logging.getLogger("kubernetes_tpu")
 
 TRACE_HEADER = "X-Trace-Context"
 
@@ -70,14 +84,40 @@ STAGES = (
     "bound.fanout",      # BOUND event fanout to watch streams
     "bound.observe",     # a watcher process decoded the BOUND event
     "pod.e2e",           # admission → bound (feeds the e2e histogram)
+    # loop stages (StageLedger): the loop's own time, a tree under `cycle`
+    "cycle",             # one turn of schedule_one; self time = still unnamed
+    "queue.pop",         # popping + signing a batch off the active queue
+    "inbox.drain",       # replaying parked watch events, journal classification
+    "hint.walk",         # host-only binds off the score hint
+    "hint.validate",     # a hint's journal replay + selection, per pod
+    "plan.patch",        # journal delta patch of a live plan + carry
+    "plan.adopt",        # session end: snapshot refresh, mirror adopts the carry
+    "loop.idle",         # the binary's idle sleep and lease ticks
 )
+# The ledger's fixed table: per-pod names that are also loop boundaries
+# (plan.build … bind.post) keep their name, so a stage reads the same in a
+# pod's trace, in the table and in a profiler trace.
+LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
+               "hint.validate", "plan.build", "plan.patch", "plan.adopt",
+               "device.dispatch", "device.wait", "host.commit", "bind.post",
+               "loop.idle")
 # A bound pod's minimal complete chain. Device stages are optional (host-
 # path pods legitimately skip them); observe spans prove the fanout landed.
 CORE_CHAIN = ("queue.wait", "host.commit", "bind.post", "api.bind",
               "wal.append", "bound.fanout")
 # Always-sampled forensic stages (recorded with force=True contexts).
 FORCED_STAGES = ("bind.conflict", "device.fallback", "shard.adopt",
-                 "trace.slow_step", "replication.promote")
+                 "trace.slow_stage", "replication.promote")
+# The one slow-stage rule (schedule_one.go:574 logs any step over 100ms): a
+# stage whose SELF time passes its threshold leaves a forced span and asks
+# for a flight-recorder dump. The per-pod forms (leaf, annotate=False) keep
+# the reference's 100ms. A batch or loop-turn stage grows with the batch and
+# the cluster (at 5,000 nodes a plan build, a 10,000-event inbox replay or a
+# session's adoption ordinarily takes 0.1-0.2s, a full collection inside one
+# 0.1s more), so it gets a second: what that catches is a compile or cache
+# load inside a dispatch, and the seconds-long device wait.
+SLOW_STAGE_S = 0.1
+SLOW_BATCH_STAGE_S = 1.0
 
 _SAMPLE_ENV = "TPU_SCHED_TRACE_SAMPLE"
 _ENABLE_ENV = "TPU_SCHED_TRACE"
@@ -294,6 +334,228 @@ def chrome_trace(spans: Iterable[dict]) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# the loop's stage ledger
+# ---------------------------------------------------------------------------
+
+
+def _trace_annotation():
+    """``jax.profiler.TraceAnnotation`` when this process already runs JAX
+    (the device-backed scheduler), else None: the JAX-free planes (host
+    scheduler under a controller, apiserver) never import it for a span."""
+    if "jax" not in sys.modules:
+        return None
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
+
+
+class _Stage:
+    """One open stage of a ``StageLedger`` (``with stages.stage(...)``).
+    ``attrs`` may be filled while it is open (a plan's ``kind`` is known
+    only once it is acquired); ``span = False`` keeps the span out of the
+    pods' traces (an attempt that did not bind) while a slow stage still
+    reports on the pod's trace."""
+
+    __slots__ = ("_ledger", "name", "ctxs", "attrs", "point", "span", "_ann",
+                 "_per_pod", "_t0", "_child_s", "_events0", "_parts")
+
+    def __init__(self, ledger: "StageLedger", name: str, ctxs, point: str,
+                 annotate: bool, attrs: dict):
+        self._ledger = ledger
+        self.name = name
+        self.ctxs = ctxs
+        self.attrs = attrs
+        self.point = point
+        self.span = True
+        self._per_pod = not annotate
+        ann = ledger._annotation if annotate else None
+        self._ann = ann(ledger._ann_names[name]) if ann is not None else None
+        self._child_s = 0.0
+        self._parts: Optional[dict] = None  # a root's: self time by stage
+
+    def __enter__(self) -> "_Stage":
+        ledger = self._ledger
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._events0 = ledger._compile_events[0]
+        if not ledger._stack:
+            self._parts = {}
+        ledger._stack.append(self)
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        duration = time.perf_counter() - self._t0
+        ledger = self._ledger
+        ledger._stack.pop()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if exc_type is not None:
+            self.attrs["error"] = exc_type.__name__
+        ledger._close(self, duration)
+        return False
+
+
+class StageLedger:
+    """The scheduling loop's account of its own time (one per scheduler,
+    scheduling thread only). Every boundary of the loop is one ``with
+    stages.stage(name)`` block, and that one call site:
+
+    1. adds the stage's SELF time (its duration minus what its child stages
+       cover) and a count to the fixed per-stage table — always on;
+    2. lies in any profiler session as ``sched.<name>`` on the clock of the
+       device operations (per batch and per loop turn: ``annotate=False``
+       and ``leaf`` are the per-pod forms, table only);
+    3. feeds ``scheduler_loop_stage_seconds_total`` / ``_stages_total``
+       (``publish``) and, where ``point`` names one, the extension-point
+       histogram;
+    4. copies the span into each sampled pod's trace (``ctxs``);
+    5. applies the one slow-stage rule (``SLOW_STAGE_S`` per pod,
+       ``SLOW_BATCH_STAGE_S`` per batch or loop turn).
+    """
+
+    def __init__(self, tracer: "SpanRecorder", metrics=None):
+        self.tracer = tracer
+        self.metrics = metrics
+        self.seconds: Dict[str, float] = dict.fromkeys(LOOP_STAGES, 0.0)
+        self.counts: Dict[str, int] = dict.fromkeys(LOOP_STAGES, 0)
+        # Batches dispatched and not yet retired: the session keeps it, a
+        # slow stage's span reports it.
+        self.inflight = 0
+        # The last root stages closed, each with its self time by stage:
+        # what a loop that does not end is asked for (``report``).
+        self.recent: "deque" = deque(maxlen=32)
+        self._stack: List[_Stage] = []
+        self._annotation = _trace_annotation() if tracer.enabled else None
+        self._ann_names = {n: "sched." + n for n in LOOP_STAGES}
+        self._compile_events = COMPILE_EVENTS
+
+    def stage(self, name: str, ctxs: Sequence[SpanContext] = (),
+              point: str = "", annotate: bool = True, **attrs) -> _Stage:
+        return _Stage(self, name, ctxs, point, annotate, attrs)
+
+    def leaf(self, name: str, seconds: float, **attrs) -> None:
+        """A finished child with no children of its own, timed by the
+        caller (the per-pod form: two clock reads, no object, no
+        annotation)."""
+        self._account(name, seconds, seconds)
+        if seconds > SLOW_STAGE_S:
+            self._slow(name, (), attrs, seconds, seconds, 0)
+
+    def _account(self, name: str, self_s: float, duration: float) -> bool:
+        """One closed stage into the table, its duration onto the open
+        parent's children. False when it had no parent (a root)."""
+        self.seconds[name] += self_s
+        self.counts[name] += 1
+        stack = self._stack
+        if not stack:
+            return False
+        stack[-1]._child_s += duration
+        parts = stack[0]._parts
+        parts[name] = parts.get(name, 0.0) + self_s
+        return True
+
+    def _close(self, st: _Stage, duration: float) -> None:
+        name = st.name
+        self_s = duration - st._child_s
+        if not self._account(name, self_s, duration):
+            self.recent.append((name, time.time() - duration, duration,
+                                self_s, st._parts))
+        if st.point:
+            self.metrics.framework_extension_point_duration.observe(
+                duration, st.point, "Success", "")
+        if st.ctxs and st.span:
+            wall = time.time() - duration
+            record = self.tracer.record
+            for ctx in st.ctxs:
+                record(name, ctx, duration, start=wall, **st.attrs)
+        if self_s > (SLOW_STAGE_S if st._per_pod else SLOW_BATCH_STAGE_S):
+            self._slow(name, st.ctxs, st.attrs, duration, self_s,
+                       self._compile_events[0] - st._events0)
+
+    def _slow(self, name: str, ctxs, attrs: dict, duration: float,
+              self_s: float, compiles: int) -> None:
+        """The slow-stage rule: log it, leave one forced span with what an
+        operator needs (on the first sampled pod's trace, else on the
+        process's), and ask the flight recorder for a dump."""
+        kv = " ".join(f"{k}={v}" for k, v in attrs.items())
+        logger.warning(
+            "slow scheduling stage: %s %s self=%.0fms total=%.0fms "
+            "inflight=%d compiles=%d", name, kv, self_s * 1e3,
+            duration * 1e3, self.inflight, compiles)
+        tracer = self.tracer
+        if tracer.enabled:
+            ctx = ctxs[0] if ctxs else tracer.proc_ctx()
+            tracer.record("trace.slow_stage", ctx, duration, stage=name,
+                          self_ms=round(self_s * 1e3, 3),
+                          inflight=self.inflight, compiles=compiles,
+                          **{k: str(v) for k, v in attrs.items()})
+        request_dump("slow_stage")
+
+    def publish(self) -> None:
+        """Copy the table onto the two ``/metrics`` counters (at scrape
+        time: the loop itself pays nothing for them)."""
+        metrics = self.metrics
+        for name in LOOP_STAGES:
+            metrics.loop_stage_seconds.set_total(self.seconds[name], name)
+            metrics.loop_stages.set_total(float(self.counts[name]), name)
+
+    def report(self, last: int = 8) -> str:
+        """The table and the last root stages, as text (a deadline's or a
+        flight recorder's account of where the loop was)."""
+        lines = ["stage               self_s      count"]
+        for name in LOOP_STAGES:
+            if self.counts[name]:
+                lines.append(f"{name:<16} {self.seconds[name]:>10.4f} "
+                             f"{self.counts[name]:>10d}")
+        open_now = " > ".join(st.name for st in self._stack)
+        lines.append(f"open now: {open_now or '-'}")
+        for name, ts, duration, self_s, parts in list(self.recent)[-last:]:
+            inner = " ".join(f"{k}={v * 1e3:.2f}ms"
+                             for k, v in sorted(parts.items()))
+            lines.append(f"{name} ts={ts:.3f} total={duration * 1e3:.2f}ms "
+                         f"self={self_s * 1e3:.2f}ms {inner}")
+        return "\n".join(lines)
+
+
+class GcClock:
+    """Seconds the interpreter's cyclic collector ran, by generation
+    (``gc.callbacks``). A collection stops every thread of the process, so
+    the binaries' mains install one and put it on their ``/metrics``."""
+
+    def __init__(self):
+        self.seconds = [0.0, 0.0, 0.0]
+        self.collections = [0, 0, 0]
+        self._t0 = 0.0
+
+    def _callback(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        else:
+            g = info["generation"]
+            self.seconds[g] += time.perf_counter() - self._t0
+            self.collections[g] += 1
+
+    def install(self) -> "GcClock":
+        gc.callbacks.append(self._callback)
+        return self
+
+    def close(self) -> None:
+        if self._callback in gc.callbacks:
+            gc.callbacks.remove(self._callback)
+
+    def expose(self, prefix: str) -> List[str]:
+        """Prometheus lines ``<prefix>_gc_pause_seconds_total{generation}``
+        and ``<prefix>_gc_collections_total{generation}``."""
+        out = []
+        for series, values in (("gc_pause_seconds_total", self.seconds),
+                               ("gc_collections_total", self.collections)):
+            out.append(f"# TYPE {prefix}_{series} counter")
+            out.extend(f'{prefix}_{series}{{generation="{g}"}} {float(v)}'
+                       for g, v in enumerate(values))
+        return out
+
+
+# ---------------------------------------------------------------------------
 # process-global default tracer
 # ---------------------------------------------------------------------------
 
@@ -322,7 +584,7 @@ _FLIGHT: Optional["FlightRecorder"] = None
 
 def request_dump(reason: str) -> Optional[str]:
     """Dump through the installed flight recorder (rate-limited); no-op
-    when none is installed. The seam StepTrace/ShardMember call so they
+    when none is installed. The seam StageLedger/ShardMember call so they
     need no direct dependency on recorder wiring."""
     if _FLIGHT is None:
         return None
@@ -333,7 +595,7 @@ class FlightRecorder:
     """Crash-safe forensic dumps: span ring + last-K events/errors/counters
     per process, written to ``<dir>/flightrec-<pid>.jsonl``.
 
-    Triggers: SIGUSR2, StepTrace slow-step breach (via ``request_dump``),
+    Triggers: SIGUSR2, a slow-stage breach (via ``request_dump``),
     unhandled crash (sys.excepthook chain + atexit; ``faulthandler`` covers
     native faults into ``flightrec-<pid>.crash``), an optional periodic
     timer — the only trigger that survives SIGKILL chaos (``kill -9``
@@ -503,6 +765,10 @@ class FlightRecorder:
                  "state_unwinds": s.state_unwinds,
                  "device_scheduled": getattr(s, "device_scheduled", 0),
                  "host_path_pods": getattr(s, "host_path_pods", 0)}]
+        stages = getattr(s, "stages", None)
+        if stages is not None:
+            rows.append({"kind": "stages", "seconds": dict(stages.seconds),
+                         "counts": dict(stages.counts)})
         for line in list(s.error_log)[-self.keep_events:]:
             rows.append({"kind": "error", "message": line})
         member = getattr(s, "shard_member", None)

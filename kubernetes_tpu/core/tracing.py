@@ -1,82 +1,16 @@
-"""Lightweight scheduling-step tracing + event recording.
+"""Event recording.
 
-The reference logs any scheduling step that exceeds 100ms through utiltrace
-(schedule_one.go:574-575) and emits API Events per scheduling outcome
-(EventRecorder, schedule_one.go:1138). This module is the framework's
-equivalent: a per-cycle trace with a slow-step threshold wired to Python
-logging (structured key=value formatting, klog-style), plus a bounded
-in-memory event recorder the server can expose.
+The reference emits API Events per scheduling outcome (EventRecorder,
+schedule_one.go:1138): a bounded in-memory event recorder the server can
+expose. (Its utiltrace slow-step log, schedule_one.go:574-575, is the
+stage ledger's slow-stage rule: core/spans.py.)
 """
 
 from __future__ import annotations
 
-import logging
 import time
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
-
-logger = logging.getLogger("kubernetes_tpu")
-
-SLOW_STEP_THRESHOLD_S = 0.1  # schedule_one.go:574 — log any step > 100ms
-
-
-class StepTrace:
-    """utiltrace.New analogue: one trace per scheduling attempt; steps are
-    recorded with durations and the whole trace is logged when it crosses
-    the threshold. On a breach, individual steps over the reference's
-    stepThreshold (threshold / #steps, utiltrace trace.go) are named
-    explicitly, each emits a forced span event, and the flight recorder is
-    asked for a forensic dump (core/spans.py request_dump)."""
-
-    __slots__ = ("name", "fields", "t0", "steps", "_last", "ctx")
-
-    def __init__(self, name: str, ctx=None, **fields):
-        self.name = name
-        self.fields = fields
-        self.ctx = ctx  # optional spans.SpanContext tying the trace to a pod
-        self.t0 = time.perf_counter()
-        self._last = self.t0
-        self.steps: List[Tuple[str, float]] = []
-
-    def step(self, msg: str) -> None:
-        now = time.perf_counter()
-        self.steps.append((msg, now - self._last))
-        self._last = now
-
-    def log_if_long(self, threshold: float = SLOW_STEP_THRESHOLD_S) -> float:
-        total = time.perf_counter() - self.t0
-        if total > threshold:
-            # stepThreshold (utiltrace): with the total over budget, any
-            # step carrying more than its even share is an offender.
-            step_threshold = threshold / max(1, len(self.steps))
-            slow = [(m, d) for m, d in self.steps if d > step_threshold]
-            kv = " ".join(f"{k}={v}" for k, v in self.fields.items())
-            parts = "; ".join(f"{m}: {d*1000:.0f}ms" for m, d in self.steps)
-            offenders = "; ".join(f"{m}: {d*1000:.0f}ms" for m, d in slow)
-            logger.warning("slow scheduling step: %s %s total=%.0fms (%s)"
-                           "%s", self.name, kv, total * 1000, parts,
-                           f" slow step(s) over {step_threshold*1000:.0f}ms: "
-                           f"{offenders}" if offenders else "")
-            self._emit_breach(slow, total)
-        return total
-
-    def _emit_breach(self, slow: List[Tuple[str, float]],
-                     total: float) -> None:
-        """One forced span event per offending step + a flight-recorder
-        dump request (rate-limited there)."""
-        from . import spans
-        tracer = spans.default_tracer()
-        if tracer.enabled:
-            ctx = self.ctx if (self.ctx is not None and self.ctx.sampled) \
-                else tracer.proc_ctx()
-            base = {k: str(v) for k, v in self.fields.items()
-                    if k not in ("start", "duration", "parent", "name",
-                                 "ctx")}
-            for msg, dur in slow:
-                attrs = dict(base, step=msg, trace_name=self.name,
-                             total_ms=round(total * 1e3, 3))
-                tracer.record("trace.slow_step", ctx, dur, **attrs)
-        spans.request_dump("slow_step")
 
 
 class Event:

@@ -49,6 +49,18 @@ from ..ops.kernel import schedule_batch
 _GANG_SESSION = "__gang_device_session__"
 
 
+class _Batch(list):
+    """A popped batch and the span contexts of its sampled members, found
+    once as the batch is collected: each stage of the batch copies its span
+    into those traces without another lookup per pod."""
+
+    __slots__ = ("sampled",)
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.sampled: list = []
+
+
 class _SessionDelta:
     """A live session's journal-patchable view: the device state + carry the
     delta patches rewrite, the seq watermark already consumed, and whether a
@@ -97,6 +109,8 @@ class TPUScheduler(Scheduler):
         # device while the host commits retired ones (2 = double buffering).
         self.pipeline_depth = getattr(self.config, "pipeline_depth", 2)
         enable_persistent_compilation_cache()
+        from ..compile_cache import watch_compiles
+        watch_compiles()  # a slow stage says whether a compile ran inside it
         # Multi-chip: with >1 device the node axis shards over a
         # ("cells", "nodes") mesh and the SAME jitted kernel compiles SPMD
         # (GSPMD from committed input shardings; reductions ride ICI
@@ -138,14 +152,10 @@ class TPUScheduler(Scheduler):
         self.placement_device_evals = 0
         # DryRunPreemption kernel calls (one per device-evaluated PostFilter).
         self.preemption_device_evals = 0
-        # Host/device time split (schedule_one.go:574-style step accounting,
-        # re-shaped for the batch pipeline): plan_build_s = snapshot→features
-        # host work, device_wait_s = time blocked on a device result fetch,
-        # host_commit_s = assume/reserve/permit/bind tails. Exported by the
-        # perf harness so perf regressions are attributable, not guessed.
-        self.plan_build_s = 0.0
-        self.device_wait_s = 0.0
-        self.host_commit_s = 0.0
+        # The sampled span context of the entity _pop handed out last (None
+        # for group entities and with tracing off): the batch collectors
+        # keep it, so a batch's sampled members are found once.
+        self._popped_ctx = None
         # Terminal-failure memos: state key -> (unschedulable plugins,
         # message) for side-effect-free host diagnoses (see _fail_from_memo).
         # A small keyed LRU, not a single slot: two ALTERNATING unschedulable
@@ -207,9 +217,33 @@ class TPUScheduler(Scheduler):
         self.hint_misses = 0
         self.hint_invalidations = 0
 
+    # Host/device time split (schedule_one.go:574-style step accounting,
+    # re-shaped for the batch pipeline), exported by the perf harness and
+    # the benchmark so regressions are attributable, not guessed. Views of
+    # the loop's stage table (core/spans.py StageLedger), not counters of
+    # their own.
+
+    @property
+    def plan_build_s(self) -> float:
+        """Snapshot→features host work (self time of `plan.build`)."""
+        return self.stages.seconds["plan.build"]
+
+    @property
+    def device_wait_s(self) -> float:
+        """Time blocked on a device result fetch (`device.wait`)."""
+        return self.stages.seconds["device.wait"]
+
+    @property
+    def host_commit_s(self) -> float:
+        """assume/reserve/permit/bind tails: `host.commit` with its one
+        child `bind.post`, which runs under nothing else."""
+        seconds = self.stages.seconds
+        return seconds["host.commit"] + seconds["bind.post"]
+
     # -- batch accumulation ------------------------------------------------
 
     def _pop(self) -> Optional[QueuedPodInfo]:
+        self._popped_ctx = None
         while True:
             if self._holdover is not None:
                 qpi, self._holdover = self._holdover, None
@@ -228,12 +262,20 @@ class TPUScheduler(Scheduler):
                     # skipped whole — their .pod is just the first member.)
                     self.queue.done(qpi.pod.uid)
                     continue
-                if self.tracer.enabled:
-                    # queue.wait ends here for device-path pods (host-path
-                    # pods record in process_one; the qpi guard dedups).
-                    self.record_queue_wait(
-                        qpi, self.tracer.context_for(qpi.pod.uid))
+                # queue.wait ends here for device-path pods (host-path
+                # pods record in process_one; the qpi guard dedups).
+                ctx = self._popped_ctx = (
+                    self.tracer.context_for(qpi.pod.uid)
+                    if self.tracer.enabled else None)
+                self.record_queue_wait(qpi, ctx)
             return qpi
+
+    def _take(self, batch: "_Batch", qpi: QueuedPodInfo) -> None:
+        """Accept the entity _pop just handed out into `batch`."""
+        batch.append(qpi)
+        ctx = self._popped_ctx
+        if ctx is not None and ctx.sampled:
+            batch.sampled.append(ctx)
 
     def _collect_batch(self) -> Tuple[Optional[Framework], List[QueuedPodInfo], Optional[str]]:
         """Pop a maximal run of consecutive identical-signature pods.
@@ -275,13 +317,14 @@ class TPUScheduler(Scheduler):
             for n in getattr(head.pod, "resource_claims", ()) or ())
         self._session_aux_shape = self._aux_shape(head.pod)
         self._session_neutral_sig = self._neutral_sig(fw, head.pod, sig)
-        batch = [head]
+        batch = _Batch()
+        self._take(batch, head)  # still the last entity _pop handed out
         while len(batch) < self.max_batch:
             nxt = self._pop()
             if nxt is None:
                 break
             if self._session_compatible(nxt, fw, sig):
-                batch.append(nxt)
+                self._take(batch, nxt)
             else:
                 self._holdover = nxt
                 break
@@ -382,8 +425,11 @@ class TPUScheduler(Scheduler):
         claims_rv = getattr(self.clientset, "resource_claims_rv", 0)
         # Gang resumes stay exact-signature (nsig=None): the neutral erasure
         # targets plain-pod namespace sweeps, not group entities.
-        state, plan, carry, node_names, _rkind = self._resume_or_rebuild(
-            fw, first.members[0].pod, sig, None, aux_shape, claims_rv)
+        stages = self.stages
+        with stages.stage("plan.build") as st:
+            state, plan, carry, node_names, st.attrs["kind"] = \
+                self._resume_or_rebuild(fw, first.members[0].pod, sig, None,
+                                        aux_shape, claims_rv)
         sd = _SessionDelta(state, carry, self.cluster_event_seq)
         del state, carry
         start_unwinds = self.state_unwinds
@@ -425,30 +471,32 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if pack is None:
-                    pack = collect_pack() or None
+                    with stages.stage("queue.pop"):
+                        pack = collect_pack() or None
                     if pack is None:
                         break
                     pending.append(pack)
                 members = [m for g in pack for m in self._sorted_members(g)]
-                results, sd.carry = self._dispatch(
-                    sd.state, plan, len(members), sd.carry)
-                results.copy_to_host_async()
+                with stages.stage("device.dispatch", batch=len(members)):
+                    results, sd.carry = self._dispatch(
+                        sd.state, plan, len(members), sd.carry)
+                    results.copy_to_host_async()
                 self.device_batches += 1
                 self.metrics.batch_attempts.inc("dispatched")
                 self.metrics.batch_size.observe(len(members))
                 inflight.append((pack, results))
+                stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),
                                             "device_dispatch")
                 pack = None
             if not inflight:
                 break
             groups, results = inflight.pop(0)
+            stages.inflight = len(inflight)
             self.metrics.goroutines.set(float(len(inflight)),
                                         "device_dispatch")
-            _t0 = _time.perf_counter()
-            res = np.asarray(results)
-            _t1 = _time.perf_counter()
-            self.device_wait_s += _t1 - _t0
+            with stages.stage("device.wait"):
+                res = np.asarray(results)
             if (invalidated or self.state_unwinds != start_unwinds
                     or not self._note_session_events(sd, plan, node_names,
                                                      busy=True)):
@@ -460,36 +508,36 @@ class TPUScheduler(Scheduler):
                 if groups in pending:
                     pending.remove(groups)
                 continue
-            i = 0
-            for g in groups:
-                ms = self._sorted_members(g)
-                rows = res[0, i:i + len(ms)]
-                self.next_start_node_index = int(res[1, i + len(ms) - 1])
-                i += len(ms)
-                if invalidated or (rows < 0).any():
-                    # Some member infeasible (or a prior group diverged):
-                    # every row this group DID take is charged dirty (the
-                    # carry placed them), and the exact host group cycle
-                    # owns the entity (diagnosis, PodGroupPostFilter).
-                    for r in rows:
-                        if r >= 0:
-                            dirty_rows.append(int(r))
-                    for _ in ms:
-                        self.host_path_pods += 1
-                    self.process_one(g)
-                    invalidated = True
-                    continue
-                if not self._commit_gang_group(fw, g, ms, rows, node_names,
-                                               ok_rows, dirty_rows):
-                    invalidated = True  # a member's host commit rejected a
-                    # placement the carry already applied
-                if (self.state_unwinds != start_unwinds
-                        or not self._note_session_events(sd, plan, node_names,
-                                                         busy=True)):
-                    invalidated = True
-                    sd.start_seq = self.cluster_event_seq
-                    start_unwinds = self.state_unwinds
-            self.host_commit_s += _time.perf_counter() - _t1
+            with stages.stage("host.commit", groups=len(groups)):
+                i = 0
+                for g in groups:
+                    ms = self._sorted_members(g)
+                    rows = res[0, i:i + len(ms)]
+                    self.next_start_node_index = int(res[1, i + len(ms) - 1])
+                    i += len(ms)
+                    if invalidated or (rows < 0).any():
+                        # Some member infeasible (or a prior group diverged):
+                        # every row this group DID take is charged dirty (the
+                        # carry placed them), and the exact host group cycle
+                        # owns the entity (diagnosis, PodGroupPostFilter).
+                        for r in rows:
+                            if r >= 0:
+                                dirty_rows.append(int(r))
+                        for _ in ms:
+                            self.host_path_pods += 1
+                        self.process_one(g)
+                        invalidated = True
+                        continue
+                    if not self._commit_gang_group(fw, g, ms, rows, node_names,
+                                                   ok_rows, dirty_rows):
+                        invalidated = True  # a member's host commit rejected a
+                        # placement the carry already applied
+                    if (self.state_unwinds != start_unwinds
+                            or not self._note_session_events(sd, plan, node_names,
+                                                             busy=True)):
+                        invalidated = True
+                        sd.start_seq = self.cluster_event_seq
+                        start_unwinds = self.state_unwinds
             if getattr(self, "_after_flush", False):
                 # First retired pack after a flush (pod_scheduled_after_flush
                 # consumption for gang sessions).
@@ -506,20 +554,21 @@ class TPUScheduler(Scheduler):
             if pack in pending:
                 pending.remove(pack)
 
-        self.cache.update_snapshot(self.snapshot)
-        dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
-        if invalidated:
-            self.mirror.invalidate()
-            self.metrics.batch_cache_flushed.inc("gang_session_invalidated")
-            self._after_flush = True
-        else:
-            self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
-                              sd.carry.req_r, sd.carry.nonzero,
-                              sd.carry.pod_count, dirty_rows=dirty_rows)
-            if sd.carry is not None and not dirty_rows:
-                self._save_resume(fw, first.members[0].pod, sig, aux_shape,
-                                  sd.state, plan, sd.carry, node_names,
-                                  neutral_ok=False)
+        with stages.stage("plan.adopt"):
+            self.cache.update_snapshot(self.snapshot)
+            dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
+            if invalidated:
+                self.mirror.invalidate()
+                self.metrics.batch_cache_flushed.inc("gang_session_invalidated")
+                self._after_flush = True
+            else:
+                self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
+                                  sd.carry.req_r, sd.carry.nonzero,
+                                  sd.carry.pod_count, dirty_rows=dirty_rows)
+                if sd.carry is not None and not dirty_rows:
+                    self._save_resume(fw, first.members[0].pod, sig, aux_shape,
+                                      sd.state, plan, sd.carry, node_names,
+                                      neutral_ok=False)
         self._note_device_success()
 
     def _commit_gang_group(self, fw: Framework, qgpi: QueuedPodGroupInfo,
@@ -753,24 +802,6 @@ class TPUScheduler(Scheduler):
         return candidates
 
     # -- resilience: device→host fallback + circuit breaker ----------------
-
-    def _batch_spans(self, name: str, qpis, duration: float,
-                     **attrs) -> None:
-        """Record one batch-level stage span into each SAMPLED member's
-        trace (per-pod copies keep the per-pod chain complete while the
-        cost scales with sampled pods, not batch size). Entities without a
-        plain pod (group infos riding gang paths) are skipped."""
-        tr = self.tracer
-        if not tr.enabled or not qpis:
-            return
-        wall = _time.time() - duration
-        for qpi in qpis:
-            pod = getattr(qpi, "pod", None)
-            if pod is None:
-                continue
-            ctx = tr.context_for(pod.uid)
-            if ctx.sampled:
-                tr.record(name, ctx, duration, start=wall, **attrs)
 
     def _note_device_failure(self, exc: BaseException, where: str) -> None:
         """One unexpected device-path exception: log it, count it, charge
@@ -1441,6 +1472,11 @@ class TPUScheduler(Scheduler):
         dispatched-but-uncommitted device results exist."""
         if self.cluster_event_seq == sd.start_seq and not sd.patch_pending:
             return True
+        with self.stages.stage("inbox.drain"):  # the journal's half of it
+            return self._consume_session_events(sd, plan, node_names, busy)
+
+    def _consume_session_events(self, sd, plan, node_names,
+                                busy: bool) -> bool:
         events = self.journal.since(sd.start_seq)
         if events is None:
             return False
@@ -1515,6 +1551,11 @@ class TPUScheduler(Scheduler):
         (`busy`)."""
         if not names:
             return state, carry
+        with self.stages.stage("plan.patch", rows=len(names)):
+            return self._patch_rows(plan, node_names, names, state, carry,
+                                    busy)
+
+    def _patch_rows(self, plan, node_names, names, state, carry, busy: bool):
         row_of = getattr(self, "_session_row_of", None)
         if row_of is None or row_of[0] is not node_names:
             row_of = (node_names, {n: i for i, n in enumerate(node_names)})
@@ -1604,9 +1645,7 @@ class TPUScheduler(Scheduler):
         self.metrics.get_node_hint_duration.observe(
             _time.perf_counter() - _t_hint)
         if kind == "full":
-            _t0 = _time.perf_counter()
             state, plan = self.build_plan(fw, head_pod, self.max_batch)
-            self.plan_build_s += _time.perf_counter() - _t0
             node_names = [ni.name for ni in self.snapshot.node_info_list]
         self._count_rebuild(kind)
         return state, plan, carry, node_names, kind
@@ -1756,13 +1795,13 @@ class TPUScheduler(Scheduler):
     def _collect_session_batch(self, fw: Framework, sig) -> List[QueuedPodInfo]:
         """Pop up to max_batch pods matching the session signature; an
         incompatible entity goes to the holdover slot and ends the refill."""
-        batch: List[QueuedPodInfo] = []
+        batch = _Batch()
         while len(batch) < self.max_batch:
             nxt = self._pop()
             if nxt is None:
                 break
             if self._session_compatible(nxt, fw, sig):
-                batch.append(nxt)
+                self._take(batch, nxt)
             else:
                 self._holdover = nxt
                 break
@@ -1800,17 +1839,15 @@ class TPUScheduler(Scheduler):
         # could chain onto a volume session's attach-room plan (fuzz-caught).
         aux_shape = self._aux_shape(first_batch[0].pod)
         claims_rv = getattr(self.clientset, "resource_claims_rv", 0)
-        _tp0 = _time.perf_counter()
-        state, plan, carry, node_names, _rkind = self._resume_or_rebuild(
-            fw, first_batch[0].pod, sig, nsig, aux_shape, claims_rv)
-        _tp = _time.perf_counter() - _tp0
+        stages = self.stages
         # Plan acquisition latency: the extension-point histogram gets
         # EVERY session (p50/p99 truth); sampled pods get plan.build spans
         # tagged with the acquisition kind (full/delta/resume).
-        self.metrics.framework_extension_point_duration.observe(
-            _tp, "DevicePlan", "Success", "")
-        self._batch_spans("plan.build", first_batch, _tp,
-                          kind=_rkind, batch=len(first_batch))
+        with stages.stage("plan.build", first_batch.sampled, "DevicePlan",
+                          batch=len(first_batch)) as st:
+            state, plan, carry, node_names, st.attrs["kind"] = \
+                self._resume_or_rebuild(fw, first_batch[0].pod, sig, nsig,
+                                        aux_shape, claims_rv)
         sd = _SessionDelta(state, carry, self.cluster_event_seq)
         del state, carry
         start_unwinds = self.state_unwinds
@@ -1833,7 +1870,8 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if batch is None:
-                    batch = self._collect_session_batch(fw, sig) or None
+                    with stages.stage("queue.pop"):
+                        batch = self._collect_session_batch(fw, sig) or None
                     if batch is None and self._event_inbox:
                         # A concurrent client (threaded watch feed) may have
                         # parked pod-add events while this session ran: drain
@@ -1848,24 +1886,25 @@ class TPUScheduler(Scheduler):
                         elif sd.patch_pending:
                             continue  # patch (or drain) before collecting
                         else:
-                            batch = self._collect_session_batch(fw, sig) or None
+                            with stages.stage("queue.pop"):
+                                batch = self._collect_session_batch(
+                                    fw, sig) or None
                     if batch is None:
                         break
                     pending.append(batch)
-                _td0 = _time.perf_counter()
-                results, sd.carry = self._dispatch(
-                    sd.state, plan, len(batch), sd.carry)
-                self._batch_spans("device.dispatch", batch,
-                                  _time.perf_counter() - _td0,
-                                  batch=len(batch))
-                # Start the device→host copy NOW: issuing it at dispatch
-                # time overlaps the fetch latency with the host commit loop
-                # of the previous batch.
-                results.copy_to_host_async()
+                with stages.stage("device.dispatch", batch.sampled,
+                                  batch=len(batch)):
+                    results, sd.carry = self._dispatch(
+                        sd.state, plan, len(batch), sd.carry)
+                    # Start the device→host copy NOW: issuing it at
+                    # dispatch time overlaps the fetch latency with the
+                    # host commit loop of the previous batch.
+                    results.copy_to_host_async()
                 self.device_batches += 1
                 self.metrics.batch_attempts.inc("dispatched")
                 self.metrics.batch_size.observe(len(batch))
                 inflight.append((batch, results))
+                stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),
                                             "device_dispatch")
                 batch = None
@@ -1874,23 +1913,17 @@ class TPUScheduler(Scheduler):
             # Retire the oldest batch: block on its results (the device is
             # already computing the NEXT batch), then run the host tail.
             b, results = inflight.pop(0)
+            stages.inflight = len(inflight)
             self.metrics.goroutines.set(float(len(inflight)),
                                         "device_dispatch")
-            _t0 = _time.perf_counter()
-            res = np.asarray(results)  # one device→host fetch
-            _t1 = _time.perf_counter()
-            self.device_wait_s += _t1 - _t0
-            self.metrics.framework_extension_point_duration.observe(
-                _t1 - _t0, "DeviceWait", "Success", "")
-            self._batch_spans("device.wait", b, _t1 - _t0, batch=len(b))
+            with stages.stage("device.wait", b.sampled, "DeviceWait",
+                              batch=len(b)):
+                res = np.asarray(results)  # one device→host fetch
             if not invalidated:
-                invalidated = self._commit_batch(
-                    b, res, fw, node_names, ok_rows, dirty_rows)
-                _tc = _time.perf_counter() - _t1
-                self.host_commit_s += _tc
-                self.metrics.framework_extension_point_duration.observe(
-                    _tc, "HostCommit", "Success", "")
-                self._batch_spans("host.commit", b, _tc, batch=len(b))
+                with stages.stage("host.commit", b.sampled, "HostCommit",
+                                  batch=len(b)):
+                    invalidated = self._commit_batch(
+                        b, res, fw, node_names, ok_rows, dirty_rows)
                 if getattr(self, "_after_flush", False):
                     # First retired batch after a flush: its pods scheduled
                     # from a fresh (non-chained) evaluation.
@@ -1925,34 +1958,35 @@ class TPUScheduler(Scheduler):
             if batch in pending:
                 pending.remove(batch)
 
-        self.cache.update_snapshot(self.snapshot)
-        dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
-        if invalidated:
-            # The carry charged host-diverged placements; staging is the
-            # authority again — force a full re-encode + upload.
-            self.mirror.invalidate()
-            self.metrics.batch_cache_flushed.inc("session_invalidated")
-            self._after_flush = True
-        else:
-            # Keep the device state resident: the final carry reflects every
-            # successful placement, so the next flush uploads nothing.
-            self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
-                              sd.carry.req_r, sd.carry.nonzero,
-                              sd.carry.pod_count, dirty_rows=dirty_rows)
-            if sd.carry is not None and not dirty_rows:
-                self._save_resume(fw, first_batch[0].pod, sig, aux_shape,
-                                  sd.state, plan, sd.carry, node_names)
-                # Score-hint install (the cross-cycle OpportunisticBatch
-                # save): the final host-commit completed cleanly, so the
-                # carry IS the kernel's sorted-score truth for the next
-                # identical pod — persist it for the host-only bind loop.
-                from .score_hints import hint_eligible
-                if self._hints.enabled and hint_eligible(
-                        plan, self.mesh, aux_shape, first_batch[0].pod,
-                        self.extenders, self.queue.nominator,
-                        self.cache.affinity_pod_refs):
-                    self._hints.install(fw, first_batch[0].pod, sig, nsig,
-                                        plan, node_names, sd.carry)
+        with stages.stage("plan.adopt"):
+            self.cache.update_snapshot(self.snapshot)
+            dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
+            if invalidated:
+                # The carry charged host-diverged placements; staging is the
+                # authority again — force a full re-encode + upload.
+                self.mirror.invalidate()
+                self.metrics.batch_cache_flushed.inc("session_invalidated")
+                self._after_flush = True
+            else:
+                # Keep the device state resident: the final carry reflects every
+                # successful placement, so the next flush uploads nothing.
+                self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
+                                  sd.carry.req_r, sd.carry.nonzero,
+                                  sd.carry.pod_count, dirty_rows=dirty_rows)
+                if sd.carry is not None and not dirty_rows:
+                    self._save_resume(fw, first_batch[0].pod, sig, aux_shape,
+                                      sd.state, plan, sd.carry, node_names)
+                    # Score-hint install (the cross-cycle OpportunisticBatch
+                    # save): the final host-commit completed cleanly, so the
+                    # carry IS the kernel's sorted-score truth for the next
+                    # identical pod — persist it for the host-only bind loop.
+                    from .score_hints import hint_eligible
+                    if self._hints.enabled and hint_eligible(
+                            plan, self.mesh, aux_shape, first_batch[0].pod,
+                            self.extenders, self.queue.nominator,
+                            self.cache.affinity_pod_refs):
+                        self._hints.install(fw, first_batch[0].pod, sig, nsig,
+                                            plan, node_names, sd.carry)
         # The session ran to completion (invalidation included — that is a
         # NORMAL end, not a device failure): a half-open breaker closes.
         self._note_device_success()
@@ -2206,9 +2240,15 @@ class TPUScheduler(Scheduler):
         tail (bulk-binding path included). Any miss — signature, validation,
         infeasibility — parks the entity in the holdover slot and returns,
         so the normal batch path owns it. Returns pods bound."""
-        hints = self._hints
-        if hints.entry is None:
+        if self._hints.entry is None:
             return 0
+        with self.stages.stage("hint.walk"):
+            return self._walk_hint()
+
+    def _walk_hint(self) -> int:
+        hints = self._hints
+        stages = self.stages
+        clock = _time.perf_counter
         bound = 0
         handled = 0
         while True:
@@ -2216,6 +2256,9 @@ class TPUScheduler(Scheduler):
                 # Surface thread-mode async bind errors (409 → per-node
                 # hint invalidation) while the loop runs.
                 self.process_async_api_errors()
+            # Per pod the walk's parts are leaves of the table (clock reads,
+            # no profiler annotation): queue.pop, hint.validate, host.commit.
+            _t0 = clock()
             qpi = self._pop()
             if qpi is None:
                 if self._event_inbox:
@@ -2223,28 +2266,30 @@ class TPUScheduler(Scheduler):
                     # (queue-only events): drain so a creation burst does
                     # not end the hint run early — the session refill seam.
                     self.drain_event_inbox()
+                    _t0 = clock()
                     qpi = self._pop()
                 if qpi is None:
                     break
+            stages.leaf("queue.pop", clock() - _t0)
             if (isinstance(qpi, (QueuedPodGroupInfo,
                                  QueuedCompositeGroupInfo))
                     or qpi.pod.scheduler_name not in self.profiles):
                 self._holdover = qpi
                 break
             fw = self.framework_for_pod(qpi.pod)
-            _t0 = _time.perf_counter()
+            _t0 = clock()
             served = hints.serve(fw, qpi.pod)
+            if served is not None:
+                entry, kind = served
+                row, evaluated = entry.select(self.next_start_node_index)
+            # Misses pay validation too (a stale-entry journal replay is
+            # the EXPENSIVE path) — the histogram must see them.
+            _tv = clock() - _t0
+            self.metrics.hint_validation_duration.observe(_tv)
+            stages.leaf("hint.validate", _tv)
             if served is None:
-                # Misses pay validation too (a stale-entry journal replay
-                # is the EXPENSIVE path) — the histogram must see them.
-                self.metrics.hint_validation_duration.observe(
-                    _time.perf_counter() - _t0)
                 self._holdover = qpi
                 break
-            entry, kind = served
-            row, evaluated = entry.select(self.next_start_node_index)
-            self.metrics.hint_validation_duration.observe(
-                _time.perf_counter() - _t0)
             if row < 0:
                 # No feasible node under the hint: the normal path owns the
                 # exact diagnosis (FitError / PostFilter) — fall through.
@@ -2252,7 +2297,8 @@ class TPUScheduler(Scheduler):
                 self._holdover = qpi
                 break
             node = entry.node_names[row]
-            committed = self._commit(fw, qpi, node)
+            with stages.stage("host.commit", annotate=False):
+                committed = self._commit(fw, qpi, node)
             hints.note_own_attempt(node if committed else "", entry)
             handled += 1
             if not committed:
@@ -2290,9 +2336,9 @@ class TPUScheduler(Scheduler):
 
     # -- run loop ----------------------------------------------------------
 
-    def schedule_one(self) -> bool:
+    def _cycle(self) -> bool:
         if not self.device_enabled:
-            return super().schedule_one()  # TPUBatchScheduling gate off
+            return super()._cycle()  # TPUBatchScheduling gate off
         if not self.device_breaker.allows():
             # Breaker open: the host Evaluator owns every cycle until the
             # cool-down elapses (then ONE probe session runs half-open).
@@ -2304,7 +2350,7 @@ class TPUScheduler(Scheduler):
                 self.host_path_pods += len(getattr(qpi, "members", ()) or (1,))
                 self.process_one(qpi)
                 return True
-            return super().schedule_one()
+            return super()._cycle()
         self.process_async_api_errors()
         # Score-hint fast path FIRST: while a fresh hint matches the queue
         # head, identical replicas bind in a host-only loop with zero
@@ -2312,7 +2358,8 @@ class TPUScheduler(Scheduler):
         # path below (the popped entity waits in the holdover slot).
         if self._hints.entry is not None and self._try_hint_binds():
             return True
-        fw, batch, fallback_reason = self._collect_batch()
+        with self.stages.stage("queue.pop"):
+            fw, batch, fallback_reason = self._collect_batch()
         if not batch:
             return False
         if fallback_reason is _GANG_SESSION:

@@ -104,6 +104,17 @@ class ScanCarry(NamedTuple):
     aux_cnt: jnp.ndarray      # [NP] i32 aux units consumed by landings (CSI)
 
 
+def entry_name(name: str):
+    """Pin a jitted entry's program name: `jit_<name>` is what a profiler
+    trace and XLA's module table call the program, and the trace reduction
+    finds the scheduling programs by `jit_schedule_batch*`. Pinned here, a
+    rename of the Python function cannot move it."""
+    def pin(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return pin
+
+
 def _tolerates(f: BatchFeatures, taint_key, taint_val, taint_eff):
     """tolerated[n, t] — any toleration row matches the taint
     (component-helpers ToleratesTaint, api/types.py Toleration.tolerates)."""
@@ -214,6 +225,7 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
                                    "has_na_pref", "port_selfblock", "has_aux",
                                    "has_nom"),
          donate_argnames=("carry_in",))
+@entry_name("schedule_batch")
 def schedule_batch(
     state: DeviceNodeState,
     f: BatchFeatures,
@@ -273,7 +285,8 @@ def schedule_batch(
     # truncation inactive every window spans the whole rotation, L=1).
     static_scores = incremental_feas and scores_carried and batch_pad > 64
 
-    taint_ok, pns_cnt, sel_ok, name_ok, unsched_ok, exist_anti_ok = _static_masks(state, f)
+    with jax.named_scope("taints"):
+        taint_ok, pns_cnt, sel_ok, name_ok, unsched_ok, exist_anti_ok = _static_masks(state, f)
 
     # Static topology vid gathers [C, NP].
     dns_vid = state.topo[f.dns_axis] if C1 else jnp.zeros((0, NP), jnp.int32)
@@ -324,15 +337,16 @@ def schedule_batch(
             ok &= ~blocked
         if has_aux:
             ok &= aux_cnt + f.aux_inc <= f.aux_room
-        if C1:
-            # All-int32 skew math (counts are pods-per-domain, far below 2^31;
-            # int64 vector ops cost ~2x in the per-op-latency regime).
-            min_match = jnp.where(f.dns_dom, dns_counts, _BIG).min(axis=1)  # [C1]
-            min_match = jnp.where(f.dns_forced0 == 1, 0, min_match)
-            skew_bad = (mnum + f.dns_self[:, None] - min_match[:, None]
-                        ) > jnp.minimum(f.dns_max_skew, _BIG)[:, None]
-            dns_reject = (f.dns_active[:, None] == 1) & (~(dns_vid > 0) | skew_bad)
-            ok &= ~dns_reject.any(axis=0)
+        with jax.named_scope("spread_lanes"):
+            if C1:
+                # All-int32 skew math (counts are pods-per-domain, far below 2^31;
+                # int64 vector ops cost ~2x in the per-op-latency regime).
+                min_match = jnp.where(f.dns_dom, dns_counts, _BIG).min(axis=1)  # [C1]
+                min_match = jnp.where(f.dns_forced0 == 1, 0, min_match)
+                skew_bad = (mnum + f.dns_self[:, None] - min_match[:, None]
+                            ) > jnp.minimum(f.dns_max_skew, _BIG)[:, None]
+                dns_reject = (f.dns_active[:, None] == 1) & (~(dns_vid > 0) | skew_bad)
+                ok &= ~dns_reject.any(axis=0)
         if A1:
             ok &= ~((anti_vid > 0) & (acnt > 0)).any(axis=0)
         if A2:
@@ -348,173 +362,180 @@ def schedule_batch(
          mnum, scnt, acnt, fcnt, dproj, aff_total, t, out) = carry
         active = t < n_act
 
-        if not incremental_feas:
-            okd = feasibility_proj(fit_ok, dns_counts, mnum, acnt, fcnt,
-                                   aff_total, blocked, aux_cnt)
-            F = jnp.cumsum(okd.astype(jnp.int32))          # inclusive, row order
+        with jax.named_scope("feasibility"):
+            if not incremental_feas:
+                okd = feasibility_proj(fit_ok, dns_counts, mnum, acnt, fcnt,
+                                       aff_total, blocked, aux_cnt)
+                F = jnp.cumsum(okd.astype(jnp.int32))          # inclusive, row order
 
-        # ---- sampling truncation + rotation (schedule_one.go:779-892) -----
-        # Gather-free formulation: rank[row] = #feasible rows at rotation
-        # positions <= rot(row), from the row-order prefix-sum with wrap
-        # adjustment (feasible count in [start..row] resp. wrapped).
-        total_feas = F[-1]
-        f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
-        rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
-        kept = okd & (rank <= f.to_find)
-        rot_of_row = (idx - start) % num                   # row -> rotation pos
+        with jax.named_scope("select"):
+            # ---- sampling truncation + rotation (schedule_one.go:779-892) -----
+            # Gather-free formulation: rank[row] = #feasible rows at rotation
+            # positions <= rot(row), from the row-order prefix-sum with wrap
+            # adjustment (feasible count in [start..row] resp. wrapped).
+            total_feas = F[-1]
+            f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
+            rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
+            kept = okd & (rank <= f.to_find)
+            rot_of_row = (idx - start) % num                   # row -> rotation pos
 
-        # ---- reductions: everything as stacked maxes (mins ride negated) --
-        # lane 0: window-boundary rotation (evaluated).
-        bound_lane = jnp.where(okd & (rank == f.to_find),
-                               (num - 1 - rot_of_row).astype(jnp.int64), 0)
-        if scores_carried:
-            # total is already known: boundary + packed selection key
-            # (max-score-then-min-rotation; scores non-negative) collapse
-            # into ONE reduction round.
-            key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
-            red = jnp.max(jnp.stack(
-                [jnp.where(kept, key, -1), bound_lane]), axis=1)
-            best_key = red[0]
-            evaluated = (num - red[1]).astype(jnp.int32)
-        else:
-            lanes = [bound_lane]
-            if has_pns:
-                lanes.append(jnp.where(kept, pns_cnt, 0))              # mx_pns
-            if C2:
-                raw_sa = (scnt.astype(jnp.int64) * f.sa_wq[:, None] +
-                          (f.sa_skew[:, None] - 1) * 1024).sum(axis=0)
-                live = kept & ~sa_ignored
-                lanes.append(jnp.where(live, raw_sa, 0))               # mx_sa
-                lanes.append(jnp.where(live, -raw_sa, -_INF64))        # -mn_sa
-            if KD or has_ipa_base:
-                raw_ipa = f.ipa_base
-                if KD:
-                    raw_ipa = raw_ipa + dproj.sum(axis=0)
-                lanes.append(jnp.where(kept, raw_ipa, -_INF64))        # mx_ipa
-                lanes.append(jnp.where(kept, -raw_ipa, -_INF64))       # -mn_ipa
-            if has_na_pref:
-                lanes.append(jnp.where(kept, f.na_raw, 0))             # mx_na
-            red = jnp.max(jnp.stack(lanes), axis=1)
-            evaluated = (num - red[0]).astype(jnp.int32)
-            li = 1
-            # ---- score assembly (runtime/framework.go:1526-1582) ----------
-            if has_pns:
-                tt = _normalize_default_reverse(pns_cnt, red[li]); li += 1
+        with jax.named_scope("score_normalise"):
+            # ---- reductions: everything as stacked maxes (mins ride negated) --
+            # lane 0: window-boundary rotation (evaluated).
+            bound_lane = jnp.where(okd & (rank == f.to_find),
+                                   (num - 1 - rot_of_row).astype(jnp.int64), 0)
+            if scores_carried:
+                # total is already known: boundary + packed selection key
+                # (max-score-then-min-rotation; scores non-negative) collapse
+                # into ONE reduction round.
+                key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
+                red = jnp.max(jnp.stack(
+                    [jnp.where(kept, key, -1), bound_lane]), axis=1)
+                best_key = red[0]
+                evaluated = (num - red[1]).astype(jnp.int32)
             else:
-                tt = jnp.int64(MAX_NODE_SCORE)
-            if C2:
-                mx, mn = red[li], -red[li + 1]; li += 2
-                norm = jnp.where(
-                    mx > 0,
-                    MAX_NODE_SCORE * (mx + jnp.minimum(mn, mx) - raw_sa) // jnp.maximum(mx, 1),
-                    jnp.int64(MAX_NODE_SCORE))
-                pts = jnp.where(sa_ignored, 0, norm)
-            else:
-                pts = jnp.int64(0)
-            if KD or has_ipa_base:
-                mx_i, mn_i = red[li], -red[li + 1]; li += 2
-                diff = mx_i - mn_i
-                ipa = jnp.where(diff > 0,
-                                MAX_NODE_SCORE * (raw_ipa - mn_i) // jnp.maximum(diff, 1), 0)
-            else:
-                ipa = jnp.int64(0)
-            if has_na_pref:
-                # default_normalize_score(max=100, reverse=False): raw*100//mx
-                # over the kept set; all-zero raws stay zero.
-                mx_na = red[li]; li += 1
-                na = jnp.where(mx_na > 0,
-                               MAX_NODE_SCORE * f.na_raw // jnp.maximum(mx_na, 1), 0)
-            else:
-                na = jnp.int64(0)
-            total = (w_tt * tt + w_fit * fit_sc + w_ba * ba + w_pts * pts
-                     + w_ipa * ipa + w_na * na + il_term)
-            # second reduction round: packed selection over the fresh scores
-            key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
-            best_key = jnp.max(jnp.where(kept, key, -1))
-        any_kept = (best_key >= 0) & active
-        chosen_rot = jnp.int32(NP - 1) - (best_key % NP).astype(jnp.int32)
-        chosen = jnp.where(any_kept, (start + chosen_rot) % num, -1).astype(jnp.int32)
+                lanes = [bound_lane]
+                if has_pns:
+                    lanes.append(jnp.where(kept, pns_cnt, 0))              # mx_pns
+                if C2:
+                    raw_sa = (scnt.astype(jnp.int64) * f.sa_wq[:, None] +
+                              (f.sa_skew[:, None] - 1) * 1024).sum(axis=0)
+                    live = kept & ~sa_ignored
+                    lanes.append(jnp.where(live, raw_sa, 0))               # mx_sa
+                    lanes.append(jnp.where(live, -raw_sa, -_INF64))        # -mn_sa
+                if KD or has_ipa_base:
+                    raw_ipa = f.ipa_base
+                    if KD:
+                        raw_ipa = raw_ipa + dproj.sum(axis=0)
+                    lanes.append(jnp.where(kept, raw_ipa, -_INF64))        # mx_ipa
+                    lanes.append(jnp.where(kept, -raw_ipa, -_INF64))       # -mn_ipa
+                if has_na_pref:
+                    lanes.append(jnp.where(kept, f.na_raw, 0))             # mx_na
+                red = jnp.max(jnp.stack(lanes), axis=1)
+                evaluated = (num - red[0]).astype(jnp.int32)
+                li = 1
+                # ---- score assembly (runtime/framework.go:1526-1582) ----------
+                if has_pns:
+                    tt = _normalize_default_reverse(pns_cnt, red[li]); li += 1
+                else:
+                    tt = jnp.int64(MAX_NODE_SCORE)
+                if C2:
+                    mx, mn = red[li], -red[li + 1]; li += 2
+                    norm = jnp.where(
+                        mx > 0,
+                        MAX_NODE_SCORE * (mx + jnp.minimum(mn, mx) - raw_sa) // jnp.maximum(mx, 1),
+                        jnp.int64(MAX_NODE_SCORE))
+                    pts = jnp.where(sa_ignored, 0, norm)
+                else:
+                    pts = jnp.int64(0)
+                if KD or has_ipa_base:
+                    mx_i, mn_i = red[li], -red[li + 1]; li += 2
+                    diff = mx_i - mn_i
+                    ipa = jnp.where(diff > 0,
+                                    MAX_NODE_SCORE * (raw_ipa - mn_i) // jnp.maximum(diff, 1), 0)
+                else:
+                    ipa = jnp.int64(0)
+                if has_na_pref:
+                    # default_normalize_score(max=100, reverse=False): raw*100//mx
+                    # over the kept set; all-zero raws stay zero.
+                    mx_na = red[li]; li += 1
+                    na = jnp.where(mx_na > 0,
+                                   MAX_NODE_SCORE * f.na_raw // jnp.maximum(mx_na, 1), 0)
+                else:
+                    na = jnp.int64(0)
+                total = (w_tt * tt + w_fit * fit_sc + w_ba * ba + w_pts * pts
+                         + w_ipa * ipa + w_na * na + il_term)
+                # second reduction round: packed selection over the fresh scores
+                key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
+                best_key = jnp.max(jnp.where(kept, key, -1))
+        with jax.named_scope("select"):
+            any_kept = (best_key >= 0) & active
+            chosen_rot = jnp.int32(NP - 1) - (best_key % NP).astype(jnp.int32)
+            chosen = jnp.where(any_kept, (start + chosen_rot) % num, -1).astype(jnp.int32)
 
-        # ---- carry updates (inert when this step is padding) --------------
-        row = jnp.maximum(chosen, 0)
-        apply = jnp.where(any_kept, 1, 0).astype(jnp.int64)
-        req_r = req_r.at[row].add(f.request * apply)
-        nonzero = nonzero.at[row].add(f.nz_request * apply)
-        pod_count = pod_count.at[row].add(apply.astype(jnp.int32))
-        # Re-evaluate ONLY the landed row's resource-derived values (when
-        # nothing was applied the inputs are unchanged, so this is identity).
-        r_ok, r_fit, r_ba = _resource_eval(
-            f, fit_strategy, state.alloc_r[row], state.alloc_pods[row],
-            req_r[row], nonzero[row], pod_count[row],
-            nom_r=f.nom_req[row] if has_nom else None,
-            nom_p=f.nom_pods[row] if has_nom else None)
-        fit_ok = fit_ok.at[row].set(r_ok)
-        fit_sc = fit_sc.at[row].set(r_fit)
-        ba = ba.at[row].set(r_ba)
-        # All scatter/gather index operands stay int32 (matching `row` and
-        # the vid tables): with x64 enabled a bare arange defaults to int64,
-        # and mixed s64/s32 index tuples miscompile under GSPMD on this
-        # environment's XLA (compare(s64, s32) after spmd-partitioning —
-        # ROADMAP open item, fixed by this uniform-dtype normalization).
-        if C1:
-            c1i = jnp.arange(C1, dtype=jnp.int32)
-            upd = (f.dns_self * dns_elig[c1i, row].astype(jnp.int32)
-                   * apply.astype(jnp.int32))
-            dns_counts = dns_counts.at[c1i, dns_vid[:, row]].add(upd)
-            mnum = mnum + upd[:, None] * (dns_vid == dns_vid[:, row][:, None])
-        if C2:
-            upd = (f.sa_self * jnp.where(sa_ignored[row], 0, 1) * apply.astype(jnp.int32))
-            sa_counts = sa_counts.at[jnp.arange(C2, dtype=jnp.int32),
-                                     sa_vid[:, row]].add(upd)
-            scnt = scnt + upd[:, None] * (sa_vid == sa_vid[:, row][:, None])
-        if A1:
-            upd = f.anti_self * (anti_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
-            anti_counts = anti_counts.at[jnp.arange(A1, dtype=jnp.int32),
-                                         anti_vid[:, row]].add(upd)
-            acnt = acnt + upd[:, None] * (anti_vid == anti_vid[:, row][:, None])
-        if A2:
-            upd = f.aff_self * (aff_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
-            aff_counts = aff_counts.at[jnp.arange(A2, dtype=jnp.int32),
-                                       aff_vid[:, row]].add(upd)
-            fcnt = fcnt + upd[:, None] * (aff_vid == aff_vid[:, row][:, None])
-            aff_total = aff_total + upd.sum()
-        if KD:
-            upd = f.ipa_wland * (ipa_vid[:, row] > 0) * apply
-            ipa_delta = ipa_delta.at[jnp.arange(KD, dtype=jnp.int32),
-                                     ipa_vid[:, row]].add(upd)
-            dproj = dproj + upd[:, None] * (ipa_vid == ipa_vid[:, row][:, None])
-        if port_selfblock:
-            blocked = blocked.at[row].set(blocked[row] | any_kept)
-        if has_aux:
-            aux_cnt = aux_cnt.at[row].add(f.aux_inc * apply.astype(jnp.int32))
-        if incremental_feas:
-            # Feasibility flips only at the landed row: patch okd and shift
-            # the prefix-sum tail by the delta (replaces the full cumsum).
-            new_ok_row = static_ok[row] & r_ok & (row < num)
+        with jax.named_scope("carry_update"):
+            # ---- carry updates (inert when this step is padding) --------------
+            row = jnp.maximum(chosen, 0)
+            apply = jnp.where(any_kept, 1, 0).astype(jnp.int64)
+            req_r = req_r.at[row].add(f.request * apply)
+            nonzero = nonzero.at[row].add(f.nz_request * apply)
+            pod_count = pod_count.at[row].add(apply.astype(jnp.int32))
+            with jax.named_scope("resource_fit"):
+                # Re-evaluate ONLY the landed row's resource-derived values (when
+                # nothing was applied the inputs are unchanged, so this is identity).
+                r_ok, r_fit, r_ba = _resource_eval(
+                    f, fit_strategy, state.alloc_r[row], state.alloc_pods[row],
+                    req_r[row], nonzero[row], pod_count[row],
+                    nom_r=f.nom_req[row] if has_nom else None,
+                    nom_p=f.nom_pods[row] if has_nom else None)
+            fit_ok = fit_ok.at[row].set(r_ok)
+            fit_sc = fit_sc.at[row].set(r_fit)
+            ba = ba.at[row].set(r_ba)
+            # All scatter/gather index operands stay int32 (matching `row` and
+            # the vid tables): with x64 enabled a bare arange defaults to int64,
+            # and mixed s64/s32 index tuples miscompile under GSPMD on this
+            # environment's XLA (compare(s64, s32) after spmd-partitioning —
+            # ROADMAP open item, fixed by this uniform-dtype normalization).
+            with jax.named_scope("spread_lanes"):
+                if C1:
+                    c1i = jnp.arange(C1, dtype=jnp.int32)
+                    upd = (f.dns_self * dns_elig[c1i, row].astype(jnp.int32)
+                           * apply.astype(jnp.int32))
+                    dns_counts = dns_counts.at[c1i, dns_vid[:, row]].add(upd)
+                    mnum = mnum + upd[:, None] * (dns_vid == dns_vid[:, row][:, None])
+                if C2:
+                    upd = (f.sa_self * jnp.where(sa_ignored[row], 0, 1) * apply.astype(jnp.int32))
+                    sa_counts = sa_counts.at[jnp.arange(C2, dtype=jnp.int32),
+                                             sa_vid[:, row]].add(upd)
+                    scnt = scnt + upd[:, None] * (sa_vid == sa_vid[:, row][:, None])
             if A1:
-                new_ok_row &= ~((anti_vid[:, row] > 0) & (acnt[:, row] > 0)).any()
+                upd = f.anti_self * (anti_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
+                anti_counts = anti_counts.at[jnp.arange(A1, dtype=jnp.int32),
+                                             anti_vid[:, row]].add(upd)
+                acnt = acnt + upd[:, None] * (anti_vid == anti_vid[:, row][:, None])
+            if A2:
+                upd = f.aff_self * (aff_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
+                aff_counts = aff_counts.at[jnp.arange(A2, dtype=jnp.int32),
+                                           aff_vid[:, row]].add(upd)
+                fcnt = fcnt + upd[:, None] * (aff_vid == aff_vid[:, row][:, None])
+                aff_total = aff_total + upd.sum()
+            if KD:
+                upd = f.ipa_wland * (ipa_vid[:, row] > 0) * apply
+                ipa_delta = ipa_delta.at[jnp.arange(KD, dtype=jnp.int32),
+                                         ipa_vid[:, row]].add(upd)
+                dproj = dproj + upd[:, None] * (ipa_vid == ipa_vid[:, row][:, None])
             if port_selfblock:
-                new_ok_row &= ~blocked[row]
+                blocked = blocked.at[row].set(blocked[row] | any_kept)
             if has_aux:
-                new_ok_row &= aux_cnt[row] + f.aux_inc <= f.aux_room[row]
-            delta = new_ok_row.astype(jnp.int32) - okd[row].astype(jnp.int32)
-            okd = okd.at[row].set(new_ok_row)
-            F = F + jnp.where(idx >= row, delta, 0)
-        if scores_carried:
-            total = total.at[row].set(
-                w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * r_fit + w_ba * r_ba
-                + il_term[row])
-        start = jnp.where(active, (start + evaluated) % num, start).astype(jnp.int32)
-        # Results accumulate in the CARRY via a one-hot masked write (the
-        # int32 step counter `t` also rides the carry): lax.scan's own
-        # ys-stacking would index its dynamic_update_slice with the internal
-        # s64 loop counter (x64 mode), which this environment's XLA
-        # miscompiles under GSPMD — compare(s64, s32) after
-        # spmd-partitioning, the ROADMAP open item. The elementwise write
-        # keeps the carry uniformly int32-indexed and is also exact under
-        # vmap (the cells axis), where a batched-index update slice is not.
-        out = jnp.where(jnp.arange(batch_pad, dtype=jnp.int32)[None, :] == t,
-                        jnp.stack([chosen, start])[:, None], out)
+                aux_cnt = aux_cnt.at[row].add(f.aux_inc * apply.astype(jnp.int32))
+            if incremental_feas:
+                # Feasibility flips only at the landed row: patch okd and shift
+                # the prefix-sum tail by the delta (replaces the full cumsum).
+                new_ok_row = static_ok[row] & r_ok & (row < num)
+                if A1:
+                    new_ok_row &= ~((anti_vid[:, row] > 0) & (acnt[:, row] > 0)).any()
+                if port_selfblock:
+                    new_ok_row &= ~blocked[row]
+                if has_aux:
+                    new_ok_row &= aux_cnt[row] + f.aux_inc <= f.aux_room[row]
+                delta = new_ok_row.astype(jnp.int32) - okd[row].astype(jnp.int32)
+                okd = okd.at[row].set(new_ok_row)
+                F = F + jnp.where(idx >= row, delta, 0)
+            if scores_carried:
+                total = total.at[row].set(
+                    w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * r_fit + w_ba * r_ba
+                    + il_term[row])
+            start = jnp.where(active, (start + evaluated) % num, start).astype(jnp.int32)
+            # Results accumulate in the CARRY via a one-hot masked write (the
+            # int32 step counter `t` also rides the carry): lax.scan's own
+            # ys-stacking would index its dynamic_update_slice with the internal
+            # s64 loop counter (x64 mode), which this environment's XLA
+            # miscompiles under GSPMD — compare(s64, s32) after
+            # spmd-partitioning, the ROADMAP open item. The elementwise write
+            # keeps the carry uniformly int32-indexed and is also exact under
+            # vmap (the cells axis), where a batched-index update slice is not.
+            out = jnp.where(jnp.arange(batch_pad, dtype=jnp.int32)[None, :] == t,
+                            jnp.stack([chosen, start])[:, None], out)
 
         new_carry = (req_r, nonzero, pod_count, fit_ok, fit_sc, ba,
                      dns_counts, sa_counts, anti_counts, aff_counts,
@@ -583,6 +604,7 @@ def schedule_batch(
 
 
 @partial(jax.jit, static_argnames=("fit_strategy", "has_nom"))
+@entry_name("patch_carry_rows")
 def patch_carry_rows(
     state: DeviceNodeState,
     f: BatchFeatures,
@@ -656,6 +678,7 @@ def patch_carry_rows_pinned(
 @partial(jax.jit, static_argnames=("batch_pad", "fit_strategy", "vmax",
                                    "has_pns", "has_na_pref",
                                    "port_selfblock", "has_aux"))
+@entry_name("schedule_placements")
 def schedule_placements(
     state: DeviceNodeState,
     f: BatchFeatures,
@@ -725,6 +748,7 @@ def schedule_placements(
 
 
 @partial(jax.jit, static_argnames=("k",))
+@entry_name("dry_run_preemption")
 def dry_run_preemption(
     state: DeviceNodeState,
     f: BatchFeatures,
@@ -833,76 +857,81 @@ def _lap_schedule(state, f, batch_pad, fit_strategy, ext0,
     def body(c):
         (done, req_r, nonzero, pod_count, anti_counts, blocked, aux_cnt,
          start, out) = c
-        # Dense per-lap recompute (no scatters/gathers — TPU scatters
-        # serialize per index, so one-hot masked vector ops win):
-        fit_ok, fit_sc, ba = _resource_eval(
-            f, fit_strategy, state.alloc_r, state.alloc_pods,
-            req_r, nonzero, pod_count,
-            nom_r=f.nom_req if has_nom else None,
-            nom_p=f.nom_pods if has_nom else None)
-        okd = static_ok & fit_ok & (idx < num)
-        if port_selfblock:
-            okd &= ~blocked
-        if has_aux:
-            okd &= aux_cnt + f.aux_inc <= f.aux_room
-        if A1:
-            acnt = jnp.take_along_axis(anti_counts, anti_vid.astype(jnp.int32), axis=1)
-            okd &= ~((anti_vid > 0) & (acnt > 0)).any(axis=0)
-        F = jnp.cumsum(okd.astype(jnp.int32))
-        total = (w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * fit_sc
-                 + w_ba * ba + il_term)
-        total_feas = F[-1]
-        f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
-        rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
-        rot = (idx - start) % num
-        l_full = total_feas // tf
-        L = jnp.clip(jnp.minimum(l_full, n_act - done), 1, LAP_MAX)
-        # window of each feasible row; singleton window 0 when sampling
-        # truncation is inactive (total_feas <= to_find ⇒ all rows rank<=tf)
-        w = jnp.minimum((rank - 1) // tf, LAP_MAX)
-        seg = jnp.where(okd & (w < L), w, LAP_MAX)           # [NP]
-        in_w = seg[None, :] == lanes[:, None]                # [LAP_MAX, NP]
-        # max-score-then-min-rotation packed argmax per window
-        key = total * NP + (jnp.int32(NP - 1) - rot)
-        key_w = jnp.max(jnp.where(in_w, key[None, :], -1), axis=1)
-        has_w = (lanes < L) & (key_w >= 0)
-        rot_w = jnp.int32(NP - 1) - (key_w % NP).astype(jnp.int32)
-        row_w = jnp.where(has_w, (start + rot_w) % num, -1).astype(jnp.int32)
-        # window end boundaries: the row with feasible rank (w+1)*to_find is
-        # the last one examined for window w (numFeasibleNodesToFind cut);
-        # empty ⇒ the window ran to the end of the rotation (evaluated=num).
-        is_b = okd & (rank % tf == 0)
-        seg_b = jnp.where(is_b, jnp.minimum(rank // tf - 1, LAP_MAX), LAP_MAX)
-        in_b = seg_b[None, :] == lanes[:, None]
-        ev_w = jnp.min(jnp.where(in_b, rot[None, :] + 1, num), axis=1)  # [LAP_MAX]
-        # per-pod cumulative start: start_after lane w = boundary of its window
-        start_w = (start + ev_w) % num                        # [LAP_MAX]
-        # ---- apply the L placements (windows are disjoint ⇒ each row gets
-        # at most one pod: a one-hot sum over lanes is an exact update) -----
-        chosen_1h = (idx[None, :] == row_w[:, None]) & has_w[:, None]
-        cnt = chosen_1h.any(axis=0)                           # [NP] bool
-        c64 = cnt.astype(jnp.int64)
-        req_r = req_r + f.request[None, :] * c64[:, None]
-        nonzero = nonzero + f.nz_request[None, :] * c64[:, None]
-        pod_count = pod_count + cnt.astype(jnp.int32)
-        if port_selfblock:
-            blocked |= cnt
-        if has_aux:
-            aux_cnt = aux_cnt + f.aux_inc * cnt.astype(jnp.int32)
-        if A1:
-            # hostname-anti landings: +self at each landed row's own value
-            # (duplicate vids cannot occur — the axis is singleton-per-node).
-            rr = jnp.maximum(row_w, 0)
-            upd = (f.anti_self[:, None] * (anti_vid[:, rr] > 0).astype(jnp.int32)
-                   * has_w[None, :].astype(jnp.int32))        # [A1, LAP_MAX]
-            anti_counts = anti_counts.at[
-                jnp.arange(A1, dtype=jnp.int32)[:, None],
-                anti_vid[:, rr]].add(upd)
-        # ---- emit results (positions >= n_act are sliced off by the host) -
-        chosen_w = jnp.where(has_w, row_w, -1)
-        block = jnp.stack([chosen_w, start_w.astype(jnp.int32)])  # [2, LAP_MAX]
-        out = lax.dynamic_update_slice(out, block, (jnp.int32(0), done))
-        start = start_w[jnp.maximum(L - 1, 0)]
+        with jax.named_scope("resource_fit"):
+            # Dense per-lap recompute (no scatters/gathers — TPU scatters
+            # serialize per index, so one-hot masked vector ops win):
+            fit_ok, fit_sc, ba = _resource_eval(
+                f, fit_strategy, state.alloc_r, state.alloc_pods,
+                req_r, nonzero, pod_count,
+                nom_r=f.nom_req if has_nom else None,
+                nom_p=f.nom_pods if has_nom else None)
+        with jax.named_scope("feasibility"):
+            okd = static_ok & fit_ok & (idx < num)
+            if port_selfblock:
+                okd &= ~blocked
+            if has_aux:
+                okd &= aux_cnt + f.aux_inc <= f.aux_room
+            if A1:
+                acnt = jnp.take_along_axis(anti_counts, anti_vid.astype(jnp.int32), axis=1)
+                okd &= ~((anti_vid > 0) & (acnt > 0)).any(axis=0)
+            F = jnp.cumsum(okd.astype(jnp.int32))
+        with jax.named_scope("score_normalise"):
+            total = (w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * fit_sc
+                     + w_ba * ba + il_term)
+        with jax.named_scope("select"):
+            total_feas = F[-1]
+            f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
+            rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
+            rot = (idx - start) % num
+            l_full = total_feas // tf
+            L = jnp.clip(jnp.minimum(l_full, n_act - done), 1, LAP_MAX)
+            # window of each feasible row; singleton window 0 when sampling
+            # truncation is inactive (total_feas <= to_find ⇒ all rows rank<=tf)
+            w = jnp.minimum((rank - 1) // tf, LAP_MAX)
+            seg = jnp.where(okd & (w < L), w, LAP_MAX)           # [NP]
+            in_w = seg[None, :] == lanes[:, None]                # [LAP_MAX, NP]
+            # max-score-then-min-rotation packed argmax per window
+            key = total * NP + (jnp.int32(NP - 1) - rot)
+            key_w = jnp.max(jnp.where(in_w, key[None, :], -1), axis=1)
+            has_w = (lanes < L) & (key_w >= 0)
+            rot_w = jnp.int32(NP - 1) - (key_w % NP).astype(jnp.int32)
+            row_w = jnp.where(has_w, (start + rot_w) % num, -1).astype(jnp.int32)
+            # window end boundaries: the row with feasible rank (w+1)*to_find is
+            # the last one examined for window w (numFeasibleNodesToFind cut);
+            # empty ⇒ the window ran to the end of the rotation (evaluated=num).
+            is_b = okd & (rank % tf == 0)
+            seg_b = jnp.where(is_b, jnp.minimum(rank // tf - 1, LAP_MAX), LAP_MAX)
+            in_b = seg_b[None, :] == lanes[:, None]
+            ev_w = jnp.min(jnp.where(in_b, rot[None, :] + 1, num), axis=1)  # [LAP_MAX]
+            # per-pod cumulative start: start_after lane w = boundary of its window
+            start_w = (start + ev_w) % num                        # [LAP_MAX]
+        with jax.named_scope("carry_update"):
+            # ---- apply the L placements (windows are disjoint ⇒ each row gets
+            # at most one pod: a one-hot sum over lanes is an exact update) -----
+            chosen_1h = (idx[None, :] == row_w[:, None]) & has_w[:, None]
+            cnt = chosen_1h.any(axis=0)                           # [NP] bool
+            c64 = cnt.astype(jnp.int64)
+            req_r = req_r + f.request[None, :] * c64[:, None]
+            nonzero = nonzero + f.nz_request[None, :] * c64[:, None]
+            pod_count = pod_count + cnt.astype(jnp.int32)
+            if port_selfblock:
+                blocked |= cnt
+            if has_aux:
+                aux_cnt = aux_cnt + f.aux_inc * cnt.astype(jnp.int32)
+            if A1:
+                # hostname-anti landings: +self at each landed row's own value
+                # (duplicate vids cannot occur — the axis is singleton-per-node).
+                rr = jnp.maximum(row_w, 0)
+                upd = (f.anti_self[:, None] * (anti_vid[:, rr] > 0).astype(jnp.int32)
+                       * has_w[None, :].astype(jnp.int32))        # [A1, LAP_MAX]
+                anti_counts = anti_counts.at[
+                    jnp.arange(A1, dtype=jnp.int32)[:, None],
+                    anti_vid[:, rr]].add(upd)
+            # ---- emit results (positions >= n_act are sliced off by the host) -
+            chosen_w = jnp.where(has_w, row_w, -1)
+            block = jnp.stack([chosen_w, start_w.astype(jnp.int32)])  # [2, LAP_MAX]
+            out = lax.dynamic_update_slice(out, block, (jnp.int32(0), done))
+            start = start_w[jnp.maximum(L - 1, 0)]
         return (done + L, req_r, nonzero, pod_count, anti_counts, blocked,
                 aux_cnt, start, out)
 

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..ops.device_state import DeviceNodeState
 from ..ops.features import BatchFeatures
 from ..ops.kernel import (LAP_MAX, MAX_NODE_SCORE, ScanCarry, _resource_eval,
+                          entry_name,
                           _static_masks, schedule_batch)
 
 
@@ -391,6 +392,10 @@ class _ShardedLap:
         def chained(state, f, n_active, carry_in):
             return body(state, f, n_active, carry_in)
 
+        # Named under jit_schedule_batch*, where the trace reduction looks
+        # for the scheduling programs.
+        entry_name("schedule_batch_sharded_lap_fresh")(fresh)
+        entry_name("schedule_batch_sharded_lap_chained")(chained)
         self.fresh = jax.jit(jax.shard_map(
             fresh, mesh=mesh,
             in_specs=(state_specs, feat_specs, P()),

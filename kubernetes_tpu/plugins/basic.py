@@ -9,6 +9,7 @@ duck-typed extension-point protocol in kubernetes_tpu/core/framework.py.
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..api.types import (
@@ -150,6 +151,15 @@ class DefaultBinder:
     def __init__(self, handle=None):
         self.handle = handle
 
+    def _posted(self, t0: float) -> None:
+        """A synchronous bind call returned: its round trip as the scheduler
+        sees it, for every pod (the histogram) and as the loop's bind.post
+        stage. Thread-mode binds run on the dispatcher's worker, off the
+        loop's clock: only sampled pods' spans time those."""
+        seconds = time.perf_counter() - t0
+        self.handle.metrics.pod_stage_duration.observe(seconds, "bind.post")
+        self.handle.stages.leaf("bind.post", seconds)
+
     def bind(self, state: CycleState, pod: Pod, node_name: str) -> Status:
         dispatcher = getattr(self.handle, "api_dispatcher", None)
         try:
@@ -158,9 +168,11 @@ class DefaultBinder:
                 # allocation and go straight to the API (this runs once per
                 # scheduled pod on a >10k pods/s path). Counter/error
                 # accounting matches APIDispatcher._execute.
+                t0 = time.perf_counter()
                 try:
                     self.handle.clientset.bind(pod, node_name)
                 except Exception as e:  # noqa: BLE001
+                    self._posted(t0)
                     if getattr(e, "code", None) == 429:
                         # Flow-control shed (core/flowcontrol.py): the bind
                         # never ran. Tagged so the binding cycle requeues
@@ -184,6 +196,7 @@ class DefaultBinder:
                         from ..core.api_dispatcher import CALL_BINDING
                         dispatcher.errors.append(f"{CALL_BINDING}/{pod.uid}: {e!r}")
                     return Status.error(str(e))
+                self._posted(t0)
                 if dispatcher is not None:
                     dispatcher.executed += 1
                 return OK

@@ -295,6 +295,9 @@ def test_metric_name_parity_with_reference():
     # series, docs/RESILIENCE.md; shard-plane series, docs/SHARDING.md).
     assert extra <= {"scheduler_batch_size",
                      "scheduler_e2e_scheduling_duration_seconds",
+                     "scheduler_pod_stage_duration_seconds",
+                     "scheduler_loop_stage_seconds_total",
+                     "scheduler_loop_stages_total",
                      "scheduler_podgroup_generated_placements",
                      "scheduler_async_api_call_retries_total",
                      "scheduler_device_path_fallback_total",

@@ -2,15 +2,17 @@
 deterministic head sampling, ring-buffer wraparound, cross-process trace
 context propagation over the real apiserver wire (bind POST → WAL → BOUND
 event → foreign observer span), the crash-safe flight recorder (SIGUSR2 +
-real two-OS-process artifacts), StepTrace slow-step span events, the
-/debug/events read surface, and the trace analyzer CLI's golden output on
-a recorded fixture trace."""
+real two-OS-process artifacts), the loop's stage ledger (self times, the
+counters as views of it, the slow-stage rule, `sched.*` spans in a profiler
+trace), the /debug/events read surface, and the trace analyzer CLI's golden
+output on a recorded fixture trace. No timing is asserted."""
 
 import io
 import json
 import logging
 import os
 import signal
+import subprocess
 import sys
 import time
 
@@ -191,6 +193,169 @@ class TestSchedulerSpans:
 
 
 # ---------------------------------------------------------------------------
+# the loop's stage ledger
+# ---------------------------------------------------------------------------
+
+
+class TestStageLedger:
+    def test_self_times_of_a_nest_sum_to_the_roots_duration(self):
+        """Self time = duration minus what the children cover, so the self
+        times of a whole nest add up to the root's duration exactly —
+        whatever the clock did (no timing is asserted)."""
+        ledger = spans.StageLedger(SpanRecorder(sample_n=1, proc="t"))
+        t0 = time.perf_counter()
+        with ledger.stage("cycle"):
+            with ledger.stage("queue.pop"):
+                sum(range(2000))
+            with ledger.stage("plan.build", kind="full"):
+                with ledger.stage("plan.patch"):
+                    sum(range(2000))
+            with ledger.stage("host.commit"):
+                ledger.leaf("bind.post", 1e-4)  # a child timed by its caller
+                with ledger.stage("host.commit", annotate=False):
+                    pass                        # a stage nested in itself
+        wall = time.perf_counter() - t0
+        name, _ts, duration, self_s, parts = ledger.recent[-1]
+        assert name == "cycle" and 0 < duration <= wall
+        assert sum(ledger.seconds.values()) == pytest.approx(duration,
+                                                             abs=1e-9)
+        assert self_s + sum(parts.values()) == pytest.approx(duration,
+                                                             abs=1e-9)
+        assert set(parts) == {"queue.pop", "plan.build", "plan.patch",
+                              "host.commit", "bind.post"}
+        assert ledger.counts["host.commit"] == 2
+        assert ledger.counts["cycle"] == ledger.counts["bind.post"] == 1
+        assert ledger.seconds["bind.post"] == 1e-4
+        assert not ledger._stack
+        report = ledger.report()
+        assert "plan.build" in report and "open now: -" in report
+
+    def test_an_exception_closes_the_stage_it_left(self):
+        ledger = spans.StageLedger(SpanRecorder(sample_n=1, proc="t"))
+        with pytest.raises(KeyError):
+            with ledger.stage("cycle"):
+                with ledger.stage("plan.build"):
+                    raise KeyError("boom")
+        assert not ledger._stack
+        assert ledger.counts["cycle"] == ledger.counts["plan.build"] == 1
+
+    def test_counters_are_views_of_the_table(self, tracer):
+        """plan_build_s / device_wait_s / host_commit_s are computed from
+        the table, /metrics publishes the same table, and every pod — not
+        only the sampled — feeds the per-pod stage histogram."""
+        from kubernetes_tpu.models import TPUScheduler
+
+        cs = FakeClientset()
+        s = TPUScheduler(clientset=cs)
+        for i in range(8):
+            cs.create_node(_node(f"n{i}", cpu="32"))
+        proto = _pod("proto", cpu="100m")
+        for i in range(32):
+            cs.create_pod(proto.clone_from_template(f"p{i}"))
+        s.run_until_idle()
+        assert s.device_scheduled == 32
+        sec, cnt = s.stages.seconds, s.stages.counts
+        assert s.plan_build_s == sec["plan.build"] > 0
+        assert s.device_wait_s == sec["device.wait"] > 0
+        assert s.host_commit_s == sec["host.commit"] + sec["bind.post"] > 0
+        assert cnt["bind.post"] == 32 and cnt["plan.build"] == 1
+        assert cnt["device.dispatch"] == cnt["device.wait"] == s.device_batches
+        assert cnt["cycle"] >= 1 and cnt["queue.pop"] >= 1
+        h = s.metrics.pod_stage_duration
+        assert h.count("queue.wait") == h.count("bind.post") == 32
+        text = s.expose_metrics()
+        assert (f'scheduler_loop_stages_total{{stage="device.wait"}} '
+                f'{float(s.device_batches)}') in text
+        assert (f'scheduler_loop_stage_seconds_total{{stage="plan.build"}} '
+                f'{s.plan_build_s}') in text
+        assert "scheduler_gc_pause_seconds_total" not in text  # mains only
+
+    def test_unsampled_batch_copies_no_span_and_looks_nothing_up(self):
+        """A batch's sampled members are found once, at collection: with no
+        sampled member the batch stages leave nothing in the ring."""
+        from kubernetes_tpu.models import TPUScheduler
+
+        prev = spans.default_tracer()
+        t = SpanRecorder(sample_n=1 << 60, proc="test")  # nobody sampled
+        spans.set_default_tracer(t)
+        try:
+            cs = FakeClientset()
+            s = TPUScheduler(clientset=cs)
+            for i in range(4):
+                cs.create_node(_node(f"n{i}", cpu="32"))
+            proto = _pod("proto", cpu="100m")
+            for i in range(16):
+                cs.create_pod(proto.clone_from_template(f"p{i}"))
+            s.run_until_idle()
+            assert s.device_scheduled == 16
+            assert not [r for r in t.snapshot()
+                        if r["name"] != "trace.slow_stage"]
+            assert s.metrics.pod_stage_duration.count("queue.wait") == 16
+        finally:
+            spans.set_default_tracer(prev)
+
+    def test_gc_clock_counts_collections_by_generation(self):
+        import gc
+        clock = spans.GcClock().install()
+        try:
+            gc.collect()
+        finally:
+            clock.close()
+        assert clock.collections[2] >= 1 and clock.seconds[2] >= 0.0
+        lines = clock.expose("scheduler")
+        assert 'scheduler_gc_collections_total{generation="2"} ' \
+            f'{float(clock.collections[2])}' in lines
+        assert any(line.startswith(
+            'scheduler_gc_pause_seconds_total{generation="0"}')
+            for line in lines)
+        before = list(clock.collections)
+        gc.collect()
+        assert clock.collections == before  # closed: no longer listening
+
+    def test_rehearsal_trace_holds_the_programs_spans_inside_the_wave(self):
+        """A profiler trace of a cell shows the program's own `sched.*`
+        spans on the timeline of the device operations, nested inside the
+        benchmark's `bench.wave` (CPU rehearsal of a traced run)."""
+        bench = os.path.join(REPO, "benchmark")
+        env = {k: v for k, v in os.environ.items()
+               if k not in ("BENCH_RUN", "XLA_FLAGS")}
+        proc = subprocess.run(
+            [sys.executable, os.path.join(bench, "run.py"), "--workload",
+             "spread-5k.waves", "--seed", "2147483777", "--seconds", "0.5",
+             "--trace", "1", "--rehearse", "--keep-out"],
+            cwd=REPO, env=env, capture_output=True, text=True, timeout=600)
+        assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
+        import re
+        import shutil
+        kept = re.findall(r"kept (\S+)", proc.stdout)
+        assert len(kept) == 1
+        try:
+            if bench not in sys.path:
+                sys.path.insert(0, bench)
+            import progspans
+            import tracereduce
+            events = progspans.host_events(
+                tracereduce.newest_xplane(os.path.join(kept[0], "trace")))
+        finally:
+            shutil.rmtree(kept[0])
+        waves = [(s0, s0 + d) for n, s0, d in events if n == "bench.wave"]
+        assert waves
+
+        def inside(name, outer):
+            return [(s0, s0 + d) for n, s0, d in events if n == name
+                    and any(a <= s0 and s0 + d <= b for a, b in outer)]
+
+        cycles = inside("sched.cycle", waves)
+        assert cycles
+        for stage in ("sched.plan.build", "sched.device.dispatch",
+                      "sched.device.wait", "sched.host.commit",
+                      "sched.queue.pop", "sched.plan.adopt"):
+            assert inside(stage, cycles), stage
+        line = json.loads(proc.stdout.strip().splitlines()[-1])
+        assert 0 <= line["metrics"]["loop_unnamed_share"]["value"] <= 100
+
+
+# ---------------------------------------------------------------------------
 # cross-process propagation over the real wire
 # ---------------------------------------------------------------------------
 
@@ -353,46 +518,97 @@ class TestFlightRecorder:
         finally:
             fr.close()
 
-    def test_rate_limited_request_dump_and_slow_step_trigger(
-            self, tracer, tmp_path, caplog):
-        from kubernetes_tpu.core.tracing import StepTrace
-
+    def test_rate_limited_request_dump_and_slow_stage_trigger(
+            self, tracer, tmp_path, caplog, monkeypatch):
+        """The one slow-stage rule on the host path: a stage over its
+        threshold is logged by name, leaves a forced span on the pod's
+        trace and dumps the flight recorder (which then rate-limits)."""
+        monkeypatch.setattr(spans, "SLOW_STAGE_S", -1.0)  # all stages slow
         fr = FlightRecorder(str(tmp_path), tracer=tracer).install(
             sigusr2=False, on_crash=False)
         try:
-            tr = StepTrace("Scheduling", ctx=tracer.context_for("slowpod"),
-                           pod="default/slowpod")
-            tr.t0 -= 0.5
-            tr._last = tr.t0
-            tr.step("plan build")
-            tr.step("fast tail")
+            cs = FakeClientset()
+            s = Scheduler(clientset=cs, deterministic_ties=True)
+            fr.scheduler = s
+            cs.create_node(_node("n0"))
+            pod = _pod("slowpod")
+            cs.create_pod(pod)
             with caplog.at_level(logging.WARNING, logger="kubernetes_tpu"):
-                tr.log_if_long()
-            # offending step named explicitly (utiltrace stepThreshold)
-            assert any("slow step(s) over" in r.getMessage()
-                       and "plan build" in r.getMessage()
+                s.run_until_idle()
+            # the offending stage named explicitly, with the pod
+            assert any("slow scheduling stage: host.commit" in r.getMessage()
+                       and "pod=default/slowpod" in r.getMessage()
                        for r in caplog.records)
-            # a span event per offending step, on the pod's trace
+            # a forced span for it, on the pod's trace
             slow = [r for r in tracer.snapshot()
-                    if r["name"] == "trace.slow_step"]
-            assert slow and slow[0]["attrs"]["step"] == "plan build"
-            assert slow[0]["trace"] == trace_id_for("slowpod")
-            # the breach dumped the flight recorder (then rate-limits)
+                    if r["name"] == "trace.slow_stage"
+                    and r["attrs"]["stage"] == "host.commit"]
+            assert slow and slow[0]["trace"] == trace_id_for(pod.uid)
+            assert slow[0]["attrs"]["pod"] == "default/slowpod"
+            assert {"self_ms", "inflight", "compiles"} <= set(slow[0]["attrs"])
+            # the breach dumped the flight recorder (then rate-limits), and
+            # the dump carries the stage table
             assert fr.dumps == 1
             assert fr.dump("again", rate_limited=True) is None
+            rows = [json.loads(line) for line in
+                    open(fr.path).read().splitlines()]
+            table = next(r for r in rows if r["kind"] == "stages")
+            assert set(table["seconds"]) == set(spans.LOOP_STAGES)
         finally:
             fr.close()
 
-    def test_individual_slow_step_without_pod_ctx_uses_proc_ctx(self, tracer):
-        from kubernetes_tpu.core.tracing import StepTrace
+    def test_slow_stage_without_pod_ctx_uses_proc_ctx(
+            self, tracer, monkeypatch):
+        """A slow stage that belongs to no sampled pod (a loop turn, a
+        batch with no sampled member) reports on the process's trace."""
+        monkeypatch.setattr(spans, "SLOW_STAGE_S", -1.0)
+        monkeypatch.setattr(spans, "SLOW_BATCH_STAGE_S", -1.0)
+        ledger = spans.StageLedger(tracer)
+        with ledger.stage("cycle"):
+            ledger.leaf("queue.pop", 0.0)
+        slow = {r["attrs"]["stage"]: r for r in tracer.snapshot()
+                if r["name"] == "trace.slow_stage"}
+        assert set(slow) == {"cycle", "queue.pop"}
+        assert all(r["trace"] == tracer.proc_ctx().trace_id
+                   for r in slow.values())
 
-        tr = StepTrace("Scheduling", pod="default/anon")
-        tr.t0 -= 0.3
-        tr._last = tr.t0
-        tr.step("everything")
-        tr.log_if_long()
-        slow = [r for r in tracer.snapshot() if r["name"] == "trace.slow_step"]
-        assert slow and slow[0]["trace"] == tracer.proc_ctx().trace_id
+    def test_slow_stage_in_a_device_session(self, tracer, tmp_path,
+                                            monkeypatch):
+        """The rule covers device sessions (where the host path's old
+        StepTrace never looked): a slow batch stage leaves a forced span
+        with the batch size, the plan kind, the pipeline depth and whether
+        a compile ran inside it — and a dump."""
+        from kubernetes_tpu.models import TPUScheduler
+
+        monkeypatch.setattr(spans, "SLOW_BATCH_STAGE_S", -1.0)
+        fr = FlightRecorder(str(tmp_path), tracer=tracer).install(
+            sigusr2=False, on_crash=False)
+        try:
+            cs = FakeClientset()
+            s = TPUScheduler(clientset=cs)
+            for i in range(8):
+                cs.create_node(_node(f"n{i}", cpu="32"))
+            proto = _pod("proto", cpu="100m")
+            for i in range(32):
+                cs.create_pod(proto.clone_from_template(f"p{i}"))
+            s.run_until_idle()
+            assert s.device_batches > 0
+            slow = {}
+            for r in tracer.snapshot():
+                if r["name"] == "trace.slow_stage":
+                    slow.setdefault(r["attrs"]["stage"], r)
+            assert {"plan.build", "device.dispatch", "device.wait",
+                    "host.commit", "plan.adopt"} <= set(slow)
+            assert slow["plan.build"]["attrs"]["kind"] == "full"
+            assert slow["device.wait"]["attrs"]["batch"] == "32"
+            assert slow["device.wait"]["attrs"]["inflight"] == 0
+            assert slow["device.dispatch"]["attrs"]["compiles"] >= 0
+            # every pod is sampled here: the batch stages report on the
+            # first member's trace, not on the process's
+            assert slow["device.wait"]["trace"] != tracer.proc_ctx().trace_id
+            assert fr.dumps >= 1
+        finally:
+            fr.close()
 
     def test_autodump_timer_leaves_periodic_artifacts(self, tracer, tmp_path):
         fr = FlightRecorder(str(tmp_path), tracer=tracer).install(

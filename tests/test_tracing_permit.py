@@ -1,12 +1,11 @@
-"""Timer-driven Permit WAIT expiry (runtime/framework.go:2097), slow-step
-tracing (schedule_one.go:574), and the event recorder (schedule_one.go:1138)."""
+"""Timer-driven Permit WAIT expiry (runtime/framework.go:2097), the slow-stage
+log (schedule_one.go:574), and the event recorder (schedule_one.go:1138)."""
 
 import logging
 
-from kubernetes_tpu.core import FakeClientset, Scheduler
+from kubernetes_tpu.core import FakeClientset, Scheduler, spans
 from kubernetes_tpu.core.framework import OK, Status, WAIT
 from kubernetes_tpu.core.registry import DEFAULT_PLUGINS, build_framework
-from kubernetes_tpu.core.tracing import StepTrace
 from kubernetes_tpu.testing.wrappers import make_node, make_pod
 
 
@@ -71,13 +70,18 @@ def test_scheduled_events_recorded():
     assert any(e.reason == "Scheduled" and "n0" in e.message for e in evs)
 
 
-def test_slow_step_trace_logs(caplog):
-    tr = StepTrace("Scheduling", pod="default/slow")
-    tr.t0 -= 0.5  # pretend the cycle took 500ms
-    tr._last = tr.t0
-    tr.step("scheduling cycle done")
+def test_slow_stage_logs(caplog, monkeypatch):
+    """The slow-stage rule on the host path: a cycle over its threshold is
+    logged with the pod it was scheduling (utiltrace's slow-step log)."""
+    monkeypatch.setattr(spans, "SLOW_STAGE_S", -1.0)  # every stage is slow
+    cs = FakeClientset()
+    s = Scheduler(clientset=cs, deterministic_ties=True)
+    cs.create_node(make_node().name("n0").capacity(
+        {"cpu": 8, "memory": "32Gi", "pods": 110}).obj())
+    cs.create_pod(make_pod().name("slow").req({"cpu": "1"}).obj())
     with caplog.at_level(logging.WARNING, logger="kubernetes_tpu"):
-        total = tr.log_if_long()
-    assert total > 0.4
-    assert any("slow scheduling step" in r.message for r in caplog.records)
-    assert any("default/slow" in r.getMessage() for r in caplog.records)
+        s.run_until_idle()
+    slow = [r.getMessage() for r in caplog.records
+            if "slow scheduling stage: host.commit" in r.getMessage()]
+    assert slow and "pod=default/slow" in slow[0] and "total=" in slow[0]
+    assert s.stages.seconds["host.commit"] > 0
