@@ -24,7 +24,9 @@ plans where the kernel itself proves the total score row-local
 PreferNoSchedule counts) and feasibility row-local (``incremental_feas``
 with no anti/affinity axes at all — ``BatchPlan.pod_local``), so the walk
 is the kernel's scan step with the dead lanes removed: same int64 fit/BA
-arithmetic (ops/kernel.py _resource_eval), same adaptive-sampling
+integers (ops/kernel.py _resource_eval reaches each quotient by bounded
+compare-subtract steps, this walk by numpy's ``//``: both are the exact
+floor, tests/test_kernel_division.py), same adaptive-sampling
 truncation and rotation (schedule_one.go:779-892 emulation), same
 max-score-then-min-rotation packed selection.
 
@@ -338,7 +340,8 @@ class HintEntry:
         self.pod_count[row] += 1
         self._reval_row(row)
 
-    # -- row re-evaluation (ops/kernel.py _resource_eval, one row) ----------
+    # -- row re-evaluation (ops/kernel.py _resource_eval, one row; on the
+    # host a real int64 `//` is one instruction, so it stays `//`) ----------
 
     def _reval_row(self, row: int) -> None:
         alloc = self.alloc_r[row]

@@ -21,6 +21,12 @@ the scan body is written to MINIMIZE DEPENDENT STAGES, not op count —
 - all windowed normalization min/max reductions collapse into ONE stacked
   [k, NP] max-reduction (mins ride as negated lanes), and selection is a
   second single reduction over a packed (score, rotation) key;
+- no general integer `//` or `%` runs inside a scan or lap step (XLA:TPU
+  expands an int64 one into a 64-step long division, ~1,900 scalar
+  instructions): every quotient has a bound (a score 0..100, a fraction
+  below 10^6, a window index up to LAP_MAX) and `_bounded_divmod` reaches it
+  exactly in that many compare-subtract steps; the rotation wraps are one
+  compare each and the selection key unpacks with a mask;
 - batches whose score vector cannot change except at the landed row carry
   the total score; batches with no cross-window coupling at all take the
   lap-vectorized path (_lap_schedule) which places L pods per iteration.
@@ -73,7 +79,7 @@ from .codebook import (
     OP_EXISTS,
 )
 from .device_state import DeviceNodeState
-from .features import BatchFeatures
+from .features import BatchFeatures, _pow2
 
 MAX_NODE_SCORE = 100
 _BIG = jnp.int32(1 << 30)
@@ -162,11 +168,56 @@ def _static_masks(state: DeviceNodeState, f: BatchFeatures):
     return taint_ok, pns_cnt, sel_ok, name_ok, unsched_ok, exist_anti_ok
 
 
+# Bits of a quotient that is a node score (0..MAX_NODE_SCORE) and of one that
+# is a fraction in millionths (below SCALE): facts of the arithmetic, not of
+# a configuration.
+_SCORE_BITS = 7
+_SCALE = 1_000_000
+_SCALE_BITS = 20
+
+
+def _bounded_divmod(n, d, bits: int):
+    """Exact (n // d, n % d) for n >= 0, d >= 1 and a quotient below
+    2**bits, as `bits` compare-subtract steps. XLA:TPU expands a general
+    int64 `//` into 64 such steps (~1,900 instructions for one scalar
+    division); every quotient the kernels need has a far smaller bound.
+
+    Total: a quotient at or over the bound saturates at 2**bits - 1 (the
+    remainder is then >= d), n < 0 gives (0, n). Rows outside the kept set
+    carry such inputs and are masked by the caller. `d << (bits - 1)` must
+    fit the dtype: memory bytes up to 2**41 at 20 bits do."""
+    q = jnp.int32(0)
+    r = n
+    for k in range(bits - 1, -1, -1):
+        less = r - (d << k)
+        ge = less >= 0
+        r = jnp.where(ge, less, r)
+        q = (q << 1) | ge.astype(jnp.int32)
+    return q.astype(n.dtype), r
+
+
+def _bounded_div(n, d, bits: int):
+    return _bounded_divmod(n, d, bits)[0]
+
+
+def _wrap(x, num):
+    """x % num for 0 <= x < 2 * num."""
+    return jnp.where(x >= num, x - num, x)
+
+
+def _unwrap(x, num):
+    """x % num for -num <= x < num."""
+    return jnp.where(x < 0, x + num, x)
+
+
 def _normalize_default_reverse(raw, mx):
     """default_normalize_score(max=100, reverse=True); mx precomputed over
     the kept set (one lane of the step's batched reduction)."""
-    return jnp.where(mx > 0, MAX_NODE_SCORE - MAX_NODE_SCORE * raw // mx,
-                     jnp.int64(MAX_NODE_SCORE))
+    return jnp.where(
+        mx > 0,
+        MAX_NODE_SCORE - _bounded_div(MAX_NODE_SCORE * raw, jnp.maximum(mx, 1),
+                                      _SCORE_BITS),
+        jnp.int64(MAX_NODE_SCORE))
 
 
 def _resource_eval(f: BatchFeatures, fit_strategy: int,
@@ -199,22 +250,31 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
                          jnp.where(slot == 1, used1,
                                    jnp.take(req_r, slot, axis=-1) + f.request[slot]))
         if fit_strategy == 0:  # LeastAllocated
-            rscore = jnp.where((alloc > 0) & (used <= alloc),
-                               (alloc - used) * MAX_NODE_SCORE // jnp.maximum(alloc, 1), 0)
+            part = jnp.where((alloc > 0) & (used <= alloc), alloc - used, 0)
         else:  # MostAllocated
-            rscore = jnp.where(alloc > 0,
-                               jnp.minimum(used, alloc) * MAX_NODE_SCORE // jnp.maximum(alloc, 1), 0)
+            part = jnp.where(alloc > 0, jnp.minimum(used, alloc), 0)
+        rscore = _bounded_div(part * MAX_NODE_SCORE, jnp.maximum(alloc, 1),
+                              _SCORE_BITS)
         fit_num = fit_num + jnp.where(alloc > 0, rscore * w, 0)
         fit_den = fit_den + jnp.where(alloc > 0, w, 0)
-    fit_sc = jnp.where(fit_den > 0, fit_num // jnp.maximum(fit_den, 1), 0)
-    SCALE = jnp.int64(1_000_000)
+    fit_sc = jnp.where(fit_den > 0,
+                       _bounded_div(fit_num, jnp.maximum(fit_den, 1), _SCORE_BITS), 0)
+    SCALE = jnp.int64(_SCALE)
+
+    def millionths(used, alloc):
+        # min(used * SCALE // alloc, SCALE): the min IS `used >= alloc`,
+        # and below it the quotient is under SCALE.
+        return jnp.where(used >= alloc, SCALE,
+                         _bounded_div(used * SCALE, jnp.maximum(alloc, 1), _SCALE_BITS))
+
     a_cpu = alloc_r[..., 0]
     a_mem = alloc_r[..., 1]
-    q_cpu = jnp.minimum(used0 * SCALE // jnp.maximum(a_cpu, 1), SCALE)
-    q_mem = jnp.minimum(used1 * SCALE // jnp.maximum(a_mem, 1), SCALE)
+    q_cpu = millionths(used0, a_cpu)
+    q_mem = millionths(used1, a_mem)
     both = (a_cpu > 0) & (a_mem > 0)
     ba_val = jnp.where(both,
-                       (MAX_NODE_SCORE * SCALE - 50 * jnp.abs(q_cpu - q_mem)) // SCALE,
+                       _bounded_div(MAX_NODE_SCORE * SCALE - 50 * jnp.abs(q_cpu - q_mem),
+                                    SCALE, _SCORE_BITS),
                        jnp.int64(MAX_NODE_SCORE))
     ba = jnp.where(f.ba_skip == 1, 0, ba_val)
     return fit_ok, fit_sc, ba
@@ -267,6 +327,9 @@ def schedule_batch(
     KD = f.ipa_axis.shape[0]
     idx = jnp.arange(NP, dtype=jnp.int32)
     num = jnp.maximum(f.num_nodes, 1)
+    # Radix of the packed (score, rotation) selection key: a power of two,
+    # so the rotation comes back out with a mask.
+    RADIX = _pow2(NP)
 
     # Feasibility can change only at the landed row when no cross-window
     # topology filter is active — DNS skew and required-affinity counts
@@ -377,7 +440,7 @@ def schedule_batch(
             f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
             rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
             kept = okd & (rank <= f.to_find)
-            rot_of_row = (idx - start) % num                   # row -> rotation pos
+            rot_of_row = _unwrap(idx - start, num)             # row -> rotation pos
 
         with jax.named_scope("score_normalise"):
             # ---- reductions: everything as stacked maxes (mins ride negated) --
@@ -388,7 +451,7 @@ def schedule_batch(
                 # total is already known: boundary + packed selection key
                 # (max-score-then-min-rotation; scores non-negative) collapse
                 # into ONE reduction round.
-                key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
+                key = total * RADIX + (jnp.int32(RADIX - 1) - rot_of_row)
                 red = jnp.max(jnp.stack(
                     [jnp.where(kept, key, -1), bound_lane]), axis=1)
                 best_key = red[0]
@@ -423,7 +486,8 @@ def schedule_batch(
                     mx, mn = red[li], -red[li + 1]; li += 2
                     norm = jnp.where(
                         mx > 0,
-                        MAX_NODE_SCORE * (mx + jnp.minimum(mn, mx) - raw_sa) // jnp.maximum(mx, 1),
+                        _bounded_div(MAX_NODE_SCORE * (mx + jnp.minimum(mn, mx) - raw_sa),
+                                     jnp.maximum(mx, 1), _SCORE_BITS),
                         jnp.int64(MAX_NODE_SCORE))
                     pts = jnp.where(sa_ignored, 0, norm)
                 else:
@@ -432,7 +496,8 @@ def schedule_batch(
                     mx_i, mn_i = red[li], -red[li + 1]; li += 2
                     diff = mx_i - mn_i
                     ipa = jnp.where(diff > 0,
-                                    MAX_NODE_SCORE * (raw_ipa - mn_i) // jnp.maximum(diff, 1), 0)
+                                    _bounded_div(MAX_NODE_SCORE * (raw_ipa - mn_i),
+                                                 jnp.maximum(diff, 1), _SCORE_BITS), 0)
                 else:
                     ipa = jnp.int64(0)
                 if has_na_pref:
@@ -440,18 +505,19 @@ def schedule_batch(
                     # over the kept set; all-zero raws stay zero.
                     mx_na = red[li]; li += 1
                     na = jnp.where(mx_na > 0,
-                                   MAX_NODE_SCORE * f.na_raw // jnp.maximum(mx_na, 1), 0)
+                                   _bounded_div(MAX_NODE_SCORE * f.na_raw,
+                                                jnp.maximum(mx_na, 1), _SCORE_BITS), 0)
                 else:
                     na = jnp.int64(0)
                 total = (w_tt * tt + w_fit * fit_sc + w_ba * ba + w_pts * pts
                          + w_ipa * ipa + w_na * na + il_term)
                 # second reduction round: packed selection over the fresh scores
-                key = total * NP + (jnp.int32(NP - 1) - rot_of_row)
+                key = total * RADIX + (jnp.int32(RADIX - 1) - rot_of_row)
                 best_key = jnp.max(jnp.where(kept, key, -1))
         with jax.named_scope("select"):
             any_kept = (best_key >= 0) & active
-            chosen_rot = jnp.int32(NP - 1) - (best_key % NP).astype(jnp.int32)
-            chosen = jnp.where(any_kept, (start + chosen_rot) % num, -1).astype(jnp.int32)
+            chosen_rot = jnp.int32(RADIX - 1) - (best_key & (RADIX - 1)).astype(jnp.int32)
+            chosen = jnp.where(any_kept, _wrap(start + chosen_rot, num), -1).astype(jnp.int32)
 
         with jax.named_scope("carry_update"):
             # ---- carry updates (inert when this step is padding) --------------
@@ -525,7 +591,7 @@ def schedule_batch(
                 total = total.at[row].set(
                     w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * r_fit + w_ba * r_ba
                     + il_term[row])
-            start = jnp.where(active, (start + evaluated) % num, start).astype(jnp.int32)
+            start = jnp.where(active, _wrap(start + evaluated, num), start).astype(jnp.int32)
             # Results accumulate in the CARRY via a one-hot masked write (the
             # int32 step counter `t` also rides the carry): lax.scan's own
             # ys-stacking would index its dynamic_update_slice with the internal
@@ -815,10 +881,12 @@ def dry_run_preemption(
 
 
 # Max pods placed per lap iteration (bounds the segment tensors; L_full =
-# total_feasible // to_find never exceeds ~20 for the reference's adaptive
-# percentage formula, schedule_one.go:866, but custom percentageOfNodesToScore
-# can push it higher — excess windows spill to later laps).
+# floor(total_feasible / to_find) never exceeds ~20 for the reference's
+# adaptive percentage formula, schedule_one.go:866, but custom
+# percentageOfNodesToScore can push it higher — the lap's quotients saturate
+# above LAP_MAX and the excess windows spill to later laps).
 LAP_MAX = 32
+_LAP_BITS = LAP_MAX.bit_length()  # a window index saturates above LAP_MAX
 
 
 def _lap_schedule(state, f, batch_pad, fit_strategy, ext0,
@@ -844,6 +912,7 @@ def _lap_schedule(state, f, batch_pad, fit_strategy, ext0,
     too: a landing only blocks its own row, which later windows never
     examine; `anti_counts` is refreshed per lap from the placements."""
     NP = state.valid.shape[0]
+    RADIX = _pow2(NP)  # of the packed selection key, as in schedule_batch
     A1 = anti_vid.shape[0]
     tf = jnp.maximum(f.to_find, 1)
     B = batch_pad
@@ -882,29 +951,34 @@ def _lap_schedule(state, f, batch_pad, fit_strategy, ext0,
             total_feas = F[-1]
             f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
             rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
-            rot = (idx - start) % num
-            l_full = total_feas // tf
+            rot = _unwrap(idx - start, num)
+            l_full = _bounded_div(total_feas, tf, _LAP_BITS)
             L = jnp.clip(jnp.minimum(l_full, n_act - done), 1, LAP_MAX)
             # window of each feasible row; singleton window 0 when sampling
-            # truncation is inactive (total_feas <= to_find ⇒ all rows rank<=tf)
-            w = jnp.minimum((rank - 1) // tf, LAP_MAX)
+            # truncation is inactive (total_feas <= to_find ⇒ all rows rank<=tf).
+            # One division serves the window and its end boundary:
+            # rank = w * tf + rem + 1, so rank % tf == 0 is rem == tf - 1 and
+            # then rank // tf - 1 == w. Windows at or past LAP_MAX saturate
+            # into the dump lane either way.
+            w, rem = _bounded_divmod(rank - 1, tf, _LAP_BITS)
+            w = jnp.minimum(w, LAP_MAX)
             seg = jnp.where(okd & (w < L), w, LAP_MAX)           # [NP]
             in_w = seg[None, :] == lanes[:, None]                # [LAP_MAX, NP]
             # max-score-then-min-rotation packed argmax per window
-            key = total * NP + (jnp.int32(NP - 1) - rot)
+            key = total * RADIX + (jnp.int32(RADIX - 1) - rot)
             key_w = jnp.max(jnp.where(in_w, key[None, :], -1), axis=1)
             has_w = (lanes < L) & (key_w >= 0)
-            rot_w = jnp.int32(NP - 1) - (key_w % NP).astype(jnp.int32)
-            row_w = jnp.where(has_w, (start + rot_w) % num, -1).astype(jnp.int32)
+            rot_w = jnp.int32(RADIX - 1) - (key_w & (RADIX - 1)).astype(jnp.int32)
+            row_w = jnp.where(has_w, _wrap(start + rot_w, num), -1).astype(jnp.int32)
             # window end boundaries: the row with feasible rank (w+1)*to_find is
             # the last one examined for window w (numFeasibleNodesToFind cut);
             # empty ⇒ the window ran to the end of the rotation (evaluated=num).
-            is_b = okd & (rank % tf == 0)
-            seg_b = jnp.where(is_b, jnp.minimum(rank // tf - 1, LAP_MAX), LAP_MAX)
+            is_b = okd & (rem == tf - 1)
+            seg_b = jnp.where(is_b, w, LAP_MAX)
             in_b = seg_b[None, :] == lanes[:, None]
             ev_w = jnp.min(jnp.where(in_b, rot[None, :] + 1, num), axis=1)  # [LAP_MAX]
             # per-pod cumulative start: start_after lane w = boundary of its window
-            start_w = (start + ev_w) % num                        # [LAP_MAX]
+            start_w = _wrap(start + ev_w, num)                    # [LAP_MAX]
         with jax.named_scope("carry_update"):
             # ---- apply the L placements (windows are disjoint ⇒ each row gets
             # at most one pod: a one-hot sum over lanes is an exact update) -----

@@ -28,7 +28,12 @@ two paths against each other on hint-eligible shapes.
 
 Every integer division below runs on non-negative numerators (guards
 mirror `_resource_eval`'s `where` clauses), where numpy's and XLA's
-int64 ``//`` agree exactly.
+int64 ``//`` agree exactly — and agree with the kernel, which since PR 25
+computes the same floor quotients without ``//`` (ops/kernel.py
+`_bounded_divmod`: compare-subtract steps to each quotient's bound,
+because XLA:TPU expands a general int64 ``//`` into 64 of them). The
+forms differ, the integers do not; tests/test_kernel_division.py holds
+the kernel's to Python's ``//``.
 """
 
 from __future__ import annotations

@@ -29,10 +29,11 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.device_state import DeviceNodeState
-from ..ops.features import BatchFeatures
-from ..ops.kernel import (LAP_MAX, MAX_NODE_SCORE, ScanCarry, _resource_eval,
-                          entry_name,
-                          _static_masks, schedule_batch)
+from ..ops.features import BatchFeatures, _pow2
+from ..ops.kernel import (LAP_MAX, MAX_NODE_SCORE, ScanCarry, _LAP_BITS,
+                          _bounded_div, _bounded_divmod,
+                          _resource_eval, _static_masks, _unwrap, _wrap,
+                          entry_name, schedule_batch)
 
 
 def make_mesh(
@@ -250,7 +251,8 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
     ~2× the collectives per step because it cannot prove the carried
     per-node lanes stay shard-local (MULTICHIP_r05 baseline)."""
     NPl = state.valid.shape[0]
-    NP = NPl * n_shards
+    RADIX = _pow2(NPl * n_shards)  # of the packed selection key
+    SHARD_BITS = max(n_shards - 1, 1).bit_length()
     B = batch_pad
     names = tuple(n for n, _s in axis_sizes)
     gather_axis = names if len(names) > 1 else names[0]
@@ -297,23 +299,27 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
         tots = g[:, 0]                                       # [S]
         total_feas = tots.sum()
         F = Fl + jnp.where(svec < shard, tots, 0).sum()      # global prefix
-        owner = jnp.clip(sidx // jnp.int32(NPl), 0, n_shards - 1)
+        owner = jnp.clip(_bounded_div(sidx, jnp.int32(NPl), SHARD_BITS),
+                         0, n_shards - 1)
         f_start = jnp.where(
             start > 0,
             jnp.where(svec < owner, tots, 0).sum() + g[owner, 1], 0)
         rank = jnp.where(gidx >= start, F - f_start,
                          F + total_feas - f_start)
-        rot = (gidx - start) % num
-        l_full = total_feas // tf
+        rot = _unwrap(gidx - start, num)
+        l_full = _bounded_div(total_feas, tf, _LAP_BITS)
         L = jnp.clip(jnp.minimum(l_full, n_act - done),
                      1, LAP_MAX).astype(jnp.int32)
-        w = jnp.minimum((rank - 1) // tf, LAP_MAX)
+        # one division for the window and its end boundary, as in
+        # ops/kernel.py _lap_schedule
+        w, rem = _bounded_divmod(rank - 1, tf, _LAP_BITS)
+        w = jnp.minimum(w, LAP_MAX)
         seg = jnp.where(okd & (w < L), w, LAP_MAX)
         in_w = seg[None, :] == lanes[:, None]                # [LAP_MAX, NPl]
-        key = total * NP + (jnp.int32(NP - 1) - rot)
+        key = total * RADIX + (jnp.int32(RADIX - 1) - rot)
         key_w_l = jnp.max(jnp.where(in_w, key[None, :], -1), axis=1)
-        is_b = okd & (rank % tf == 0)
-        seg_b = jnp.where(is_b, jnp.minimum(rank // tf - 1, LAP_MAX), LAP_MAX)
+        is_b = okd & (rem == tf - 1)
+        seg_b = jnp.where(is_b, w, LAP_MAX)
         in_b = seg_b[None, :] == lanes[:, None]
         ev_w_l = jnp.min(jnp.where(in_b, rot[None, :] + 1, num), axis=1)
         # ---- collective 2: packed per-window reduction (mins negated) ----
@@ -322,9 +328,9 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
         key_w = red[:LAP_MAX]
         ev_w = (-red[LAP_MAX:]).astype(jnp.int32)
         has_w = (lanes < L) & (key_w >= 0)
-        rot_w = jnp.int32(NP - 1) - (key_w % NP).astype(jnp.int32)
-        row_w = jnp.where(has_w, (start + rot_w) % num, -1).astype(jnp.int32)
-        start_w = (start + ev_w) % num
+        rot_w = jnp.int32(RADIX - 1) - (key_w & (RADIX - 1)).astype(jnp.int32)
+        row_w = jnp.where(has_w, _wrap(start + rot_w, num), -1).astype(jnp.int32)
+        start_w = _wrap(start + ev_w, num)
         # ---- apply the landings: shard-local one-hot updates -------------
         chosen_1h = (gidx[None, :] == row_w[:, None]) & has_w[:, None]
         cnt = chosen_1h.any(axis=0)

@@ -1,0 +1,153 @@
+"""Compile-only guards for the v5e (on-chip-measurement guide §2): the
+scheduling kernels' loop bodies, compiled in the sandbox by the TPU's own
+compiler for a chip that is described and not attached. Counts of optimized-HLO
+instructions, never times. XLA:TPU expands a general int64 `//` into a 64-step
+long division (~1,900 scalar instructions each); ops/kernel.py's
+`_bounded_divmod` is what keeps that out of the loops, and these tests are what
+keeps it so.
+
+All of it in this one file, behind one module-scoped fixture: only one process
+may hold the TPU's library."""
+
+import collections
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import SingleDeviceSharding
+
+# Ceilings, with what was counted when they were set (PR 25; the parent of
+# that PR in brackets).
+RESOURCE_EVAL_CEILING = 2_500   # 1,766 LeastAllocated, 1,758 Most [11,493]
+SCAN_BODY_CEILING = 3_000       # 2,095 [13,575]
+LAP_BODY_CEILING = 3_500        # 2,385 [21,147]
+
+_INSTRUCTION = re.compile(r"\s+(ROOT )?%?[\w.\-]+ = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # whatever the missing compiler raises
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep these out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _instructions(hlo_text, under=""):
+    return [l for l in hlo_text.splitlines()
+            if _INSTRUCTION.match(l) and under in l]
+
+
+def _primitives(lines):
+    """The JAX primitive each instruction came from (last part of op_name)."""
+    names = (_OP_NAME.search(l) for l in lines)
+    return collections.Counter(m.group(1).rsplit("/", 1)[-1] for m in names if m)
+
+
+@pytest.mark.parametrize("fit_strategy", [0, 1], ids=["LeastAllocated", "MostAllocated"])
+def test_landed_row_resource_eval_stays_small(one_chip, fit_strategy):
+    """The scan's per-step scalar work: `_resource_eval` on the one landed
+    row, inside a loop, as `step` calls it."""
+    from kubernetes_tpu.ops.kernel import _resource_eval
+    from kubernetes_tpu.ops.features import BatchFeatures
+
+    NP, R, STEPS = 8192, 8, 1024
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    fields = dict(request=S((R,), jnp.int64), has_request=S((), jnp.int64),
+                  enable=S((5,), jnp.int32), nz_request=S((2,), jnp.int64),
+                  fit_slots=S((2,), jnp.int32), fit_weights=S((2,), jnp.int64),
+                  ba_skip=S((), jnp.int64))
+    f = BatchFeatures(**{k: fields.get(k) for k in BatchFeatures._fields})
+
+    def loop(f, alloc_r, alloc_pods, req_r, nonzero, pod_count, rows):
+        def body(i, c):
+            req_r, nonzero, pod_count, ok, sc, ba = c
+            row = rows[i]
+            req_r = req_r.at[row].add(f.request)
+            nonzero = nonzero.at[row].add(f.nz_request)
+            pod_count = pod_count.at[row].add(1)
+            with jax.named_scope("resource_fit"):
+                r_ok, r_sc, r_ba = _resource_eval(
+                    f, fit_strategy, alloc_r[row], alloc_pods[row],
+                    req_r[row], nonzero[row], pod_count[row])
+            return (req_r, nonzero, pod_count, ok.at[row].set(r_ok),
+                    sc.at[row].set(r_sc), ba.at[row].set(r_ba))
+        z = jnp.zeros(NP, jnp.int64)
+        return lax.fori_loop(0, STEPS, body,
+                             (req_r, nonzero, pod_count, jnp.zeros(NP, bool), z, z))
+
+    hlo = jax.jit(loop).lower(
+        f, S((NP, R), jnp.int64), S((NP,), jnp.int64), S((NP, R), jnp.int64),
+        S((NP, 2), jnp.int64), S((NP,), jnp.int32), S((STEPS,), jnp.int32),
+    ).compile().as_text()
+    scoped = _instructions(hlo, under="resource_fit")
+    assert 200 < len(scoped) <= RESOURCE_EVAL_CEILING, len(scoped)
+    prims = _primitives(scoped)
+    assert prims["div"] == 0 and prims["rem"] == 0, prims
+
+
+def _small_plan(batch, spread):
+    from kubernetes_tpu.core import FakeClientset
+    from kubernetes_tpu.models import TPUScheduler
+    from kubernetes_tpu.testing.wrappers import make_node, make_pod
+
+    cs = FakeClientset()
+    s = TPUScheduler(clientset=cs, max_batch=batch)
+    for i in range(48):
+        cs.create_node(make_node().name(f"node-{i}").capacity(
+            {"cpu": "32", "memory": "256Gi", "pods": 110}).zone(f"zone-{i % 4}").obj())
+    pod = make_pod().name("probe").req({"cpu": "100m", "memory": "128Mi"}).labels(
+        {"app": "spread"})
+    if spread:
+        pod = pod.spread_constraint(1, "topology.kubernetes.io/zone",
+                                    "DoNotSchedule", {"app": "spread"})
+    return s.build_plan(next(iter(s.profiles.values())), pod.obj(), batch)
+
+
+@pytest.mark.parametrize("kernel,batch,spread,ceiling", [
+    ("scan", 8, True, SCAN_BODY_CEILING),      # spread-5k.waves' program
+    ("lap", 128, False, LAP_BODY_CEILING),     # basic-5k.waves' program
+])
+def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, ceiling):
+    """`schedule_batch` as the two wave cells run it, at a small cluster's
+    shapes (the loop body's scalar code does not depend on them): nothing in
+    the loop comes from a `div` or `rem`, and the body stays under its
+    ceiling."""
+    from kubernetes_tpu.ops.kernel import schedule_batch
+
+    state, plan = _small_plan(batch, spread)
+    assert (plan.batch_pad > 64) == (kernel == "lap")
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    hlo = schedule_batch.lower(
+        jax.tree_util.tree_map(sds, state), jax.tree_util.tree_map(sds, plan.features),
+        plan.batch_pad, plan.fit_strategy, plan.vmax,
+        n_active=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        carry_in=None, has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
+        anti_rowlocal=plan.anti_rowlocal, has_na_pref=plan.has_na_pref,
+        port_selfblock=plan.port_selfblock, has_aux=plan.has_aux,
+        has_nom=plan.has_nom).compile().as_text()
+    body = _instructions(hlo, under="/while/body/")
+    prims = _primitives(body)
+    assert prims["div"] == 0 and prims["rem"] == 0, prims
+    assert 200 < len(body) <= ceiling, len(body)
+    assert any("resource_fit" in l for l in body), "the scope names left the HLO"
