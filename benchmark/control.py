@@ -14,7 +14,11 @@ fractions in millionths) and two guarantees on the order of work. Controls
   with (the sequential carry left out: the guarantee "identical to sequential
   scheduling in creation order" broken);
 - `last_maximum`: ties broken the other way (the guarantee "first maximum in
-  walk order" broken).
+  walk order" broken);
+- `<key>.<name>`: for each pod feature that the configuration's templates use,
+  the controls its own file states (`reference_features/<key>.py`,
+  `CONTROLS`): the feature's state with one guarantee broken, put in its
+  place. Found by name: this file names no feature.
 
 Reading, NOT a control: `float32`, the score terms in float32 and floored
 where the reference floors. On these uniform clusters (equal nodes, equal
@@ -43,6 +47,7 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 
+import features  # noqa: E402
 import objects  # noqa: E402
 import reference  # noqa: E402
 from reference import FRACTION_SCALE, MAX_NODE_SCORE  # noqa: E402
@@ -52,7 +57,7 @@ class Float32Scores(reference.Reference):
     """The same score terms, floored where the reference floors them, with
     every quantity and operation in float32."""
 
-    def scores(self, shape, rows):
+    def resource_scores(self, shape, rows):
         f = np.float32
         a_cpu, a_mem = self.alloc_cpu[rows].astype(f), self.alloc_mem[rows].astype(f)
         u_cpu = (self.nz_cpu[rows] + shape.nz_cpu).astype(f)
@@ -73,7 +78,7 @@ class Int32Scores(reference.Reference):
     taken, in 32-bit integers (wrapping, as the hardware would); a division
     by a capacity that wrapped to 0 gives 0."""
 
-    def scores(self, shape, rows):
+    def resource_scores(self, shape, rows):
         i = np.int32
         with np.errstate(all="ignore"):
             a_cpu = self.alloc_cpu[rows].astype(i)
@@ -104,13 +109,13 @@ class StaleBatch(reference.Reference):
 
     GROUP = 64
 
-    def scores(self, shape, rows):
+    def resource_scores(self, shape, rows):
         if len(self.placed) % self.GROUP == 0 or not hasattr(self, "_frozen"):
             self._frozen = (self.nz_cpu.copy(), self.nz_mem.copy())
         live = self.nz_cpu, self.nz_mem
         self.nz_cpu, self.nz_mem = self._frozen
         try:
-            return super().scores(shape, rows)
+            return super().resource_scores(shape, rows)
         finally:
             self.nz_cpu, self.nz_mem = live
 
@@ -129,6 +134,31 @@ class LastMaximum(reference.Reference):
 CONTROLS = {"int32": Int32Scores, "stale_batch": StaleBatch,
             "last_maximum": LastMaximum}
 READINGS = {"float32": Float32Scores}
+
+
+def _swapped(key: str, broken_state: type) -> type:
+    """The reference with feature `key`'s state replaced by `broken_state`."""
+
+    class Swapped(reference.Reference):
+        def feature_state(self, k, module):
+            if k == key:
+                return broken_state(self)
+            return super().feature_state(k, module)
+
+    return Swapped
+
+
+def feature_controls(cfg: dict) -> dict:
+    """As `<key>.<name>`, the controls of every pod feature that the
+    configuration's templates use."""
+    out = {}
+    keys = {k for group in ("initPods", "measurePods")
+            for k in cfg[group]["template"]} - reference.CORE_POD_KEYS
+    for key in sorted(keys):
+        module = features.load("reference", key)
+        for name, broken in getattr(module, "CONTROLS", {}).items():
+            out[f"{key}.{name}"] = _swapped(key, broken)
+    return out
 
 
 def differing(cfg: dict, seed: int, control: type) -> tuple:
@@ -153,17 +183,22 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     cfg = objects.load_config(
         os.path.join(HERE, "configs", args.config + ".json"), args.rehearse)
-    least = None
+    controls = {**CONTROLS, **feature_controls(cfg)}
+    held = set(controls)          # the controls that differed on every seed
     for seed in args.seeds:
-        for name, control in {**CONTROLS, **READINGS}.items():
+        for name, control in {**controls, **READINGS}.items():
             total, differ = differing(cfg, seed, control)
-            if name in CONTROLS:
-                least = differ if least is None else min(least, differ)
-            print(f"{'control' if name in CONTROLS else 'reading'} {name} "
+            if not differ:
+                held.discard(name)
+            print(f"{'control' if name in controls else 'reading'} {name} "
                   f"config {args.config} seed {seed}: {differ} of {total} "
                   f"placements differ from the reference (limit of the "
                   f"comparison: 0)", flush=True)
-    return 0 if least else 1
+    for name in sorted(set(controls) - held):
+        print(f"control {name} placed every pod of some seed where the "
+              f"reference does: on {args.config} it is a reading, and "
+              f"`correct` does not guard what it breaks", flush=True)
+    return 0 if held == set(controls) else 1
 
 
 if __name__ == "__main__":

@@ -75,7 +75,8 @@ def watcher(args) -> int:
 def sender(args) -> int:
     import objects
     from kubernetes_tpu.core.apiserver import KeepAliveClient, pod_to_wire
-    proto = objects.make_pod_prototype(json.loads(args.template))
+    proto = objects.make_pod_prototype(json.loads(args.template),
+                                       args.bench_dir)
     poster = KeepAliveClient(args.base)
     print("ready", flush=True)
     phase_no = 0
@@ -146,6 +147,8 @@ def main(argv=None) -> int:
     ap.add_argument("--root", required=True)
     ap.add_argument("--base", required=True)
     ap.add_argument("--template", default="{}", help="pod template, JSON")
+    ap.add_argument("--bench-dir", default=None,
+                    help="where pod features are looked for first")
     ap.add_argument("--connections", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)

@@ -105,8 +105,10 @@ def run(ctx) -> dict:
         cs.create_node(objects.make_node(desc))
     log = []                                   # what the reference replays
     placements = {}
-    init_proto = objects.make_pod_prototype(cfg["initPods"]["template"])
-    wave_proto = objects.make_pod_prototype(cfg["measurePods"]["template"])
+    init_proto = objects.make_pod_prototype(
+        cfg["initPods"]["template"], ctx.bench_dir)
+    wave_proto = objects.make_pod_prototype(
+        cfg["measurePods"]["template"], ctx.bench_dir)
     # A cell whose waves never reach the device by design (score hints bind
     # them) is traced from the init pods on, which the kernel places: every
     # traced run then holds device work, and the idle share says how little.

@@ -1,9 +1,14 @@
 """From a configuration file and a seed to what the program is handed.
 
-The only module of the benchmark that builds the program's own object types
-(``kubernetes_tpu.testing`` builders, the apiserver's wire dicts). The plain
-descriptions it starts from are the same ones ``reference.py`` reads, so the
-two sides see the same cluster without sharing any code.
+With the files under ``object_features/``, the only code of the benchmark
+that builds the program's own object types (``kubernetes_tpu.testing``
+builders, the apiserver's wire dicts). The plain descriptions it starts from
+are the same ones ``reference.py`` reads, so the two sides see the same
+cluster without sharing any code. It builds the core's pod keys
+(``reference.CORE_POD_KEYS``) itself and hands every further key to
+``object_features/<key>.py`` (``apply(builder, value, template) -> builder``),
+the builder's half of the pod feature that ``reference_features/<key>.py``
+states; a key without both files is refused (``features.py``).
 
 ``--seed`` changes the order in which the nodes are created (names keep their
 zone): the node tree, every tie-break and the rotating start index then see
@@ -13,13 +18,12 @@ another cluster, while sizes and shapes stay the configuration's.
 from __future__ import annotations
 
 import json
-from typing import List
+from typing import List, Optional
 
 import numpy as np
 
+import features
 import reference
-
-ZONE = reference.ZONE_KEY
 
 
 def load_config(path: str, rehearse: bool) -> dict:
@@ -52,20 +56,24 @@ def make_node(desc: dict):
             .zone(desc["zone"]).obj())
 
 
-def make_pod_prototype(template: dict):
+def make_pod_prototype(template: dict, bench_dir: Optional[str] = None):
     """One pod of the template; stamp the rest with
     ``proto.clone_from_template(name)`` as the program's own perf harness
-    does, so that creating a wave costs the client what it costs there."""
+    does, so that creating a wave costs the client what it costs there.
+    Feature files are looked for under ``bench_dir`` (the run's
+    ``--bench-dir``) ahead of this file's directory."""
     from kubernetes_tpu.testing import make_pod as builder
     b = builder().name("prototype").req(
         {k: template[k] for k in ("cpu", "memory") if k in template})
     for k, v in template.get("labels", {}).items():
         b = b.label(k, v)
-    for c in template.get("topologySpreadConstraints", ()):
-        b = b.spread_constraint(
-            c.get("maxSkew", 1), c.get("topologyKey", ZONE),
-            c.get("whenUnsatisfiable", "DoNotSchedule"),
-            c.get("labelSelector", template.get("labels", {})))
+    for key in template:
+        if key in reference.CORE_POD_KEYS:
+            continue
+        feature = features.load("objects", key, bench_dir)
+        if feature is None:
+            raise reference.Unmodelled(f"pod template key {key!r}")
+        b = feature.apply(b, template[key], template)
     return b.obj()
 
 

@@ -14,20 +14,43 @@ Semantics, per pod (kube-scheduler ``schedule_one.go``, default plugin set):
 - the adaptive sample: walk the order from the rotating start index and stop at
   ``num_feasible_nodes_to_find`` feasible nodes (50 - n/125 percent, at least
   5 %, at least 100 nodes); the start index advances by the nodes walked;
-- feasibility: NodeResourcesFit (cpu, memory, pod count) and the hard
-  PodTopologySpread constraint (``count[zone] + self - min(count) > maxSkew``
-  rejects);
+- feasibility: NodeResourcesFit (cpu, memory, pod count), and every active
+  pod feature's own filter;
 - score: NodeResourcesFit LeastAllocated + NodeResourcesBalancedAllocation,
   weight 1 each, over non-zero requests (100m / 200Mi defaults), in the
-  integer forms the configuration states. TaintToleration (3 x 100),
-  NodeAffinity, InterPodAffinity, ImageLocality and PodTopologySpread's soft
-  score are the same for every node for these pods and cannot move the
-  maximum, so the reference refuses any pod or node that would make them vary
-  instead of modelling them;
+  integer forms the configuration states, plus what an active pod feature
+  adds. TaintToleration (3 x 100), NodeAffinity, InterPodAffinity,
+  ImageLocality and PodTopologySpread's soft score are the same for every
+  node for the core's pods and cannot move the maximum, so a pod or node that
+  would make them vary is refused unless a feature file models it;
 - the first maximum in walk order wins.
 
-A pod or node feature outside this list raises ``Unmodelled``: the reference
-never passes what it does not understand.
+This file is the core: it knows the pod template's ``cpu``, ``memory`` and
+``labels`` and nothing else. Every further key of a pod template is a pod
+feature with a file of its own, ``reference_features/<key>.py`` (found by
+``features.py``: ``Reference``'s ``bench_dir`` first, which is the run's
+``--bench-dir``, beside this file second),
+numpy only, which states its own semantics and refusals and supplies:
+
+- ``parse(value, template) -> terms``: the key's value as the feature's own
+  terms, raising ``Unmodelled`` on every sub-key or value it does not model;
+  kept as ``pod.features[key]`` on the parsed pod (``PodShape``);
+- optionally ``State``, one per ``Reference``, made as ``State(ref)`` when the
+  first template that carries the key is met and then told of every pod
+  already placed. The core calls, for EVERY pod from then on, whether or not
+  it carries the key (a filter like anti-affinity is symmetric):
+  ``feasible(pod) -> bool[n] or None`` (a mask over all rows, ``None`` = no
+  say), ``score(pod, rows) -> int64[len(rows)] or None`` (already normalised
+  and weighted as the default plugin set does it; added to the core's sum
+  before the first maximum is taken), ``account(row, pod, sign)`` (a pod
+  landed on, +1, or left, -1, that row). ``ref`` offers ``n``, ``names``,
+  ``zones``, ``zone_of``, ``n_zones`` and ``placed`` (pod name -> (row, pod));
+- optionally ``CONTROLS``: name -> a ``State`` with one guarantee broken,
+  which ``control.py`` puts in the feature's place and which has to come out
+  as not correct.
+
+A pod key without such a file, and any node key outside the list, raises
+``Unmodelled``: the reference never passes what it does not understand.
 """
 
 from __future__ import annotations
@@ -36,15 +59,14 @@ from typing import Dict, Iterable, List, Optional, Sequence
 
 import numpy as np
 
-ZONE_KEY = "topology.kubernetes.io/zone"
+import features
+
 DEFAULT_MILLI_CPU = 100                 # GetNonzeroRequests
 DEFAULT_MEMORY = 200 * 1024 * 1024
 FRACTION_SCALE = 1_000_000              # BalancedAllocation's integer fractions
 MAX_NODE_SCORE = 100
 NODE_KEYS = {"cpu", "memory", "pods", "zones"}
-POD_KEYS = {"cpu", "memory", "labels", "topologySpreadConstraints"}
-CONSTRAINT_KEYS = {"maxSkew", "topologyKey", "whenUnsatisfiable",
-                   "labelSelector"}
+CORE_POD_KEYS = {"cpu", "memory", "labels"}
 
 _SUFFIX = {"Ki": 1 << 10, "Mi": 1 << 20, "Gi": 1 << 30, "Ti": 1 << 40,
            "k": 10 ** 3, "M": 10 ** 6, "G": 10 ** 9, "T": 10 ** 12}
@@ -98,40 +120,26 @@ def node_descriptions(template: dict, count: int,
              "pods": int(template["pods"])} for i in order]
 
 
-class _PodShape:
-    """One pod template, parsed once."""
+class PodShape:
+    """One pod template, parsed once: the core's keys, and under ``features``
+    each further key's terms as its feature file parsed them."""
 
     def __init__(self, template: dict):
-        unknown = set(template) - POD_KEYS
-        if unknown:
-            raise Unmodelled(f"pod template keys {sorted(unknown)}")
         self.cpu = milli_cpu(template.get("cpu", 0))
         self.memory = quantity(template.get("memory", 0))
         self.labels = dict(template.get("labels", {}))
         self.nz_cpu = self.cpu or DEFAULT_MILLI_CPU
         self.nz_memory = self.memory or DEFAULT_MEMORY
-        self.constraints = []
-        for c in template.get("topologySpreadConstraints", ()):
-            unknown = set(c) - CONSTRAINT_KEYS
-            if unknown:
-                raise Unmodelled(f"spread constraint keys {sorted(unknown)}")
-            if c.get("topologyKey", ZONE_KEY) != ZONE_KEY:
-                raise Unmodelled(f"spread over {c.get('topologyKey')!r}")
-            if c.get("whenUnsatisfiable", "DoNotSchedule") != "DoNotSchedule":
-                raise Unmodelled("soft spread constraints change the score")
-            selector = dict(c.get("labelSelector", self.labels))
-            self.constraints.append(
-                (int(c.get("maxSkew", 1)), tuple(sorted(selector.items()))))
-
-
-def _matches(selector: tuple, labels: dict) -> bool:
-    return all(labels.get(k) == v for k, v in selector)
+        self.features: Dict[str, object] = {}
 
 
 class Reference:
     """Sequential scheduler over plain arrays, nodes in node-tree order."""
 
-    def __init__(self, nodes: Iterable[dict]):
+    def __init__(self, nodes: Iterable[dict],
+                 bench_dir: Optional[str] = None):
+        # where pod features are looked for ahead of this file's directory
+        self.bench_dir = bench_dir
         by_zone: Dict[str, List[dict]] = {}
         for n in nodes:
             by_zone.setdefault(n["zone"], []).append(n)
@@ -147,6 +155,7 @@ class Reference:
         if len(set(self.names)) != len(self.names):
             raise ValueError("duplicate node names")
         self.n = len(ordered)
+        self.zones = zones
         self.zone_of = np.array([zones.index(n["zone"]) for n in ordered])
         self.n_zones = len(zones)
         self.alloc_cpu = np.array([n["cpu"] for n in ordered], np.int64)
@@ -160,56 +169,63 @@ class Reference:
         self.n_pods = z.copy()
         self.start = 0
         self.to_find = num_feasible_nodes_to_find(self.n)
-        self.placed: Dict[str, tuple] = {}      # pod name -> (row, shape)
-        self._zone_counts: Dict[tuple, np.ndarray] = {}
-        self._shapes: Dict[int, _PodShape] = {}
+        self.placed: Dict[str, tuple] = {}      # pod name -> (row, pod)
+        self._states: Dict[str, object] = {}    # feature key -> its State
+        self._shapes: Dict[int, PodShape] = {}
 
     # -- bookkeeping -------------------------------------------------------
 
-    def _shape(self, template: dict) -> _PodShape:
+    def feature_state(self, key: str, module):
+        """The state of feature ``key`` for this cluster (a control puts
+        another in its place)."""
+        return module.State(self)
+
+    def _shape(self, template: dict) -> PodShape:
         s = self._shapes.get(id(template))
-        if s is None:
-            s = self._shapes[id(template)] = _PodShape(template)
-            # keep the template alive so its id stays its own
-            s.template = template
+        if s is not None:
+            return s
+        s = PodShape(template)
+        # keep the template alive so its id stays its own
+        s.template = template
+        keys = [k for k in template if k not in CORE_POD_KEYS]
+        modules = {k: features.load("reference", k, self.bench_dir)
+                   for k in keys}
+        unknown = sorted(k for k, m in modules.items() if m is None)
+        if unknown:
+            raise Unmodelled(f"pod template keys {unknown}")
+        for key, module in modules.items():
+            s.features[key] = module.parse(template[key], template)
+            if key not in self._states and hasattr(module, "State"):
+                state = self._states[key] = self.feature_state(key, module)
+                for row, pod in self.placed.values():
+                    state.account(row, pod, +1)
+        self._shapes[id(template)] = s
         return s
 
-    def _counts(self, selector: tuple) -> np.ndarray:
-        c = self._zone_counts.get(selector)
-        if c is None:
-            c = np.zeros(self.n_zones, np.int64)
-            for row, shape in self.placed.values():
-                if _matches(selector, shape.labels):
-                    c[self.zone_of[row]] += 1
-            self._zone_counts[selector] = c
-        return c
-
-    def _account(self, row: int, shape: _PodShape, sign: int) -> None:
+    def _account(self, row: int, shape: PodShape, sign: int) -> None:
         self.req_cpu[row] += sign * shape.cpu
         self.req_mem[row] += sign * shape.memory
         self.nz_cpu[row] += sign * shape.nz_cpu
         self.nz_mem[row] += sign * shape.nz_memory
         self.n_pods[row] += sign
-        for selector, counts in self._zone_counts.items():
-            if _matches(selector, shape.labels):
-                counts[self.zone_of[row]] += sign
+        for state in self._states.values():
+            state.account(row, shape, sign)
 
     # -- one scheduling cycle ----------------------------------------------
 
-    def feasible(self, shape: _PodShape) -> np.ndarray:
+    def feasible(self, shape: PodShape) -> np.ndarray:
         ok = self.n_pods + 1 <= self.alloc_pods
         if shape.cpu > 0:
             ok &= shape.cpu <= self.alloc_cpu - self.req_cpu
         if shape.memory > 0:
             ok &= shape.memory <= self.alloc_mem - self.req_mem
-        for max_skew, selector in shape.constraints:
-            counts = self._counts(selector)
-            self_match = 1 if _matches(selector, shape.labels) else 0
-            ok &= (counts[self.zone_of] + self_match - counts.min()
-                   <= max_skew)
+        for state in self._states.values():
+            mask = state.feasible(shape)
+            if mask is not None:
+                ok &= mask
         return ok
 
-    def scores(self, shape: _PodShape, rows: np.ndarray) -> np.ndarray:
+    def resource_scores(self, shape: PodShape, rows: np.ndarray) -> np.ndarray:
         """LeastAllocated + BalancedAllocation for the candidate rows; the
         other default score plugins are constant over nodes (see module
         docstring)."""
@@ -226,6 +242,16 @@ class Reference:
         balanced = ((MAX_NODE_SCORE * FRACTION_SCALE
                      - 50 * np.abs(q_cpu - q_mem)) // FRACTION_SCALE)
         return least + balanced
+
+    def scores(self, shape: PodShape, rows: np.ndarray) -> np.ndarray:
+        """What the first maximum is taken over: the core's sum plus each
+        active feature's contribution."""
+        total = self.resource_scores(shape, rows)
+        for state in self._states.values():
+            more = state.score(shape, rows)
+            if more is not None:
+                total = total + more
+        return total
 
     def schedule(self, name: str, template: dict) -> str:
         """Place one pod; returns the node's name."""

@@ -13,7 +13,9 @@ labelled `"rehearsal": true` with `platform: cpu` — never a device number.
 Everything that belongs to one cell is data found by name: the cell in
 `BENCHMARK.json`, its configuration in `configs/<config>.json`, its traffic
 in `traffic/<traffic>.json`, whose `driver` names `drivers/<driver>.py`, and
-each per-layer metric's reader in `layer_metrics/<metric>.py`. See README.md.
+each per-layer metric's reader in `layer_metrics/<metric>.py`; a pod template's
+key beyond the core's in `reference_features/<key>.py` and
+`object_features/<key>.py` (`features.py`). See README.md.
 """
 
 from __future__ import annotations
@@ -93,10 +95,10 @@ def read_layer_metrics(bench_dir: str, metrics: list, obs: dict) -> dict:
     return out
 
 
-def replay(result: dict) -> tuple:
+def replay(result: dict, bench_dir: str) -> tuple:
     """The reference over what the run did, in the order it did it."""
     import reference
-    ref = reference.Reference(result["nodes"])
+    ref = reference.Reference(result["nodes"], bench_dir)
     expected = {}
     for op, name, group in result["log"]:
         if op == "create":
@@ -130,8 +132,9 @@ def main(argv=None) -> int:
     ap.add_argument("--rehearse", action="store_true",
                     help="CPU by name, toy counts, labelled; for the tests")
     ap.add_argument("--bench-dir", default=HERE,
-                    help="directory holding configs/, traffic/, drivers/ "
-                         "and layer_metrics/ (the tests point it elsewhere)")
+                    help="directory holding configs/, traffic/, drivers/, "
+                         "layer_metrics/ and pod features looked for ahead "
+                         "of this one's (the tests point it elsewhere)")
     ap.add_argument("--manifest", default=os.path.join(ROOT, "BENCHMARK.json"))
     ap.add_argument("--keep-out", action="store_true",
                     help="keep this run's directory (trace, client files, "
@@ -203,14 +206,15 @@ def main(argv=None) -> int:
         config=objects.load_config(found["config_path"], args.rehearse),
         traffic=found["traffic"], seed=args.seed,
         seconds=args.seconds, trace=bool(args.trace), rehearse=args.rehearse,
-        root=ROOT, out_dir=out_dir, say=say, profiler=profiler,
+        root=ROOT, bench_dir=os.path.abspath(args.bench_dir),
+        out_dir=out_dir, say=say, profiler=profiler,
         window_opens=window_opens, window_closes=window_closes)
     driver = load_module(found["driver_path"])
     result = driver.run(ctx)
 
     # -- correct: every placement against the reference, and the chip did it
     t_ref = time.perf_counter()
-    cmp_, over = replay(result)
+    cmp_, over = replay(result, ctx.bench_dir)
     say(f"reference replayed {cmp_['compared']} pods in "
         f"{time.perf_counter() - t_ref:.2f}s")
     # a program first met inside the window shows as a compile or as a load
@@ -223,9 +227,11 @@ def main(argv=None) -> int:
               ("nodes_over_allocatable", len(over), 0),
               ("compiles_in_window", compiled, 0)] + list(result["guards"])
     correct = True
+    compared = {}
     for name, got, limit in checks:
         ok = got <= limit
         correct &= ok
+        compared[name] = {"value": got, "limit": limit}
         say(f"compared {name}: {got} (limit {limit})"
             + ("" if ok else "  <-- FAILS"))
     if cmp_["examples"]:
@@ -259,11 +265,18 @@ def main(argv=None) -> int:
                                        "unit": m["unit"]}
                            for m in found["end_to_end"]}
     line["device"] = device
+    # each number compared beside its limit: last in the line, and the last
+    # lines on standard error (what a driver keeps of a run that fails)
+    line["compared"] = compared
     # a run that did not get this far leaves its directory for the post-mortem
     if args.keep_out:
         say(f"kept {out_dir}")
     else:
         shutil.rmtree(out_dir, ignore_errors=True)
+    for name, c in compared.items():
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

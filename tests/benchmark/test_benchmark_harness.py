@@ -64,11 +64,20 @@ def _expected_metrics(cell, kind):
 @pytest.mark.parametrize("trace", (0, 1))
 @pytest.mark.parametrize("cell", CELLS)
 def test_rehearsal_prints_the_contracts_line(cell, trace):
-    line = _last_line(_run([RUN, "--workload", cell, "--seed", "2147483659",
-                            "--seconds", "1", "--trace", str(trace),
-                            "--rehearse"], env={"BENCH_RUN": "ignored"}))
-    keys = {"correct", "attempted", "failed", "metrics", "device"}
+    proc = _run([RUN, "--workload", cell, "--seed", "2147483659",
+                 "--seconds", "1", "--trace", str(trace), "--rehearse"],
+                env={"BENCH_RUN": "ignored"})
+    line = _last_line(proc)
+    keys = {"correct", "attempted", "failed", "metrics", "device", "compared"}
     assert set(line) == keys | ({"breakdown"} if trace else set())
+    # each number compared beside its limit: last in the line, and the last
+    # lines of standard error
+    assert list(line)[-1] == "compared" and "placements_differing" in line[
+        "compared"]
+    assert all(c["value"] <= c["limit"] for c in line["compared"].values())
+    assert proc.stderr.strip().splitlines()[-len(line["compared"]):] == [
+        f"compared {k}: {c['value']} (limit {c['limit']})"
+        for k, c in line["compared"].items()]
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0
     dev = line["device"]
@@ -157,21 +166,45 @@ sys.exit(run.main(["--workload", "basic-5k.waves", "--seed", "5",
 """)
     line = _last_line(_run([str(script)]))
     assert line["correct"] is False
+    differing = line["compared"]["placements_differing"]
+    assert differing["value"] >= 1 and differing["limit"] == 0
 
 
-def test_new_files_are_found_by_name(tmp_path):
-    """A third configuration, a second driver with its traffic file, a cell
-    and a layer metric that reads a new observation: new files and new
-    entries only, run.py untouched."""
+TOY_BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                         "toy_bench")
+TOY_FEATURE = {"toyZoneAffinity": {
+    "required": [f"zone-{i}" for i in range(8)], "preferred": ["zone-3"]}}
+
+
+def _bench_with_a_toy_feature(tmp_path, rehearse):
+    """A bench directory with a configuration of its own, whose measured
+    pods carry a pod feature that only this directory has the files of."""
     bench = tmp_path / "bench"
     for d in ("configs", "traffic", "drivers", "layer_metrics"):
         (bench / d).mkdir(parents=True)
-    cfg = json.load(open(os.path.join(BENCH, "configs", "basic-5k.json")))
+    for d in ("reference_features", "object_features"):
+        shutil.copytree(os.path.join(TOY_BENCH, d), bench / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(BENCH, "configs", "basic-5k.json")) as f:
+        cfg = json.load(f)
     cfg["name"] = "tiny-basic"
-    cfg["rehearse"] = {"nodes": 120, "initPods": 10, "measurePods": 60}
+    cfg["rehearse"] = rehearse
+    cfg["measurePods"]["template"].update(TOY_FEATURE)
     (bench / "configs" / "tiny-basic.json").write_text(json.dumps(cfg))
+    return bench
+
+
+def test_new_files_are_found_by_name(tmp_path):
+    """A third configuration with a pod feature of its own (a reference file
+    with a filter and a score, a builder file), a second driver with its
+    traffic file, a cell and a layer metric that reads a new observation:
+    new files and new entries only, no file of the repository touched."""
+    bench = _bench_with_a_toy_feature(
+        tmp_path, {"nodes": 120, "initPods": 10, "measurePods": 60})
+    # two warm-up waves: the second wave of these pods patches the plan, and
+    # that program must be met before the window (compiles_in_window)
     (bench / "traffic" / "waves-twice.json").write_text(json.dumps(
-        {"driver": "twice", "warmup_waves": 1, "traced_waves": 1}))
+        {"driver": "twice", "warmup_waves": 2, "traced_waves": 1}))
     (bench / "drivers" / "twice.py").write_text(f"""
 import importlib.util
 spec = importlib.util.spec_from_file_location(
@@ -207,6 +240,41 @@ def run(ctx):
     assert set(line["metrics"]) == {"waves_seen_twice", "hint_hit_rate"}
     assert line["metrics"]["waves_seen_twice"]["value"] >= 2
     assert line["correct"] is True
+    # without the feature's files the same cell is refused, not run blind
+    shutil.rmtree(bench / "object_features")
+    proc = _run(base + ["--trace", "0"])
+    assert proc.returncode != 0 and '"correct"' not in proc.stdout
+    assert "Unpaired" in proc.stderr
+    # a run that dies keeps its directory for the post-mortem; this one's is
+    # not wanted
+    out = os.path.join(ROOT, "benchmark_out")
+    for d in os.listdir(out):
+        if d.startswith("tiny-basic.twice-9-"):
+            shutil.rmtree(os.path.join(out, d))
+
+
+def test_the_open_loops_children_find_a_feature_by_name(tmp_path):
+    """The sender process builds its pods from the template: it looks for
+    the feature's builder where the run does (--bench-dir first)."""
+    bench = _bench_with_a_toy_feature(
+        tmp_path, {"nodes": 200, "initPods": 40, "measurePods": 400})
+    shutil.copy(os.path.join(BENCH, "traffic", "open-0.8knee.json"),
+                bench / "traffic")
+    for f in ("open.py", "open_client.py"):
+        shutil.copy(os.path.join(BENCH, "drivers", f), bench / "drivers")
+    manifest = {
+        "workloads": [{"name": "tiny-basic.open", "config": "tiny-basic",
+                       "traffic": "open-0.8knee", "chips": 1, "why": "test"}],
+        "end_to_end": [{"name": "bind_p50_ms", "unit": "ms"},
+                       {"name": "setup_s", "unit": "s"}],
+        "per_layer": []}
+    (tmp_path / "manifest.json").write_text(json.dumps(manifest))
+    line = _last_line(_run([
+        RUN, "--workload", "tiny-basic.open", "--seed", "9", "--seconds", "1",
+        "--trace", "0", "--rehearse", "--bench-dir", str(bench), "--manifest",
+        str(tmp_path / "manifest.json")]))
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0
 
 
 # -- the manifest ----------------------------------------------------------
@@ -367,3 +435,42 @@ def test_kernel_bytes_and_peaks():
         kernelcost.peaks("cpu")
     share = kernelcost.hbm_roofline_share(1.0, 1, 5000, 1024, 50, "TPU v5 lite")
     assert 0 < share < 100
+
+
+# -- the served cell's tail: a share end to end, the percentile per layer ----
+
+def _load(path, name):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.mark.parametrize("limit_ms, want", [
+    (50, 50.0),      # the sample AT the limit counts as within it
+    (100, 75.0),
+    (200, 75.0),
+    (500, 100.0),
+])
+def test_share_of_pods_bound_within_a_limit(limit_ms, want):
+    """Four pods: one unbound until the client gave up counts beyond every
+    limit a manifest names, a share is of ALL samples, in per cent."""
+    open_driver = _load(os.path.join(BENCH, "drivers", "open.py"),
+                        "bench_open_for_share")
+    assert limit_ms in open_driver.WITHIN_MS
+    latency = [10.0, 50.0, 100.0, 499.0]
+    assert open_driver._within_share(latency, limit_ms) == want
+    assert open_driver._within_share(latency + [15000.0] * 4, limit_ms) \
+        == want / 2
+
+
+def test_the_tail_reader_takes_the_drivers_own_number():
+    """One code path for one number: the reader hands on the percentile that
+    the driver worked out beside its end-to-end metrics."""
+    read = _load(os.path.join(BENCH, "layer_metrics", "bind_tail_p99_ms.py"),
+                 "reader_bind_tail").read
+    assert read({"client": {"e2e": {"bind_p99_ms": 312.5}}}) == 312.5
+    # another driver's cell has no such sample: nothing, not 0
+    assert read({"client": {"latency_ms": []}}) is None
+    assert read({}) is None
