@@ -347,6 +347,18 @@ class SchedulerMetrics:
             "a mesh 'full' tears down and re-uploads the whole sharded "
             "state, the cost the delta patches exist to avoid.",
             ("kind", "plane")))
+        self.plan_ipa_terms = r(Counter(
+            "scheduler_plan_ipa_terms_total",
+            "What the required inter-pod term tables of the plans built "
+            "(loop stage plan.ipa) cost the host: 'matches' = term.matches "
+            "evaluations, 'term_pods' = existing pods carrying a required "
+            "anti-affinity term that were walked.", ("what",)))
+        self.plan_anti_lane = r(Counter(
+            "scheduler_plan_anti_lane_total",
+            "Plans built whose anti-affinity filter had something to "
+            "refuse (the pod's own required anti terms, or existing pods' "
+            "terms hitting it), by BatchPlan.anti_rowlocal: 'true' rides "
+            "the lap kernel, 'false' the scan.", ("rowlocal",)))
         self.plan_rebuild_dirty_rows = r(Counter(
             "scheduler_plan_rebuild_dirty_rows_total",
             "Node rows re-encoded + scattered by delta plan patches.", ()))

@@ -91,6 +91,7 @@ STAGES = (
     "hint.walk",         # host-only binds off the score hint
     "hint.validate",     # a hint's journal replay + selection, per pod
     "plan.patch",        # journal delta patch of a live plan + carry
+    "plan.ipa",          # required inter-pod term tables of a full plan build
     "plan.adopt",        # session end: snapshot refresh, mirror adopts the carry
     "loop.idle",         # the binary's idle sleep and lease ticks
 )
@@ -98,7 +99,8 @@ STAGES = (
 # (plan.build … bind.post) keep their name, so a stage reads the same in a
 # pod's trace, in the table and in a profiler trace.
 LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
-               "hint.validate", "plan.build", "plan.patch", "plan.adopt",
+               "hint.validate", "plan.build", "plan.ipa", "plan.patch",
+               "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
                "loop.idle")
 # A bound pod's minimal complete chain. Device stages are optional (host-

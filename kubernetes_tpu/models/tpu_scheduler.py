@@ -225,8 +225,10 @@ class TPUScheduler(Scheduler):
 
     @property
     def plan_build_s(self) -> float:
-        """Snapshot→features host work (self time of `plan.build`)."""
-        return self.stages.seconds["plan.build"]
+        """Snapshot→features host work: `plan.build` with its child
+        `plan.ipa` (the required inter-pod term tables), the whole build."""
+        seconds = self.stages.seconds
+        return seconds["plan.build"] + seconds["plan.ipa"]
 
     @property
     def device_wait_s(self) -> float:
@@ -1136,12 +1138,28 @@ class TPUScheduler(Scheduler):
             dra_enabled=dra_enabled,
             dra_in_use=dra_in_use,
             nominated=self._nominated_lane(pod),
+            stages=self.stages,
         )
+        self._count_ipa(plan)
         state = self.mirror.flush()  # committed to the mesh placement
         if self.mesh is not None:
             from ..parallel import shard_features
             plan.features = shard_features(plan.features, self.mesh)
         return state, plan
+
+    def _count_ipa(self, plan) -> None:
+        """What a built plan's required inter-pod term tables cost
+        (scheduler_plan_ipa_terms_total; the `plan.ipa` span carries the same
+        two numbers), and whether its anti filter has something to refuse,
+        by BatchPlan.anti_rowlocal (scheduler_plan_anti_lane_total: true is
+        the lap path; false shared domains, or only existing pods' terms)."""
+        if plan.ipa_matches:
+            self.metrics.plan_ipa_terms.inc("matches", value=plan.ipa_matches)
+            self.metrics.plan_ipa_terms.inc("term_pods",
+                                            value=plan.ipa_term_pods)
+        if plan.anti_lane:
+            self.metrics.plan_anti_lane.inc(
+                "true" if plan.anti_rowlocal else "false")
 
     def warm_for(self, pod, batch_sizes: Optional[List[int]] = None,
                  nominated: bool = False) -> None:

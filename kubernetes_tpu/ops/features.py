@@ -16,6 +16,7 @@ is enforced by tests/test_device_equivalence.py.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
@@ -185,6 +186,14 @@ class BatchPlan:
     # ONLY row n's resource aggregates — the precondition for the event-
     # journal delta patch (models/tpu_scheduler.py _classify_delta).
     pod_local: bool = False
+    # Host bookkeeping of the required inter-pod term tables (the stage
+    # `plan.ipa`): the kernel's anti filter has something to refuse (the
+    # pod's own required anti terms, or existing pods' hits in exist_anti),
+    # and what the tables cost to build.
+    anti_lane: bool = False
+    ipa_matches: int = 0          # `term.matches` evaluations
+    ipa_term_pods: int = 0        # existing pods with a required anti term
+
     @property
     def row_local(self) -> bool:
         """True when a landing changes feasibility AND scores only at its
@@ -433,11 +442,17 @@ def build_batch(
     dra_enabled=False,
     dra_in_use=None,
     nominated=None,
+    stages=None,
 ) -> BatchPlan:
     """Build kernel inputs for a batch of `batch_size` pods identical to `pod`.
 
     `mirror` must already be synced to `snapshot`. Raises Unsupported for
     feature combinations the kernel does not cover.
+
+    `stages`: the caller's StageLedger (core/spans.py). The build of the
+    required inter-pod term tables is its stage `plan.ipa`, opened only
+    where there is a term to evaluate (the pod's own, or an existing pod's
+    required anti-affinity), so a cluster without any never shows it.
 
     `nominated`: [(node_row, PodInfo)] of preemption-nominated pods with
     priority >= the batch pod's, pre-filtered by the caller (the device
@@ -717,58 +732,76 @@ def build_batch(
     aff_counts = np.zeros((a2, vmax), i32)
     exist_anti = np.zeros(npc, i32)
     anti_rowlocal = bool(anti_terms)
-    for ti, t in enumerate(anti_terms):
-        ax = mirror.axes[t.topology_key]
-        anti_axis[ti] = ax.index
-        anti_self[ti] = 1 if t.matches(pod, ns_labels_fn) else 0
-        if anti_rowlocal:
-            vids = mirror.h_topo[ax.index, :n]
-            nz = vids[vids > 0]
-            if nz.size and np.bincount(nz).max() > 1:
-                anti_rowlocal = False  # shared domains: cross-window coupling
-    for ti, t in enumerate(aff_terms):
-        aff_axis[ti] = mirror.axes[t.topology_key].index
-        aff_self[ti] = 1 if t.matches(pod, ns_labels_fn) else 0
-        aff_active[ti] = 1
-    aff_own_all = i32(1 if aff_terms and all(
-        t.matches(pod, ns_labels_fn) for t in aff_terms) else 0)
+    # What the tables cost the host: `term.matches` evaluations, and the
+    # existing pods that carry a required anti-affinity term.
+    ipa_matches = 0
+    ipa_term_pods = 0
+    ipa_live = bool(aff_terms or anti_terms
+                    or snapshot.have_pods_with_required_anti_affinity_list)
+    with (stages.stage("plan.ipa") if stages is not None and ipa_live
+          else contextlib.nullcontext()) as ipa_stage:
+        for ti, t in enumerate(anti_terms):
+            ax = mirror.axes[t.topology_key]
+            anti_axis[ti] = ax.index
+            anti_self[ti] = 1 if t.matches(pod, ns_labels_fn) else 0
+            if anti_rowlocal:
+                vids = mirror.h_topo[ax.index, :n]
+                nz = vids[vids > 0]
+                if nz.size and np.bincount(nz).max() > 1:
+                    anti_rowlocal = False  # shared domains: cross-window coupling
+        for ti, t in enumerate(aff_terms):
+            aff_axis[ti] = mirror.axes[t.topology_key].index
+            aff_self[ti] = 1 if t.matches(pod, ns_labels_fn) else 0
+            aff_active[ti] = 1
+        aff_own_all = i32(1 if aff_terms and aff_self[:len(aff_terms)].all()
+                          else 0)
+        ipa_matches += len(anti_terms) + len(aff_terms)
 
-    # Existing pods' required anti-affinity vs the incoming pod
-    # (filtering.go:217-241) — accumulated per (axis, value) then broadcast to
-    # a per-row hit count.
-    exist_pairs: Dict[Tuple[int, int], int] = {}
-    for r_i, ni in enumerate(nodes):
-        if not ni.pods_with_required_anti_affinity:
-            continue
-        node = ni.node
-        for epi in ni.pods_with_required_anti_affinity:
-            for term in existing_terms(epi, "required_anti_affinity_terms"):
-                tp_val = node.labels.get(term.topology_key)
-                if tp_val is None:
-                    continue
-                if term.matches(pod, ns_labels_fn):
-                    ax = mirror.axes[term.topology_key]
-                    key = (ax.index, ax.lookup_value(tp_val))
-                    exist_pairs[key] = exist_pairs.get(key, 0) + 1
-    for (ax_i, vid), cnt in exist_pairs.items():
-        if cnt > 0 and vid >= 0:
-            exist_anti[:n] += (mirror.h_topo[ax_i, :n] == vid).astype(i32)
-
-    # Incoming pod's required terms vs all existing pods (filtering.go:247-284).
-    if aff_terms or anti_terms:
+        # Existing pods' required anti-affinity vs the incoming pod
+        # (filtering.go:217-241) — accumulated per (axis, value) then
+        # broadcast to a per-row hit count.
+        exist_pairs: Dict[Tuple[int, int], int] = {}
         for r_i, ni in enumerate(nodes):
-            if not ni.pods:
+            if not ni.pods_with_required_anti_affinity:
                 continue
-            for epi in ni.pods:
-                ep = epi.pod
-                for ti, term in enumerate(aff_terms):
-                    vid = mirror.h_topo[mirror.axes[term.topology_key].index, r_i]
-                    if vid > 0 and term.matches(ep, ns_labels_fn):
-                        aff_counts[ti, vid] += 1
-                for ti, term in enumerate(anti_terms):
-                    vid = mirror.h_topo[mirror.axes[term.topology_key].index, r_i]
-                    if vid > 0 and term.matches(ep, ns_labels_fn):
-                        anti_counts[ti, vid] += 1
+            node = ni.node
+            ipa_term_pods += len(ni.pods_with_required_anti_affinity)
+            for epi in ni.pods_with_required_anti_affinity:
+                for term in existing_terms(epi, "required_anti_affinity_terms"):
+                    tp_val = node.labels.get(term.topology_key)
+                    if tp_val is None:
+                        continue
+                    ipa_matches += 1
+                    if term.matches(pod, ns_labels_fn):
+                        ax = mirror.axes[term.topology_key]
+                        key = (ax.index, ax.lookup_value(tp_val))
+                        exist_pairs[key] = exist_pairs.get(key, 0) + 1
+        for (ax_i, vid), cnt in exist_pairs.items():
+            if cnt > 0 and vid >= 0:
+                exist_anti[:n] += (mirror.h_topo[ax_i, :n] == vid).astype(i32)
+
+        # Incoming pod's required terms vs all existing pods
+        # (filtering.go:247-284).
+        if aff_terms or anti_terms:
+            for r_i, ni in enumerate(nodes):
+                if not ni.pods:
+                    continue
+                for epi in ni.pods:
+                    ep = epi.pod
+                    for ti, term in enumerate(aff_terms):
+                        vid = mirror.h_topo[mirror.axes[term.topology_key].index, r_i]
+                        if vid > 0:
+                            ipa_matches += 1
+                            if term.matches(ep, ns_labels_fn):
+                                aff_counts[ti, vid] += 1
+                    for ti, term in enumerate(anti_terms):
+                        vid = mirror.h_topo[mirror.axes[term.topology_key].index, r_i]
+                        if vid > 0:
+                            ipa_matches += 1
+                            if term.matches(ep, ns_labels_fn):
+                                anti_counts[ti, vid] += 1
+        if ipa_stage is not None:
+            ipa_stage.attrs.update(matches=ipa_matches, term_pods=ipa_term_pods)
 
     # ---- IPA scoring -----------------------------------------------------
     # Base per-node preferred-term score (scoring.go PreScore accumulation),
@@ -972,6 +1005,9 @@ def build_batch(
         port_selfblock=port_selfblock,
         has_aux=has_aux_flag or bool(aux_driver and aux_inc_n),
         has_nom=has_nom,
+        anti_lane=bool(anti_terms) or bool((exist_anti != 0).any()),
+        ipa_matches=ipa_matches,
+        ipa_term_pods=ipa_term_pods,
         dns_node_counts=dns_node_counts,
         dns_node_elig=dns_node_elig,
         dns_min_domains=dns_min_domains,
