@@ -107,19 +107,6 @@ def main(argv=None) -> int:
             for e in errs:
                 print(f"invalid configuration: {e}", file=sys.stderr)
             return 1
-    if args.shard_index >= 0 and cfg is None:
-        # Shard-plane processes bind over real HTTP. Async dispatch (the
-        # SchedulerAsyncAPICalls thread mode) overlaps every bind's RTT
-        # with the commit loop instead of stalling it per pod — the single
-        # worker preserves write order, and a late 409 unwinds through
-        # on_async_bind_error into the conflict requeue path. A tighter
-        # GIL switch interval keeps the worker's socket wakeups from being
-        # convoy-delayed behind the reflector thread (which is busy
-        # decoding every peer shard's events): at the default 5ms, worker
-        # throughput alone can cap binds near 200/s.
-        import sys as _sys
-        _sys.setswitchinterval(0.001)
-        cfg = SchedulerConfiguration(async_dispatch_threads=True)
     cs_kw = {}
     if args.api_url:
         from .core.apiserver import HTTPClientset

@@ -7,10 +7,25 @@ pod_status_patch.go). Gated by SchedulerAsyncAPICalls
 (kube_features.go:1048).
 
 Execution modes:
-- inline  — calls run at enqueue (deterministic; default for tests/bench
-  where the "API server" is an in-process dict and there is no RTT to hide);
+- inline  — calls run at enqueue (deterministic; what a scheduler over the
+  in-process store gets: its "API server" is a dict and there is no round
+  trip to hide);
 - thread  — a worker thread drains the queue, overlapping binding writes
   with the next scheduling cycle exactly like the reference's goroutine.
+
+The scheduler picks the mode from the clientset it is given
+(core/scheduler.py ``_dispatch_mode``): a clientset that says its writes
+cross a socket (``remote_writes``: the HTTPClientset, also under a
+RetryingClientset) gets ``thread``, every other one ``inline``; the
+SchedulerAsyncAPICalls gate off means inline everywhere.
+
+A thread-mode call is not done when it is queued. The worker stamps each
+call with the instants it read (``enqueued_at``, ``sent_at``, ``acked_at``)
+and hands acknowledged calls back through ``drain_done`` the way it hands
+failures back through ``drain_errors``: the loop runs ``on_done`` /
+``on_error`` on its own thread, so a bind is settled (counted, finished in
+the cache, observed in the latency series) at its acknowledgement and by
+the thread that owns cache and queue.
 
 Merging semantics (call_queue.go): one pending slot per (call_type, object
 uid); a newly enqueued call replaces a queued one when its relevance is >=
@@ -19,9 +34,9 @@ the queued call's (e.g. a binding supersedes a pending status patch).
 
 from __future__ import annotations
 
-import queue
 import threading
-from dataclasses import dataclass, field
+import time
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
 # Call types + relevance (api_calls/ relevances: deletion > binding > patch).
@@ -53,6 +68,14 @@ class APICall:
     # `trace=<ctx>` in the error log links an async bind failure to its
     # merged cross-process trace in the analyzer).
     trace_ctx: Optional[str] = None
+    # Acknowledgement seam: the loop runs on_done(call) on its own thread
+    # after the call succeeded (drain_done), with the worker's instants
+    # (time.perf_counter) on the call: queued, the request that carried it
+    # sent, its reply received.
+    on_done: Optional[Callable[["APICall"], None]] = None
+    enqueued_at: float = 0.0
+    sent_at: float = 0.0
+    acked_at: float = 0.0
 
     @property
     def relevance(self) -> int:
@@ -93,6 +116,13 @@ class APIDispatcher:
         # the scheduling loop, so the loop drains this inbox itself
         # (drain_errors), keeping all cache/queue mutation single-threaded.
         self._error_inbox: List[Tuple[APICall, Exception]] = []
+        # Acknowledged calls that carry an on_done, for the loop likewise.
+        self._done_inbox: List[APICall] = []
+        # Binding requests the worker sent and the pods they carried
+        # (scheduler_bind_requests_total{kind}, ..._request_pods_total):
+        # a retried request counts once.
+        self.bind_requests = {"single": 0, "bulk": 0}
+        self.bind_request_pods = 0
         if mode == "thread":
             self._thread = threading.Thread(target=self._run, daemon=True)
             self._thread.start()
@@ -106,6 +136,7 @@ class APIDispatcher:
         key = (call.call_type, call.object_uid)
         skip_key = (CALL_STATUS_PATCH, call.object_uid) \
             if call.call_type == CALL_BINDING else None
+        call.enqueued_at = time.perf_counter()
         with self._cv:
             if key in self._pending:
                 self.merged += 1  # replace: newest call wins its slot
@@ -121,21 +152,35 @@ class APIDispatcher:
                 self.merged += 1
             self._cv.notify_all()
 
-    def _execute(self, call: APICall, defer_errors: bool = False) -> None:
-        import time as _time
-        _t0 = _time.perf_counter()
+    def _execute(self, call: APICall, deferred: bool = False) -> None:
+        """One call, with its retry budget. ``deferred`` (the worker): the
+        outcome goes to an inbox for the loop, and is not run here."""
+        _t0 = time.perf_counter()
+        if call.call_type == CALL_BINDING:
+            self.bind_requests["single"] += 1
+            self.bind_request_pods += 1
         delays = self._retry_cfg.delays()
         while True:
             try:
+                call.sent_at = time.perf_counter()
                 call.execute()
+                call.acked_at = time.perf_counter()
                 self.executed += 1
                 if self.metrics is not None:
                     self.metrics.async_api_call_execution_total.inc(
                         call.call_type, "success")
                     self.metrics.async_api_call_execution_duration.observe(
-                        _time.perf_counter() - _t0, call.call_type, "success")
+                        call.acked_at - _t0, call.call_type, "success")
+                if call.on_done is None:
+                    return
+                if deferred:
+                    with self._cv:
+                        self._done_inbox.append(call)
+                else:
+                    call.on_done(call)
                 return
             except Exception as e:  # noqa: BLE001
+                call.acked_at = time.perf_counter()
                 if self._retry_cfg.retriable(e):
                     try:
                         delay = next(delays)
@@ -146,17 +191,17 @@ class APIDispatcher:
                         if self.metrics is not None:
                             self.metrics.async_api_call_retries.inc(
                                 call.call_type)
-                        _time.sleep(delay)
+                        time.sleep(delay)
                         continue
                 self.errors.append(call._fail(e))
                 if self.metrics is not None:
                     self.metrics.async_api_call_execution_total.inc(
                         call.call_type, "error")
                     self.metrics.async_api_call_execution_duration.observe(
-                        _time.perf_counter() - _t0, call.call_type, "error")
+                        call.acked_at - _t0, call.call_type, "error")
                 if call.on_error is None:
                     return
-                if defer_errors:
+                if deferred:
                     with self._cv:
                         self._error_inbox.append((call, e))
                 else:
@@ -206,7 +251,7 @@ class APIDispatcher:
                 if len(batch) > 1:
                     self._execute_bulk(batch)
                 else:
-                    self._execute(call, defer_errors=True)
+                    self._execute(call, deferred=True)
             finally:
                 with self._cv:
                     self._in_flight -= 1
@@ -214,12 +259,15 @@ class APIDispatcher:
 
     def _execute_bulk(self, calls: List[APICall]) -> None:
         """One batch through bulk_execute, with the same transient-retry
-        budget as _execute; per-item failures land in the error inbox for
-        the scheduling loop to drain (never run on this thread)."""
-        import time as _time
-        _t0 = _time.perf_counter()
+        budget as _execute; per-item outcomes land in the two inboxes for
+        the scheduling loop to drain (never run on this thread). Every call
+        of the batch is stamped with the batch's request and reply."""
+        _t0 = time.perf_counter()
+        self.bind_requests["bulk"] += 1
+        self.bind_request_pods += len(calls)
         delays = self._retry_cfg.delays()
         while True:
+            sent = time.perf_counter()
             try:
                 results = calls[0].bulk_execute(calls)
                 break
@@ -234,16 +282,20 @@ class APIDispatcher:
                         if self.metrics is not None:
                             self.metrics.async_api_call_retries.inc(
                                 calls[0].call_type)
-                        _time.sleep(delay)
+                        time.sleep(delay)
                         continue
                 results = [e] * len(calls)
                 break
-        dur = _time.perf_counter() - _t0
+        acked = time.perf_counter()
+        dur = acked - _t0
         if len(results) < len(calls):  # defensive: short executor response
             results = list(results) + [RuntimeError("short bulk response")] \
                 * (len(calls) - len(results))
-        deferred = []
+        done = []
+        failed = []
         for call, err in zip(calls, results):
+            call.sent_at = sent
+            call.acked_at = acked
             outcome = "success" if err is None else "error"
             if self.metrics is not None:
                 self.metrics.async_api_call_execution_total.inc(
@@ -252,17 +304,30 @@ class APIDispatcher:
                     dur / len(calls), call.call_type, outcome)
             if err is None:
                 self.executed += 1
+                if call.on_done is not None:
+                    done.append(call)
                 continue
             self.errors.append(call._fail(err))
             if call.on_error is not None:
-                deferred.append((call, err))
-        if deferred:
+                failed.append((call, err))
+        if done or failed:
             with self._cv:
-                self._error_inbox.extend(deferred)
+                self._done_inbox.extend(done)
+                self._error_inbox.extend(failed)
 
     def has_errors(self) -> bool:
         """Cheap emptiness probe (list read is atomic under the GIL)."""
         return bool(self._error_inbox)
+
+    def has_done(self) -> bool:
+        return bool(self._done_inbox)
+
+    def drain_done(self) -> List[APICall]:
+        """Take the acknowledged calls that carry an on_done; the scheduling
+        loop runs the handlers on its own thread, as for drain_errors."""
+        with self._cv:
+            out, self._done_inbox = self._done_inbox, []
+        return out
 
     def drain_errors(self) -> List[Tuple[APICall, Exception]]:
         """Take pending (call, exception) failures. The scheduling loop calls
@@ -280,7 +345,23 @@ class APIDispatcher:
             self._cv.wait_for(
                 lambda: not self._order and self._in_flight == 0, timeout=timeout)
 
+    def wait_for_outcome(self, timeout: float) -> None:
+        """Park the loop until the worker has something for it (an
+        acknowledged or a failed call to drain), or has nothing left to do,
+        or ``timeout`` passed. The worker notifies at the end of every
+        request, so a settle follows its acknowledgement at once."""
+        if self.mode == "inline":
+            return
+        with self._cv:
+            self._cv.wait_for(
+                lambda: bool(self._done_inbox or self._error_inbox)
+                or not (self._order or self._in_flight), timeout=timeout)
+
     def close(self) -> None:
+        """Stop the worker after the request it is in. What is still queued
+        is never sent (a bind stays pending at the apiserver, as after a
+        crash); what was acknowledged stays in the inboxes for one last
+        drain."""
         self._stop = True
         with self._cv:
             self._cv.notify_all()
@@ -292,9 +373,11 @@ class APIDispatcher:
             return len(self._order)
 
     def idle(self) -> bool:
-        """Nothing queued and nothing mid-execution (inline mode executes at
-        enqueue, so it is always idle)."""
+        """Nothing queued, nothing mid-execution and no outcome waiting for
+        the loop to drain it (inline mode executes at enqueue, so it is
+        always idle)."""
         if self.mode == "inline":
             return True
         with self._lock:
-            return not self._order and self._in_flight == 0
+            return not (self._order or self._in_flight
+                        or self._done_inbox or self._error_inbox)

@@ -363,6 +363,9 @@ EVICTED_ANNOTATION = "node-lifecycle.kubernetes.io/evicted"
 # They ride every durability/replication surface the store kinds do: WAL
 # records, apply_frame, snapshots, watch/list/paged-list.
 WORKLOAD_KINDS = ("replicasets", "deployments", "pdbs")
+# Most a watch stream gathers into one socket write before it flushes (the
+# attach replay's page buffer uses the same size).
+STREAM_WRITE_BYTES = 65536
 
 
 # ---------------------------------------------------------------------------
@@ -2635,20 +2638,44 @@ class APIServer:
                             # Stream-end sentinel (snapshot RESYNC skipped
                             # frames): close; the client re-lists fresh.
                             break
-                        # Encode HERE, on this stream's own thread, in
-                        # THIS stream's codec — never under the broadcast
-                        # lock the fanout path holds; WireItems cache the
-                        # result so it happens once per codec, not per
-                        # stream (session frames are per-connection and
-                        # never cached).
-                        _t0 = time.perf_counter()
-                        data = encode_stream_item(data, codec, enc)
-                        server._count_encode_us(
-                            "watch", time.perf_counter() - _t0)
-                        server._count_wire(codec, "watch", len(data))
-                        self.wfile.write(
-                            f"{len(data):x}\r\n".encode() + data + b"\r\n")
+                        # One write for everything that is queued by now
+                        # (one chunk an event, as ever). Sending one small
+                        # segment an event, this loop was seen, behind a
+                        # burst, to hand an idle reader some ten events a
+                        # second for minutes, with every socket queue
+                        # empty (PERF.md, PR 28: cause not established);
+                        # draining the queue per write has not.
+                        buf = bytearray()
+                        end = False
+                        while True:
+                            # Encode HERE, on this stream's own thread,
+                            # in THIS stream's codec — never under the
+                            # broadcast lock the fanout path holds;
+                            # WireItems cache the result so it happens
+                            # once per codec, not per stream (session
+                            # frames are per-connection and never
+                            # cached).
+                            _t0 = time.perf_counter()
+                            data = encode_stream_item(data, codec, enc)
+                            server._count_encode_us(
+                                "watch", time.perf_counter() - _t0)
+                            server._count_wire(codec, "watch", len(data))
+                            buf += f"{len(data):x}\r\n".encode()
+                            buf += data
+                            buf += b"\r\n"
+                            if len(buf) >= STREAM_WRITE_BYTES:
+                                break
+                            try:
+                                data = st.q.get_nowait()
+                            except queue.Empty:
+                                break
+                            if data is None:
+                                end = True
+                                break
+                        self.wfile.write(bytes(buf))
                         self.wfile.flush()
+                        if end:
+                            break
                 except (BrokenPipeError, ConnectionResetError):
                     pass
                 finally:
@@ -3386,6 +3413,12 @@ class HTTPClientset:
     # shard.ShardMember's optimistic session patching relies on. The
     # FakeClientset binds unconditionally and must not claim it.
     validates_bind_capacity = True
+    # Every write is a round trip over a socket. A scheduler given this
+    # clientset (also under RetryingClientset, whose attribute lookups fall
+    # through) hides it: its API dispatcher runs in thread mode and binds
+    # go out in bulk (core/scheduler.py _dispatch_mode). The in-process
+    # FakeClientset has no round trip and does not say so.
+    remote_writes = True
 
     def __init__(self, base_url: str, sync_timeout: float = 30.0,
                  fallbacks=(), shard=None, extra_kinds=()):

@@ -361,6 +361,11 @@ class Cache:
                     self._remove_pod_from_node(st.pod)
                     self._add_pod_to_node(pod)
                 self.assumed_pods.discard(pod.uid)
+                # The apiserver's own event says the bind landed. A queued
+                # bind's event can overtake its acknowledgement's settle
+                # (finish_binding, a no-op by then), and the post-restart
+                # reconcile tells a completed bind by this flag.
+                st.binding_finished = True
             st.pod = pod
             st.deadline = None
         else:

@@ -335,6 +335,10 @@ class RetryingClientset:
 
     _WRITE_VERBS = frozenset({
         "create_pod", "update_pod", "delete_pod", "bind", "patch_pod_status",
+        # The bulk bind (a thread-mode dispatcher's run of queued binds in
+        # one request): a transport failure replays the whole request, which
+        # the binding subresource answers idempotently item by item.
+        "bind_many",
         "create_node", "update_node", "delete_node",
         "create_namespace", "create_pod_group", "create_composite_pod_group",
         "create_pv", "create_pvc", "create_storage_class", "create_csi_node",

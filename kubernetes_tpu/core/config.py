@@ -62,8 +62,12 @@ class SchedulerConfiguration:
     # TPU batch knobs (replace `parallelism`, types.go:48-49).
     max_batch: int = 1024
     extenders: List[dict] = field(default_factory=list)
-    # Async API writes run on a worker thread when set (the reference's
-    # dispatcher goroutine); inline otherwise for determinism.
+    # Async API writes run on a worker thread (the reference's dispatcher
+    # goroutine) whenever the clientset's writes cross a socket: the
+    # scheduler sees that for itself (core/scheduler.py _dispatch_mode), and
+    # over the in-process store they run inline, for determinism. Set, this
+    # forces the worker over an in-process clientset too (tests, and the
+    # perf harness's simulated round trip).
     async_dispatch_threads: bool = False
     # Per-tenant weighted fair dequeue on the pending queue (core/queue.py
     # _FairTenantHeap; docs/RESILIENCE.md § overload & fairness). Off by

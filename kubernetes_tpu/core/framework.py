@@ -57,6 +57,11 @@ class Status:
     # only needs to wait out a backoff (the server's Retry-After horizon),
     # never the unschedulable pool, and never the error log.
     shed: bool = False
+    # A success that is not settled yet: the bind plugin handed the call to
+    # the thread-mode API dispatcher (core/api_dispatcher.py). The pod stays
+    # assumed; the scheduler finishes and counts it when the apiserver's
+    # acknowledgement comes back (Scheduler._settle_bind), or unwinds it.
+    queued: bool = False
 
     @classmethod
     def bind_conflict(cls, *reasons: str, plugin: str = "") -> "Status":
@@ -99,6 +104,7 @@ class Status:
 
 
 OK = Status()
+BIND_QUEUED = Status(queued=True)
 
 # Distinguishes "memoized as unsignable (None)" from "not memoized" in the
 # template-shared signature holder (sign_pod).

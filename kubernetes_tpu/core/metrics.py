@@ -206,9 +206,22 @@ class SchedulerMetrics:
         self.pod_stage_duration = r(Histogram(
             "scheduler_pod_stage_duration_seconds",
             "The e2e latency split where it is made, for EVERY pod: "
-            "queue.wait (admission -> pop) and bind.post (the bind call's "
-            "round trip as the scheduler sees it).", ("stage",),
+            "queue.wait (admission -> pop), bind.queue (a queued bind's "
+            "wait in the API dispatcher: enqueue -> its request sent; not "
+            "observed for an inline bind) and bind.post (the round trip of "
+            "the request that carried the pod, single or bulk, as the "
+            "scheduler sees it).", ("stage",),
             buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
+        self.bind_requests = r(Counter(
+            "scheduler_bind_requests_total",
+            "Binding requests sent to the apiserver: single (one pod: an "
+            "inline bind on the loop, or a queued bind that went out "
+            "alone) or bulk (one POST /api/v1/bindings for a run of queued "
+            "binds). Published at scrape time.", ("kind",)))
+        self.bind_request_pods = r(Counter(
+            "scheduler_bind_request_pods_total",
+            "Pods those binding requests carried: over the requests, the "
+            "mean batch. Published at scrape time.", ()))
         self.loop_stage_seconds = r(Counter(
             "scheduler_loop_stage_seconds_total",
             "Self time of each stage of the scheduling loop "

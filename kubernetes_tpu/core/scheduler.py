@@ -100,6 +100,21 @@ class ScheduleResult:
     waiting: bool = False  # a Permit plugin returned WAIT
 
 
+class QueuedBind:
+    """What settling a queued bind needs, kept from the binding cycle that
+    queued it until the apiserver answers."""
+
+    __slots__ = ("fw", "state", "qpi", "node_name", "device", "attempt_t0")
+
+    def __init__(self, fw, state, qpi, node_name, device=False):
+        self.fw = fw
+        self.state = state
+        self.qpi = qpi
+        self.node_name = node_name
+        self.device = device     # counts in device_scheduled at the settle
+        self.attempt_t0 = None   # host cycle: its attempt series wait too
+
+
 class Handle:
     """framework.Handle (interface.go:844) subset plugins consume."""
 
@@ -168,17 +183,25 @@ class Handle:
             return None
         return fn(fw, state, pod, node_to_status, num_candidates, start)
 
+    def on_async_bind_done(self, pod, acked_at: float) -> None:
+        """Async dispatcher bind acknowledged (loop thread, from the done
+        inbox): settle the pod as of ``acked_at`` (time.perf_counter)."""
+        self._scheduler._settle_bind(pod, acked_at)
+
     def on_async_bind_error(self, pod, exc: Exception) -> None:
-        """Async dispatcher bind failure: unwind the optimistic commit. A
-        409 is an optimistic-binding conflict (another scheduler won the
-        pod/node): counted, not logged as an error — the re-added pod is
-        skipped once the winner's commit lands through the watch feed."""
+        """Async dispatcher bind failure: unwind the assumed placement; the
+        pod was never finished nor counted (a queued bind settles only at
+        its acknowledgement). A 409 is an optimistic-binding conflict
+        (another scheduler won the pod/node): counted, not logged as an
+        error — the re-added pod is skipped once the winner's commit lands
+        through the watch feed."""
         s = self._scheduler
+        s._unsettled.pop(pod.uid, None)
+        s._note_async_bind_lost(pod)
         s.state_unwinds += 1
         lost_node = pod.node_name  # captured for the conflict span below
         s.cache.forget_pod(pod)
         pod.node_name = ""
-        s.scheduled = max(0, s.scheduled - 1)
         s.failures += 1
         if getattr(exc, "code", None) == 409:
             # Classify from the 409 BODY ({"error": AlreadyBound|
@@ -209,6 +232,13 @@ class Handle:
             # enqueued_at stamp, so the e2e histogram spans the shed retry.
             s._note_bind_shed(pod, lost_node)
             s.queue.requeue_conflict(s.queue._new_qpi(pod))
+            return
+        if s.clientset.pods.get(pod.uid) is None:
+            # Deleted while its bind was queued or in flight (the apiserver
+            # answered NotFound): nothing to requeue. Re-adding a pod the
+            # informer no longer holds would schedule, fail and re-add it
+            # forever, at full cycle speed (the reference's failure handler
+            # likewise drops a pod missing from the informer cache).
             return
         s.error_log.append(
             f"async bind {pod.namespace}/{pod.name}: {exc!r}")
@@ -249,6 +279,9 @@ class Scheduler:
     # Queue wait past this horizon force-samples the pod's trace and emits
     # a queue.starved event (overload plane, docs/RESILIENCE.md).
     STARVATION_FORCE_S = 30.0
+    # Longest park of an otherwise idle loop while API writes are in flight:
+    # what a pod that arrives meanwhile can wait in the event inbox.
+    BIND_WAIT_SLICE_S = 0.002
 
     def __init__(
         self,
@@ -342,12 +375,11 @@ class Scheduler:
                 self.extenders.append(ext)
         # Async API dispatcher (backend/api_dispatcher; SchedulerAsyncAPICalls).
         from .api_dispatcher import APIDispatcher
-        from .features import SCHEDULER_ASYNC_API_CALLS
-        mode = "inline"
-        if self.gates.enabled(SCHEDULER_ASYNC_API_CALLS) and getattr(
-                self.config, "async_dispatch_threads", False):
-            mode = "thread"
-        self.api_dispatcher = APIDispatcher(mode=mode, metrics=self.metrics)
+        self.api_dispatcher = APIDispatcher(
+            mode=self._dispatch_mode(), metrics=self.metrics)
+        # Queued binds the apiserver has not answered yet, by pod uid: the
+        # pod is assumed, and is finished and counted by _settle_bind.
+        self._unsettled: Dict[str, QueuedBind] = {}
         # Callback gauges (free until exposed): queue/dispatcher depth series.
         self.metrics.inflight_events._fn = lambda: {
             (): float(len(self.queue._event_log))}
@@ -777,6 +809,22 @@ class Scheduler:
         elif kind == "delete":
             self.cache.remove_node(new.name)
 
+    def _dispatch_mode(self) -> str:
+        """How API writes leave this scheduler, from what it can observe:
+        ``thread`` when the clientset says its writes cross a socket
+        (``remote_writes``: HTTPClientset, also under RetryingClientset) or
+        the configuration asks for it, ``inline`` over the in-process store,
+        where there is no round trip to hide and tests and the in-process
+        cells rely on the determinism. SchedulerAsyncAPICalls off: inline
+        everywhere."""
+        from .features import SCHEDULER_ASYNC_API_CALLS
+        if not self.gates.enabled(SCHEDULER_ASYNC_API_CALLS):
+            return "inline"
+        if (getattr(self.config, "async_dispatch_threads", False)
+                or getattr(self.clientset, "remote_writes", False)):
+            return "thread"
+        return "inline"
+
     # -- profiles ----------------------------------------------------------
 
     def framework_for_pod(self, pod: Pod) -> Framework:
@@ -805,17 +853,21 @@ class Scheduler:
             if not self.schedule_one():
                 self.queue.flush_backoff_completed()
                 self.flush_expired_waiters()
-                # Drain async bind failures on THIS thread (the inbox keeps
-                # cache/queue mutation off the dispatcher worker), then
-                # re-check: an unwound pod goes back onto the queue. The
-                # flush is a SHORT slice, not a full barrier — with binds in
-                # flight, a blocking flush would starve the event inbox
-                # (newly created pods can't enter the queue while the loop
-                # is parked), which capped sharded throughput at the bind
-                # drain rate. Only a fully idle dispatcher ends the loop, so
-                # the contract is unchanged: on return, the queue is drained
-                # AND every accepted write has landed or reported.
-                self.api_dispatcher.flush(timeout=0.05)
+                # Settle acknowledged binds and drain failed ones on THIS
+                # thread (the inboxes keep cache/queue mutation off the
+                # dispatcher worker), then re-check: an unwound pod goes
+                # back onto the queue. With writes in flight the loop parks
+                # until the worker has an outcome for it, and for a SHORT
+                # slice at most: pods created meanwhile sit in the event
+                # inbox, which nothing wakes the loop for (parks of 50 ms
+                # were a fifth of a served wave). Only a fully idle
+                # dispatcher ends the loop, so the contract is unchanged:
+                # on return, the queue is drained AND every accepted write
+                # has landed or reported, and has been settled.
+                if not self.api_dispatcher.idle():
+                    with self.stages.stage("loop.idle"):
+                        self.api_dispatcher.wait_for_outcome(
+                            self.BIND_WAIT_SLICE_S)
                 self.process_async_api_errors()
                 if not self.schedule_one():
                     if self.api_dispatcher.idle():
@@ -856,17 +908,35 @@ class Scheduler:
         return unwound
 
     def process_async_api_errors(self) -> int:
-        """Run deferred thread-mode on_error handlers on the scheduling loop
-        (the reference's dispatcher invokes onError on the scheduling side via
-        the cache adapter; backend/api_dispatcher/). Also replays off-thread
-        watch events parked by _threaded. Cheap no-op when both are empty."""
+        """Run the thread-mode dispatcher's deferred handlers on the
+        scheduling loop: on_done for acknowledged calls (a queued bind
+        settles here), then on_error for failed ones (the reference's
+        dispatcher invokes onError on the scheduling side via the cache
+        adapter; backend/api_dispatcher/). Also replays off-thread watch
+        events parked by _threaded. Cheap no-op when all three are empty.
+        Returns the failures handled."""
         self.drain_event_inbox()
-        if not self.api_dispatcher.has_errors():
+        dispatcher = self.api_dispatcher
+        if dispatcher.has_done():
+            # The commit's own tail, deferred to the acknowledgement.
+            with self.stages.stage("host.commit", annotate=False):
+                for call in dispatcher.drain_done():
+                    call.on_done(call)
+        if not dispatcher.has_errors():
             return 0
-        drained = self.api_dispatcher.drain_errors()
+        drained = dispatcher.drain_errors()
         for call, exc in drained:
             call.on_error(exc)
         return len(drained)
+
+    def shutdown(self, timeout: float = 3.0) -> None:
+        """The process is going down: give the dispatcher ``timeout`` seconds
+        to send what is queued, stop it, and settle what was acknowledged.
+        A bind that was never sent stays pending at the apiserver for the
+        next scheduler, as after a crash."""
+        self.api_dispatcher.flush(timeout=timeout)
+        self.api_dispatcher.close()
+        self.process_async_api_errors()
 
     # -- one cycle ---------------------------------------------------------
 
@@ -965,18 +1035,29 @@ class Scheduler:
             return
         bound = self.run_binding_cycle(fw, state, qpi, result)
         self.queue.done(pod.uid)
-        elapsed = time.perf_counter() - t0
         if bound:
             # Host-path commit span: the whole cycle (algorithm + bind
             # enqueue) — the device path records finer-grained stages.
             stage.attrs["node"] = result.suggested_host
             stage.span = True
-        self.metrics.schedule_attempts.inc("scheduled" if bound else "error", fw.profile_name)
+            rec = self._unsettled.get(pod.uid)
+            if rec is not None:
+                rec.attempt_t0 = t0  # the attempt's series wait for the ack
+                return
+        self._observe_attempt(fw, qpi, bound, time.perf_counter() - t0)
+
+    def _observe_attempt(self, fw: Framework, qpi: QueuedPodInfo, bound: bool,
+                         elapsed: float, ack_age: float = 0.0) -> None:
+        """The host cycle's attempt series (algorithm + binding), at the end
+        of the binding: now, or ``ack_age`` seconds ago for a queued bind."""
+        result = "scheduled" if bound else "error"
+        self.metrics.schedule_attempts.inc(result, fw.profile_name)
         self.metrics.scheduling_attempt_duration.observe(
-            elapsed, "scheduled" if bound else "error", fw.profile_name)
+            elapsed, result, fw.profile_name)
         if bound and qpi.initial_attempt_timestamp is not None:
             self.metrics.pod_scheduling_sli_duration.observe(
-                self.now() - qpi.initial_attempt_timestamp, str(qpi.attempts))
+                self.now() - ack_age - qpi.initial_attempt_timestamp,
+                str(qpi.attempts))
         if bound:
             self.metrics.pod_scheduling_attempts.observe(max(1, qpi.attempts))
 
@@ -1611,16 +1692,49 @@ class Scheduler:
         if not st.is_success():
             self._unwind_binding(fw, state, qpi, node_name, st)
             return False
+        if st.queued:
+            # On its way through the thread-mode dispatcher: the pod stays
+            # assumed, and _settle_bind finishes it at the acknowledgement.
+            self._unsettled[pod.uid] = QueuedBind(fw, state, qpi, node_name)
+            return True
+        self._bound(fw, state, qpi, node_name)
+        return True
+
+    def _bound(self, fw: Framework, state: CycleState, qpi: QueuedPodInfo,
+               node_name: str, ack_age: float = 0.0) -> None:
+        """The apiserver holds the bind: finish it in the cache, count it,
+        close the pod's latency series ``ack_age`` seconds ago (0 for a
+        synchronous bind, which returned just now)."""
+        pod = qpi.pod
         self.cache.finish_binding(pod)
         self.queue.nominator.delete_nominated_pod(pod)
         self.scheduled += 1
-        self.observe_bound(qpi, node_name)
+        self.observe_bound(qpi, node_name, ack_age)
         self.recorder.eventf(
             pod.namespace + "/" + pod.name, "Normal", "Scheduled",
             ("Successfully assigned %s/%s to %s",
              (pod.namespace, pod.name, node_name)))
         fw.run_post_bind_plugins(state, pod, node_name)
-        return True
+
+    def _settle_bind(self, pod: Pod, acked_at: float) -> Optional[QueuedBind]:
+        """A queued bind was acknowledged at ``acked_at`` (perf_counter, read
+        by the dispatcher's worker); runs on the loop's thread, from the
+        done inbox. The latency series end at the acknowledgement, not at
+        this drain. Returns what was settled."""
+        rec = self._unsettled.pop(pod.uid, None)
+        if rec is None:
+            return None
+        ack_age = max(0.0, time.perf_counter() - acked_at)
+        self._bound(rec.fw, rec.state, rec.qpi, rec.node_name, ack_age)
+        if rec.attempt_t0 is not None:
+            self._observe_attempt(rec.fw, rec.qpi, True,
+                                  acked_at - rec.attempt_t0, ack_age)
+        return rec
+
+    def _note_async_bind_lost(self, pod: Pod) -> None:
+        """Seam: a queued bind failed, so whatever was counted on the
+        strength of the enqueue is taken back (models/tpu_scheduler.py: the
+        score-hint hit)."""
 
     def _unwind_binding(self, fw, state, qpi: QueuedPodInfo, node_name: str, st: Status) -> None:
         """handleBindingCycleError (schedule_one.go:507): unreserve, forget,
@@ -1700,21 +1814,24 @@ class Scheduler:
         tr.record("queue.wait", ctx, wait, start=wall_pop - wait,
                   attempts=qpi.attempts)
 
-    def observe_bound(self, qpi, node_name: str) -> None:
+    def observe_bound(self, qpi, node_name: str, ack_age: float = 0.0) -> None:
         """Every successful bind feeds scheduler_e2e_scheduling_duration_
-        seconds (queue admission -> bound, ALL pods — the histogram is
-        latency truth, sampling only thins the span ring) and closes the
-        sampled pod's trace with its pod.e2e span."""
+        seconds (queue admission -> the apiserver's acknowledgement, ALL
+        pods — the histogram is latency truth, sampling only thins the span
+        ring) and closes the sampled pod's trace with its pod.e2e span. A
+        queued bind is observed when the loop drains it, ``ack_age`` seconds
+        after the acknowledgement, and the series end there."""
         start = getattr(qpi, "enqueued_at", None)
         if start is None:
             return
-        e2e = max(0.0, self.now() - start)
+        e2e = max(0.0, self.now() - ack_age - start)
         self.metrics.e2e_scheduling_duration.observe(e2e)
         tr = self.tracer
         ctx = tr.context_for(qpi.pod.uid)
         if tr.wants(ctx):
             tr.record("pod.e2e", ctx, e2e, node=node_name,
-                      attempts=qpi.attempts)
+                      attempts=qpi.attempts,
+                      start=time.time() - ack_age - e2e)
 
     # -- failure (schedule_one.go:1152 handleSchedulingFailure) ------------
 
@@ -1818,10 +1935,25 @@ class Scheduler:
         self.metrics.pending_pods.set(unsched - gated, "unschedulable")
         self.metrics.pending_pods.set(gated, "gated")
 
+    def _publish_bind_requests(self) -> None:
+        """The binding requests' two counters, from counts kept where the
+        requests are made: the dispatcher's worker for queued binds, and
+        for inline ones the loop's bind.post stage, entered once for each
+        synchronous single-pod request (plugins/basic.py DefaultBinder)."""
+        dispatcher = self.api_dispatcher
+        inline = self.stages.counts["bind.post"]
+        singles = dispatcher.bind_requests["single"] + inline
+        self.metrics.bind_requests.set_total(float(singles), "single")
+        self.metrics.bind_requests.set_total(
+            float(dispatcher.bind_requests["bulk"]), "bulk")
+        self.metrics.bind_request_pods.set_total(
+            float(dispatcher.bind_request_pods + inline))
+
     def expose_metrics(self) -> str:
         """/metrics (app/server.go:376)."""
         self.update_pending_metrics()
         self.stages.publish()
+        self._publish_bind_requests()
         out = self.metrics.expose()
         # Step-accounting counters (plan/device/host split, device-vs-host
         # path mix, conflict/unwind tallies): in-process harnesses read

@@ -135,6 +135,11 @@ class SchedulerServer:
         return "\n".join(lines) + "\n"
 
     def shutdown(self) -> None:
+        """Stop serving; before that, let the scheduler's dispatcher send
+        what is queued (bounded) and settle what the apiserver answered, so
+        the binary's last ``scheduled=`` line counts every acknowledged
+        bind."""
+        self.scheduler.shutdown()
         if self._httpd is not None:
             self._httpd.shutdown()
             self._httpd = None

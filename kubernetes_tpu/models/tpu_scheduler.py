@@ -38,7 +38,7 @@ from ..core.framework import OK as _OK_STATUS
 from ..core.framework import WAIT, Framework
 from ..core.queue import (QueuedCompositeGroupInfo, QueuedPodGroupInfo,
                           QueuedPodInfo)
-from ..core.scheduler import Scheduler, ScheduleResult
+from ..core.scheduler import QueuedBind, Scheduler, ScheduleResult
 from ..ops.device_state import NodeStateMirror, enable_persistent_compilation_cache
 from ..ops.features import Unsupported, batch_supported, build_batch
 from ..ops.kernel import schedule_batch
@@ -847,13 +847,10 @@ class TPUScheduler(Scheduler):
         """Bind-409 (sync unwind or async dispatcher error): beyond the
         base accounting, invalidate the score hint for the conflicted NODE
         only — the winner's commit re-encodes the row through the journal
-        (docs/PERF.md hint-cache freshness contract). An async 409 also
-        takes back the optimistic hint hit: the loser was counted when its
-        thread-mode bind committed, and it will be counted again when it
-        actually binds."""
+        (docs/PERF.md hint-cache freshness contract). An async failure of
+        any kind has already taken back the optimistic hint hit
+        (_note_async_bind_lost)."""
         super()._note_bind_conflict(message, pod, node)
-        if pod is not None and pod.__dict__.pop("_hint_bound", False):
-            self.hint_hits = max(0, self.hint_hits - 1)
         if node:
             self._hints.note_conflict(node)
 
@@ -2193,6 +2190,15 @@ class TPUScheduler(Scheduler):
             self.cache.assume_pod(pod, qpi.pod_info)
             st = fw.bind_plugins[0].bind(
                 TPUScheduler._EMPTY_STATE, pod, node_name)
+            if st.queued:
+                # Thread-mode dispatcher: assumed until the apiserver's
+                # acknowledgement is drained (_settle_bind does the tail
+                # below then, with the acknowledgement's instant).
+                self._unsettled[pod.uid] = QueuedBind(
+                    fw, TPUScheduler._EMPTY_STATE, qpi, node_name,
+                    device=True)
+                self.queue.done(pod.uid)
+                return True
             if st.is_success():
                 self.cache.finish_binding(pod)
                 nom = self.queue.nominator
@@ -2244,9 +2250,29 @@ class TPUScheduler(Scheduler):
         if not self.run_binding_cycle(fw, state, qpi, ScheduleResult(suggested_host=node_name)):
             self.queue.done(pod.uid)
             return False  # bind failed and unwound
-        self.device_scheduled += 1
+        rec = self._unsettled.get(pod.uid)
+        if rec is not None:
+            rec.device = True  # counted when the bind settles
+        else:
+            self.device_scheduled += 1
         self.queue.done(pod.uid)
         return True
+
+    def _settle_bind(self, pod, acked_at: float):
+        rec = super()._settle_bind(pod, acked_at)
+        if rec is not None:
+            if rec.device:
+                self.device_scheduled += 1
+            # Acknowledged: no failure can follow, so the optimistic hint
+            # hit stands (see _walk_hint).
+            pod.__dict__.pop("_hint_bound", None)
+        return rec
+
+    def _note_async_bind_lost(self, pod) -> None:
+        """The optimistic hint hit was counted when the bind was queued;
+        the pod will be counted again when it actually binds."""
+        if pod.__dict__.pop("_hint_bound", False):
+            self.hint_hits = max(0, self.hint_hits - 1)
 
     # -- score-hint fast path (models/score_hints.py) ----------------------
 
@@ -2271,8 +2297,9 @@ class TPUScheduler(Scheduler):
         handled = 0
         while True:
             if bound and bound % 64 == 0:
-                # Surface thread-mode async bind errors (409 → per-node
-                # hint invalidation) while the loop runs.
+                # Settle the queued binds the apiserver has answered, and
+                # surface the failed ones (409 → per-node hint
+                # invalidation), while the walk runs.
                 self.process_async_api_errors()
             # Per pod the walk's parts are leaves of the table (clock reads,
             # no profiler annotation): queue.pop, hint.validate, host.commit.
@@ -2338,15 +2365,16 @@ class TPUScheduler(Scheduler):
                 # waiter unwinds through state_unwinds, killing the hint).
                 hints._hit(kind)
                 if qpi.pod.uid in self.cache.assumed_pods:
-                    # Still assumed ⇒ the bind committed OPTIMISTICALLY
-                    # (thread-mode dispatcher; an inline clientset confirms
+                    # Still assumed ⇒ the bind is queued or its own event
+                    # has not come back yet (the in-process store confirms
                     # inside _commit and never reaches here). Tag the pod
-                    # so a later async 409 takes this hit back — hint_hits
+                    # so a later async failure takes this hit back — hint_hits
                     # must never exceed pods actually bound, or HintHitRate
                     # reads > 1.0 on exactly the contended runs where it
-                    # matters. The tag is dropped at the own-bind confirm
-                    # (_note_own_bind_confirm): once settled, a later life
-                    # of the same object must not erase a real hit.
+                    # matters. The tag is dropped when the bind settles
+                    # (_settle_bind) or its own event confirms it
+                    # (_note_own_bind_confirm): after that, a later life of
+                    # the same object must not erase a real hit.
                     qpi.pod.__dict__["_hint_bound"] = True
             if hints.entry is not entry:
                 break  # invalidated mid-loop (conflict burst)

@@ -22,6 +22,7 @@ from ..api.types import (
     find_matching_untolerated_taint,
 )
 from ..core.framework import (
+    BIND_QUEUED,
     MAX_NODE_SCORE,
     OK,
     CycleState,
@@ -142,9 +143,22 @@ class PrioritySort:
 
 
 class DefaultBinder:
-    """plugins/defaultbinder: POST /binding — routed through the async API
-    dispatcher when available (framework/api_calls/pod_binding.go:32
-    PodBindingCall via APIDispatcher; inline mode executes immediately)."""
+    """plugins/defaultbinder: POST /binding (framework/api_calls/
+    pod_binding.go:32 PodBindingCall via APIDispatcher). How a bind goes out
+    follows the dispatcher's mode, which the scheduler takes from its
+    clientset (core/scheduler.py ``_dispatch_mode``): over the in-process
+    store the call runs here, on the loop, and OK means bound; against a
+    remote apiserver it is queued for the dispatcher's worker, which sends
+    runs of queued binds as one bulk request, and BIND_QUEUED means the pod
+    stays assumed until the acknowledgement is drained (``_acked``) or the
+    failure is (``Handle.on_async_bind_error``).
+
+    Every bound pod feeds scheduler_pod_stage_duration_seconds in both
+    modes: ``bind.post`` is the round trip of the request that carried the
+    pod (each pod of a bulk request observes that request's), ``bind.queue``
+    the time from the enqueue to that request's start (0 inline: not
+    observed). The loop's own table gets ``bind.post`` only for time the
+    loop was blocked in a bind, which is the inline mode's."""
 
     name = "DefaultBinder"
 
@@ -154,11 +168,19 @@ class DefaultBinder:
     def _posted(self, t0: float) -> None:
         """A synchronous bind call returned: its round trip as the scheduler
         sees it, for every pod (the histogram) and as the loop's bind.post
-        stage. Thread-mode binds run on the dispatcher's worker, off the
-        loop's clock: only sampled pods' spans time those."""
+        stage (whose count is the inline mode's single requests)."""
         seconds = time.perf_counter() - t0
         self.handle.metrics.pod_stage_duration.observe(seconds, "bind.post")
         self.handle.stages.leaf("bind.post", seconds)
+
+    def _acked(self, call) -> None:
+        """Loop thread, from the dispatcher's done inbox: the apiserver
+        answered 200 for this pod at ``call.acked_at``. The two per-pod
+        stages come from the instants the worker read."""
+        observe = self.handle.metrics.pod_stage_duration.observe
+        observe(call.sent_at - call.enqueued_at, "bind.queue")
+        observe(call.acked_at - call.sent_at, "bind.post")
+        self.handle.on_async_bind_done(call.bind_args[0], call.acked_at)
 
     def bind(self, state: CycleState, pod: Pod, node_name: str) -> Status:
         dispatcher = getattr(self.handle, "api_dispatcher", None)
@@ -202,9 +224,9 @@ class DefaultBinder:
                 return OK
             from ..core.api_dispatcher import APICall, CALL_BINDING
             from ..core import spans as _spans
-            on_error = getattr(self.handle, "on_async_bind_error", None)
             _tr = _spans.default_tracer()
             _ctx = _tr.context_for(pod.uid)
+            on_error = self.handle.on_async_bind_error
             dispatcher.add(APICall(
                 call_type=CALL_BINDING, object_uid=pod.uid,
                 trace_ctx=_spans.format_ctx(_ctx) if _tr.wants(_ctx) else None,
@@ -213,11 +235,11 @@ class DefaultBinder:
                 # Stable bound method: the dispatcher batches consecutive
                 # binding calls whose bulk_execute is the SAME callable.
                 bulk_execute=self._bulk_bind,
-                on_error=(lambda e, _p=pod: on_error(_p, e))
-                if on_error is not None else None))
+                on_done=self._acked,
+                on_error=lambda e, _p=pod: on_error(_p, e)))
         except Exception as e:  # noqa: BLE001
             return Status.error(str(e))
-        return OK
+        return BIND_QUEUED
 
     def _bulk_bind(self, calls) -> list:
         """Commit a run of queued binding calls as ONE bulk request

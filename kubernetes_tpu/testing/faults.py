@@ -53,7 +53,8 @@ from ..core.backoff import TransientAPIError
 # Clientset write verbs the chaos layer may afflict (the API-mutation
 # surface the scheduler exercises).
 WRITE_VERBS = (
-    "create_pod", "update_pod", "delete_pod", "bind", "patch_pod_status",
+    "create_pod", "update_pod", "delete_pod", "bind", "bind_many",
+    "patch_pod_status",
     "create_node", "update_node", "delete_node", "evict_pod",
 )
 

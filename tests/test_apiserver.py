@@ -309,3 +309,53 @@ def test_flow_admin_endpoint_reweights_live():
         assert e.value.code == 400
     finally:
         api.shutdown()
+
+
+def test_a_burst_reaches_a_late_reader_whole_in_order_and_in_few_writes():
+    """A watch stream writes everything queued in one send (one chunk an
+    event as ever): a burst that queues up behind one slow send goes out
+    whole and in order, in far fewer socket writes than events. (On the
+    chip machine a stream that sent one small segment an event was seen
+    crawling at some ten events a second behind a burst.)"""
+    import http.client as hc
+    import json
+
+    api = APIServer()
+    port = api.serve(0)
+    n = 600
+    conn = hc.HTTPConnection("127.0.0.1", port, timeout=30)
+    writes = []
+    try:
+        conn.request("GET", "/api/v1/pods?watch=true",
+                     headers={"Accept": "application/json"})
+        resp = conn.getresponse()
+        assert resp.status == 200
+        deadline = time.monotonic() + 30
+        while json.loads(resp.readline()).get("type") != "SYNC":
+            assert time.monotonic() < deadline
+        # count the stream thread's writes from here on
+        import socketserver
+        real = socketserver._SocketWriter.write
+
+        def counting(self, b):
+            writes.append(len(b))
+            if len(writes) == 1:
+                time.sleep(0.5)      # the burst queues up behind this send
+            return real(self, b)
+        socketserver._SocketWriter.write = counting
+        try:
+            for p in _pods(n):
+                api.store.create_pod(p)
+            names = []
+            while len(names) < n:
+                assert time.monotonic() < deadline
+                d = json.loads(resp.readline())
+                if d.get("type") == "ADDED":
+                    names.append(d["object"]["name"])
+        finally:
+            socketserver._SocketWriter.write = real
+        assert names == [f"p{i}" for i in range(n)]
+        assert len(writes) < n / 20, len(writes)
+    finally:
+        conn.close()
+        api.shutdown()

@@ -311,6 +311,8 @@ def test_metric_name_parity_with_reference():
                      "scheduler_hint_cache_invalidations_total",
                      "scheduler_hint_validation_duration_seconds",
                      "scheduler_bind_conflict_total",
+                     "scheduler_bind_requests_total",
+                     "scheduler_bind_request_pods_total",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",
