@@ -7,9 +7,9 @@ Drives the Filter→Score path once through the entry points a user calls, at
 the reference's own scheduler_perf cluster sizes (BASELINE.md; rows of
 kubernetes_tpu/perf/configs/performance-config.yaml, scale 1.0):
 
-- library stage — the surface bench.py and `python -m kubernetes_tpu.perf`
-  measure: three 5,000-node rows through perf.harness.run_workload with a
-  default TPUScheduler (hints, pipeline depth, mesh="auto" all at defaults);
+- library stage — the surface `python -m kubernetes_tpu.perf` measures:
+  three 5,000-node rows through perf.harness.run_workload with a
+  default TPUScheduler (mesh="auto");
 - server stage — the deployed shape: one `python -m
   kubernetes_tpu.core.apiserver` process, one `python -m kubernetes_tpu
   --api-url ... --platform tpu` process, 5,000 nodes and 2,000
@@ -290,12 +290,12 @@ def _slug(row: str) -> str:
 
 
 def _child_env(cpu: bool) -> dict:
-    """Environment of a stage child: no TPU_SCHED_*/BENCH_* seam set, one
+    """Environment of a stage child: no TPU_SCHED_* variable set, one
     compile cache for every process (compile_cache.py), and — for every
     child that must stay off the chip — the CPU by name."""
     from kubernetes_tpu.compile_cache import export
     env = {k: v for k, v in os.environ.items()
-           if not k.startswith(("TPU_SCHED_", "BENCH_"))}
+           if not k.startswith("TPU_SCHED_")}
     env["PYTHONPATH"] = ROOT
     if cpu:
         env["JAX_PLATFORMS"] = "cpu"

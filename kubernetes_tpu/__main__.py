@@ -133,7 +133,7 @@ def main(argv=None) -> int:
     # Observability (docs/OBSERVABILITY.md): label this process's spans so
     # cross-process trace merges attribute stages, and install the flight
     # recorder when a dump directory is configured (the shard harness sets
-    # TPU_SCHED_FLIGHTREC_DIR for bench --trace and the chaos suites).
+    # TPU_SCHED_FLIGHTREC_DIR for traced runs and the chaos suites).
     import os
     sched.tracer.proc = (f"shard-{args.shard_index}"
                          if args.shard_index >= 0 else args.identity)

@@ -136,7 +136,7 @@ class _ConstCoercer(_ast.NodeTransformer):
     name, so runtime comparisons are plain int/float ops against the (also
     coerced) map values — the coerced value classes need no cross-type
     string equality, keeping their __eq__ consistent with their int/float
-    __hash__ (ADVICE r5; regression in tests/test_dra.py
+    __hash__ (regression in tests/test_dra.py
     test_quantity_hash_eq_consistency).
 
     Scope: ONLY direct comparator operands (and their tuple/list members,
@@ -284,9 +284,9 @@ class _CoercingMap(dict):
 class _QtyMixin:
     """Coerced quantity values: EQUALITY is strictly numeric (inherited
     int/float __eq__/__hash__ — equal objects hash equal, so coerced values
-    are safe set members / dict keys next to any other form; the ADVICE-r5
-    hash/eq asymmetry is gone). The CEL surface still holds —
-    device.capacity["mem"] == "40Gi" and == 40*1024**3 are both True —
+    are safe set members / dict keys next to any other form). The CEL
+    surface still holds — device.capacity["mem"] == "40Gi" and
+    == 40*1024**3 are both True —
     because expression string LITERALS are coerced once at compile time
     (_ConstCoercer) and the map values once per device (_CoercingMap), so
     both sides of every runtime comparison are already numeric. ORDERING

@@ -3434,7 +3434,7 @@ class HTTPClientset:
         # server delivers full pod wire only for owned + wire-relevant
         # pods; the rest arrive as slim projections this client MERGES
         # onto its cache (pod_from_slim). The decode counters below are
-        # what bench.py --shards surfaces per shard — the measurable 1/N.
+        # what a sharded perf row surfaces per shard — the measurable 1/N.
         self.shard = tuple(shard) if shard else None
         self.watch_events_full = 0
         self.watch_events_slim = 0

@@ -349,9 +349,9 @@ class Scheduler:
             # RESILIENCE.md): config-driven, with an env seam so the shard
             # harness's OS-process schedulers can switch it on uniformly.
             fair_tenant_dequeue=(
-                getattr(self.config, "fair_tenant_dequeue", False)
+                self.config.fair_tenant_dequeue
                 or _os.environ.get("TPU_SCHED_FAIR_TENANTS", "") == "1"),
-            tenant_weights=getattr(self.config, "tenant_weights", None),
+            tenant_weights=self.config.tenant_weights,
         )
         self.queue.metrics = self.metrics  # queueing-hint latency series
         # Extenders (extender.go; config extenders or injected objects).
@@ -395,7 +395,7 @@ class Scheduler:
         # Watch decode cost, by wire form (core/watchcache.py shard-filtered
         # streams) and codec (core/wire.py binary vs JSON): counters live on
         # the HTTP clientset's reflector thread; the gauges read them at
-        # scrape time so bench.py --shards can show the per-shard
+        # scrape time so a sharded perf row can show the per-shard
         # decoded-events/bytes 1/N and which plane ran. Empty on a
         # FakeClientset (no wire).
         _cs = self.clientset
@@ -820,7 +820,7 @@ class Scheduler:
         from .features import SCHEDULER_ASYNC_API_CALLS
         if not self.gates.enabled(SCHEDULER_ASYNC_API_CALLS):
             return "inline"
-        if (getattr(self.config, "async_dispatch_threads", False)
+        if (self.config.async_dispatch_threads
                 or getattr(self.clientset, "remote_writes", False)):
             return "thread"
         return "inline"
@@ -1960,7 +1960,7 @@ class Scheduler:
         # these attributes directly, but a shard-plane scheduler is only
         # reachable over HTTP — the split must ride /metrics for a sharded
         # run to be diagnosable from outside (docs/SHARDING.md
-        # observability; bench.py --shards detail).
+        # observability; a sharded perf row's detail).
         extra = []
         for name, val in (
                 ("scheduler_plan_build_seconds_total",

@@ -69,8 +69,8 @@ logger = logging.getLogger("kubernetes_tpu")
 
 TRACE_HEADER = "X-Trace-Context"
 
-# The pinned stage names (docs/OBSERVABILITY.md). bench.py --trace and the
-# trace analyzer CLI key on these strings; renames are contract breaks.
+# The pinned stage names (docs/OBSERVABILITY.md). The trace analyzer CLI
+# keys on these strings; renames are contract breaks.
 STAGES = (
     "queue.admission",   # pod entered this scheduler's queue (event)
     "queue.wait",        # admission → pop

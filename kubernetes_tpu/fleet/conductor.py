@@ -213,8 +213,8 @@ class FleetConductor:
         env = dict(self._env)
         env.update(spec.shard_env)
         if spec.mesh_devices > 1:
-            # The BENCH_MESH_DEVICES seam, applied where it must land for
-            # a CHILD process: XLA_FLAGS before backend init gives every
+            # Applied where it must land for a CHILD process: XLA_FLAGS
+            # before backend init gives every
             # shard a virtual device mesh, so TPUScheduler(mesh="auto")
             # builds it and row-local plans dispatch mesh-SPMD.
             flags = env.get("XLA_FLAGS", "")

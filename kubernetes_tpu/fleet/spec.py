@@ -16,7 +16,7 @@ tests share one format — docs/SCALE.md § fleet conductor documents it:
      "mesh_devices": 8, "hollow_procs": 2,
      "hollow": {"count": 100000, "zones": 100, "heartbeat_s": 120.0,
                 "drift": 0.02, "churn_per_s": 2.0},
-     "env": {"TPU_SCHED_HINT_LRU": "2"}}
+     "env": {"TPU_SCHED_LIST_PAGE": "500"}}
 """
 
 from __future__ import annotations
@@ -55,8 +55,8 @@ class FleetSpec:
     shard_lease_s: float = 15.0
     pin_shards: bool = True         # taskset shard i -> core i%cores (n>1)
     # mesh_devices > 1 gives every shard a virtual device mesh
-    # (XLA_FLAGS --xla_force_host_platform_device_count=N, the
-    # BENCH_MESH_DEVICES seam) so row-local plans dispatch mesh-SPMD.
+    # (XLA_FLAGS --xla_force_host_platform_device_count=N) so row-local
+    # plans dispatch mesh-SPMD.
     mesh_devices: int = 0
     # Replicated control plane: follower apiservers tailing the leader.
     replicas: int = 0
@@ -75,9 +75,9 @@ class FleetSpec:
     # {"managers": 2, "lease_ttl": s, "tick": s, "hysteresis": n,
     #  "max_moves": n, "device": bool}.
     deschedule: Optional[dict] = None
-    # Env seams every child inherits (wire plane TPU_SCHED_WIRE, hint
-    # A/B TPU_SCHED_HINT_LRU / TPU_SCHED_SCORE_HINTS, ...); shard_env
-    # lands on shard schedulers only.
+    # Environment every child inherits (the control plane's settings:
+    # TPU_SCHED_WIRE, TPU_SCHED_LIST_PAGE, ...); shard_env lands on shard
+    # schedulers only.
     env: Dict[str, str] = field(default_factory=dict)
     shard_env: Dict[str, str] = field(default_factory=dict)
     # Observability / durability seams.

@@ -77,24 +77,24 @@ from ..ops.kernel import LAP_MAX as _LAP_MAX
 
 MAX_NODE_SCORE = 100
 _BA_SCALE = 1_000_000
+# Live hints kept, one per pod signature. Two: a rollout alternates the
+# old and the new replica shape through one queue.
+HINT_LRU_SLOTS = 2
 
 
-def hint_eligible(plan, mesh, aux_shape, head_pod, extenders,
+def hint_eligible(plan, aux_shape, head_pod, extenders,
                   nominator, affinity_pod_refs: int) -> bool:
-    """Can a clean session of this shape seed a score hint? Mirrors the
-    kernel's scores_carried ∧ incremental_feas preconditions (the walk
-    replicates exactly that fast path) plus the host-side state the walk
-    does not model: counted claims, extenders, nominated lanes, and any
-    live affinity-term pod (cluster-wide disable — the 0→1 transition
-    mirrors the watch plane's selector gate). Mesh sessions are eligible
-    too (ROADMAP 12d): the install fetches the per-node aggregates/score
-    vector from the SHARDED carry via one device→host gather at clean
-    session end — sharded and single-device carries are bit-identical
-    (integer arithmetic), so the walk stays oracle-exact."""
-    del mesh  # sharded carries install through the same gather
-    return (plan.pod_local
-            and not (plan.has_pns or plan.has_ipa_base or plan.has_na_pref
-                     or plan.port_selfblock or plan.has_aux or plan.has_nom)
+    """Can a clean session of this shape seed a score hint? The plan must
+    be row-local (BatchPlan.row_local: the walk replicates exactly that
+    fast path), and the host-side state the walk does not model must be
+    absent: counted claims, extenders, nominated pods, and any live
+    affinity-term pod (cluster-wide disable — the 0→1 transition mirrors
+    the watch plane's selector gate). Mesh sessions are eligible too: the
+    install fetches the per-node aggregates/score vector from the SHARDED
+    carry via one device→host gather at clean session end — sharded and
+    single-device carries are bit-identical (integer arithmetic), so the
+    walk stays oracle-exact."""
+    return (plan.row_local
             and aux_shape == (None, None)
             and not head_pod.volumes
             and not getattr(head_pod, "resource_claims", None)
@@ -127,7 +127,7 @@ class HintEntry:
         # adaptive-sampling windows of consecutive pods are disjoint, so
         # one cumsum serves up to total_feas//to_find pods. Any row
         # mutation that is NOT the served head's own apply() clears it.
-        "_pending", "lap_enabled", "lap_walks",
+        "_pending", "lap_walks",
     )
 
     # -- construction -------------------------------------------------------
@@ -202,9 +202,7 @@ class HintEntry:
         e.attempts = sched.attempts
         e.unwinds = sched.state_unwinds
         e.nom_version = sched.queue.nominator.version
-        import os
         e._pending = []
-        e.lap_enabled = os.environ.get("TPU_SCHED_HINT_LAP", "1") != "0"
         e.lap_walks = 0
         return e
 
@@ -274,7 +272,7 @@ class HintEntry:
             # per-window evaluated values).
             tf = max(to_find, 1)
             L = min(total_feas // tf, _LAP_MAX)
-            if self.lap_enabled and L >= 2:
+            if L >= 2:
                 got = self._lap_select(start, ok, rank, rot, int(L), tf,
                                        num, NP)
                 if got is not None:
@@ -515,11 +513,10 @@ class ScoreHintCache:
     Counters live on the scheduler (WINDOW_COUNTERS surface); labeled
     series on its SchedulerMetrics.
 
-    The cache is a small signature-keyed LRU (``TPU_SCHED_HINT_LRU``
-    slots, default 2, MRU first): alternating deployment waves — two
-    replica shapes interleaving through one queue — keep BOTH shapes on
-    the host path instead of thrashing a single slot. ``=1`` is the A/B
-    seam back to the historical single-entry behavior. Coherence across
+    The cache is a small signature-keyed LRU (``HINT_LRU_SLOTS``, MRU
+    first): alternating deployment waves — two replica shapes interleaving
+    through one queue — keep BOTH shapes on the host path instead of
+    thrashing a single slot. Coherence across
     entries is push-based, not journal-based, because own binds are
     deliberately journal-benign: every own attempt bumps EVERY live
     entry's attempt watermark, and a committed bind re-encodes the landed
@@ -528,11 +525,10 @@ class ScoreHintCache:
     entry serve a stale row."""
 
     def __init__(self, sched, enabled: bool = True):
-        import os
         self.sched = sched
+        # Off only in a scheduler with no device path, and in the tests'
+        # always-dispatch oracle (`_hints.enabled = False`).
         self.enabled = enabled
-        self.capacity = max(1, int(os.environ.get("TPU_SCHED_HINT_LRU",
-                                                  "2") or 2))
         self.entries: list = []  # HintEntry, MRU first
 
     @property
@@ -593,7 +589,7 @@ class ScoreHintCache:
                 self.sched.metrics.hint_cache_invalidations.inc(
                     "cross_reencode")
         self.entries = [e] + kept
-        while len(self.entries) > self.capacity:
+        while len(self.entries) > HINT_LRU_SLOTS:
             self._drop(self.entries[-1], "lru_evict")
 
     def note_conflict(self, node: str) -> None:
@@ -641,10 +637,10 @@ class ScoreHintCache:
         pod, else None (counted as a miss; stale entries are dropped +
         counted as invalidations). A served entry moves to the LRU head."""
         if not self.enabled:
-            # The A/B seam (`_hints.enabled = False` /
-            # TPU_SCHED_SCORE_HINTS=0) must hold on a WARM scheduler too:
-            # live entries installed before the flip may not keep serving,
-            # or the dispatch-only baseline is silently invalid.
+            # The oracle's switch (`_hints.enabled = False`) must hold on
+            # a WARM scheduler too: live entries installed before the flip
+            # may not keep serving, or the dispatch-only baseline is
+            # silently invalid.
             self.entries = []
             return None
         s = self.sched

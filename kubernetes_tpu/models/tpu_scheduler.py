@@ -26,8 +26,10 @@ generalized from one-pod hint reuse to true multi-pod kernel batches.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 import time as _time
+from functools import partial
 from typing import List, Optional, Tuple
 
 import numpy as np
@@ -47,6 +49,14 @@ from ..ops.kernel import schedule_batch
 # Sentinel fallback_reason: the popped entity is a pod GROUP that can ride a
 # device gang session (schedule_one routes it to run_gang_device_session).
 _GANG_SESSION = "__gang_device_session__"
+
+# Batches that may be in flight on the device while the host commits retired
+# ones (2 = double buffering).
+PIPELINE_DEPTH = 2
+# Device-path circuit breaker (core/backoff.py CircuitBreaker): consecutive
+# failures that open it, and the seconds it then pins the host path.
+DEVICE_BREAKER_THRESHOLD = 3
+DEVICE_BREAKER_COOLDOWN_S = 5.0
 
 
 class _Batch(list):
@@ -105,9 +115,6 @@ class TPUScheduler(Scheduler):
         from ..core.features import TPU_BATCH_SCHEDULING
         self.device_enabled = self.gates.enabled(TPU_BATCH_SCHEDULING)
         self.max_batch = max_batch if max_batch is not None else self.config.max_batch
-        # Dispatch pipeline depth: how many batches may be in flight on
-        # device while the host commits retired ones (2 = double buffering).
-        self.pipeline_depth = getattr(self.config, "pipeline_depth", 2)
         enable_persistent_compilation_cache()
         from ..compile_cache import watch_compiles
         watch_compiles()  # a slow stage says whether a compile ran inside it
@@ -128,13 +135,6 @@ class TPUScheduler(Scheduler):
             self.mesh = mesh  # explicit Mesh, or None to force single-device
         self.mirror = NodeStateMirror()
         self._holdover: Optional[QueuedPodInfo] = None
-        # Explicit shard_map dispatch for row-local plans under a mesh
-        # (parallel/mesh.py sharded_lap_schedule): cross-shard collectives
-        # are hand-placed and minimal instead of GSPMD-inferred.
-        # TPU_SCHED_SHARD_MAP=0 pins the GSPMD path (the A/B seam).
-        import os as _os2
-        self._shard_map_enabled = (
-            _os2.environ.get("TPU_SCHED_SHARD_MAP", "1") != "0")
         # metrics
         self.device_batches = 0
         self.device_scheduled = 0
@@ -194,9 +194,8 @@ class TPUScheduler(Scheduler):
         # invariant), so degradation is graceful, never a crashed cycle.
         from ..core.backoff import CircuitBreaker
         self.device_breaker = CircuitBreaker(
-            failure_threshold=getattr(
-                self.config, "device_breaker_threshold", 3),
-            cooldown=getattr(self.config, "device_breaker_cooldown", 5.0))
+            failure_threshold=DEVICE_BREAKER_THRESHOLD,
+            cooldown=DEVICE_BREAKER_COOLDOWN_S)
         # Chaos seam (testing/faults.py DeviceFaults): called at every
         # device kernel boundary crossing; may raise.
         self._fault_hook = None
@@ -204,15 +203,9 @@ class TPUScheduler(Scheduler):
         # KEP-5598 OpportunisticBatch, cross-cycle): a clean session's end
         # carry seeds a host-side walk that binds the NEXT identical pods
         # without any device dispatch. Event-driven freshness rides the
-        # journal; TPU_SCHED_SCORE_HINTS=0 forces the dispatch-only
-        # baseline (the bench A/B seam).
-        import os as _os
+        # journal.
         from .score_hints import ScoreHintCache
-        self._hints = ScoreHintCache(
-            self,
-            enabled=(self.device_enabled
-                     and _os.environ.get("TPU_SCHED_SCORE_HINTS", "1") != "0"
-                     and getattr(self.config, "score_hints", True)))
+        self._hints = ScoreHintCache(self, enabled=self.device_enabled)
         self.hint_hits = 0
         self.hint_misses = 0
         self.hint_invalidations = 0
@@ -464,7 +457,7 @@ class TPUScheduler(Scheduler):
             return groups
 
         while True:
-            while not invalidated and len(inflight) < self.pipeline_depth:
+            while not invalidated and len(inflight) < PIPELINE_DEPTH:
                 if sd.patch_pending:
                     if inflight:
                         break  # retire dispatched packs before patching
@@ -920,10 +913,10 @@ class TPUScheduler(Scheduler):
         except Unsupported:
             return None
         except Exception as e:  # noqa: BLE001 - crash-proof fallback
-            # The failure class ADVICE r5 found (victim tensors at one
-            # r_slots width, the plan at another) lands here if a new
-            # variant ever appears: one count, one breaker charge, and the
-            # host Evaluator reruns the dry run exactly — never a crashed
+            # A shape error (once: victim tensors at one r_slots width,
+            # the plan at another) lands here if a new variant ever
+            # appears: one count, one breaker charge, and the host
+            # Evaluator reruns the dry run exactly — never a crashed
             # PostFilter cycle.
             self._note_device_failure(e, "preemption_dry_run")
             return None
@@ -946,7 +939,7 @@ class TPUScheduler(Scheduler):
         if vic_req.shape[2] != self.mirror.r_slots:
             # build_plan interned the preemptor's never-seen scalar slots
             # AFTER the victim tensors were built, growing the mirror's
-            # resource tier (ADVICE r5 medium). The grown slots name
+            # resource tier. The grown slots name
             # resources no victim carries, so zero-padding vic_req to the
             # plan's width is exact — without it the kernel's
             # `state.req_r - sum_vic` raises a shape error.
@@ -1158,11 +1151,10 @@ class TPUScheduler(Scheduler):
             self.metrics.plan_anti_lane.inc(
                 "true" if plan.anti_rowlocal else "false")
 
-    def warm_for(self, pod, batch_sizes: Optional[List[int]] = None,
-                 nominated: bool = False) -> None:
+    def warm_for(self, pod, nominated: bool = False) -> None:
         """Compile the kernel shapes a workload of `pod`-shaped pods will hit,
         WITHOUT scheduling anything: dispatches with n_active=0 are fully
-        inert (every scan step is padding). Benchmark harnesses call this so
+        inert (every scan step is padding). Measuring harnesses call this so
         XLA compilation lands outside the measured window. Warms both the
         fresh-carry and chained-carry traces.
 
@@ -1170,22 +1162,21 @@ class TPUScheduler(Scheduler):
         dispatch (run_device_session) — `carry_in=None` passed explicitly is
         a DIFFERENT kwargs pytree than omitting the kwarg, and a mismatch
         recompiles (~1 min) inside the measured window. Sessions always plan
-        with self.max_batch, so that is the only batch_pad tier to warm;
-        `batch_sizes` is accepted for compatibility but ignored."""
-        del batch_sizes
+        with self.max_batch, so that is the only batch_pad tier to warm."""
         fw = self.framework_for_pod(pod)
         if batch_supported(pod, self.snapshot,
                            fit_plugin=fw.plugin("NodeResourcesFit")) is not None:
             return
         state, plan = self.build_plan(fw, pod, self.max_batch)
-        # Warm dispatches must ride _dispatch (call-path identity) but must
-        # not count as engagement: shard_map_dispatches is what the bench
-        # detail and the MULTICHIP dryrun assert LIVE dispatches against.
-        _smd0 = self.shard_map_dispatches
-        results, carry = self._dispatch(state, plan, 0, None)
-        results2, _ = self._dispatch(state, plan, 0, carry)
-        np.asarray(results2)  # block until compiled + executed
-        self.shard_map_dispatches = _smd0
+
+        def warm(p, dispatch=partial(self._dispatch, count=False)):
+            # The live call path itself, uncounted: shard_map_dispatches
+            # says how many LIVE dispatches rode the sharded lap.
+            _res, carry = dispatch(state, p, 0, None)
+            res, _ = dispatch(state, p, 0, carry)
+            np.asarray(res)  # block until compiled + executed
+
+        warm(plan)
         if self._shard_map_fn(plan) is not None:
             # The live dispatch rides the shard_map lap path — but a
             # mid-workload row_local flip (an anti-affinity pod lands and
@@ -1194,25 +1185,18 @@ class TPUScheduler(Scheduler):
             # schedule_batch fallback. Warm that trace too, or the flip
             # puts its ~1min XLA compile inside the measured window (the
             # same hazard as the anti_rowlocal fallback below).
-            r1, c1 = self._gspmd_dispatch(state, plan, 0, None)
-            r2, _ = self._gspmd_dispatch(state, plan, 0, c1)
-            np.asarray(r2)
+            warm(plan, self._gspmd_dispatch)
         if plan.anti_rowlocal:
             # anti_rowlocal is topology-derived (all anti axes singleton) and
             # can flip to False mid-workload (e.g. churn adds a node sharing a
             # hostname-like value): warm the conservative fallback trace too
             # so the flip can't put a compile inside the measured window.
-            import dataclasses
-            fb = dataclasses.replace(plan, anti_rowlocal=False)
-            r1, c1 = self._dispatch(state, fb, 0, None)
-            r2, _ = self._dispatch(state, fb, 0, c1)
-            np.asarray(r2)
+            warm(dataclasses.replace(plan, anti_rowlocal=False))
         if nominated and not plan.has_nom:
             # Preemption workloads flip the nominated lane on mid-run (the
             # first nomination would otherwise compile inside the measured
             # window): warm the has_nom variant with an empty lane — shapes
             # and statics are identical to the live nominated plan.
-            import dataclasses
             import jax.numpy as jnp
             nom_req = jnp.zeros((self.mirror.np_cap, self.mirror.r_slots),
                                 jnp.int64)
@@ -1227,10 +1211,7 @@ class TPUScheduler(Scheduler):
                 nom_pods = jax.device_put(
                     nom_pods, NamedSharding(self.mesh, P("nodes")))
             nf = plan.features._replace(nom_req=nom_req, nom_pods=nom_pods)
-            nv = dataclasses.replace(plan, features=nf, has_nom=True)
-            r1, c1 = self._dispatch(state, nv, 0, None)
-            r2, _ = self._dispatch(state, nv, 0, c1)
-            np.asarray(r2)
+            warm(dataclasses.replace(plan, features=nf, has_nom=True))
 
     def warm_for_placements(self, pod, group_size: int,
                             n_placements: int) -> None:
@@ -1273,14 +1254,13 @@ class TPUScheduler(Scheduler):
 
     def _shard_map_fn(self, plan):
         """The explicit-collectives lap kernel for this plan, or None when
-        the GSPMD-compiled schedule_batch owns the dispatch. Row-local
-        plans (BatchPlan.row_local) at production batch tiers ride
-        shard_map: per-shard work is provably local and the per-lap
-        collectives are two small exchanges (vs GSPMD's inferred ~2×
-        count, MULTICHIP_r05). Small batches keep the scan path — the lap
-        gains nothing there (ops/kernel.py static_scores threshold)."""
-        if (self.mesh is None or not self._shard_map_enabled
-                or not plan.row_local or plan.batch_pad <= 64):
+        the GSPMD-compiled schedule_batch owns the dispatch. Under a mesh a
+        plan that rides the lap (BatchPlan.rides_lap) and is row-local
+        (BatchPlan.row_local) takes shard_map: per-shard work is provably
+        local and the per-lap collectives are two small exchanges, where
+        GSPMD infers about twice as many (collective counts of compiled
+        programs, a CPU dry run — not a speed)."""
+        if self.mesh is None or not (plan.rides_lap and plan.row_local):
             return None
         from ..parallel.mesh import mesh_shard_count, sharded_lap_schedule
         if self.mirror.np_cap % mesh_shard_count(self.mesh):
@@ -1288,20 +1268,20 @@ class TPUScheduler(Scheduler):
         return sharded_lap_schedule(self.mesh, plan.batch_pad,
                                     plan.fit_strategy, plan.vmax)
 
-    def _dispatch(self, state, plan, n_active: int, carry):
+    def _dispatch(self, state, plan, n_active: int, carry, count=True):
         """The ONLY kernel call site. Every dispatch — warm or live — must
         be call-signature-identical (kwarg set included: static kwargs are
         part of jit's cache-key pytree structure), or the warmed trace
         misses and a ~1min XLA compile lands inside the measured window.
         The path choice (shard_map lap vs GSPMD schedule_batch) is a pure
-        function of (mesh, plan statics), so it is constant for a
-        session's lifetime and warm_for warms the same path the live
-        session runs."""
+        function of (mesh, plan), so it is constant for a session's
+        lifetime and warm_for warms the same path the live session runs
+        (with `count=False`: not a live dispatch)."""
         if self._fault_hook is not None:
             self._fault_hook("dispatch")
         fn = self._shard_map_fn(plan)
         if fn is not None:
-            self.shard_map_dispatches += 1
+            self.shard_map_dispatches += count
             return fn(state, plan.features, np.int32(n_active), carry)
         return self._gspmd_dispatch(state, plan, n_active, carry)
 
@@ -1320,9 +1300,9 @@ class TPUScheduler(Scheduler):
     def collective_counts(self, pod, batch_size: Optional[int] = None):
         """Compile-time per-step collective profile of the EXACT dispatch a
         `pod`-shaped session runs (ici/dcn split via
-        parallel/mesh.py collective_report), or None off-mesh. This is the
-        number the MULTICHIP rows regression-pin: the row-local shard_map
-        path must stay at-or-below the GSPMD baseline per step."""
+        parallel/mesh.py collective_report), or None off-mesh: the count
+        tests/test_sharded_mesh.py pins (the row-local shard_map path
+        at-or-below the GSPMD baseline per step)."""
         if self.mesh is None:
             return None
         from ..parallel.mesh import collective_report, mesh_host_split
@@ -1388,8 +1368,7 @@ class TPUScheduler(Scheduler):
         else:
             self.plan_rebuilds_resume += 1
         # plane label: mesh full rebuilds are the cost the delta patches
-        # exist to avoid (a sharded teardown re-uploads the whole state) —
-        # the MULTICHIP rows regression-pin the split.
+        # exist to avoid (a sharded teardown re-uploads the whole state).
         self.metrics.plan_rebuild_total.inc(
             kind, "mesh" if self.mesh is not None else "single")
 
@@ -1876,7 +1855,7 @@ class TPUScheduler(Scheduler):
         while True:
             # Refill the dispatch pipeline (depth-bounded): dispatch is
             # async — these calls enqueue device work and return immediately.
-            while not invalidated and len(inflight) < self.pipeline_depth:
+            while not invalidated and len(inflight) < PIPELINE_DEPTH:
                 if sd.patch_pending:
                     if inflight:
                         break  # retire dispatched work before patching
@@ -1997,7 +1976,7 @@ class TPUScheduler(Scheduler):
                     # identical pod — persist it for the host-only bind loop.
                     from .score_hints import hint_eligible
                     if self._hints.enabled and hint_eligible(
-                            plan, self.mesh, aux_shape, first_batch[0].pod,
+                            plan, aux_shape, first_batch[0].pod,
                             self.extenders, self.queue.nominator,
                             self.cache.affinity_pod_refs):
                         self._hints.install(fw, first_batch[0].pod, sig, nsig,

@@ -180,11 +180,12 @@ class BatchPlan:
     # (static per plan; any nomination add/delete invalidates the session
     # via Nominator.version).
     has_nom: bool = False
-    # No pod-derived feature coupling anywhere in the plan: no spread or
-    # (anti-)affinity count tables, no landing score deltas, no existing-pod
-    # anti-affinity hits. A pod arriving on / leaving node n then dirties
-    # ONLY row n's resource aggregates — the precondition for the event-
-    # journal delta patch (models/tpu_scheduler.py _classify_delta).
+    # No pod-derived lane anywhere in the plan: every width ops/kernel.py
+    # `coupling` reads is zero, and (what only the values can say) no
+    # nonzero ipa_base and no existing-pod anti-affinity hit in exist_anti.
+    # A pod arriving on / leaving node n then dirties ONLY row n's resource
+    # aggregates — the precondition for the event-journal delta patch
+    # (models/tpu_scheduler.py _classify_delta).
     pod_local: bool = False
     # Host bookkeeping of the required inter-pod term tables (the stage
     # `plan.ipa`): the kernel's anti filter has something to refuse (the
@@ -195,17 +196,36 @@ class BatchPlan:
     ipa_term_pods: int = 0        # existing pods with a required anti term
 
     @property
+    def coupling(self):
+        """ops/kernel.py `coupling` of this plan: what schedule_batch
+        derives at trace time from the same shapes and flags."""
+        from .kernel import coupling
+        return coupling(self.features, self.batch_pad, has_pns=self.has_pns,
+                        has_ipa_base=self.has_ipa_base,
+                        anti_rowlocal=self.anti_rowlocal,
+                        has_na_pref=self.has_na_pref)
+
+    @property
+    def rides_lap(self) -> bool:
+        """schedule_batch places this plan with the lap kernel, not the
+        scan."""
+        return self.coupling.lap
+
+    @property
     def row_local(self) -> bool:
         """True when a landing changes feasibility AND scores only at its
-        own landed row (the kernel's scores_carried ∧ incremental_feas with
-        zero cross-row coupling of any kind): the precondition for the
-        explicit shard_map lap kernel (parallel/mesh.py sharded_lap_schedule
-        — per-shard work is provably local, collectives are two small
-        per-lap exchanges) and, with the same math host-side, for the
-        score-hint walk (models/score_hints.py)."""
-        return (self.pod_local and not self.has_pns and not self.has_na_pref
-                and not self.has_nom and not self.port_selfblock
-                and not self.has_aux)
+        own landed row, through the fit lanes alone: `pod_local` (so
+        feasibility is incremental with no anti lane at all), scores
+        carried, and none of the per-row lanes only the single-device lap
+        carries (nominated pods, host-port self-block, counted aux). The
+        precondition for the explicit shard_map lap kernel
+        (parallel/mesh.py sharded_lap_schedule — per-shard work is provably
+        local, collectives are two small per-lap exchanges) and, with the
+        same math host-side, for the score-hint walk
+        (models/score_hints.py)."""
+        return (self.pod_local and self.coupling.scores_carried
+                and not (self.has_nom or self.port_selfblock
+                         or self.has_aux))
 
     # Host-side per-node topology-spread columns (numpy, NOT shipped to the
     # kernel): per-constraint per-node matching-pod counts + domain

@@ -280,6 +280,54 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
     return fit_ok, fit_sc, ba
 
 
+# The largest batch_pad that stays on the scan whatever its coupling
+# (ops/features.py _batch_tier: the gang-sized tiers 8 and 64). The scan's
+# per-step body is ~6 fused ops against the lap's [LAP_MAX, NP] window
+# tensors, and a 4-member gang gets no lap parallelism anyway (with
+# truncation inactive every window spans the whole rotation, L=1).
+SCAN_MAX_BATCH = 64
+
+
+class Coupling(NamedTuple):
+    """How far one landing reaches in a built batch: which engine may place
+    it is read from here and nowhere else (schedule_batch below at trace
+    time; BatchPlan.rides_lap and .row_local on the host, and through them
+    the sharded lap, the score-hint walk and warm_for)."""
+
+    # Feasibility can change only at the landed row.
+    incremental_feas: bool
+    # The total score can change only at the landed row: it rides the carry
+    # instead of being recomputed.
+    scores_carried: bool
+    # Both, in a batch past the scan's tiers: _lap_schedule places a whole
+    # lap of pods per iteration.
+    lap: bool
+
+
+def coupling(f: BatchFeatures, batch_pad: int, *, has_pns: bool,
+             has_ipa_base: bool, anti_rowlocal: bool,
+             has_na_pref: bool) -> Coupling:
+    """The coupling facts of a batch from its lane widths (only the shapes
+    of `f` are read, so a tracer or a sharded pytree serves) and the four
+    static flags that bear on them. DNS skew and required-affinity counts
+    couple whole domains, but a required ANTI term on a singleton axis
+    (hostname: `anti_rowlocal`) only ever blocks the landed row itself;
+    every kept-set normalization (soft spread, preferred inter-pod terms and
+    their base score, PreferNoSchedule counts, preferred node affinity)
+    makes each score depend on the whole window."""
+    C1 = f.dns_axis.shape[0]
+    C2 = f.sa_axis.shape[0]
+    A1 = f.anti_axis.shape[0]
+    A2 = f.aff_axis.shape[0]
+    KD = f.ipa_axis.shape[0]
+    incremental_feas = C1 == 0 and A2 == 0 and (A1 == 0 or anti_rowlocal)
+    scores_carried = (C2 == 0 and KD == 0 and not has_pns
+                      and not has_ipa_base and not has_na_pref)
+    return Coupling(incremental_feas, scores_carried,
+                    incremental_feas and scores_carried
+                    and batch_pad > SCAN_MAX_BATCH)
+
+
 @partial(jax.jit, static_argnames=("batch_pad", "fit_strategy", "vmax",
                                    "has_pns", "has_ipa_base", "anti_rowlocal",
                                    "has_na_pref", "port_selfblock", "has_aux",
@@ -331,22 +379,9 @@ def schedule_batch(
     # so the rotation comes back out with a mask.
     RADIX = _pow2(NP)
 
-    # Feasibility can change only at the landed row when no cross-window
-    # topology filter is active — DNS skew and required-affinity counts
-    # couple whole domains, but a required ANTI term on a singleton axis
-    # (hostname) only ever blocks the landed row itself.
-    incremental_feas = C1 == 0 and A2 == 0 and (A1 == 0 or anti_rowlocal)
-    # The total score vector changes only at the landed row (no kept-set
-    # normalization terms): it rides the carry instead of being recomputed.
-    scores_carried = (C2 == 0 and KD == 0 and not has_pns
-                      and not has_ipa_base and not has_na_pref)
-    # No cross-window coupling at all: place a whole lap of pods per
-    # iteration (the fast path for fit-only and hostname-anti-affinity pods).
-    # Small batches (gang-sized placement sims) stay on the scan path — its
-    # per-step body is ~6 fused ops vs the lap's [LAP_MAX, NP] window
-    # tensors, and a 4-member gang gets no lap parallelism anyway (with
-    # truncation inactive every window spans the whole rotation, L=1).
-    static_scores = incremental_feas and scores_carried and batch_pad > 64
+    incremental_feas, scores_carried, static_scores = coupling(
+        f, batch_pad, has_pns=has_pns, has_ipa_base=has_ipa_base,
+        anti_rowlocal=anti_rowlocal, has_na_pref=has_na_pref)
 
     with jax.named_scope("taints"):
         taint_ok, pns_cnt, sel_ok, name_ok, unsched_ok, exist_anti_ok = _static_masks(state, f)

@@ -170,7 +170,7 @@ def collective_report(compiled_text: str, n_hosts: int, per_host: int) -> dict:
         # Match the FULL braced list: a non-greedy `\{(.*?)\}` would stop at
         # the first '}' of nested groups like {{0,1},{2,3}} and classify
         # only the first replica group — a collective whose later groups
-        # span hosts would be misreported as ICI (ADVICE r5).
+        # span hosts would be misreported as ICI.
         rg = re.search(
             r"replica_groups=\{(\{[^}]*\}(?:,\{[^}]*\})*|[^{}]*)\}", line)
         if rg is not None:
@@ -249,7 +249,9 @@ def _lap_body(state: DeviceNodeState, f: BatchFeatures, n_active, ext0,
     single-device lap (and therefore to the scan and the host oracle).
     GSPMD compiles the same math from sharding propagation but inserts
     ~2× the collectives per step because it cannot prove the carried
-    per-node lanes stay shard-local (MULTICHIP_r05 baseline)."""
+    per-node lanes stay shard-local (collective counts of the two compiled
+    programs on a virtual CPU mesh, tests/test_sharded_mesh.py — a count,
+    not a speed)."""
     NPl = state.valid.shape[0]
     RADIX = _pow2(NPl * n_shards)  # of the packed selection key
     SHARD_BITS = max(n_shards - 1, 1).bit_length()

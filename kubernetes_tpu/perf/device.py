@@ -1,9 +1,8 @@
 """What a measuring entry point ran on, and whether the device path held.
 
-Shared by bench.py, bench_sweep.py, `python -m kubernetes_tpu.perf`,
-chip_smoke.py and the binaries' `--platform` flag so that no number (and no
-ready line) is labelled with a device that did not
-produce it: the platform comes from `jax.devices()`, never from an
+Shared by `python -m kubernetes_tpu.perf`, chip_smoke.py, benchmark/run.py's
+drivers and the binaries' `--platform` flag so that no number (and no ready
+line) is labelled with a device that did not produce it: the platform comes from `jax.devices()`, never from an
 environment variable, and a run during which the device-path circuit
 breaker was charged (models/tpu_scheduler.py `_note_device_failure` — the
 pods were rescheduled on the host Evaluator) is a failed measurement even

@@ -641,8 +641,6 @@ def run_hollow_workload(wl: Workload) -> PerfResult:
         # dispatch mesh-SPMD (the 100k fusion row runs both).
         hollow_procs=int(params.get("hollowProcs", 1)),
         mesh_devices=int(params.get("meshDevices", 0)),
-        child_env=({"TPU_SCHED_HINT_LRU": str(params["hintLru"])}
-                   if params.get("hintLru") else None),
         replicas=int(params.get("replicas", 0)),
         lease_duration=float(params.get("leaseDuration", 15.0)),
         warm_pods=warm_pods,
@@ -1015,19 +1013,6 @@ def run_workload(wl: Workload, sched: Optional[Scheduler] = None) -> PerfResult:
     # Device→host fallbacks by reason: `python -m kubernetes_tpu.perf`
     # fails a run whose breaker was charged (perf/device.py).
     result.detail["device_path_fallback"] = fallbacks_by_reason(sched)
-    # Mesh plane: compile-time per-step ici/dcn collective counts of the
-    # workload's own dispatch path (the MULTICHIP collective budget).
-    # Opt-in (one lower+compile per run) — the bench/dryrun mains set it.
-    import os as _os
-    if (getattr(sched, "mesh", None) is not None
-            and wl.default_pod_template
-            and _os.environ.get("TPU_SCHED_COLLECTIVES_DETAIL") == "1"):
-        try:
-            result.detail["collectives"] = sched.collective_counts(
-                _make_pod_from_template("collective-probe",
-                                        dict(wl.default_pod_template)))
-        except Exception as e:  # noqa: BLE001 - detail only, never the run
-            result.detail["collectives"] = {"error": str(e)[:200]}
     # Per-extension-point latency (scheduler_perf.go:866-871 collects the
     # framework_extension_point_duration_seconds histogram per workload).
     hist = sched.metrics.framework_extension_point_duration

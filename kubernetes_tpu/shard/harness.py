@@ -2,8 +2,9 @@
 processes (`python -m kubernetes_tpu --shard-index i --shard-count n`),
 driven entirely over HTTP. This is the production-shaped scale-out path —
 each shard is an OS process with its own GIL, so shard throughput actually
-adds up on CPU — used by ``bench.py --shards N``, the perf harness's
-ShardedSchedulingBasic workload, and the shard-kill chaos test.
+adds up on CPU — used by the perf harness's sharded and hollow rows
+(``python -m kubernetes_tpu.perf --labels sharded``) and the shard-kill
+chaos test.
 """
 
 from __future__ import annotations
@@ -475,7 +476,6 @@ def run_sharded_cluster(
     hollow=None,
     hollow_procs: int = 1,
     mesh_devices: int = 0,
-    child_env: Optional[dict] = None,
     node_lifecycle=None,
     flood=None,
     workload=None,
@@ -562,7 +562,6 @@ def run_sharded_cluster(
             # SIGTERM stats lines collectable at teardown.
             workload=workload,
             deschedule=deschedule,
-            env=dict(child_env or {}),
             fair_tenants=flood is not None,
             # A tightened workload lane makes shedding demonstrable at
             # test-box scale (stock lanes mostly ADMIT a paced flood — APF
@@ -894,7 +893,7 @@ def run_sharded_cluster(
         except Exception:  # noqa: BLE001 - replica down mid-teardown
             pass
         # Cross-shard e2e latency truth (queue admission -> bound): merged
-        # cumulative buckets, the p50/p99 bench.py --shards reports.
+        # cumulative buckets, the p50/p99 a sharded perf row reports.
         e2e = merge_histograms(e2e_hists)
         e2e_ms = None
         if e2e is not None and e2e["count"]:
@@ -904,7 +903,7 @@ def run_sharded_cluster(
                 "count": int(e2e["count"]),
             }
         # Replication detail: per-replica role/lag (leader + followers) —
-        # the bench.py --shards --replicas detail line.
+        # the detail of a sharded perf row with replicas.
         replication = None
         if cluster.follower_urls:
             replication = []

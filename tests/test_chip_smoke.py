@@ -6,10 +6,9 @@
   missing chip — as it does standing alone without the package;
 - `python -m kubernetes_tpu --platform tpu` exits non-zero before any ready
   line when JAX has no TPU;
-- bench.py / `python -m kubernetes_tpu.perf` refuse a backend nobody asked
-  for, label what ran from `jax.devices()`, and fail a run during which the
-  device-path breaker was charged — while scheduling still completes on the
-  host path;
+- `python -m kubernetes_tpu.perf` refuses a backend nobody asked for, labels
+  what ran from `jax.devices()`, and fails a run during which the device-path
+  breaker was charged — while scheduling still completes on the host path;
 - the compile cache goes where JAX_COMPILATION_CACHE_DIR says and nowhere
   else, `<checkout>/.jax_cache` otherwise, and a second process on the same
   shapes adds nothing (the spawned-binary case rides
@@ -109,45 +108,19 @@ def test_measuring_device_refuses_a_backend_nobody_asked_for(monkeypatch):
         measuring_device()
 
 
-def _fail_first_dispatch_after(monkeypatch, method: str):
-    """Every TPUScheduler fails the first device dispatch that follows its
-    `method` call — once, so the breaker is charged but stays closed."""
-    from kubernetes_tpu.models import TPUScheduler
-
-    orig = getattr(TPUScheduler, method)
-
-    def wrapped(self, *a, **kw):
-        out = orig(self, *a, **kw)
-        self._fault_hook = DeviceFaults(dispatch={1})
-        return out
-
-    monkeypatch.setattr(TPUScheduler, method, wrapped)
-
-
-def test_bench_fails_when_the_breaker_was_charged(monkeypatch, capsys):
-    sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setenv("BENCH_NODES", "40")
-    monkeypatch.setenv("BENCH_PODS", "300")
-    monkeypatch.setenv("BENCH_WARMUP", "8")
-    _fail_first_dispatch_after(monkeypatch, "warm_for")  # the warm block's
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 1
-    cap = capsys.readouterr()
-    assert cap.out.strip() == ""  # no number on stdout
-    err = json.loads(cap.err.strip().splitlines()[-1])
-    assert err["charges"] == {"RuntimeError": 1}
-    # the breaker's guarantee is untouched: every pod still scheduled
-    assert err["result"]["detail"]["scheduled"] == 300
-    assert err["result"]["detail"]["platform"] == "cpu"
-
-
 def test_perf_table_fails_when_the_breaker_was_charged(monkeypatch, tmp_path):
+    from kubernetes_tpu.models import TPUScheduler
     from kubernetes_tpu.perf.__main__ import main
 
-    _fail_first_dispatch_after(monkeypatch, "__init__")  # the init pods'
+    orig = TPUScheduler.__init__
+
+    def init(self, *a, **kw):
+        """Every TPUScheduler fails its first device dispatch (the init
+        pods') — once, so the breaker is charged but stays closed."""
+        orig(self, *a, **kw)
+        self._fault_hook = DeviceFaults(dispatch={1})
+
+    monkeypatch.setattr(TPUScheduler, "__init__", init)
     out = tmp_path / "perf.json"
     rc = main(["--labels", "short", "--scale", "0.1", "--out", str(out),
                "--only", "SchedulingBasic/500Nodes_1000Pods"])

@@ -9,7 +9,6 @@ mismatch: a ~1min compile inside every measured window. These tests pin the
 invariant with jit's trace-cache size so it can never silently return.
 """
 
-import numpy as np
 import pytest
 
 from kubernetes_tpu.core import FakeClientset
@@ -63,3 +62,108 @@ def test_no_retrace_after_warm(template):
     assert _cache_size() == warmed, (
         "live dispatch retraced schedule_batch after warm_for — a compile "
         "would land inside the measured window on real hardware")
+
+
+def _flip_shared_hostname(cs):
+    """A node that shares another's hostname value: the anti axis is no
+    longer one node a value, so anti_rowlocal reads False."""
+    cs.create_node(
+        make_node().name("n-dup").label("kubernetes.io/hostname", "n0")
+        .capacity({"cpu": 16, "memory": "64Gi", "pods": 110})
+        .zone("z0").obj())
+
+
+def _flip_existing_anti(cs):
+    """A bound pod whose required anti-affinity term matches the measured
+    pods: exist_anti goes nonzero, the plan is no longer pod_local."""
+    cs.create_pod(
+        make_pod().name("guard").node("n1").req({"cpu": "100m"})
+        .pod_affinity("kubernetes.io/hostname", {"app": "t"}, anti=True)
+        .obj())
+
+
+@pytest.mark.parametrize("flip", [_flip_shared_hostname, _flip_existing_anti])
+def test_no_retrace_when_a_plan_leaves_the_engine_it_was_warmed_on(flip):
+    """warm_for also warms the program a mid-workload flip of the coupling
+    facts lands on: the anti-affinity plan's scan fallback (anti_rowlocal
+    turns False) and, under a mesh, the row-local plan's GSPMD
+    schedule_batch (the plan stops being row-local)."""
+    cs, s = _cluster()
+    assert s.mesh is not None
+
+    def pod(name):
+        b = make_pod().name(name).req({"cpu": "100m"}).label("app", "t")
+        if flip is _flip_shared_hostname:
+            b = b.pod_affinity("kubernetes.io/hostname", {"app": "t"},
+                               anti=True)
+        return b.obj()
+
+    fw = next(iter(s.profiles.values()))
+    _state, before = s.build_plan(fw, pod("probe"), s.max_batch)
+    assert before.rides_lap
+    assert (s._shard_map_fn(before) is not None) == (
+        flip is _flip_existing_anti)
+    s.warm_for(pod("warm-template"))
+    warmed = _cache_size()
+    flip(cs)
+    _state, after = s.build_plan(fw, pod("probe"), s.max_batch)
+    assert (before.anti_rowlocal, before.pod_local) != (
+        after.anti_rowlocal, after.pod_local)
+    assert s._shard_map_fn(after) is None
+    for i in range(30):
+        cs.create_pod(pod(f"p{i}"))
+    s.run_until_idle()
+    assert s.scheduled == 30 and s.host_path_pods == 0
+    assert s.shard_map_dispatches == 0
+    assert _cache_size() == warmed, (
+        "the flipped plan retraced schedule_batch: warm_for did not warm "
+        "the program it falls back to")
+
+
+# What one landing reaches, by plan shape: ops/kernel.py `coupling` as the
+# host reads it (BatchPlan.rides_lap / .row_local), and what follows from
+# it: the score-hint walk (hint_eligible) and, under the mesh, the explicit
+# shard_map lap (_shard_map_fn). The table is the contract; a new lane adds
+# a row.
+#   shape            (rides_lap, row_local, hint_eligible, shard_map)
+_ENGINES = {
+    "fit_only":      (True,  True,  True,  True),
+    "small_batch":   (False, True,  True,  False),
+    "hostname_anti": (True,  False, False, False),
+    "hard_spread":   (False, False, False, False),
+    "na_preferred":  (False, False, False, False),
+    "host_port":     (True,  False, False, False),
+    "nominated":     (True,  False, False, False),
+}
+
+
+@pytest.mark.parametrize("shape", list(_ENGINES))
+def test_one_predicate_decides_the_engine(shape):
+    from kubernetes_tpu.core.node_info import PodInfo
+    from kubernetes_tpu.models.score_hints import hint_eligible
+    from kubernetes_tpu.ops.kernel import SCAN_MAX_BATCH
+
+    cs, s = _cluster()
+    assert s.mesh is not None  # tests/conftest.py: 8 virtual devices
+    b = make_pod().name("probe").req({"cpu": "100m"}).label("app", "t")
+    if shape == "hostname_anti":
+        b = b.pod_affinity("kubernetes.io/hostname", {"app": "t"}, anti=True)
+    elif shape == "hard_spread":
+        b = b.spread_constraint(1, ZONE, "DoNotSchedule", {"app": "t"})
+    elif shape == "na_preferred":
+        b = b.preferred_node_affinity(10, ZONE, ["z1"])
+    elif shape == "host_port":
+        b = b.host_port(8080)
+    elif shape == "nominated":
+        s.queue.nominator.add_nominated_pod(
+            PodInfo.of(make_pod().name("nominee").priority(10)
+                       .req({"cpu": "1"}).obj()), "n3")
+    pod = b.obj()
+    batch = SCAN_MAX_BATCH if shape == "small_batch" else s.max_batch
+    fw = s.framework_for_pod(pod)
+    _state, plan = s.build_plan(fw, pod, batch)
+    got = (plan.rides_lap, plan.row_local,
+           hint_eligible(plan, (None, None), pod, s.extenders,
+                         s.queue.nominator, s.cache.affinity_pod_refs),
+           s._shard_map_fn(plan) is not None)
+    assert got == _ENGINES[shape]

@@ -362,7 +362,7 @@ def test_quantity_string_equality_in_expressions():
 
 
 def test_quantity_hash_eq_consistency():
-    """ADVICE r5 regression: coerced quantity values must satisfy the
+    """Regression: coerced quantity values must satisfy the
     hash/eq contract (a == b ⇒ hash(a) == hash(b)) for EVERY pairing of
     coerced, raw-string, and plain-numeric forms — so mixing them in one
     set or dict is well-defined. Cross-type string equality was dropped

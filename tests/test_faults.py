@@ -340,14 +340,14 @@ def test_sidecar_repeated_kill_stress(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# device-path circuit breaker + the ADVICE r5 shape-error regression
+# device-path circuit breaker + the shape-error regression
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.chaos
 class TestDeviceBreaker:
     def test_preemption_never_interned_scalar_regression(self):
-        """ADVICE r5 medium: a preemptor carrying a scalar resource the
+        """A preemptor carrying a scalar resource the
         mirror never interned grows r_slots inside build_plan AFTER the
         victim tensors were built; the dry run must zero-pad and run, not
         crash the PostFilter cycle with a shape error."""
@@ -1729,7 +1729,7 @@ def test_apiserver_chaos_run_under_lockwatch_is_cycle_free():
 
 
 # ---------------------------------------------------------------------------
-# satellite regressions (ADVICE r5 low items)
+# satellite regressions
 # ---------------------------------------------------------------------------
 
 

@@ -469,7 +469,7 @@ class TestMeshAndLapWalk:
         _both(oracle, dev, lambda s: [s.clientset.create_pod(
             proto.clone_from_template(f"a-{i}")) for i in range(8)])
         entry = dev._hints.entry
-        assert entry is not None and entry.lap_enabled
+        assert entry is not None
         _both(oracle, dev, lambda s: [s.clientset.create_pod(
             proto.clone_from_template(f"b-{i}")) for i in range(60)])
         _assert_identical(oracle, dev, "(lap walk)")
@@ -480,14 +480,15 @@ class TestMeshAndLapWalk:
         assert e.lap_walks * 2 <= dev.hint_hits, (
             e.lap_walks, dev.hint_hits)
 
-    def test_lap_disabled_env_pins_per_pod_walk(self, monkeypatch):
-        monkeypatch.setenv("TPU_SCHED_HINT_LAP", "0")
+    def test_without_truncation_the_walk_is_per_pod(self):
+        """The default percentage_of_nodes_to_score finds at least 100
+        feasible nodes: of 150 a lap would hold one window (L < 2), so each
+        pod walks alone — no lap, still bit-identical."""
         oracle = TPUScheduler(max_batch=32, mesh=None)
         oracle._hints.enabled = False
         dev = TPUScheduler(max_batch=32, mesh=None)
         for s in (oracle, dev):
-            s.percentage_of_nodes_to_score = 10
-            for i in range(200):
+            for i in range(150):
                 s.clientset.create_node(_node(f"node-{i}"))
         proto = _pod("proto", cpu="100m")
         _both(oracle, dev, lambda s: [s.clientset.create_pod(
@@ -496,7 +497,8 @@ class TestMeshAndLapWalk:
             proto.clone_from_template(f"b-{i}")) for i in range(20)])
         _assert_identical(oracle, dev, "(per-pod walk)")
         e = dev._hints.entry
-        assert e is not None and not e.lap_enabled and e.lap_walks == 0
+        assert e is not None and e.lap_walks == 0
+        assert dev.hint_hits >= 20
 
 
 class TestRequeueConflictEnqueuedAt:
@@ -647,9 +649,9 @@ def test_churn_equivalence_fuzz(seed):
 
 class TestHintLru:
     """The 2-way signature-keyed LRU (ISSUE 19 satellite): alternating
-    deployment shapes keep BOTH on the host path; TPU_SCHED_HINT_LRU=1 is
-    the single-slot A/B baseline. Exactness is non-negotiable either way —
-    every scenario holds the always-dispatch oracle equivalence."""
+    deployment shapes keep BOTH on the host path. Exactness is
+    non-negotiable — every scenario holds the always-dispatch oracle
+    equivalence."""
 
     def test_two_shapes_alternate_without_thrash(self):
         """Two replica shapes interleaving through one queue bind with
@@ -670,24 +672,6 @@ class TestHintLru:
         _assert_identical(oracle, dev)
         assert dev.device_batches == b0, "alternating shapes thrashed"
         assert dev.hint_hits - h0 >= 40
-
-    def test_lru_capacity_one_is_the_single_slot_baseline(self, monkeypatch):
-        """TPU_SCHED_HINT_LRU=1 (the A/B seam): the second shape's install
-        evicts the first (counted, labeled lru_evict) and only one entry is
-        ever live — the historical behavior, still oracle-exact."""
-        monkeypatch.setenv("TPU_SCHED_HINT_LRU", "1")
-        oracle, dev = _pair()
-        assert dev._hints.capacity == 1
-        _both(oracle, dev, lambda s: [s.clientset.create_pod(
-            _pod(f"seed-a-{i}", cpu="200m")) for i in range(6)])
-        _both(oracle, dev, lambda s: [s.clientset.create_pod(
-            _pod(f"seed-b-{i}", cpu="400m")) for i in range(6)])
-        assert len(dev._hints.entries) == 1
-        assert dev.metrics.hint_cache_invalidations.value("lru_evict") >= 1
-        _both(oracle, dev, lambda s: [s.clientset.create_pod(
-            _pod(f"alt-{i}", cpu=("200m" if i % 2 == 0 else "400m")))
-            for i in range(20)])
-        _assert_identical(oracle, dev)
 
     def test_third_shape_evicts_coldest(self):
         """At capacity 2 a third shape pushes out the least-recently-used

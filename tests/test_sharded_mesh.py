@@ -197,7 +197,8 @@ def test_shard_map_is_production_dispatch_for_row_local_plans():
 
 
 def test_shard_map_collectives_at_or_below_gspmd_baseline():
-    """The collective budget (MULTICHIP acceptance): per step, the
+    """The collective budget (counts of compiled programs on the virtual
+    CPU mesh, not a speed): per step, the
     explicit shard_map path must not exceed the GSPMD-compiled baseline in
     any op class total, and should drive the overall count DOWN."""
     import numpy as np

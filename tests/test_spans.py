@@ -317,8 +317,7 @@ class TestStageLedger:
         spans on the timeline of the device operations, nested inside the
         benchmark's `bench.wave` (CPU rehearsal of a traced run)."""
         bench = os.path.join(REPO, "benchmark")
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("BENCH_RUN", "XLA_FLAGS")}
+        env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
         proc = subprocess.run(
             [sys.executable, os.path.join(bench, "run.py"), "--workload",
              "spread-5k.waves", "--seed", "2147483777", "--seconds", "0.5",
