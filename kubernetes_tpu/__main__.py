@@ -173,10 +173,6 @@ def main(argv=None) -> int:
           f"device_kind={device['kind']!r} "
           f"devices={device['count']})", flush=True)
 
-    # Collector pauses stop the loop wherever it is: one gc.callbacks clock
-    # on this binary's /metrics (scheduler_gc_pause_seconds_total).
-    from .core.spans import GcClock
-    sched.gc_clock = GcClock().install()
     stop = {"flag": False}
 
     def _sig(_s, _f):
@@ -203,7 +199,6 @@ def main(argv=None) -> int:
                 with sched.stages.stage("loop.idle"):
                     time.sleep(0.02)
     finally:
-        sched.gc_clock.close()
         server.shutdown()
         if flight is not None:
             flight.dump("shutdown")

@@ -26,6 +26,7 @@ OpportunisticBatching (runtime/batch.go) the survey calls for (§2.4).
 
 from __future__ import annotations
 
+import gc
 import random
 import threading
 import time
@@ -432,8 +433,13 @@ class Scheduler:
         # The loop's own account (core/spans.py StageLedger): every boundary
         # of the scheduling loop is one `with self.stages.stage(...)`.
         self.stages = StageLedger(self.tracer, self.metrics)
-        # Collector pauses: the binary's main installs a spans.GcClock.
-        self.gc_clock = None
+        # This process's collector policy (core/collector.py), held from
+        # here to close(): thresholds sized to a batch, the heap frozen at
+        # the loop's first idle after work, its clock the pauses by generation.
+        from .collector import POLICY
+        self.collector = POLICY
+        self._collector_share = POLICY.acquire(self)
+        self._worked = False  # a turn did work since the loop was last idle
         # metrics
         self.attempts = 0
         self.scheduled = 0
@@ -937,6 +943,22 @@ class Scheduler:
         self.api_dispatcher.flush(timeout=timeout)
         self.api_dispatcher.close()
         self.process_async_api_errors()
+        self.close()
+
+    def close(self) -> None:
+        """Give back this scheduler's share of the process's collector
+        policy; the last one restores the thresholds it found and unfreezes
+        the heap. A scheduler that is dropped unclosed gives it back when it
+        is collected."""
+        self._collector_share()
+
+    @property
+    def gc_freezes(self) -> int:
+        return self.collector.freezes
+
+    @property
+    def gc_frozen_objects(self) -> int:
+        return gc.get_freeze_count()
 
     # -- one cycle ---------------------------------------------------------
 
@@ -944,7 +966,13 @@ class Scheduler:
         """One turn of the loop: the ledger's `cycle`, whose own self time
         is what no stage below it has a name for."""
         with self.stages.stage("cycle"):
-            return self._cycle()
+            if self._cycle():
+                self._worked = True
+                return True
+            if self._worked:
+                self._worked = False
+                self.collector.idle(self.stages)
+            return False
 
     def _cycle(self) -> bool:
         self.process_async_api_errors()
@@ -1981,8 +2009,7 @@ class Scheduler:
                 ("scheduler_attempts_total", self.attempts)):
             extra.append(f"# TYPE {name} counter")
             extra.append(f"{name} {float(val)}")
-        if self.gc_clock is not None:
-            extra.extend(self.gc_clock.expose("scheduler"))
+        extra.extend(self.collector.expose())
         return out + "\n".join(extra) + "\n"
 
     def handle_scheduling_failure(

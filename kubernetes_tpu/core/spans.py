@@ -94,6 +94,7 @@ STAGES = (
     "plan.ipa",          # required inter-pod term tables of a full plan build
     "plan.adopt",        # session end: snapshot refresh, mirror adopts the carry
     "loop.idle",         # the binary's idle sleep and lease ticks
+    "gc.settle",         # the collector policy's deliberate collect-and-freeze
 )
 # The ledger's fixed table: per-pod names that are also loop boundaries
 # (plan.build … bind.post) keep their name, so a stage reads the same in a
@@ -102,7 +103,7 @@ LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
                "hint.validate", "plan.build", "plan.ipa", "plan.patch",
                "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
-               "loop.idle")
+               "loop.idle", "gc.settle")
 # A bound pod's minimal complete chain. Device stages are optional (host-
 # path pods legitimately skip them); observe spans prove the fanout landed.
 CORE_CHAIN = ("queue.wait", "host.commit", "bind.post", "api.bind",
@@ -522,7 +523,8 @@ class StageLedger:
 class GcClock:
     """Seconds the interpreter's cyclic collector ran, by generation
     (``gc.callbacks``). A collection stops every thread of the process, so
-    the binaries' mains install one and put it on their ``/metrics``."""
+    it is on ``/metrics``: the scheduler's through its collector policy
+    (core/collector.py), the apiserver's through its binary's main."""
 
     def __init__(self):
         self.seconds = [0.0, 0.0, 0.0]

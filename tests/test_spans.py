@@ -268,7 +268,8 @@ class TestStageLedger:
                 f'{float(s.device_batches)}') in text
         assert (f'scheduler_loop_stage_seconds_total{{stage="plan.build"}} '
                 f'{s.plan_build_s}') in text
-        assert "scheduler_gc_pause_seconds_total" not in text  # mains only
+        # the collector policy's clock: library-driven schedulers too
+        assert 'scheduler_gc_pause_seconds_total{generation="2"}' in text
 
     def test_unsampled_batch_copies_no_span_and_looks_nothing_up(self):
         """A batch's sampled members are found once, at collection: with no
