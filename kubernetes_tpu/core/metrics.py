@@ -42,6 +42,10 @@ class Counter(Metric):
     def value(self, *labels: str) -> float:
         return self._values.get(tuple(labels), 0.0)
 
+    def total(self) -> float:
+        """Every label set summed."""
+        return sum(self._values.copy().values())
+
     def expose(self) -> List[str]:
         out = [f"# HELP {self.name} {self.help}", f"# TYPE {self.name} counter"]
         # Snapshot-copy before iterating: /metrics renders on an HTTP
@@ -365,7 +369,19 @@ class SchedulerMetrics:
             "What the required inter-pod term tables of the plans built "
             "(loop stage plan.ipa) cost the host: 'matches' = term.matches "
             "evaluations, 'term_pods' = existing pods carrying a required "
-            "anti-affinity term that were walked.", ("what",)))
+            "anti-affinity term that were walked; and what the "
+            "InterPodAffinity score-table walk (loop stage plan.ipa_score) "
+            "cost: 'score_matches' = its term.matches evaluations, "
+            "'pods_walked' = the pods it visited.", ("what",)))
+        self.device_batches = r(Counter(
+            "scheduler_device_batches_total",
+            "Device batches dispatched, by the engine the built plan's "
+            "coupling chose (ops/kernel.py coupling): 'scan_carried' = the "
+            "scan with scores riding the carry, 'scan_normalised' = the "
+            "scan that recomputes and normalises every score at each step "
+            "(soft spread, preferred inter-pod terms, PreferNoSchedule, "
+            "preferred node affinity), 'lap' = the lap kernel.",
+            ("engine",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

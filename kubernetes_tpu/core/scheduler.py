@@ -2001,8 +2001,6 @@ class Scheduler:
                  getattr(self, "host_path_pods", 0)),
                 ("scheduler_device_scheduled_pods_total",
                  getattr(self, "device_scheduled", 0)),
-                ("scheduler_device_batches_total",
-                 getattr(self, "device_batches", 0)),
                 ("scheduler_state_unwinds_total", self.state_unwinds),
                 ("scheduler_conflict_requeues_total", self.conflict_requeues),
                 ("scheduler_eviction_requeues_total", self.eviction_requeues),

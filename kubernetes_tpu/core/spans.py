@@ -75,7 +75,7 @@ STAGES = (
     "queue.admission",   # pod entered this scheduler's queue (event)
     "queue.wait",        # admission → pop
     "plan.build",        # session plan acquisition (attrs: kind=full|delta|resume)
-    "device.dispatch",   # kernel dispatch enqueue
+    "device.dispatch",   # kernel dispatch enqueue (attrs: batch, engine)
     "device.wait",       # blocked on the device result fetch
     "host.commit",       # assume/reserve/permit/bind host tail
     "bind.post",         # binding POST leaves the scheduler (attrs: bulk)
@@ -92,6 +92,7 @@ STAGES = (
     "hint.validate",     # a hint's journal replay + selection, per pod
     "plan.patch",        # journal delta patch of a live plan + carry
     "plan.ipa",          # required inter-pod term tables of a full plan build
+    "plan.ipa_score",    # InterPodAffinity score-table walk of a full plan build
     "plan.adopt",        # session end: snapshot refresh, mirror adopts the carry
     "loop.idle",         # the binary's idle sleep and lease ticks
     "gc.settle",         # the collector policy's deliberate collect-and-freeze
@@ -100,8 +101,8 @@ STAGES = (
 # (plan.build … bind.post) keep their name, so a stage reads the same in a
 # pod's trace, in the table and in a profiler trace.
 LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
-               "hint.validate", "plan.build", "plan.ipa", "plan.patch",
-               "plan.adopt",
+               "hint.validate", "plan.build", "plan.ipa", "plan.ipa_score",
+               "plan.patch", "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
                "loop.idle", "gc.settle")
 # A bound pod's minimal complete chain. Device stages are optional (host-
@@ -371,7 +372,10 @@ class _Stage:
         self.span = True
         self._per_pod = not annotate
         ann = ledger._annotation if annotate else None
-        self._ann = ann(ledger._ann_names[name]) if ann is not None else None
+        # what is known of the stage as it opens rides the annotation as
+        # the event's stats (a dispatch's engine, a batch's size)
+        self._ann = (ann(ledger._ann_names[name], **attrs)
+                     if ann is not None else None)
         self._child_s = 0.0
         self._parts: Optional[dict] = None  # a root's: self time by stage
 
@@ -406,8 +410,9 @@ class StageLedger:
     1. adds the stage's SELF time (its duration minus what its child stages
        cover) and a count to the fixed per-stage table — always on;
     2. lies in any profiler session as ``sched.<name>`` on the clock of the
-       device operations (per batch and per loop turn: ``annotate=False``
-       and ``leaf`` are the per-pod forms, table only);
+       device operations, the attrs given at its opening as the event's
+       stats (per batch and per loop turn: ``annotate=False`` and ``leaf``
+       are the per-pod forms, table only);
     3. feeds ``scheduler_loop_stage_seconds_total`` / ``_stages_total``
        (``publish``) and, where ``point`` names one, the extension-point
        histogram;

@@ -136,7 +136,6 @@ class TPUScheduler(Scheduler):
         self.mirror = NodeStateMirror()
         self._holdover: Optional[QueuedPodInfo] = None
         # metrics
-        self.device_batches = 0
         self.device_scheduled = 0
         self.shard_map_dispatches = 0
         self.host_path_pods = 0
@@ -218,10 +217,19 @@ class TPUScheduler(Scheduler):
 
     @property
     def plan_build_s(self) -> float:
-        """Snapshot→features host work: `plan.build` with its child
-        `plan.ipa` (the required inter-pod term tables), the whole build."""
+        """Snapshot→features host work: `plan.build` with its children
+        `plan.ipa` (the required inter-pod term tables) and `plan.ipa_score`
+        (the InterPodAffinity score-table walk), the whole build."""
         seconds = self.stages.seconds
-        return seconds["plan.build"] + seconds["plan.ipa"]
+        return (seconds["plan.build"] + seconds["plan.ipa"]
+                + seconds["plan.ipa_score"])
+
+    @property
+    def device_batches(self) -> int:
+        """Device batches dispatched: `scheduler_device_batches_total`, which
+        counts them by engine at the two dispatch sites, every engine
+        summed."""
+        return int(self.metrics.device_batches.total())
 
     @property
     def device_wait_s(self) -> float:
@@ -472,11 +480,12 @@ class TPUScheduler(Scheduler):
                         break
                     pending.append(pack)
                 members = [m for g in pack for m in self._sorted_members(g)]
-                with stages.stage("device.dispatch", batch=len(members)):
+                with stages.stage("device.dispatch", batch=len(members),
+                                  engine=plan.engine):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(members), sd.carry)
                     results.copy_to_host_async()
-                self.device_batches += 1
+                self.metrics.device_batches.inc(plan.engine)
                 self.metrics.batch_attempts.inc("dispatched")
                 self.metrics.batch_size.observe(len(members))
                 inflight.append((pack, results))
@@ -1147,6 +1156,12 @@ class TPUScheduler(Scheduler):
             self.metrics.plan_ipa_terms.inc("matches", value=plan.ipa_matches)
             self.metrics.plan_ipa_terms.inc("term_pods",
                                             value=plan.ipa_term_pods)
+        if plan.ipa_pods_walked:
+            # the score-table walk (`plan.ipa_score` carries the same two)
+            self.metrics.plan_ipa_terms.inc("score_matches",
+                                            value=plan.ipa_score_matches)
+            self.metrics.plan_ipa_terms.inc("pods_walked",
+                                            value=plan.ipa_pods_walked)
         if plan.anti_lane:
             self.metrics.plan_anti_lane.inc(
                 "true" if plan.anti_rowlocal else "false")
@@ -1887,14 +1902,14 @@ class TPUScheduler(Scheduler):
                         break
                     pending.append(batch)
                 with stages.stage("device.dispatch", batch.sampled,
-                                  batch=len(batch)):
+                                  batch=len(batch), engine=plan.engine):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(batch), sd.carry)
                     # Start the device→host copy NOW: issuing it at
                     # dispatch time overlaps the fetch latency with the
                     # host commit loop of the previous batch.
                     results.copy_to_host_async()
-                self.device_batches += 1
+                self.metrics.device_batches.inc(plan.engine)
                 self.metrics.batch_attempts.inc("dispatched")
                 self.metrics.batch_size.observe(len(batch))
                 inflight.append((batch, results))

@@ -359,14 +359,15 @@ class NodeStateMirror:
             self.h_taint_eff, self.h_unsched, self.h_valid, self.h_name_id,
         )
 
-    def _dirty_payload(self, dirty):
-        """(idx, rows) scatter operands for the given staging rows. Pads to
-        a coarse tier (patch_tier) by repeating the last index (scatter-set
-        with duplicate indices writes the same value), so the jitted scatter
-        compiles once per tier, not once per dirty-count."""
-        tier = patch_tier(len(dirty))
-        dirty = dirty + [dirty[-1]] * (tier - len(dirty))
-        idx = jnp.asarray(dirty, jnp.int32)
+    def _dirty_payload(self, dirty, width: int):
+        """(idx, rows) scatter operands for the given staging rows, padded
+        to `width` by repeating the last index (scatter-set with duplicate
+        indices writes the same value): the jitted scatter compiles once
+        per width, not once per dirty-count."""
+        dirty = dirty + [dirty[-1]] * (width - len(dirty))
+        # int32 on the host: a Python list handed to jnp.asarray with a dtype
+        # is one more compiled program (a convert) for every width
+        idx = jnp.asarray(np.asarray(dirty, np.int32))
         rows = DeviceNodeState(
             *[jnp.asarray(a[dirty]) for a in self._arrays()],
             jnp.asarray(self.h_topo[:, dirty]))
@@ -403,8 +404,14 @@ class NodeStateMirror:
         return self._device is not None and self._device.req_r.is_deleted()
 
     def _scatter_dirty(self, dirty) -> DeviceNodeState:
-        """Scatter the given staging rows into the resident device state."""
-        idx, rows = self._dirty_payload(dirty)
+        """Scatter the given staging rows into the resident device state,
+        at the ONE width a flush has: the tier of the most rows it may
+        scatter. How many rows a flush finds dirty is the workload's to
+        decide (a wave that packs its pods onto a few nodes dirties 25 rows
+        and the next one 250); were the width to follow that count, a width
+        first met mid-run would compile where work is being measured."""
+        idx, rows = self._dirty_payload(
+            dirty, patch_tier(int(self.scatter_threshold * self.np_cap)))
         if self._shardings is not None:
             return _sharded_scatter(self._shardings)(self._device, idx, rows)
         return _scatter_rows(self._device, idx, rows)
@@ -472,7 +479,7 @@ class NodeStateMirror:
         except _Regrown:
             return None  # staging reset: next flush rebuilds everything
         dirty = sorted({row for row, _ in updates})
-        idx, rows = self._dirty_payload(dirty)
+        idx, rows = self._dirty_payload(dirty, patch_tier(len(dirty)))
         if sharded_state is not None and sharded_state is self._device:
             # Mesh-first steady state: session state == resident. One
             # pinned scatter patches it — DONATED (in-place buffer reuse)

@@ -161,7 +161,7 @@ class BatchPlan:
     # Host-known batch facts passed as static jit args so the kernel can drop
     # dead score reductions from the scan body (ops/kernel.py fast paths).
     has_pns: bool = True          # any PreferNoSchedule taint staged
-    has_ipa_base: bool = True     # any nonzero preferred-affinity base score
+    has_ipa_base: bool = True     # a landing axis, or any nonzero base score
     # Every required anti-affinity term is keyed to a singleton-per-node
     # topology axis (kubernetes.io/hostname-like): a landing can only block
     # its own row, so the kernel's lap-vectorized path stays exact.
@@ -194,6 +194,11 @@ class BatchPlan:
     anti_lane: bool = False
     ipa_matches: int = 0          # `term.matches` evaluations
     ipa_term_pods: int = 0        # existing pods with a required anti term
+    # The same of the score-table walk (the stage `plan.ipa_score`): its
+    # `term.matches` evaluations and the pods it matched terms against;
+    # both 0 where the walk met no term to match.
+    ipa_score_matches: int = 0
+    ipa_pods_walked: int = 0
 
     @property
     def coupling(self):
@@ -204,6 +209,15 @@ class BatchPlan:
                         has_ipa_base=self.has_ipa_base,
                         anti_rowlocal=self.anti_rowlocal,
                         has_na_pref=self.has_na_pref)
+
+    @property
+    def engine(self) -> str:
+        """Which of schedule_batch's engines places this plan, as
+        scheduler_device_batches_total{engine} names them."""
+        c = self.coupling
+        if c.lap:
+            return "lap"
+        return "scan_carried" if c.scores_carried else "scan_normalised"
 
     @property
     def rides_lap(self) -> bool:
@@ -472,7 +486,11 @@ def build_batch(
     `stages`: the caller's StageLedger (core/spans.py). The build of the
     required inter-pod term tables is its stage `plan.ipa`, opened only
     where there is a term to evaluate (the pod's own, or an existing pod's
-    required anti-affinity), so a cluster without any never shows it.
+    required anti-affinity), so a cluster without any never shows it. The
+    walk that builds the InterPodAffinity score tables (`ipa_base`: every
+    pod against the incoming pod's preferred terms, each existing pod's
+    preferred terms against the incoming pod) is its stage `plan.ipa_score`,
+    opened at the first pod that brings that walk a term to match.
 
     `nominated`: [(node_row, PodInfo)] of preemption-nominated pods with
     priority >= the batch pod's, pre-filtered by the caller (the device
@@ -836,37 +854,70 @@ def build_batch(
 
     has_pref = bool(pref_aff or pref_anti)
     scan_nodes = nodes if has_pref else snapshot.have_pods_with_affinity_list
-    for ni in scan_nodes:
-        node = ni.node
-        if node is None:
-            continue
-        pods_iter = ni.pods if has_pref else ni.pods_with_affinity
-        for epi in pods_iter:
-            ep = epi.pod
-            for weight, term in pref_aff:
-                tp_val = node.labels.get(term.topology_key)
-                if tp_val is not None and term.matches(ep, ns_labels_fn):
-                    _add_score(term.topology_key, tp_val, weight)
-            for weight, term in pref_anti:
-                tp_val = node.labels.get(term.topology_key)
-                if tp_val is not None and term.matches(ep, ns_labels_fn):
-                    _add_score(term.topology_key, tp_val, -weight)
-            if hard_pod_affinity_weight > 0:
-                for term in existing_terms(epi, "required_affinity_terms"):
+    # What the walk costs the host: `term.matches` evaluations and the pods
+    # it matches them against. Its stage `plan.ipa_score` opens at the first
+    # pod that brings a term to match (a preferred term of the incoming pod:
+    # the first pod; else the first existing pod with a term the score
+    # reads), so a cluster whose pods carry none never shows it.
+    score_matches = 0
+    pods_walked = 0
+    term_met = False
+    score_stage = None
+    with contextlib.ExitStack() as walk:
+        for ni in scan_nodes:
+            node = ni.node
+            if node is None:
+                continue
+            pods_iter = ni.pods if has_pref else ni.pods_with_affinity
+            for epi in pods_iter:
+                if not term_met:
+                    if not (has_pref
+                            or (hard_pod_affinity_weight > 0
+                                and epi.required_affinity_terms)
+                            or (not ignore_preferred_terms_of_existing_pods
+                                and (epi.preferred_affinity_terms
+                                     or epi.preferred_anti_affinity_terms))):
+                        continue
+                    term_met = True
+                    if stages is not None:
+                        score_stage = walk.enter_context(
+                            stages.stage("plan.ipa_score"))
+                ep = epi.pod
+                pods_walked += 1
+                for weight, term in pref_aff:
                     tp_val = node.labels.get(term.topology_key)
-                    if tp_val is not None and term.matches(pod, ns_labels_fn):
-                        _add_score(term.topology_key, tp_val, hard_pod_affinity_weight)
-            if not ignore_preferred_terms_of_existing_pods:
-                for wt in epi.preferred_affinity_terms:
-                    term = compile_terms((wt.term,), ep)[0]
+                    if tp_val is not None:
+                        score_matches += 1
+                        if term.matches(ep, ns_labels_fn):
+                            _add_score(term.topology_key, tp_val, weight)
+                for weight, term in pref_anti:
                     tp_val = node.labels.get(term.topology_key)
-                    if tp_val is not None and term.matches(pod, ns_labels_fn):
-                        _add_score(term.topology_key, tp_val, wt.weight)
-                for wt in epi.preferred_anti_affinity_terms:
-                    term = compile_terms((wt.term,), ep)[0]
-                    tp_val = node.labels.get(term.topology_key)
-                    if tp_val is not None and term.matches(pod, ns_labels_fn):
-                        _add_score(term.topology_key, tp_val, -wt.weight)
+                    if tp_val is not None:
+                        score_matches += 1
+                        if term.matches(ep, ns_labels_fn):
+                            _add_score(term.topology_key, tp_val, -weight)
+                if hard_pod_affinity_weight > 0:
+                    for term in existing_terms(epi, "required_affinity_terms"):
+                        tp_val = node.labels.get(term.topology_key)
+                        if tp_val is not None:
+                            score_matches += 1
+                            if term.matches(pod, ns_labels_fn):
+                                _add_score(term.topology_key, tp_val,
+                                           hard_pod_affinity_weight)
+                if not ignore_preferred_terms_of_existing_pods:
+                    for sign, weighted in ((1, epi.preferred_affinity_terms),
+                                           (-1, epi.preferred_anti_affinity_terms)):
+                        for wt in weighted:
+                            term = compile_terms((wt.term,), ep)[0]
+                            tp_val = node.labels.get(term.topology_key)
+                            if tp_val is not None:
+                                score_matches += 1
+                                if term.matches(pod, ns_labels_fn):
+                                    _add_score(term.topology_key, tp_val,
+                                               sign * wt.weight)
+        if score_stage is not None:
+            score_stage.attrs.update(matches=score_matches,
+                                     pods_walked=pods_walked)
 
     ipa_base = np.zeros(npc, i64)
     for tp_key, vals in topology_score.items():
@@ -1016,7 +1067,11 @@ def build_batch(
         fit_strategy=strategy,
         vmax=vmax,
         has_pns=bool((mirror.h_taint_eff[:n] == EFFECT_PREFER_NO_SCHEDULE).any()),
-        has_ipa_base=bool((ipa_base != 0).any()),
+        # With a landing axis the kernel reads ipa_base whatever this says
+        # (`if KD or has_ipa_base`), so the flag follows the axis: an empty
+        # cluster's plan and a loaded one's are then one compiled program,
+        # not two that differ in a static argument alone.
+        has_ipa_base=bool(kd) or bool((ipa_base != 0).any()),
         pod_local=bool(c1 == 0 and c2 == 0 and a1 == 0 and a2 == 0
                        and kd == 0 and not (ipa_base != 0).any()
                        and not (exist_anti != 0).any()),
@@ -1028,6 +1083,8 @@ def build_batch(
         anti_lane=bool(anti_terms) or bool((exist_anti != 0).any()),
         ipa_matches=ipa_matches,
         ipa_term_pods=ipa_term_pods,
+        ipa_score_matches=score_matches,
+        ipa_pods_walked=pods_walked,
         dns_node_counts=dns_node_counts,
         dns_node_elig=dns_node_elig,
         dns_min_domains=dns_min_domains,

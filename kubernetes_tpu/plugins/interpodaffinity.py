@@ -358,8 +358,18 @@ class InterPodAffinity:
         diff = max_count - min_count
         for s in scores:
             if diff > 0:
-                # floor division: identical to the reference's float-then-trunc
-                # for the non-negative numerator, and exact on device int64.
+                # Floor division, exact on device int64 (ops/kernel.py does
+                # the same). NOT the reference's form in general: scoring.go
+                # computes int64(100 * (float64(a) / float64(b))), and the
+                # float quotient falls short of a/b at (a, b) = (29, 50),
+                # (29, 100), (57, 100), (58, 100), (87, 150), (58, 200),
+                # (114, 200), (116, 200) (every case up to b = 220), where it
+                # truncates to one less than the floor (57 against 58 at
+                # 29/50). None is reachable while every raw score is even and
+                # max - min <= 80 (weight-1 terms pulling both ways, at most
+                # 40 pods a node: benchmark prefaffinity-5k), which
+                # tests/test_ipa_normalise_forms.py enumerates; a node that
+                # holds 50 such pods reaches (58, 100).
                 s.score = MAX_NODE_SCORE * (s.score - min_count) // diff
             else:
                 s.score = 0

@@ -120,6 +120,43 @@ def test_no_retrace_when_a_plan_leaves_the_engine_it_was_warmed_on(flip):
         "the program it falls back to")
 
 
+def test_a_preferred_term_is_one_program_on_an_empty_and_a_loaded_cluster():
+    """A pod with a preferred inter-pod term that selects itself has a
+    landing axis, and with one the kernel reads `ipa_base` whether or not any
+    entry is nonzero. The plan over an empty cluster (all-zero base) and the
+    plan over a loaded one are then the same compiled program: warming on
+    the empty cluster covers the workload (on the chip each variant of the
+    scan is 2.5-3.8 s to load even from the cache: benchmark
+    prefaffinity-5k, PR 31)."""
+    cs, s = _cluster()
+
+    def pod(name):
+        return (make_pod().name(name).req({"cpu": "100m"}).label("app", "t")
+                .pod_affinity("kubernetes.io/hostname", {"app": "t"},
+                              weight=1).obj())
+
+    fw = next(iter(s.profiles.values()))
+    _state, empty = s.build_plan(fw, pod("probe"), s.max_batch)
+    assert empty.has_ipa_base and empty.engine == "scan_normalised"
+    s.warm_for(pod("warm-template"))
+    warmed = _cache_size()
+    for wave in range(2):
+        for i in range(30):
+            cs.create_pod(pod(f"w{wave}-{i}"))
+        s.run_until_idle()
+    _state, loaded = s.build_plan(fw, pod("probe"), s.max_batch)
+    assert loaded.has_ipa_base and bool((loaded.features.ipa_base != 0).any())
+    assert s.scheduled == 60 and s.host_path_pods == 0
+    assert _cache_size() == warmed, (
+        "the loaded cluster's plan retraced schedule_batch: a second program "
+        "for a flag that changes nothing in it")
+    # a plain pod among pods that pull it has a base and no axis: the flag
+    # still says so, and its program is another one
+    plain = make_pod().name("plain").req({"cpu": "100m"}).label("app", "t").obj()
+    _state, pulled = s.build_plan(fw, plain, s.max_batch)
+    assert pulled.has_ipa_base and pulled.features.ipa_axis.shape[0] == 0
+
+
 # What one landing reaches, by plan shape: ops/kernel.py `coupling` as the
 # host reads it (BatchPlan.rides_lap / .row_local), and what follows from
 # it: the score-hint walk (hint_eligible) and, under the mesh, the explicit

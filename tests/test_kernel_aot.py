@@ -24,6 +24,7 @@ from jax.sharding import SingleDeviceSharding
 RESOURCE_EVAL_CEILING = 2_500   # 1,766 LeastAllocated, 1,758 Most [11,493]
 SCAN_BODY_CEILING = 3_000       # 2,095 [13,575]
 LAP_BODY_CEILING = 3_500        # 2,385 [21,147]
+NORMALISING_BODY_CEILING = 3_500  # 2,434 (PR 31: the scan that normalises)
 
 _INSTRUCTION = re.compile(r"\s+(ROOT )?%?[\w.\-]+ = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -103,7 +104,7 @@ def test_landed_row_resource_eval_stays_small(one_chip, fit_strategy):
     assert prims["div"] == 0 and prims["rem"] == 0, prims
 
 
-def _small_plan(batch, spread):
+def _small_plan(batch, spread, preferred=False):
     from kubernetes_tpu.core import FakeClientset
     from kubernetes_tpu.models import TPUScheduler
     from kubernetes_tpu.testing.wrappers import make_node, make_pod
@@ -118,22 +119,30 @@ def _small_plan(batch, spread):
     if spread:
         pod = pod.spread_constraint(1, "topology.kubernetes.io/zone",
                                     "DoNotSchedule", {"app": "spread"})
+    if preferred:
+        pod = pod.pod_affinity("kubernetes.io/hostname", {"app": "spread"},
+                               weight=1)
     return s.build_plan(next(iter(s.profiles.values())), pod.obj(), batch)
 
 
 @pytest.mark.parametrize("kernel,batch,spread,ceiling", [
     ("scan", 8, True, SCAN_BODY_CEILING),      # spread-5k.waves' program
     ("lap", 128, False, LAP_BODY_CEILING),     # basic-5k.waves' program
+    # prefaffinity-5k.waves' program: a preferred inter-pod term, so every
+    # score is recomputed and normalised over the kept rows at each step
+    ("scan_normalised", 128, False, NORMALISING_BODY_CEILING),
 ])
 def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, ceiling):
-    """`schedule_batch` as the two wave cells run it, at a small cluster's
+    """`schedule_batch` as the wave cells run it, at a small cluster's
     shapes (the loop body's scalar code does not depend on them): nothing in
     the loop comes from a `div` or `rem`, and the body stays under its
     ceiling."""
     from kubernetes_tpu.ops.kernel import schedule_batch
 
-    state, plan = _small_plan(batch, spread)
-    assert (plan.batch_pad > 64) == (kernel == "lap")
+    state, plan = _small_plan(batch, spread,
+                              preferred=kernel == "scan_normalised")
+    assert (plan.batch_pad > 64) == (kernel != "scan")
+    assert plan.engine == {"scan": "scan_carried"}.get(kernel, kernel)
 
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)

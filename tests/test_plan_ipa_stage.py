@@ -140,3 +140,158 @@ def test_a_zone_wide_term_counts_as_shared():
     assert _lanes(sched)[0] == 0 and _lanes(sched)[1] >= 1
     zones = {cs.nodes[p.node_name].labels[ZONE] for p in cs.pods.values()}
     assert len(zones) == 2
+
+
+# -- the score-table walk: stage `plan.ipa_score` and the engine counter ----
+
+def _red(name, weight=0, anti=False):
+    b = make_pod().name(name).req({"cpu": "100m"}).label("color", "red")
+    if weight:
+        b = b.pod_affinity(HOSTNAME, {"color": "red"}, anti=anti,
+                           weight=weight)
+    return b.obj()
+
+
+def _score_walk(sched):
+    """(`term.matches` evaluations of the score walk, pods it visited)."""
+    c = sched.metrics.plan_ipa_terms
+    return int(c.value("score_matches")), int(c.value("pods_walked"))
+
+
+def _engines(sched):
+    c = sched.metrics.device_batches
+    return {e: int(c.value(e))
+            for e in ("scan_carried", "scan_normalised", "lap")}
+
+
+def test_plan_ipa_score_is_a_loop_stage():
+    assert "plan.ipa_score" in spans.STAGES
+    assert "plan.ipa_score" in spans.LOOP_STAGES
+
+
+def test_required_terms_alone_never_open_the_score_stage():
+    """Required anti-affinity feeds no score: the walk visits the carriers
+    and finds no term to match, so the stage stays shut and nothing is
+    counted."""
+    sched, cs = _cluster()
+    for i in range(5):
+        cs.create_pod(_green(f"a{i}", HOSTNAME))
+    sched.run_until_idle()
+    cs.delete_pod(next(p for p in cs.pods.values() if p.name == "a0"))
+    cs.create_pod(_green("a5", HOSTNAME))
+    cs.create_pod(_green("plain"))
+    sched.run_until_idle()
+    assert sched.stages.counts["plan.ipa"] >= 2
+    assert sched.stages.counts["plan.ipa_score"] == 0
+    assert sched.stages.seconds["plan.ipa_score"] == 0.0
+    assert _score_walk(sched) == (0, 0)
+    assert not any("score_matches" in line or "pods_walked" in line
+                   for line in _series(sched, "scheduler_plan_ipa_terms_total"))
+    assert _engines(sched)["scan_normalised"] == 0
+    assert sum(_engines(sched).values()) == sched.device_batches
+
+
+def test_a_preferred_term_opens_the_score_stage_and_counts_the_walk():
+    sched, cs = _cluster()
+    for i in range(4):
+        cs.create_pod(_red(f"r{i}", weight=1))
+    sched.run_until_idle()
+    # the first plan is built over an empty cluster: the incoming pod has a
+    # preferred term and the walk meets no pod to match it with, so the
+    # stage stays shut
+    assert sched.stages.counts["plan.ipa_score"] == 0
+    assert _score_walk(sched) == (0, 0)
+    # the four pack onto one node: the score decided
+    assert len({p.node_name for p in cs.pods.values()}) == 1
+    # a delete voids the plan; the next is built over the three that are
+    # left: each is matched by the incoming pod's term and matches it with
+    # its own (2 a pod)
+    cs.delete_pod(next(p for p in cs.pods.values() if p.name == "r0"))
+    for i in range(4, 6):
+        cs.create_pod(_red(f"r{i}", weight=1))
+    sched.run_until_idle()
+    assert sched.stages.counts["plan.ipa_score"] == 1
+    assert _score_walk(sched) == (6, 3)
+    assert len({p.node_name for p in cs.pods.values()}) == 1
+    assert sched.host_path_pods == 0
+    # every batch rode the scan that normalises (preferred terms couple the
+    # whole window), and the engines sum to the batches
+    engines = _engines(sched)
+    assert engines["scan_normalised"] == sched.device_batches >= 2
+    assert engines["scan_carried"] == engines["lap"] == 0
+    assert _series(sched, "scheduler_device_batches_total") == [
+        f'scheduler_device_batches_total{{engine="scan_normalised"}} '
+        f'{float(sched.device_batches)}']
+    assert 'scheduler_plan_ipa_terms_total{what="pods_walked"} 3.0' in _series(
+        sched, "scheduler_plan_ipa_terms_total")
+    # inside the build, and inside what plan_build_s means
+    seconds = sched.stages.seconds
+    assert seconds["plan.ipa_score"] > 0
+    assert sched.plan_build_s == pytest.approx(
+        seconds["plan.build"] + seconds["plan.ipa"]
+        + seconds["plan.ipa_score"])
+    roots = [parts for _n, _ts, _d, _s, parts in sched.stages.recent
+             if parts and "plan.ipa_score" in parts]
+    assert roots and all("plan.build" in parts for parts in roots)
+    sched.stages.publish()
+    assert any('stage="plan.ipa_score"' in line for line in _series(
+        sched, "scheduler_loop_stage_seconds_total"))
+
+
+def test_existing_preferred_terms_open_the_stage_for_a_plain_pod():
+    """The symmetric half alone: the incoming pod has no term, the pods
+    already there pull it, and the stage opens at the first of them."""
+    sched, cs = _cluster()
+    for i in range(3):
+        cs.create_pod(_red(f"r{i}", weight=1))
+    sched.run_until_idle()
+    before = sched.stages.counts["plan.ipa_score"]
+    cs.create_pod(_red("plain"))
+    sched.run_until_idle()
+    assert sched.stages.counts["plan.ipa_score"] == before + 1
+    # three carriers visited, one match each (their own term against the
+    # incoming pod; it has none to match them with)
+    assert _score_walk(sched) == (3, 3)
+    held = {p.node_name for p in cs.pods.values() if p.name != "plain"}
+    plain = next(p for p in cs.pods.values() if p.name == "plain")
+    assert held == {plain.node_name}
+
+
+def test_plain_pods_ride_the_carried_scan_or_the_lap():
+    sched, cs = _cluster()
+    for i in range(6):
+        cs.create_pod(_green(f"p{i}"))
+    sched.run_until_idle()
+    engines = _engines(sched)
+    assert engines["scan_normalised"] == 0
+    assert sum(engines.values()) == sched.device_batches >= 1
+
+
+def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
+    """What is known of a stage as it opens rides its annotation, so a
+    profiler session holds each `sched.device.dispatch` with the engine
+    that placed the batch as a stat of the event."""
+    opened = []
+
+    class Annotation:
+        def __init__(self, name, **stats):
+            opened.append((name, stats))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    sched, cs = _cluster()
+    monkeypatch.setattr(sched.stages, "_annotation", Annotation)
+    for i in range(3):
+        cs.create_pod(_red(f"r{i}", weight=1))
+    sched.run_until_idle()
+    dispatches = [stats for name, stats in opened
+                  if name == "sched.device.dispatch"]
+    assert len(dispatches) == sched.device_batches >= 1
+    assert all(d == {"batch": 3, "engine": "scan_normalised"}
+               for d in dispatches)
+    # what is filled in while the stage is open (a plan's kind) is not there
+    assert ("sched.plan.build", {"batch": 3}) in opened
