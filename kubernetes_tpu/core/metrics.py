@@ -382,6 +382,14 @@ class SchedulerMetrics:
             "(soft spread, preferred inter-pod terms, PreferNoSchedule, "
             "preferred node affinity), 'lap' = the lap kernel.",
             ("engine",)))
+        self.device_scan_steps = r(Counter(
+            "scheduler_device_scan_steps_total",
+            "Steps of the scan engines' dispatches (ops/kernel.py "
+            "schedule_batch loops over the pods a dispatch holds, not over "
+            "the plan's padded width): 'run' = the sum of n_active, "
+            "'skipped' = the sum of batch_pad - n_active, the steps a "
+            "fixed-length scan would have run and placed nothing with.",
+            ("kind",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

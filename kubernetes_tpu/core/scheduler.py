@@ -20,7 +20,7 @@ pkg/scheduler/schedule_one.go — the hot path:
 TPU-first deviation: when the active profile has a `batch_evaluator` (the
 device backend), schedule_one pulls a *row-block* of same-signature pods and
 dispatches one kernel call that runs the whole greedy sequential assignment as
-a lax.scan on device (kubernetes_tpu/ops.kernel) — the generalization of
+a loop on device (kubernetes_tpu/ops.kernel) — the generalization of
 OpportunisticBatching (runtime/batch.go) the survey calls for (§2.4).
 """
 

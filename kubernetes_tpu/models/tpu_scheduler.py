@@ -480,14 +480,12 @@ class TPUScheduler(Scheduler):
                         break
                     pending.append(pack)
                 members = [m for g in pack for m in self._sorted_members(g)]
-                with stages.stage("device.dispatch", batch=len(members),
-                                  engine=plan.engine):
+                attrs = plan.dispatch_attrs(len(members))
+                with stages.stage("device.dispatch", **attrs):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(members), sd.carry)
                     results.copy_to_host_async()
-                self.metrics.device_batches.inc(plan.engine)
-                self.metrics.batch_attempts.inc("dispatched")
-                self.metrics.batch_size.observe(len(members))
+                self._count_dispatch(attrs)
                 inflight.append((pack, results))
                 stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),
@@ -1168,8 +1166,8 @@ class TPUScheduler(Scheduler):
 
     def warm_for(self, pod, nominated: bool = False) -> None:
         """Compile the kernel shapes a workload of `pod`-shaped pods will hit,
-        WITHOUT scheduling anything: dispatches with n_active=0 are fully
-        inert (every scan step is padding). Measuring harnesses call this so
+        WITHOUT scheduling anything: a dispatch with n_active=0 runs the
+        call's prologue and a loop of zero trips. Measuring harnesses call this so
         XLA compilation lands outside the measured window. Warms both the
         fresh-carry and chained-carry traces.
 
@@ -1299,6 +1297,21 @@ class TPUScheduler(Scheduler):
             self.shard_map_dispatches += count
             return fn(state, plan.features, np.int32(n_active), carry)
         return self._gspmd_dispatch(state, plan, n_active, carry)
+
+    def _count_dispatch(self, attrs: dict) -> None:
+        """One live dispatch into the registry, from what its stage said of
+        it (BatchPlan.dispatch_attrs): which engine placed it and, for the
+        scans, the steps it ran beside those its padded width would have
+        cost a fixed-length scan."""
+        m = self.metrics
+        m.device_batches.inc(attrs["engine"])
+        steps = attrs.get("steps")
+        if steps is not None:
+            m.device_scan_steps.inc("run", value=steps)
+            m.device_scan_steps.inc("skipped",
+                                    value=attrs["batch_pad"] - steps)
+        m.batch_attempts.inc("dispatched")
+        m.batch_size.observe(attrs["batch"])
 
     def _gspmd_dispatch(self, state, plan, n_active: int, carry):
         """The GSPMD-compiled schedule_batch call — one kwargs set shared
@@ -1901,17 +1914,15 @@ class TPUScheduler(Scheduler):
                     if batch is None:
                         break
                     pending.append(batch)
-                with stages.stage("device.dispatch", batch.sampled,
-                                  batch=len(batch), engine=plan.engine):
+                attrs = plan.dispatch_attrs(len(batch))
+                with stages.stage("device.dispatch", batch.sampled, **attrs):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(batch), sd.carry)
                     # Start the device→host copy NOW: issuing it at
                     # dispatch time overlaps the fetch latency with the
                     # host commit loop of the previous batch.
                     results.copy_to_host_async()
-                self.metrics.device_batches.inc(plan.engine)
-                self.metrics.batch_attempts.inc("dispatched")
-                self.metrics.batch_size.observe(len(batch))
+                self._count_dispatch(attrs)
                 inflight.append((batch, results))
                 stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),

@@ -8,7 +8,7 @@ expensive O(all-pods) PreFilter aggregations (PodTopologySpread
 filtering.go:241 calPreFilterState, InterPodAffinity filtering.go:287) are
 computed ONCE here on the host, and the *sequential* inter-pod dependency —
 each assignment shifting the counts the next pod sees — runs entirely on
-device inside the kernel's lax.scan carry (ops/kernel.py).
+device inside the kernel's loop carry (ops/kernel.py).
 
 Everything here mirrors the host-oracle plugin semantics exactly; equivalence
 is enforced by tests/test_device_equivalence.py.
@@ -155,7 +155,7 @@ class BatchPlan:
     """A built batch: kernel inputs + host bookkeeping."""
 
     features: BatchFeatures
-    batch_pad: int                # scan length (>= len(pods))
+    batch_pad: int                # result width (>= len(pods)): a tier, not a trip count
     fit_strategy: int             # 0 = LeastAllocated, 1 = MostAllocated
     vmax: int
     # Host-known batch facts passed as static jit args so the kernel can drop
@@ -218,6 +218,18 @@ class BatchPlan:
         if c.lap:
             return "lap"
         return "scan_carried" if c.scores_carried else "scan_normalised"
+
+    def dispatch_attrs(self, n_active: int) -> dict:
+        """What a `device.dispatch` stage says of a dispatch of `n_active`
+        pods on this plan. The scan loops `steps` = n_active times over a
+        result buffer `batch_pad` wide; how many laps the lap kernel takes
+        only the device knows."""
+        engine = self.engine
+        attrs = {"batch": n_active, "engine": engine,
+                 "batch_pad": self.batch_pad}
+        if engine != "lap":
+            attrs["steps"] = n_active
+        return attrs
 
     @property
     def rides_lap(self) -> bool:
@@ -1110,9 +1122,10 @@ def _pad_i64(vals, npc: int) -> np.ndarray:
 
 
 def _batch_tier(n: int) -> int:
-    """Coarse scan-length tiers: each distinct tier is a separate XLA compile
-    (~1 min on first use), so bound them to {8, 64, 512, 1024, ...}. Padded
-    steps cost device time but sliced-off outputs keep semantics exact."""
+    """Coarse tiers of the result buffer's width: each distinct tier is a
+    separate XLA compile (~1 min on first use), so bound them to {8, 64,
+    512, 1024, ...}. The width costs no steps: the kernels loop over the
+    pods a dispatch holds (`n_active`), and the host slices the rest off."""
     if n <= 8:
         return 8
     if n <= 64:

@@ -1,7 +1,8 @@
 """The batch scheduling kernel: the whole Filter→Score hot path
 (schedule_one.go findNodesThatFitPod :630 / prioritizeNodes :945) as ONE
 jit-compiled dense pods×nodes evaluation, with the greedy sequential
-assignment loop running on device as a lax.scan.
+assignment loop running on device: "the scan", a `lax.while_loop` of one
+trip a pod the call holds (`n_active`), not one a column of its padded width.
 
 Replaces the reference's per-node goroutine fan-out
 (parallelize/parallelism.go:28 Parallelizer, 16 goroutines, √n chunks) with
@@ -350,12 +351,14 @@ def schedule_batch(
     has_aux: bool = False,
     has_nom: bool = False,
 ) -> Tuple[jnp.ndarray, ScanCarry]:
-    """Greedy-assign up to `batch_pad` identical pods (`n_active` of them
-    real; padded steps are inert so the returned carry stays exact).
+    """Greedy-assign `n_active` identical pods, at most `batch_pad`: both
+    engines loop over the pods the call holds, so `batch_pad` is the static
+    width of the results and costs no steps.
 
     Returns (results, carry) where results is the stacked [2, B] array of
     (chosen row or -1, start_index_after) — one array so the host fetches
-    with a single transfer; slice results[:, :n_active]. Passing the returned
+    with a single transfer; slice results[:, :n_active] (past it the scan
+    leaves its initial -1). Passing the returned
     ScanCarry back as `carry_in` chains the NEXT batch of identical pods
     without re-uploading features or node state (dispatch pipelining: the
     host commits batch N while the device computes batch N+1 — the TPU-era
@@ -453,11 +456,13 @@ def schedule_batch(
             ok &= term_ok.all(axis=0) | bootstrap
         return ok
 
-    def step(carry, _):
+    def step(carry):
         (req_r, nonzero, pod_count, fit_ok, fit_sc, ba,
          dns_counts, sa_counts, anti_counts, aff_counts, ipa_delta, start,
          blocked, aux_cnt, okd, F, total,
          mnum, scnt, acnt, fcnt, dproj, aff_total, t, out) = carry
+        # True at every trip of the loop below; a step past n_act (the tests'
+        # fixed-length reference runs them) lands nothing, moves no start.
         active = t < n_act
 
         with jax.named_scope("feasibility"):
@@ -555,7 +560,7 @@ def schedule_batch(
             chosen = jnp.where(any_kept, _wrap(start + chosen_rot, num), -1).astype(jnp.int32)
 
         with jax.named_scope("carry_update"):
-            # ---- carry updates (inert when this step is padding) --------------
+            # ---- carry updates (inert when nothing was kept) ------------------
             row = jnp.maximum(chosen, 0)
             apply = jnp.where(any_kept, 1, 0).astype(jnp.int64)
             req_r = req_r.at[row].add(f.request * apply)
@@ -627,14 +632,15 @@ def schedule_batch(
                     w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * r_fit + w_ba * r_ba
                     + il_term[row])
             start = jnp.where(active, _wrap(start + evaluated, num), start).astype(jnp.int32)
-            # Results accumulate in the CARRY via a one-hot masked write (the
-            # int32 step counter `t` also rides the carry): lax.scan's own
-            # ys-stacking would index its dynamic_update_slice with the internal
-            # s64 loop counter (x64 mode), which this environment's XLA
-            # miscompiles under GSPMD — compare(s64, s32) after
-            # spmd-partitioning, the ROADMAP open item. The elementwise write
-            # keeps the carry uniformly int32-indexed and is also exact under
-            # vmap (the cells axis), where a batched-index update slice is not.
+            # Results accumulate in the CARRY via a one-hot masked write at
+            # the int32 step counter `t`, which also rides the carry and is
+            # the loop's only counter: an s64 one (a lax.scan's own, in x64
+            # mode, indexing the dynamic_update_slice of its ys-stacking) is
+            # what this environment's XLA miscompiles under GSPMD —
+            # compare(s64, s32) after spmd-partitioning, the ROADMAP open
+            # item. The elementwise write keeps the carry uniformly
+            # int32-indexed and is also exact under vmap (the cells axis),
+            # where a batched-index update slice is not.
             out = jnp.where(jnp.arange(batch_pad, dtype=jnp.int32)[None, :] == t,
                             jnp.stack([chosen, start])[:, None], out)
 
@@ -643,7 +649,7 @@ def schedule_batch(
                      ipa_delta, start, blocked, aux_cnt, okd, F, total,
                      mnum, scnt, acnt, fcnt, dproj, aff_total,
                      t + jnp.int32(1), out)
-        return new_carry, None
+        return new_carry
 
     if carry_in is None:
         fit_ok0, fit_sc0, ba0 = _resource_eval(
@@ -694,7 +700,11 @@ def schedule_batch(
     carry0 = tuple(ext0) + (okd0, F0, total0,
                             mnum0, scnt0, acnt0, fcnt0, dproj0, aff_total0,
                             jnp.int32(0), out0)
-    final, _ = lax.scan(step, carry0, None, length=batch_pad)
+    # The loop stops at the pods it holds: `n_act` trips, not `batch_pad`.
+    # `t` (carry[-2]) starts as an unbatched int32 0 and only ever adds 1, so
+    # the predicate stays one scalar under vmap, and a replicated one under
+    # shard_map / GSPMD; `out` past `n_act` keeps its initial -1.
+    final = lax.while_loop(lambda c: c[-2] < n_act, step, carry0)
     # chosen+starts stacked into ONE array: the host fetches results with a
     # single device→host transfer. The final ScanCarry rides back
     # (device-resident) so the host can

@@ -204,3 +204,22 @@ def test_one_predicate_decides_the_engine(shape):
                          s.queue.nominator, s.cache.affinity_pod_refs),
            s._shard_map_fn(plan) is not None)
     assert got == _ENGINES[shape]
+
+
+@pytest.mark.parametrize("n_active", [0, 5, 1024])
+def test_one_plan_meets_one_scan_program_whatever_it_holds(n_active):
+    """The scan's trip count is the device scalar `n_active`, an input and
+    not a static argument: an empty dispatch (warm_for's), a served one of a
+    few pods and a full batch run the one pair of programs (fresh and
+    chained carry) that warm_for met."""
+    cs, s = _cluster()
+    pod = (make_pod().name("probe").req({"cpu": "100m"}).label("app", "t")
+           .spread_constraint(1, ZONE, "DoNotSchedule", {"app": "t"}).obj())
+    state, plan = s.build_plan(s.framework_for_pod(pod), pod, s.max_batch)
+    assert plan.engine == "scan_carried" and plan.batch_pad == 1024
+    s.warm_for(pod)
+    warmed = _cache_size()
+    results, carry = s._dispatch(state, plan, n_active, None)
+    chained, _carry = s._dispatch(state, plan, n_active, carry)
+    assert results.shape == chained.shape == (2, 1024)
+    assert _cache_size() == warmed

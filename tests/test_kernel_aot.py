@@ -22,12 +22,21 @@ from jax.sharding import SingleDeviceSharding
 # Ceilings, with what was counted when they were set (PR 25; the parent of
 # that PR in brackets).
 RESOURCE_EVAL_CEILING = 2_500   # 1,766 LeastAllocated, 1,758 Most [11,493]
+# PR 32 (the scan loops `n_active` times, a `while` with no trip count known
+# to the compiler): 2,093 and 2,440.
 SCAN_BODY_CEILING = 3_000       # 2,095 [13,575]
 LAP_BODY_CEILING = 3_500        # 2,385 [21,147]
 NORMALISING_BODY_CEILING = 3_500  # 2,434 (PR 31: the scan that normalises)
 
 _INSTRUCTION = re.compile(r"\s+(ROOT )?%?[\w.\-]+ = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
+# In the lowered module: the program's `n_active` argument, what the loop
+# binds it to, and the one comparison its condition makes.
+_N_ACTIVE_ARG = re.compile(r'(%arg\d+): tensor<i32>[^%]*? loc\("n_active"\)')
+_LOOP_COND = re.compile(
+    r"stablehlo\.while\((?P<binds>.*?)\)[^\n]*\n\s*cond \{\s*"
+    r"%\d+ = stablehlo\.compare\s+LT, (?P<count>%\w+), (?P<bound>%\w+),"
+    r"[^\n]*\n\s*stablehlo\.return")
 
 
 @pytest.fixture(scope="module")
@@ -135,8 +144,9 @@ def _small_plan(batch, spread, preferred=False):
 def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, ceiling):
     """`schedule_batch` as the wave cells run it, at a small cluster's
     shapes (the loop body's scalar code does not depend on them): nothing in
-    the loop comes from a `div` or `rem`, and the body stays under its
-    ceiling."""
+    the loop comes from a `div` or `rem`, the body stays under its
+    ceiling, and the loop runs until a counter reaches the `n_active`
+    argument: no program's trip count is its `batch_pad`."""
     from kubernetes_tpu.ops.kernel import schedule_batch
 
     state, plan = _small_plan(batch, spread,
@@ -147,14 +157,22 @@ def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, 
     def sds(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
-    hlo = schedule_batch.lower(
+    lowered = schedule_batch.lower(
         jax.tree_util.tree_map(sds, state), jax.tree_util.tree_map(sds, plan.features),
         plan.batch_pad, plan.fit_strategy, plan.vmax,
         n_active=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
         carry_in=None, has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
         anti_rowlocal=plan.anti_rowlocal, has_na_pref=plan.has_na_pref,
         port_selfblock=plan.port_selfblock, has_aux=plan.has_aux,
-        has_nom=plan.has_nom).compile().as_text()
+        has_nom=plan.has_nom)
+    module = lowered.as_text(debug_info=True)
+    n_active = _N_ACTIVE_ARG.search(module).group(1)
+    (loop,) = _LOOP_COND.finditer(module)
+    assert f"{loop['bound']} = {n_active}" in re.split(r",\s*", loop["binds"]), (
+        "the loop's condition does not compare with the n_active argument")
+    hlo = lowered.compile().as_text()
+    (loop_line,) = [l for l in _instructions(hlo) if " while(" in l]
+    assert "known_trip_count" not in loop_line
     body = _instructions(hlo, under="/while/body/")
     prims = _primitives(body)
     assert prims["div"] == 0 and prims["rem"] == 0, prims

@@ -69,6 +69,39 @@ def test_sharded_chained_sessions_match_host():
     assert host_asg == dev_asg
 
 
+def test_a_short_dispatch_under_the_mesh_equals_the_single_device_run():
+    """The scan's bound `n_active` is a replicated scalar under GSPMD, so
+    every device leaves the loop at the same trip: a dispatch of 5 pods on a
+    64-wide plan, fresh and chained, returns what one device returns,
+    results and carry, and writes nothing past the fifth column."""
+    def run(mesh):
+        cs = FakeClientset()
+        s = TPUScheduler(clientset=cs, mesh=mesh, max_batch=64)
+        for i in range(40):
+            cs.create_node(make_node().name(f"n{i}")
+                           .capacity({"cpu": 8 + i % 3, "memory": "32Gi",
+                                      "pods": 110})
+                           .zone(f"z{i % 4}").obj())
+        pod = (make_pod().name("probe").req({"cpu": "250m"}).label("app", "s")
+               .spread_constraint(1, ZONE, "DoNotSchedule", {"app": "s"}).obj())
+        state, plan = s.build_plan(s.framework_for_pod(pod), pod, 64)
+        assert plan.engine == "scan_carried" and plan.batch_pad == 64
+        assert s._shard_map_fn(plan) is None   # the GSPMD schedule_batch
+        fresh, carry = s._dispatch(state, plan, 5, None)
+        fresh = np.asarray(fresh)   # before the chained call donates the carry
+        chained, carry = s._dispatch(state, plan, 7, carry)
+        return s, fresh, np.asarray(chained), [np.asarray(x) for x in carry]
+
+    sharded, fresh, chained, carry = run("auto")
+    _single, fresh1, chained1, carry1 = run(None)
+    assert sharded.mesh is not None and _single.mesh is None
+    assert (fresh == fresh1).all() and (chained == chained1).all()
+    assert (fresh[0, :5] >= 0).all() and (fresh[:, 5:] == -1).all()
+    assert (chained[0, :7] >= 0).all() and (chained[:, 7:] == -1).all()
+    for got, want in zip(carry, carry1):
+        assert got.dtype == want.dtype and (got == want).all()
+
+
 def test_two_cells_schedule_independently():
     """The "cells" mesh axis (parallel/mesh.py sharded_schedule_batch):
     n_cells=2 vmaps the kernel over two INDEPENDENT scheduling cells
