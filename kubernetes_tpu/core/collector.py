@@ -28,6 +28,16 @@ from .spans import GcClock
 # generations keep the interpreter's ratios. On the chip, 40 s of
 # basic-5k.waves (PERF.md section 6, PR 30): 10,184 + 926 + 75 collections in
 # 11.05 s at (700, 10, 10), 104 + 9 + 0 in 2.38 s with these and the freeze.
+# At 50,000 pods a wave (SchedulingBasic/5000Nodes_50000Pods, the cell
+# basic-5k-50k.waves; PERF.md section 6, PR 33; some 600,000 live
+# tracked objects at a wave's end, 11 waves in 40 s): 101 + 9 + 0
+# in 3.29 s, 8.3 % of wave time: nine young collections a wave at 18 ms and
+# one of generation 1 at 0.16 s over the wave's survivors, which is nearly half
+# of the pause. No full collection in any of 17 windows, and no gc.settle or
+# move of scheduler_gc_freezes_total in the four read for them: the tenth
+# generation-1 collection since set-up falls into a twelfth wave (counted on
+# the CPU: one full collection there and one settle after it, 0.2 million
+# objects frozen). The policy holds at this heap; what it leaves is S10's.
 THRESHOLDS = (50_000, 10, 10)
 # Frozen again once the unfrozen heap has outgrown this share of the frozen
 # one: all re-freezing then walks a constant multiple of the final heap.

@@ -280,6 +280,15 @@ class TPUScheduler(Scheduler):
         if ctx is not None and ctx.sampled:
             batch.sampled.append(ctx)
 
+    def _pop_stage(self):
+        """The `queue.pop` stage of one batch, opened with the active
+        queue's depth as the pop begins (attr `backlog`: on the span and,
+        in a profiler session, a stat of the event). One `len()` a batch;
+        the hint walk's per-pod pops are leaves of the table and say
+        nothing."""
+        return self.stages.stage(
+            "queue.pop", backlog=len(self.queue.active_q))
+
     def _collect_batch(self) -> Tuple[Optional[Framework], List[QueuedPodInfo], Optional[str]]:
         """Pop a maximal run of consecutive identical-signature pods.
         Returns (framework, batch, fallback_reason); fallback_reason set when
@@ -474,7 +483,7 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if pack is None:
-                    with stages.stage("queue.pop"):
+                    with self._pop_stage():
                         pack = collect_pack() or None
                     if pack is None:
                         break
@@ -1892,7 +1901,7 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if batch is None:
-                    with stages.stage("queue.pop"):
+                    with self._pop_stage():
                         batch = self._collect_session_batch(fw, sig) or None
                     if batch is None and self._event_inbox:
                         # A concurrent client (threaded watch feed) may have
@@ -1908,7 +1917,7 @@ class TPUScheduler(Scheduler):
                         elif sd.patch_pending:
                             continue  # patch (or drain) before collecting
                         else:
-                            with stages.stage("queue.pop"):
+                            with self._pop_stage():
                                 batch = self._collect_session_batch(
                                     fw, sig) or None
                     if batch is None:
@@ -2409,7 +2418,7 @@ class TPUScheduler(Scheduler):
         # path below (the popped entity waits in the holdover slot).
         if self._hints.entry is not None and self._try_hint_binds():
             return True
-        with self.stages.stage("queue.pop"):
+        with self._pop_stage():
             fw, batch, fallback_reason = self._collect_batch()
         if not batch:
             return False
