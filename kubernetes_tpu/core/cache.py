@@ -337,6 +337,24 @@ class Cache:
         self.assumed_pods.add(pod.uid)
         self.pod_states[pod.uid] = _PodState(pod)
 
+    def assume_pods(self, run) -> None:
+        """``assume_pod`` over a run of ``(pod, pod_info)`` whose
+        ``node_name`` is set, in order (a retired batch's pods,
+        models/tpu_scheduler.py): what is per pod by nature is done per pod
+        (the node's ``add_pod``, the pod's state, the dirty mark), the
+        lookups are made once. A pod already assumed or added raises as
+        ``assume_pod`` does, with the pods before it assumed."""
+        pod_states = self.pod_states
+        assumed = self.assumed_pods
+        add_to_node = self._add_pod_to_node
+        for pod, pod_info in run:
+            uid = pod.uid
+            if uid in pod_states:
+                raise ValueError(f"pod {uid} is already assumed/added")
+            add_to_node(pod, pod_info)
+            assumed.add(uid)
+            pod_states[uid] = _PodState(pod)
+
     def finish_binding(self, pod: Pod) -> None:
         st = self.pod_states.get(pod.uid)
         if st is not None and pod.uid in self.assumed_pods:

@@ -273,6 +273,25 @@ class FakeClientset:
         for h in self._pod_handlers:
             h("update", old, new)
 
+    def bind_many(self, pairs) -> list:
+        """The bulk binding verb (``HTTPClientset.bind_many``'s signature:
+        ``(pod, node name)`` pairs in, one ``None`` or exception a pair
+        out): per pair what ``bind`` does, and the same ``update`` event to
+        every handler before the next pair is touched. One transaction:
+        it stops at the first pair the store refuses, whose exception ends
+        the list, and the pairs after it are not attempted and have no
+        entry (a remote apiserver answers every item; ``DefaultBinder.
+        _bulk_bind`` sends the rest on)."""
+        out = []
+        for pod, node_name in pairs:
+            try:
+                self.bind(pod, node_name)
+            except Exception as e:  # noqa: BLE001 - the pair's own verdict
+                out.append(e)
+                break
+            out.append(None)
+        return out
+
     def patch_pod_status(self, pod: Pod, nominated_node_name: str = "", phase: str = "") -> None:
         stored = self.pods.get(pod.uid)
         if stored is None:

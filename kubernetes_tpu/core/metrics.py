@@ -101,6 +101,22 @@ class Histogram(Metric):
         self._sums[key] = self._sums.get(key, 0.0) + value
         self._totals[key] = self._totals.get(key, 0) + 1
 
+    def observe_many(self, values: Sequence[float], *labels: str) -> None:
+        """Every value of ``values`` observed: the buckets, the sum and the
+        total of that many ``observe`` calls, with the series looked up
+        once (a retired batch's pods, models/tpu_scheduler.py)."""
+        if not values:
+            return
+        key = labels
+        counts = self._counts.get(key)
+        if counts is None:
+            counts = self._counts.setdefault(key, [0] * (len(self.buckets) + 1))
+        buckets = self.buckets
+        for value in values:
+            counts[bisect_left(buckets, value)] += 1
+        self._sums[key] = self._sums.get(key, 0.0) + sum(values)
+        self._totals[key] = self._totals.get(key, 0) + len(values)
+
     def _cumulative(self, key, counts: Optional[Dict] = None) -> List[int]:
         out = []
         c = 0
@@ -390,6 +406,12 @@ class SchedulerMetrics:
             "'skipped' = the sum of batch_pad - n_active, the steps a "
             "fixed-length scan would have run and placed nothing with.",
             ("kind",)))
+        self.commit_pods = r(Counter(
+            "scheduler_commit_pods_total",
+            "Pods of retired device batches by the host tail that committed "
+            "them (models/tpu_scheduler.py _commit_batch): 'batch' = in "
+            "passes over the batch (one assume, one bulk bind, one settle), "
+            "'single' = one _commit call a pod.", ("tail",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

@@ -510,13 +510,15 @@ class Scheduler:
         # are atomic under the GIL, so no lock is needed.
         from collections import deque
         self._event_inbox = deque()
+        # Durations of the own bind confirms handled inside the bulk bind a
+        # batch tail has open; None outside one (see _pod_events).
+        self._own_confirms: Optional[List[float]] = None
         self._wire_event_handlers()
 
     # -- event handlers (eventhandlers.go:624 addAllEventHandlers) ---------
 
     def _wire_event_handlers(self) -> None:
-        self.clientset.on_pod_event(self._threaded(
-            self._timed_event("pod", self._on_pod_event)))
+        self.clientset.on_pod_event(self._pod_events())
         self.clientset.on_node_event(self._threaded(
             self._timed_event("node", self._on_node_event)))
         self.clientset.on_namespace_event(self._threaded(self._bump(
@@ -570,6 +572,33 @@ class Scheduler:
                 handler(*args)
             else:
                 self._event_inbox.append((handler, args))
+        return dispatch
+
+    def _pod_events(self):
+        """The pod handler the clientset is given: ``_threaded`` over
+        ``_timed_event`` over ``_on_pod_event``, and one shorter way in.
+        While a batch tail's bulk bind is open (models/tpu_scheduler.py:
+        ``_own_confirms`` is its list), an ``update`` that reaches the loop's
+        thread with a node name for a pod this scheduler holds assumed is
+        the confirm of a bind it sent a moment ago: it gets
+        ``_confirm_own_bind`` at once and leaves its duration on the list,
+        which the tail hands to ``event_handling_duration_seconds`` in one
+        call. Every other event, and every event outside a bulk bind, goes
+        the long way."""
+        general = self._threaded(self._timed_event("pod", self._on_pod_event))
+        loop_ident = threading.get_ident()
+        clock = time.perf_counter
+
+        def dispatch(kind, old, new):
+            confirms = self._own_confirms
+            if (confirms is None or kind != "update" or not new.node_name
+                    or new.uid not in self.cache.assumed_pods
+                    or threading.get_ident() != loop_ident):
+                general(kind, old, new)
+                return
+            t0 = clock()
+            self._confirm_own_bind(old, new)
+            confirms.append(clock() - t0)
         return dispatch
 
     def drain_event_inbox(self) -> int:
@@ -645,7 +674,8 @@ class Scheduler:
             # onto the node (note `old` may alias the scheduler's mutated
             # object, so old.node_name can't distinguish the transition —
             # the assumed set can).
-            self._note_own_bind_confirm(new)
+            self._confirm_own_bind(old, new)
+            return
         else:
             self._record_pod_event(kind, old, new)
         if new.node_name or kind == "delete":
@@ -722,6 +752,23 @@ class Scheduler:
                     EVENT_ASSIGNED_POD_DELETE, new, None)
             else:
                 self.queue.delete(new)
+
+    def _confirm_own_bind(self, old: Optional[Pod], new: Pod) -> None:
+        """The watch feed confirmed one of OUR binds (``new`` is bound and
+        in the assumed set): no journal record, since the carry already
+        holds the placement via the assume; the evicted-pending window
+        closes; the cache takes the confirmed copy; and where ``old`` shows
+        the pending -> bound transition, parked pods whose affinity or
+        spread terms this pod satisfies requeue (eventhandlers.go
+        addPodToCache -> MoveAllToActiveOrBackoffQueue(AssignedPodAdd)).
+        Over the in-process store ``old`` is the object this scheduler
+        assumed, node name and all, so nothing moves there."""
+        self._note_own_bind_confirm(new)
+        self._eviction_residue.pop(new.uid, None)
+        self.cache.add_pod(new)
+        if old is not None and not old.node_name:
+            self.queue.move_all_to_active_or_backoff(
+                EVENT_ASSIGNED_POD_ADD, None, new)
 
     def _note_own_bind_confirm(self, new: Pod) -> None:
         """Seam: the watch stream confirmed one of OUR binds (the pod is in
@@ -1965,15 +2012,19 @@ class Scheduler:
 
     def _publish_bind_requests(self) -> None:
         """The binding requests' two counters, from counts kept where the
-        requests are made: the dispatcher's worker for queued binds, and
-        for inline ones the loop's bind.post stage, entered once for each
-        synchronous single-pod request (plugins/basic.py DefaultBinder)."""
+        requests are made: the dispatcher's for queued binds and for the
+        bulk request of a batch tail, and for the loop's single-pod
+        requests its bind.post stage, entered once for each synchronous
+        request (plugins/basic.py DefaultBinder): those less the bulk ones,
+        which in inline mode are all the loop's own."""
         dispatcher = self.api_dispatcher
+        bulk = dispatcher.bind_requests["bulk"]
         inline = self.stages.counts["bind.post"]
+        if dispatcher.mode == "inline":
+            inline -= bulk
         singles = dispatcher.bind_requests["single"] + inline
         self.metrics.bind_requests.set_total(float(singles), "single")
-        self.metrics.bind_requests.set_total(
-            float(dispatcher.bind_requests["bulk"]), "bulk")
+        self.metrics.bind_requests.set_total(float(bulk), "bulk")
         self.metrics.bind_request_pods.set_total(
             float(dispatcher.bind_request_pods + inline))
 

@@ -441,12 +441,14 @@ class StageLedger:
               point: str = "", annotate: bool = True, **attrs) -> _Stage:
         return _Stage(self, name, ctxs, point, annotate, attrs)
 
-    def leaf(self, name: str, seconds: float, **attrs) -> None:
+    def leaf(self, name: str, seconds: float, per_pod: bool = True,
+             **attrs) -> None:
         """A finished child with no children of its own, timed by the
         caller (the per-pod form: two clock reads, no object, no
-        annotation)."""
+        annotation). ``per_pod`` False: it carried a batch (a bulk bind
+        request) and is slow where a batch stage is."""
         self._account(name, seconds, seconds)
-        if seconds > SLOW_STAGE_S:
+        if seconds > (SLOW_STAGE_S if per_pod else SLOW_BATCH_STAGE_S):
             self._slow(name, (), attrs, seconds, seconds, 0)
 
     def _account(self, name: str, self_s: float, duration: float) -> bool:

@@ -62,13 +62,16 @@ DEVICE_BREAKER_COOLDOWN_S = 5.0
 class _Batch(list):
     """A popped batch and the span contexts of its sampled members, found
     once as the batch is collected: each stage of the batch copies its span
-    into those traces without another lookup per pod."""
+    into those traces without another lookup per pod. ``sampled_at`` holds
+    each context's place in the batch (the batch tail closes those pods'
+    traces)."""
 
-    __slots__ = ("sampled",)
+    __slots__ = ("sampled", "sampled_at")
 
     def __init__(self, *args):
         super().__init__(*args)
         self.sampled: list = []
+        self.sampled_at: List[int] = []
 
 
 class _SessionDelta:
@@ -232,6 +235,13 @@ class TPUScheduler(Scheduler):
         return int(self.metrics.device_batches.total())
 
     @property
+    def commit_pods(self) -> dict:
+        """Pods of retired batches by the host tail that committed them:
+        `scheduler_commit_pods_total{tail}` (`_commit_batch`)."""
+        series = self.metrics.commit_pods
+        return {tail: int(series.value(tail)) for tail in ("batch", "single")}
+
+    @property
     def device_wait_s(self) -> float:
         """Time blocked on a device result fetch (`device.wait`)."""
         return self.stages.seconds["device.wait"]
@@ -275,10 +285,11 @@ class TPUScheduler(Scheduler):
 
     def _take(self, batch: "_Batch", qpi: QueuedPodInfo) -> None:
         """Accept the entity _pop just handed out into `batch`."""
-        batch.append(qpi)
         ctx = self._popped_ctx
         if ctx is not None and ctx.sampled:
             batch.sampled.append(ctx)
+            batch.sampled_at.append(len(batch))
+        batch.append(qpi)
 
     def _pop_stage(self):
         """The `queue.pop` stage of one batch, opened with the active
@@ -1949,10 +1960,13 @@ class TPUScheduler(Scheduler):
                               batch=len(b)):
                 res = np.asarray(results)  # one device→host fetch
             if not invalidated:
+                run = self._batch_tail_run(b, res, fw)
                 with stages.stage("host.commit", b.sampled, "HostCommit",
-                                  batch=len(b)):
+                                  batch=len(b),
+                                  tail=("single" if not run else "batch"
+                                        if run == len(b) else "mixed")):
                     invalidated = self._commit_batch(
-                        b, res, fw, node_names, ok_rows, dirty_rows)
+                        b, res, fw, node_names, ok_rows, dirty_rows, run)
                 if getattr(self, "_after_flush", False):
                     # First retired batch after a flush: its pods scheduled
                     # from a fresh (non-chained) evaluation.
@@ -2020,13 +2034,144 @@ class TPUScheduler(Scheduler):
         # NORMAL end, not a device failure): a half-open breaker closes.
         self._note_device_success()
 
-    def _commit_batch(self, b, res, fw, node_names, ok_rows, dirty_rows) -> bool:
-        """Host tail for one retired batch. Returns True when the session
-        must invalidate (host/device divergence or host-path interleaving)."""
-        invalidated = False
-        for i, qpi in enumerate(b):
-            row = int(res[0, i])
-            self.next_start_node_index = int(res[1, i])
+    def _batch_tail_run(self, b, res, fw) -> int:
+        """How many pods of a retired batch, from its first on, the batch
+        tail commits (``_commit_run``); the pods after them take ``_commit``
+        one by one. Decided from what can be observed as the batch retires:
+        binds leave on the loop's own thread (the dispatcher's inline mode),
+        to a clientset with the bulk verb; the profile's tail is the lean
+        one (``_commit_fast_eligible``, no extenders); and the run ends at
+        the first pod the device could not place, that claims devices or
+        that belongs to a gang."""
+        if (self.api_dispatcher.mode != "inline" or self.extenders
+                or not self._commit_fast_eligible(fw)
+                or getattr(self.clientset, "bind_many", None) is None):
+            return 0
+        for i, row in enumerate(res[0, :len(b)].tolist()):
+            pod = b[i].pod
+            if row < 0 or pod.pod_group or pod.resource_claims:
+                return i
+        return len(b)
+
+    def _commit_run(self, b, run: int, rows, fw, node_names,
+                    ok_rows, dirty_rows) -> Tuple[int, bool]:
+        """The batch tail: the first ``run`` pods of a retired batch,
+        committed in passes over the run instead of one ``_commit`` a pod,
+        with ``_commit``'s lean tail's outcome for every pod. Returns how
+        many pods of the batch it dealt with and whether the session must
+        invalidate.
+
+        Assume all (one cache call), bind all in one request
+        (``DefaultBinder.bind_run``; this scheduler's handler confirms its
+        own binds by the short way while it is open, core/scheduler.py
+        ``_pod_events``), settle all. A refused bind ends it: the pods the
+        request bound are settled, the refused one is unwound as ``_commit``
+        unwinds it, the pods the request never reached are un-assumed and
+        left to ``_commit_batch``'s invalidated branch, where the per-pod
+        tail sends them too."""
+        pairs = []
+        assume = []
+        for i in range(run):
+            qpi = b[i]
+            pod = qpi.pod
+            pod.node_name = node_name = node_names[rows[i]]
+            pairs.append((pod, node_name))
+            assume.append((pod, qpi.pod_info))
+        self.cache.assume_pods(assume)
+        confirms = self._own_confirms = []
+        try:
+            results = fw.bind_plugins[0].bind_run(pairs)
+        finally:
+            self._own_confirms = None
+            self.metrics.event_handling_duration.observe_many(
+                confirms, "pod")
+        bound_at = self.now()
+        answered = len(results)
+        self.attempts += answered
+        self.metrics.commit_pods.inc("batch", value=float(answered))
+        if results.count(None) == run:
+            self._settle_run(b, range(run), pairs, bound_at)
+            ok_rows.extend(rows[:run])
+            return run, False
+        self._settle_run(
+            b, [i for i in range(answered) if results[i] is None], pairs,
+            bound_at)
+        for i in range(answered, run):  # never reached: as if never assumed
+            pod = b[i].pod
+            self.cache.forget_pod(pod)
+            pod.node_name = ""
+        from ..core.framework import CycleState
+        for i in range(answered):
+            if results[i] is None:
+                ok_rows.append(rows[i])
+                continue
+            self._unwind_binding(fw, CycleState(), b[i], pairs[i][1],
+                                 results[i])
+            self.queue.done(b[i].pod.uid)
+            dirty_rows.append(rows[i])
+        return answered, True
+
+    def _settle_run(self, b, bound, pairs, bound_at: float) -> None:
+        """Pods ``bound`` (places in the batch) were bound by a request that
+        returned at ``bound_at`` on the scheduler's clock: what ``_commit``'s
+        lean tail does after a bind that succeeded, for all of them."""
+        if not bound:
+            return
+        cache = self.cache
+        if cache.assumed_pods:  # a confirm is still to come: arm the expiry
+            for i in bound:
+                cache.finish_binding(b[i].pod)
+        nom = self.queue.nominator
+        if nom._pod_to_node:
+            for i in bound:
+                nom.delete_nominated_pod(b[i].pod)
+        n = len(bound)
+        self.scheduled += n
+        self.device_scheduled += n
+        # observe_bound for every pod, its series ending as the request did
+        e2e = {}
+        for i in bound:
+            start = getattr(b[i], "enqueued_at", None)
+            if start is not None:
+                e2e[i] = max(0.0, bound_at - start)
+        self.metrics.e2e_scheduling_duration.observe_many(
+            list(e2e.values()))
+        tr = self.tracer
+        if b.sampled and tr.enabled:
+            wall = _time.time()
+            for i, ctx in zip(b.sampled_at, b.sampled):
+                if i in e2e:
+                    tr.record("pod.e2e", ctx, e2e[i], node=pairs[i][1],
+                              attempts=b[i].attempts, start=wall - e2e[i])
+        eventf = self.recorder.eventf
+        done = self.queue.done
+        for i in bound:
+            pod, node_name = pairs[i]
+            eventf(pod.namespace + "/" + pod.name, "Normal", "Scheduled",
+                   ("Successfully assigned %s/%s to %s",
+                    (pod.namespace, pod.name, node_name)))
+            done(pod.uid)
+
+    def _commit_batch(self, b, res, fw, node_names, ok_rows, dirty_rows,
+                      run: int = 0) -> bool:
+        """Host tail for one retired batch: its first ``run`` pods
+        (``_batch_tail_run``) by the batch tail, the others by ``_commit``,
+        one by one. Returns True when the session must invalidate
+        (host/device divergence or host-path interleaving)."""
+        n = len(b)
+        rows = res[0, :n].tolist()
+        starts = res[1, :n].tolist()
+        first, invalidated = 0, False
+        if run:
+            first, invalidated = self._commit_run(
+                b, run, rows, fw, node_names, ok_rows, dirty_rows)
+            if first:
+                self.next_start_node_index = starts[first - 1]
+        single = 0
+        for i in range(first, n):
+            qpi = b[i]
+            row = rows[i]
+            self.next_start_node_index = starts[i]
             if invalidated:
                 if row >= 0:
                     dirty_rows.append(row)
@@ -2060,12 +2205,15 @@ class TPUScheduler(Scheduler):
                 self._memoize_failure(fw, qpi)
                 invalidated = True
                 continue
+            single += 1
             if self._commit(fw, qpi, node_names[row]):
                 ok_rows.append(row)
             else:
                 # Host rejected what the device applied in its carry.
                 dirty_rows.append(row)
                 invalidated = True
+        if single:
+            self.metrics.commit_pods.inc("single", value=float(single))
         return invalidated
 
     def _fail_state_key(self, fw: Framework, pod) -> tuple:
@@ -2196,8 +2344,11 @@ class TPUScheduler(Scheduler):
                 and self._commit_fast_eligible(fw)):
             # Lean tail: identical observable semantics to the full path
             # below for this plugin shape (the skipped plugin runs are
-            # provably no-ops on an empty CycleState), ~2x cheaper — this
-            # runs once per scheduled pod at >13k pods/s.
+            # provably no-ops on an empty CycleState), ~2x cheaper. The one
+            # per-pod tail: every pod of the hint walk, of the thread mode
+            # and of a batch the batch tail refuses (_batch_tail_run) pays
+            # it; a retired batch that qualifies is committed in passes
+            # (_commit_run), to the same outcome.
             if TPUScheduler._EMPTY_STATE is None:
                 TPUScheduler._EMPTY_STATE = CycleState()
             pod.node_name = node_name

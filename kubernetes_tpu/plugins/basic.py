@@ -182,42 +182,46 @@ class DefaultBinder:
         observe(call.acked_at - call.sent_at, "bind.post")
         self.handle.on_async_bind_done(call.bind_args[0], call.acked_at)
 
+    def _refused(self, e: Exception, pod: Pod, dispatcher) -> Status:
+        """What a synchronous bind that raised ``e`` returns for its pod."""
+        if getattr(e, "code", None) == 429:
+            # Flow-control shed (core/flowcontrol.py): the bind
+            # never ran. Tagged so the binding cycle requeues
+            # through the backoffQ with the admission stamp
+            # intact — the retry layers already honored
+            # Retry-After before this surfaced.
+            return Status.bind_shed(str(e))
+        if getattr(e, "code", None) == 409:
+            # Optimistic-binding loss (AlreadyBound /
+            # OutOfCapacity): another scheduler committed first.
+            # Tagged so the binding cycle requeues through the
+            # backoffQ instead of parking the pod as an error.
+            reason = ""
+            try:  # the 409 body names which conflict it was
+                import json as _json
+                reason = _json.loads(e.read()).get("error", "")
+            except Exception:  # noqa: BLE001
+                pass
+            return Status.bind_conflict(reason or str(e))
+        if dispatcher is not None:
+            from ..core.api_dispatcher import CALL_BINDING
+            dispatcher.errors.append(f"{CALL_BINDING}/{pod.uid}: {e!r}")
+        return Status.error(str(e))
+
     def bind(self, state: CycleState, pod: Pod, node_name: str) -> Status:
         dispatcher = getattr(self.handle, "api_dispatcher", None)
         try:
             if dispatcher is None or dispatcher.mode == "inline":
                 # Inline mode executes immediately anyway — skip the APICall
-                # allocation and go straight to the API (this runs once per
-                # scheduled pod on a >10k pods/s path). Counter/error
-                # accounting matches APIDispatcher._execute.
+                # allocation and go straight to the API (the per-pod tail of
+                # the hint walk and of every batch the batch tail refuses).
+                # Counter/error accounting matches APIDispatcher._execute.
                 t0 = time.perf_counter()
                 try:
                     self.handle.clientset.bind(pod, node_name)
                 except Exception as e:  # noqa: BLE001
                     self._posted(t0)
-                    if getattr(e, "code", None) == 429:
-                        # Flow-control shed (core/flowcontrol.py): the bind
-                        # never ran. Tagged so the binding cycle requeues
-                        # through the backoffQ with the admission stamp
-                        # intact — the retry layers already honored
-                        # Retry-After before this surfaced.
-                        return Status.bind_shed(str(e))
-                    if getattr(e, "code", None) == 409:
-                        # Optimistic-binding loss (AlreadyBound /
-                        # OutOfCapacity): another scheduler committed first.
-                        # Tagged so the binding cycle requeues through the
-                        # backoffQ instead of parking the pod as an error.
-                        reason = ""
-                        try:  # the 409 body names which conflict it was
-                            import json as _json
-                            reason = _json.loads(e.read()).get("error", "")
-                        except Exception:  # noqa: BLE001
-                            pass
-                        return Status.bind_conflict(reason or str(e))
-                    if dispatcher is not None:
-                        from ..core.api_dispatcher import CALL_BINDING
-                        dispatcher.errors.append(f"{CALL_BINDING}/{pod.uid}: {e!r}")
-                    return Status.error(str(e))
+                    return self._refused(e, pod, dispatcher)
                 self._posted(t0)
                 if dispatcher is not None:
                     dispatcher.executed += 1
@@ -241,18 +245,61 @@ class DefaultBinder:
             return Status.error(str(e))
         return BIND_QUEUED
 
+    def bind_run(self, pairs) -> list:
+        """Inline mode, a retired batch's run of ``(pod, node name)`` as ONE
+        bulk request to a clientset with the bulk verb (models/
+        tpu_scheduler.py ``_commit_batch``). One entry a pair the request
+        answered, in order: None for a bound pod, else the Status ``bind``
+        would have returned for its refusal. The in-process store stops at
+        its first refusal and answers no pair after it; a request that
+        failed whole refuses every pair. Accounted as the worker's bulk
+        request is: every answered pod observes the request's round trip
+        as its ``bind.post``, the loop's table gets it once, the dispatcher
+        counts one bulk request and the pods it carried."""
+        handle = self.handle
+        dispatcher = getattr(handle, "api_dispatcher", None)
+        t0 = time.perf_counter()
+        try:
+            results = handle.clientset.bind_many(pairs)
+        except Exception as e:  # noqa: BLE001 - nothing is known bound
+            results = [e] * len(pairs)
+        seconds = time.perf_counter() - t0
+        handle.metrics.pod_stage_duration.observe_many(
+            [seconds] * len(results), "bind.post")
+        handle.stages.leaf("bind.post", seconds, per_pod=False)
+        bound = results.count(None)
+        if bound != len(results):
+            results = [
+                None if r is None else self._refused(r, pod, dispatcher)
+                for r, (pod, _node) in zip(results, pairs)]
+        if dispatcher is not None:
+            dispatcher.bind_requests["bulk"] += 1
+            dispatcher.bind_request_pods += len(pairs)
+            dispatcher.executed += bound
+        return results
+
     def _bulk_bind(self, calls) -> list:
         """Commit a run of queued binding calls as ONE bulk request
         (dispatcher thread worker → clientset.bind_many). Per-bind POSTs
         cap the async worker far below the server's bind capacity: each
         round-trip costs a GIL wakeup in a process whose reflector/
         scheduler threads are busy, so amortizing N binds per wakeup is
-        worth ~an order of magnitude in drain rate. Falls back to per-call
-        binds for clientsets without a bulk verb (FakeClientset)."""
+        worth ~an order of magnitude in drain rate. A store whose verb
+        stops at its first refusal (FakeClientset) is sent the pairs after
+        it as the next request: queued binds are independent, each gets its
+        own verdict. Falls back to per-call binds for clientsets without a
+        bulk verb."""
         cs = self.handle.clientset
         bind_many = getattr(cs, "bind_many", None)
         if bind_many is not None:
-            return bind_many([c.bind_args for c in calls])
+            pairs = [c.bind_args for c in calls]
+            out = bind_many(pairs)
+            while len(out) < len(pairs):
+                more = bind_many(pairs[len(out):])
+                if not more:
+                    break
+                out.extend(more)
+            return out
         out = []
         for c in calls:
             try:
