@@ -46,7 +46,10 @@ REFREEZE_SHARE = 0.25
 
 class CollectorPolicy:
     def __init__(self):
-        self.clock = GcClock()
+        # The holders' stage ledgers: the clock tells the one whose loop
+        # thread is collecting, which books the pause (stage `gc.pause`).
+        self._ledgers = weakref.WeakSet()
+        self.clock = GcClock(self._ledgers)
         self.freezes = 0
         self._settled = False  # a freeze of this policy's is in place
         self._lock = threading.RLock()  # a finalizer may release mid-acquire
@@ -58,6 +61,7 @@ class CollectorPolicy:
         """A scheduler's share: given back by calling it, or at collection."""
         with self._lock:
             self._holders += 1
+            self._ledgers.add(scheduler.stages)
             if self._holders == 1:
                 self._found = gc.get_threshold()
                 gc.set_threshold(*THRESHOLDS)

@@ -232,6 +232,14 @@ class SchedulerMetrics:
             "the request that carried the pod, single or bulk, as the "
             "scheduler sees it).", ("stage",),
             buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
+        self.inbox_oldest_wait = r(Histogram(
+            "scheduler_inbox_oldest_wait_seconds",
+            "Stage inbox.wait: how long the oldest watch event parked by "
+            "another thread (the reflector, a client thread) had waited "
+            "when the loop began the drain that replayed it; one "
+            "observation a drain. It lies before queue admission, so the "
+            "e2e histogram does not hold it.",
+            buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
         self.bind_requests = r(Counter(
             "scheduler_bind_requests_total",
             "Binding requests sent to the apiserver: single (one pod: an "

@@ -510,6 +510,10 @@ class Scheduler:
         # are atomic under the GIL, so no lock is needed.
         from collections import deque
         self._event_inbox = deque()
+        # When the oldest event now parked was parked (perf_counter; 0.0:
+        # nothing stamped): the park that finds the inbox empty stamps it,
+        # the drain reads it and takes it away (stage inbox.wait).
+        self._inbox_oldest_at = 0.0
         # Durations of the own bind confirms handled inside the bulk bind a
         # batch tail has open; None outside one (see _pod_events).
         self._own_confirms: Optional[List[float]] = None
@@ -564,14 +568,20 @@ class Scheduler:
         inbox and replayed by the scheduling loop — the DeltaFIFO seam
         (client-go delta_fifo.go): cache/queue mutation stays single-threaded.
         Events raised on the scheduling thread dispatch inline, preserving the
-        synchronous semantics tests rely on."""
+        synchronous semantics tests rely on. The park that finds the inbox
+        empty stamps the clock: that event is the oldest the next drain
+        meets (a park that loses the race with a drain between its look and
+        its append leaves no stamp, and that drain observes nothing)."""
         loop_ident = threading.get_ident()  # get_ident beats current_thread
+        inbox = self._event_inbox
 
         def dispatch(*args):
             if threading.get_ident() == loop_ident:
                 handler(*args)
             else:
-                self._event_inbox.append((handler, args))
+                if not inbox:
+                    self._inbox_oldest_at = time.perf_counter()
+                inbox.append((handler, args))
         return dispatch
 
     def _pod_events(self):
@@ -605,6 +615,10 @@ class Scheduler:
         """Replay off-thread watch events on the scheduling loop."""
         if not self._event_inbox:
             return 0
+        parked_at, self._inbox_oldest_at = self._inbox_oldest_at, 0.0
+        if parked_at:
+            self.metrics.inbox_oldest_wait.observe(
+                time.perf_counter() - parked_at)
         n = 0
         with self.stages.stage("inbox.drain"):
             while self._event_inbox:
@@ -1012,13 +1026,16 @@ class Scheduler:
     def schedule_one(self) -> bool:
         """One turn of the loop: the ledger's `cycle`, whose own self time
         is what no stage below it has a name for."""
-        with self.stages.stage("cycle"):
+        stages = self.stages
+        # `pauses`: the collections charged to the table so far (a reader of
+        # a trace learns from it that `gc.pause` is booked at all)
+        with stages.stage("cycle", pauses=stages.counts["gc.pause"]):
             if self._cycle():
                 self._worked = True
                 return True
             if self._worked:
                 self._worked = False
-                self.collector.idle(self.stages)
+                self.collector.idle(stages)
             return False
 
     def _cycle(self) -> bool:

@@ -13,17 +13,20 @@ surfaces: an ``X-Trace-Context`` header on the binding subresource, a
 
 Design constraints (this rides paths benchmarked at >10k pods/s):
 
-- **Deterministic head sampling.** A pod's trace id is a keyed hash of its
-  uid, and the 1-in-N sampling decision is a pure function of that id — so
-  every process (N schedulers + the apiserver) independently agrees which
-  pods are sampled with NO coordination, and the wire context only needs
-  to carry the force-sample override (conflict/requeue/fallback/adoption
-  paths record at 100%).
+- **Deterministic head sampling.** The 1-in-N sampling verdict is a pure
+  function of the pod's uid (``sampled_uid``: a keyed CRC-32, no digest),
+  and a sampled pod's trace id is a keyed hash of the same uid — so every
+  process (N schedulers + the apiserver) independently agrees which pods
+  are sampled and under which id with NO coordination, and the wire
+  context only needs to carry the force-sample override
+  (conflict/requeue/fallback/adoption paths record at 100%).
 - **Lock-free recording.** Completed spans append to a per-process ring
   buffer (``collections.deque(maxlen=…)`` — append is GIL-atomic), so the
   reflector thread, the dispatcher worker, apiserver handler threads, and
-  the scheduling loop all record without a lock. Unsampled pods pay one
-  memoized dict lookup.
+  the scheduling loop all record without a lock. An unsampled pod pays the
+  verdict and nothing else: one ``str.encode``, one CRC-32 and a modulo
+  wherever ``context_for`` is asked about it (about 0.2 us), no digest, no
+  ``SpanContext``, no row, and no memo that a wave could overflow.
 - **Record-complete spans.** Almost every span is recorded retroactively
   with a known duration (``record``); live spans exist only as ``with``
   blocks (``span``) or the explicit ``start_span``/``end`` pair that the
@@ -60,6 +63,7 @@ import os
 import sys
 import threading
 import time
+import zlib
 from collections import deque
 from typing import Dict, Iterable, List, Optional, Sequence
 
@@ -84,6 +88,7 @@ STAGES = (
     "bound.fanout",      # BOUND event fanout to watch streams
     "bound.observe",     # a watcher process decoded the BOUND event
     "pod.e2e",           # admission → bound (feeds the e2e histogram)
+    "inbox.wait",        # oldest parked watch event → the drain that replays it
     # loop stages (StageLedger): the loop's own time, a tree under `cycle`
     "cycle",             # one turn of schedule_one; self time = still unnamed
     "queue.pop",         # popping + signing a batch off the active queue
@@ -96,6 +101,7 @@ STAGES = (
     "plan.adopt",        # session end: snapshot refresh, mirror adopts the carry
     "loop.idle",         # the binary's idle sleep and lease ticks
     "gc.settle",         # the collector policy's deliberate collect-and-freeze
+    "gc.pause",          # a collection on the loop's own thread, inside a stage
 )
 # The ledger's fixed table: per-pod names that are also loop boundaries
 # (plan.build … bind.post) keep their name, so a stage reads the same in a
@@ -104,7 +110,7 @@ LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
                "hint.validate", "plan.build", "plan.ipa", "plan.ipa_score",
                "plan.patch", "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
-               "loop.idle", "gc.settle")
+               "loop.idle", "gc.settle", "gc.pause")
 # A bound pod's minimal complete chain. Device stages are optional (host-
 # path pods legitimately skip them); observe spans prove the fanout landed.
 CORE_CHAIN = ("queue.wait", "host.commit", "bind.post", "api.bind",
@@ -128,9 +134,22 @@ _ENABLE_ENV = "TPU_SCHED_TRACE"
 DEFAULT_SAMPLE_N = 16
 
 
+# The verdict's key (CRC-32's start value): part of the cross-process
+# contract, like the digest of `trace_id_for`.
+_SAMPLE_KEY = 0x5BD1E995
+
+
+def sampled_uid(uid: str, sample_n: int) -> bool:
+    """The head-sampling verdict: a pure function of the uid that every
+    process computes alike (CRC-32 is not Python's per-process ``hash``),
+    at a sixth of a digest's cost. The trace id is not needed for it."""
+    return zlib.crc32(uid.encode(), _SAMPLE_KEY) % sample_n == 0
+
+
 class SpanContext:
-    """Trace identity + the sampling verdict. ``trace_id`` is 16 hex chars,
-    derived from the pod uid, identical in every process."""
+    """Trace identity + the sampling verdict of a pod that records.
+    ``trace_id`` is 16 hex chars, derived from the pod uid, identical in
+    every process."""
 
     __slots__ = ("trace_id", "sampled")
 
@@ -194,7 +213,10 @@ class _ScopedSpan:
 
 
 class SpanRecorder:
-    """The per-process tracer: head-sampled, ring-buffered, lock-free."""
+    """The per-process tracer: head-sampled, ring-buffered, lock-free.
+    ``context_for`` answers None for a pod outside the sample, at the cost
+    of ``sampled_uid`` alone; only the sampled 1-in-N (and the forced
+    forensic paths) pay ``trace_id_for``'s digest and a ``SpanContext``."""
 
     def __init__(self, capacity: int = 8192, sample_n: Optional[int] = None,
                  proc: str = "", enabled: Optional[bool] = None):
@@ -212,24 +234,19 @@ class SpanRecorder:
         self.ring: "deque" = deque(maxlen=capacity)
         self.recorded = 0  # total spans accepted (ring may have evicted)
         self._ids = itertools.count(1)
-        # uid → base SpanContext memo (bounded; cleared wholesale on cap).
-        self._ctx_memo: Dict[str, SpanContext] = {}
-        self._ctx_cap = 8192
         self._proc_ctx: Optional[SpanContext] = None
 
     # -- contexts ----------------------------------------------------------
 
-    def context_for(self, uid: str, force: bool = False) -> SpanContext:
-        ctx = self._ctx_memo.get(uid)
-        if ctx is None:
-            tid = trace_id_for(uid)
-            ctx = SpanContext(tid, int(tid, 16) % self.sample_n == 0)
-            if len(self._ctx_memo) >= self._ctx_cap:
-                self._ctx_memo.clear()
-            self._ctx_memo[uid] = ctx
-        if force and not ctx.sampled:
-            return SpanContext(ctx.trace_id, True)
-        return ctx
+    def context_for(self, uid: str,
+                    force: bool = False) -> Optional[SpanContext]:
+        """The pod's recording context: None unless ``sampled_uid`` says
+        the pod is in the sample or ``force`` overrides it. ``wants`` and
+        ``record`` take the None."""
+        # sampled_uid, written out: this is asked once a pod and more
+        if force or zlib.crc32(uid.encode(), _SAMPLE_KEY) % self.sample_n == 0:
+            return SpanContext(trace_id_for(uid), True)
+        return None
 
     def proc_ctx(self) -> SpanContext:
         """Force-sampled process-scoped context for non-pod forensic spans
@@ -386,6 +403,7 @@ class _Stage:
         self._events0 = ledger._compile_events[0]
         if not ledger._stack:
             self._parts = {}
+            ledger._thread = threading.get_ident()
         ledger._stack.append(self)
         self._t0 = time.perf_counter()
         return self
@@ -433,6 +451,7 @@ class StageLedger:
         # what a loop that does not end is asked for (``report``).
         self.recent: "deque" = deque(maxlen=32)
         self._stack: List[_Stage] = []
+        self._thread = 0  # the thread that opened the root stage now open
         self._annotation = _trace_annotation() if tracer.enabled else None
         self._ann_names = {n: "sched." + n for n in LOOP_STAGES}
         self._compile_events = COMPILE_EVENTS
@@ -450,6 +469,19 @@ class StageLedger:
         self._account(name, seconds, seconds)
         if seconds > (SLOW_STAGE_S if per_pod else SLOW_BATCH_STAGE_S):
             self._slow(name, (), attrs, seconds, seconds, 0)
+
+    def collecting(self, generation: int) -> Optional[_Stage]:
+        """A collection begins on the calling thread (the collector
+        policy's clock asks, core/collector.py). If that is this loop's
+        thread with a stage open, the pause is the stage `gc.pause`, a child
+        of whatever is open: entered here, left by the clock when the
+        collection ends. A collection any other thread runs, or one between
+        two turns of the loop, is none of this table's."""
+        if not self._stack or self._thread != threading.get_ident():
+            return None
+        pause = self.stage("gc.pause", generation=generation)
+        pause.__enter__()
+        return pause
 
     def _account(self, name: str, self_s: float, duration: float) -> bool:
         """One closed stage into the table, its duration onto the open
@@ -495,10 +527,11 @@ class StageLedger:
         tracer = self.tracer
         if tracer.enabled:
             ctx = ctxs[0] if ctxs else tracer.proc_ctx()
-            tracer.record("trace.slow_stage", ctx, duration, stage=name,
-                          self_ms=round(self_s * 1e3, 3),
-                          inflight=self.inflight, compiles=compiles,
-                          **{k: str(v) for k, v in attrs.items()})
+            # the stage's own attrs first: a dispatch says `inflight` too
+            said = {k: str(v) for k, v in attrs.items()}
+            said.update(stage=name, self_ms=round(self_s * 1e3, 3),
+                        inflight=self.inflight, compiles=compiles)
+            tracer.record("trace.slow_stage", ctx, duration, **said)
         request_dump("slow_stage")
 
     def publish(self) -> None:
@@ -531,20 +564,31 @@ class GcClock:
     """Seconds the interpreter's cyclic collector ran, by generation
     (``gc.callbacks``). A collection stops every thread of the process, so
     it is on ``/metrics``: the scheduler's through its collector policy
-    (core/collector.py), the apiserver's through its binary's main."""
+    (core/collector.py), the apiserver's through its binary's main.
+    ``ledgers`` are the loops to tell (``StageLedger.collecting``): the one
+    whose thread collects, inside a stage, books the pause as `gc.pause`."""
 
-    def __init__(self):
+    def __init__(self, ledgers: Iterable["StageLedger"] = ()):
         self.seconds = [0.0, 0.0, 0.0]
         self.collections = [0, 0, 0]
+        self.ledgers = ledgers
         self._t0 = 0.0
+        self._pause: Optional[_Stage] = None
 
     def _callback(self, phase: str, info: dict) -> None:
         if phase == "start":
+            for ledger in self.ledgers:
+                self._pause = ledger.collecting(info["generation"])
+                if self._pause is not None:
+                    break
             self._t0 = time.perf_counter()
         else:
             g = info["generation"]
             self.seconds[g] += time.perf_counter() - self._t0
             self.collections[g] += 1
+            if self._pause is not None:
+                self._pause.__exit__(None, None, None)
+                self._pause = None
 
     def install(self) -> "GcClock":
         gc.callbacks.append(self._callback)

@@ -140,6 +140,7 @@ class TPUScheduler(Scheduler):
         self._holdover: Optional[QueuedPodInfo] = None
         # metrics
         self.device_scheduled = 0
+        self.dispatch_seq = 0  # the last live dispatch's ordinal (`seq`)
         self.shard_map_dispatches = 0
         self.host_path_pods = 0
         # Plan acquisition attribution (scheduler_plan_rebuild_total):
@@ -456,7 +457,7 @@ class TPUScheduler(Scheduler):
         sd = _SessionDelta(state, carry, self.cluster_event_seq)
         del state, carry
         start_unwinds = self.state_unwinds
-        inflight: List[Tuple[List[QueuedPodGroupInfo], object]] = []
+        inflight: List[Tuple[List[QueuedPodGroupInfo], object, int]] = []
         ok_rows: List[int] = []
         dirty_rows: List[int] = []
         invalidated = False
@@ -500,24 +501,25 @@ class TPUScheduler(Scheduler):
                         break
                     pending.append(pack)
                 members = [m for g in pack for m in self._sorted_members(g)]
-                attrs = plan.dispatch_attrs(len(members))
+                attrs = self._dispatch_attrs(plan, len(members),
+                                             len(inflight))
                 with stages.stage("device.dispatch", **attrs):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(members), sd.carry)
                     results.copy_to_host_async()
                 self._count_dispatch(attrs)
-                inflight.append((pack, results))
+                inflight.append((pack, results, attrs["seq"]))
                 stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),
                                             "device_dispatch")
                 pack = None
             if not inflight:
                 break
-            groups, results = inflight.pop(0)
+            groups, results, seq = inflight.pop(0)
             stages.inflight = len(inflight)
             self.metrics.goroutines.set(float(len(inflight)),
                                         "device_dispatch")
-            with stages.stage("device.wait"):
+            with stages.stage("device.wait", seq=seq):
                 res = np.asarray(results)
             if (invalidated or self.state_unwinds != start_unwinds
                     or not self._note_session_events(sd, plan, node_names,
@@ -1318,6 +1320,19 @@ class TPUScheduler(Scheduler):
             return fn(state, plan.features, np.int32(n_active), carry)
         return self._gspmd_dispatch(state, plan, n_active, carry)
 
+    def _dispatch_attrs(self, plan, n_active: int, depth: int) -> dict:
+        """What the `device.dispatch` stage of this scheduler's next
+        dispatch opens with: the plan's own account of it
+        (BatchPlan.dispatch_attrs), its ordinal in this scheduler's life
+        (`seq`: the session keeps it beside the batch, and the
+        `device.wait` that retires the batch opens with the same), and the
+        pipeline depth it found (`inflight`)."""
+        self.dispatch_seq += 1
+        attrs = plan.dispatch_attrs(n_active)
+        attrs["seq"] = self.dispatch_seq
+        attrs["inflight"] = depth
+        return attrs
+
     def _count_dispatch(self, attrs: dict) -> None:
         """One live dispatch into the registry, from what its stage said of
         it (BatchPlan.dispatch_attrs): which engine placed it and, for the
@@ -1894,7 +1909,7 @@ class TPUScheduler(Scheduler):
         del state, carry
         start_unwinds = self.state_unwinds
         start_nom = self.queue.nominator.version
-        inflight: List[Tuple[List[QueuedPodInfo], object]] = []
+        inflight: List[Tuple[List[QueuedPodInfo], object, int]] = []
         ok_rows: List[int] = []
         dirty_rows: List[int] = []
         invalidated = False
@@ -1934,7 +1949,7 @@ class TPUScheduler(Scheduler):
                     if batch is None:
                         break
                     pending.append(batch)
-                attrs = plan.dispatch_attrs(len(batch))
+                attrs = self._dispatch_attrs(plan, len(batch), len(inflight))
                 with stages.stage("device.dispatch", batch.sampled, **attrs):
                     results, sd.carry = self._dispatch(
                         sd.state, plan, len(batch), sd.carry)
@@ -1943,7 +1958,7 @@ class TPUScheduler(Scheduler):
                     # host commit loop of the previous batch.
                     results.copy_to_host_async()
                 self._count_dispatch(attrs)
-                inflight.append((batch, results))
+                inflight.append((batch, results, attrs["seq"]))
                 stages.inflight = len(inflight)
                 self.metrics.goroutines.set(float(len(inflight)),
                                             "device_dispatch")
@@ -1952,12 +1967,12 @@ class TPUScheduler(Scheduler):
                 break
             # Retire the oldest batch: block on its results (the device is
             # already computing the NEXT batch), then run the host tail.
-            b, results = inflight.pop(0)
+            b, results, seq = inflight.pop(0)
             stages.inflight = len(inflight)
             self.metrics.goroutines.set(float(len(inflight)),
                                         "device_dispatch")
             with stages.stage("device.wait", b.sampled, "DeviceWait",
-                              batch=len(b)):
+                              batch=len(b), seq=seq):
                 res = np.asarray(results)  # one device→host fetch
             if not invalidated:
                 run = self._batch_tail_run(b, res, fw)

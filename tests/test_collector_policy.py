@@ -33,6 +33,7 @@ def see(name):
 
 gc.unfreeze()                 # whatever the interpreter froze at start-up
 see("before")
+obs["callbacks"] = {"before": len(gc.callbacks)}
 cs = FakeClientset()
 a = Scheduler(clientset=cs)
 see("constructed")
@@ -62,6 +63,10 @@ obs["attrs"] = [a.gc_freezes, a.gc_frozen_objects]
 obs["metrics"] = [l for l in a.expose_metrics().splitlines()
                   if l.startswith("scheduler_gc_")]
 obs["settle_count"] = a.stages.counts["gc.settle"]
+# the settle's own collection ran on the loop's thread inside that stage
+obs["pause_in_settle"] = [a.stages.counts["gc.pause"],
+                          a.stages.seconds["gc.pause"],
+                          collector.POLICY.clock.seconds[2]]
 obs["settle_in_report"] = "gc.settle" in a.stages.report()
 obs["settle_published"] = [
     l for l in a.expose_metrics().splitlines()
@@ -86,12 +91,16 @@ for i in range(4):
 obs["shared"] = b.collector is a.collector is collector.POLICY
 wave(b, cs2, "b", 20)
 see("second_scheduler_idle")
+obs["callbacks"]["two_alive"] = len(gc.callbacks)
+obs["ledgers_two_alive"] = sorted(
+    l is a.stages or l is b.stages for l in collector.POLICY._ledgers)
 a.close()
 a.close()                     # twice is once
 see("first_closed")
 del b, cs2                    # dropped unclosed: given back when collected
 gc.collect()
 see("last_gone")
+obs["callbacks"]["both_gone"] = len(gc.callbacks)
 obs["clock_listening"] = collector.POLICY.clock._callback in gc.callbacks
 
 # engaged again by a later scheduler: it freezes at its own first idle
@@ -172,14 +181,34 @@ def test_the_settle_is_a_stage_of_the_loop(probe):
         'scheduler_loop_stages_total{stage="gc.settle"} 1.0']
 
 
+def test_the_settles_collection_is_the_loops_pause(probe):
+    """`gc.collect()` inside `gc.settle` runs on the loop's thread with that
+    stage open: the table books it under `gc.pause`, for at least the seconds
+    the policy's clock counted of it (the stage encloses the clock)."""
+    count, seconds, clock_full_s = probe["pause_in_settle"]
+    assert count >= 1 and seconds > 0
+    assert seconds >= clock_full_s * 0.5
+
+
+def test_one_callback_for_all_schedulers_and_none_after_the_last(probe):
+    """The pauses reach every scheduler's table through the ONE
+    `gc.callbacks` entry the policy's clock holds, however many schedulers
+    are alive; the last holder takes it away."""
+    seen = probe["callbacks"]
+    assert seen["two_alive"] == seen["before"] + 1
+    assert seen["both_gone"] == seen["before"]
+    assert probe["ledgers_two_alive"] == [True, True]
+
+
 def test_twenty_waves_leak_nothing_through_the_frozen_set(probe):
     """The first wave's pods are bound, and so frozen with the heap, when the
     loop first goes idle; they and every later wave's are reclaimed by their
     reference counts once deleted. Tracked plus frozen objects after each
     create-bind-delete wave of 300 pods stay within 20,000 of the first
     wave's: they grow by 11,000-13,000 with or without the policy (the span
-    ring, the event logs and the context memo filling to their caps), where
-    one leaked wave a time would be 300 pods x 35 objects x 20."""
+    ring and the event logs filling to their caps), where
+    one leaked wave a time would be 300 pods x 35 objects x 20 (the span
+    recorder keeps no memo of contexts any more: a wave cannot overflow it)."""
     assert probe["pods_alive"] == 0
     tracked = probe["tracked"]
     assert len(tracked) == 20

@@ -316,6 +316,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_bind_conflict_total",
                      "scheduler_bind_requests_total",
                      "scheduler_bind_request_pods_total",
+                     "scheduler_inbox_oldest_wait_seconds",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",

@@ -292,7 +292,8 @@ def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
                   if name == "sched.device.dispatch"]
     assert len(dispatches) == sched.device_batches >= 1
     assert all(d == {"batch": 3, "engine": "scan_normalised",
-                     "batch_pad": 1024, "steps": 3}
-               for d in dispatches)
+                     "batch_pad": 1024, "steps": 3, "seq": seq,
+                     "inflight": 0}
+               for seq, d in enumerate(dispatches, 1))
     # what is filled in while the stage is open (a plan's kind) is not there
     assert ("sched.plan.build", {"batch": 3}) in opened

@@ -202,7 +202,8 @@ def test_a_dispatch_of_5_pods_on_a_1024_wide_plan_runs_5_steps(site, monkeypatch
         sched.metrics.expose())
     assert [stats for name, stats in opened
             if name == "sched.device.dispatch"] == [
-        {"batch": 5, "engine": "scan_carried", "batch_pad": 1024, "steps": 5}]
+        {"batch": 5, "engine": "scan_carried", "batch_pad": 1024, "steps": 5,
+         "seq": 1, "inflight": 0}]
 
 
 def test_the_lap_kernel_counts_no_scan_steps():

@@ -20,8 +20,8 @@ import pytest
 
 from kubernetes_tpu.core import FakeClientset, Scheduler, spans
 from kubernetes_tpu.core.spans import (FlightRecorder, SpanRecorder,
-                                       format_ctx, parse_ctx, trace_id_for,
-                                       write_jsonl)
+                                       format_ctx, parse_ctx, sampled_uid,
+                                       trace_id_for, write_jsonl)
 from kubernetes_tpu.testing.wrappers import make_node, make_pod
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -55,27 +55,67 @@ def _pod(name, cpu="200m"):
 class TestSampling:
     def test_sampling_is_deterministic_across_processes(self):
         """Two independent tracers (≈ two processes) must agree on every
-        pod's trace id AND sampling verdict with no coordination — the
-        property the whole cross-process merge stands on."""
+        pod's sampling verdict AND, for the pods that record, on the trace
+        id, with no coordination — the property the whole cross-process
+        merge stands on."""
         a = SpanRecorder(sample_n=16, proc="a")
         b = SpanRecorder(sample_n=16, proc="b")
+        sampled = 0
         for i in range(500):
             uid = f"uid-{i}"
             ca, cb = a.context_for(uid), b.context_for(uid)
-            assert ca.trace_id == cb.trace_id == trace_id_for(uid)
-            assert ca.sampled == cb.sampled
-        sampled = sum(a.context_for(f"uid-{i}").sampled for i in range(500))
+            assert (ca is None) == (cb is None) == (not sampled_uid(uid, 16))
+            if ca is not None:
+                assert ca.sampled and cb.sampled
+                assert ca.trace_id == cb.trace_id == trace_id_for(uid)
+                sampled += 1
         # 1-in-16 head sampling: statistically ~31 of 500
         assert 5 <= sampled <= 100
 
     def test_force_overrides_head_sampling(self):
         t = SpanRecorder(sample_n=1 << 30)  # nothing head-samples
         uid = "conflict-pod"
-        assert not t.context_for(uid).sampled
+        assert t.context_for(uid) is None and not t.wants(t.context_for(uid))
         forced = t.context_for(uid, force=True)
         assert forced.sampled and forced.trace_id == trace_id_for(uid)
-        # the base memo is NOT poisoned by the forced copy
-        assert not t.context_for(uid).sampled
+        # the verdict is NOT poisoned by the forced context
+        assert t.context_for(uid) is None
+
+    def test_an_unsampled_pod_pays_no_digest_and_every_process_agrees(
+            self, monkeypatch):
+        """20,000 distinct uids outside the sample (a wave twice the size of
+        the memo that PR 36 removed): no `blake2b`, no `SpanContext`; two
+        recorders give every uid the same verdict, and the wire form of a
+        context carries it through `format_ctx`/`parse_ctx`."""
+        import hashlib
+        a = SpanRecorder(sample_n=16, proc="a")
+        b = SpanRecorder(sample_n=16, proc="b")
+        uids = [f"w7-{i}" for i in range(22_000)]
+        outside = [u for u in uids if not sampled_uid(u, 16)][:20_000]
+        assert len(outside) == 20_000
+        digests, made = [], []
+        real = hashlib.blake2b
+        monkeypatch.setattr(hashlib, "blake2b", lambda *a_, **kw: (
+            digests.append(1), real(*a_, **kw))[1])
+        init = spans.SpanContext.__init__
+        monkeypatch.setattr(spans.SpanContext, "__init__", lambda self, *a_: (
+            made.append(1), init(self, *a_))[1])
+        for u in outside:
+            assert a.context_for(u) is None and b.context_for(u) is None
+        assert digests == [] and made == []
+        # the sampled sixteenth pays the digest, once a context, and the
+        # verdict survives the wire
+        inside = [u for u in uids if sampled_uid(u, 16)]
+        assert 0.04 < len(inside) / len(uids) < 0.09
+        for u in inside[:200]:
+            ca, cb = a.context_for(u), b.context_for(u)
+            assert ca.trace_id == cb.trace_id and ca.sampled and cb.sampled
+            back = parse_ctx(format_ctx(ca))
+            assert back.trace_id == ca.trace_id and back.sampled
+        assert len(digests) == 400 and len(made) == 400 + 200  # + parse_ctx
+        # an unsampled verdict on the wire (a forced context's opposite)
+        off = parse_ctx(f"{trace_id_for(outside[0])}-00")
+        assert not off.sampled and not a.wants(off)
 
     def test_wire_context_roundtrip(self):
         ctx = SpanRecorder(sample_n=1).context_for("u1")
@@ -296,6 +336,180 @@ class TestStageLedger:
             assert s.metrics.pod_stage_duration.count("queue.wait") == 16
         finally:
             spans.set_default_tracer(prev)
+
+    def test_a_collection_on_the_loops_thread_is_the_stage_gc_pause(self):
+        """A collection that the loop's own thread runs inside an open stage
+        leaves that stage's self time and lands in `gc.pause` (a child of
+        whatever is open, `generation` its stat); the nest still sums to the
+        root's duration."""
+        import gc
+        opened = []
+
+        class Annotation:
+            def __init__(self, name, **stats):
+                opened.append((name, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        ledger = spans.StageLedger(SpanRecorder(sample_n=1, proc="t"))
+        ledger._annotation = Annotation
+        clock = spans.GcClock([ledger]).install()
+        gc.disable()            # only the collections this test asks for
+        try:
+            gc.collect()        # no stage open: nobody's pause
+            assert ledger.counts["gc.pause"] == 0
+            with ledger.stage("cycle"):
+                with ledger.stage("plan.build") as build:
+                    t0 = time.perf_counter()
+                    gc.collect()
+                    inside = time.perf_counter() - t0
+                with ledger.stage("host.commit"):
+                    pass
+        finally:
+            gc.enable()
+            clock.close()
+        assert ledger.counts["gc.pause"] == 1
+        pause = ledger.seconds["gc.pause"]
+        assert 0 < pause <= inside
+        # the clock counted two collections, the table the one it was in
+        assert clock.collections[2] == 2
+        assert ("sched.gc.pause", {"generation": 2}) in opened
+        # plan.build's self time is what its duration leaves beside the pause
+        assert build._child_s == pytest.approx(pause, abs=1e-9)
+        name, _ts, duration, self_s, parts = ledger.recent[-1]
+        assert name == "cycle" and parts["gc.pause"] == pause
+        assert sum(ledger.seconds.values()) == pytest.approx(duration,
+                                                             abs=1e-9)
+        assert self_s + sum(parts.values()) == pytest.approx(duration,
+                                                             abs=1e-9)
+        assert not ledger._stack
+
+    def test_a_collection_on_another_thread_changes_no_row(self):
+        """The client thread of a wave or the reflector collects while the
+        loop has a stage open: the policy's clock counts the pause, the
+        table is not charged (a loop parked outside the interpreter was not
+        delayed by it)."""
+        import gc
+        import threading
+        ledger = spans.StageLedger(SpanRecorder(sample_n=1, proc="t"))
+        clock = spans.GcClock([ledger]).install()
+        gc.disable()
+        try:
+            with ledger.stage("cycle"):
+                with ledger.stage("device.wait"):
+                    other = threading.Thread(target=gc.collect)
+                    other.start()
+                    other.join()
+        finally:
+            gc.enable()
+            clock.close()
+        assert clock.collections[2] == 1 and clock.seconds[2] > 0
+        assert ledger.counts["gc.pause"] == 0
+        assert ledger.seconds["gc.pause"] == 0.0
+        assert ledger.counts["device.wait"] == ledger.counts["cycle"] == 1
+        assert set(ledger.recent[-1][4]) == {"device.wait"}
+
+    def test_a_batch_keeps_its_seq_from_dispatch_to_wait(self, monkeypatch):
+        """Three batches of one session, the second refused a bind (the
+        session invalidates, the third retires to the host path): every
+        `device.dispatch` opens with the scheduler's next `seq` and the
+        depth of the pipeline it found, and the `device.wait` that retires
+        a batch opens with that batch's `seq`."""
+        from contextlib import nullcontext
+        from kubernetes_tpu.models import TPUScheduler
+
+        class Refusing(FakeClientset):
+            def bind(self, pod, node_name):
+                if pod.name == "p20" and not getattr(self, "refused", False):
+                    self.refused = True
+                    raise KeyError("refused once")
+                return super().bind(pod, node_name)
+
+        opened = []
+        cs = Refusing()
+        s = TPUScheduler(clientset=cs, max_batch=16)
+        monkeypatch.setattr(
+            s.stages, "_annotation",
+            lambda name, **stats: opened.append((name, stats))
+            or nullcontext())
+        for i in range(8):
+            cs.create_node(_node(f"n{i}", cpu="32"))
+        for i in range(48):
+            cs.create_pod(_pod(f"p{i}", cpu="100m"))
+        assert s.schedule_one()      # one cycle, one session
+        assert s.device_batches == s.dispatch_seq == 3
+        assert s.metrics.batch_cache_flushed.value("session_invalidated") == 1
+        dispatches = [st for name, st in opened
+                      if name == "sched.device.dispatch"]
+        waits = [st for name, st in opened if name == "sched.device.wait"]
+        assert [d["seq"] for d in dispatches] == [1, 2, 3]
+        assert [d["inflight"] for d in dispatches] == [0, 1, 1]
+        assert [(w["seq"], w["batch"]) for w in waits] == [
+            (d["seq"], d["batch"]) for d in dispatches]
+        # in order on the loop: a wait follows its own dispatch, and the
+        # pipeline put dispatch 2 ahead of wait 1
+        order = [(name.rsplit(".", 1)[1], st["seq"]) for name, st in opened
+                 if name in ("sched.device.dispatch", "sched.device.wait")]
+        assert order == [("dispatch", 1), ("dispatch", 2), ("wait", 1),
+                         ("dispatch", 3), ("wait", 2), ("wait", 3)]
+        # the loop's turn says how many pauses the table holds
+        cycles = [st for name, st in opened if name == "sched.cycle"]
+        assert cycles and all(set(c) == {"pauses"} for c in cycles)
+        s.run_until_idle()
+        assert s.scheduled == 48
+        # the next session goes on counting
+        for i in range(4):
+            cs.create_pod(_pod(f"q{i}", cpu="300m"))
+        s.run_until_idle()
+        later = [st["seq"] for name, st in opened
+                 if name == "sched.device.dispatch"][3:]
+        assert later == list(range(4, 4 + len(later)))
+
+    def test_a_parked_events_wait_is_observed_once_a_drain(self):
+        """Served: a pod POSTed to the apiserver reaches the scheduler on the
+        reflector's thread while the loop sleeps; the drain that replays it
+        observes how long the oldest parked event waited, once."""
+        from kubernetes_tpu.core.apiserver import (APIServer, HTTPClientset,
+                                                   pod_to_wire)
+        import json as _json
+        from urllib import request as urlrequest
+
+        api = APIServer()
+        url = f"http://127.0.0.1:{api.serve(0)}"
+        cs = HTTPClientset(url)
+        s = Scheduler(clientset=cs)
+        hist = s.metrics.inbox_oldest_wait
+        try:
+            assert s.drain_event_inbox() == 0 and hist.count() == 0
+            for name in ("late-0", "late-1", "late-2"):
+                req = urlrequest.Request(
+                    url + "/api/v1/pods", method="POST",
+                    data=_json.dumps(pod_to_wire(_pod(name))).encode(),
+                    headers={"Content-Type": "application/json"})
+                urlrequest.urlopen(req, timeout=30).read()
+            end = time.monotonic() + 30
+            while len(s._event_inbox) < 3:      # the loop "sleeps"
+                assert time.monotonic() < end, "the watch never delivered"
+                time.sleep(0.002)
+            seen = time.perf_counter()
+            time.sleep(0.05)
+            remainder = time.perf_counter() - seen
+            assert s.drain_event_inbox() == 3
+            # three events parked, one observation: the oldest one's wait
+            assert hist.count() == 1
+            assert remainder <= hist.sum() < 30
+            assert s.drain_event_inbox() == 0 and hist.count() == 1
+            # on the loop's own thread nothing is parked, nothing observed
+            text = s.expose_metrics()
+            assert "scheduler_inbox_oldest_wait_seconds_count 1" in text
+        finally:
+            s.shutdown()
+            cs.close()
+            api.shutdown()
 
     def test_gc_clock_counts_collections_by_generation(self):
         import gc

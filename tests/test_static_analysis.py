@@ -682,6 +682,47 @@ class TestSpanDisciplineFixtures:
         assert _rules(fs) == ["span-in-jit"]
         assert len(fs) == 2
 
+    def test_the_collectors_callback_and_the_inbox_stamp_stay_on_the_host(self):
+        """What PR 36 stamps where the work happens (the collector clock's
+        callback booking `gc.pause`, the park's stamp and the drain's
+        observation of `scheduler_inbox_oldest_wait_seconds`, the ledger's
+        `collecting`) is reachable from no jitted entry, and the rule would
+        say so if it were: the same observation one helper below a jitted
+        function is a finding."""
+        import ast
+        import os
+
+        import kubernetes_tpu
+        from kubernetes_tpu.analysis.jit_purity import jit_reachable_functions
+
+        pkg = os.path.dirname(kubernetes_tpu.__file__)
+        stamped = {"core/spans.py": {"_callback", "collecting"},
+                   "core/collector.py": {"acquire"},
+                   "core/scheduler.py": {"dispatch", "drain_event_inbox",
+                                         "_threaded", "schedule_one"},
+                   "models/tpu_scheduler.py": {"_dispatch_attrs"}}
+        checker = checker_by_id("span-discipline")
+        for rel, names in stamped.items():
+            with open(os.path.join(pkg, rel)) as f:
+                src = f.read()
+            tree = ast.parse(src)
+            defined = {n.name for n in ast.walk(tree)
+                       if isinstance(n, ast.FunctionDef)}
+            assert names <= defined, (rel, names - defined)
+            reachable = {fn.name for fn in jit_reachable_functions(tree)}
+            assert not names & reachable, (rel, names & reachable)
+            assert check_source(checker, src) == [], rel
+        bad = textwrap.dedent("""
+            import jax
+            @jax.jit
+            def kernel(x, self):
+                return _drain(x, self)
+            def _drain(x, self):
+                self.metrics.inbox_oldest_wait.observe(0.1)
+                return x
+        """)
+        assert _rules(check_source(checker, bad)) == ["span-in-jit"]
+
     def test_host_side_span_and_metric_calls_are_clean(self):
         good = textwrap.dedent("""
             import jax
