@@ -420,6 +420,15 @@ class SchedulerMetrics:
             "them (models/tpu_scheduler.py _commit_batch): 'batch' = in "
             "passes over the batch (one assume, one bulk bind, one settle), "
             "'single' = one _commit call a pod.", ("tail",)))
+        self.queue_popped_pods = r(Counter(
+            "scheduler_queue_popped_pods_total",
+            "Pods the device path's pops accepted into a device batch "
+            "(models/tpu_scheduler.py _refill), by how the verdict was "
+            "reached: 'run' = on the session's template (the same shared "
+            "signature holder as its head, and nothing per pod that could "
+            "change the answer), 'single' = through the full "
+            "_session_compatible check, the head of each session and the "
+            "members of a gang pack included.", ("how",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

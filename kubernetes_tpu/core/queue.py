@@ -796,9 +796,17 @@ class PriorityQueue:
             qpi = self.backoff_q.pop()
         if qpi is None:
             return None
+        self._hand_out(qpi)
+        return qpi
+
+    def _hand_out(self, qpi, now: Optional[float] = None) -> None:
+        """What every entity that leaves the queue is stamped with: one more
+        attempt, the instant of its first, the admission instant on the pod,
+        its place in the event log. ``now`` is a clock reading the caller
+        already holds (a run's)."""
         qpi.attempts += 1
         if qpi.initial_attempt_timestamp is None:
-            qpi.initial_attempt_timestamp = self.now()
+            qpi.initial_attempt_timestamp = self.now() if now is None else now
         eq = getattr(qpi, "enqueued_at", None)
         pi = getattr(qpi, "pod_info", None)
         if eq is not None and pi is not None:
@@ -808,7 +816,41 @@ class PriorityQueue:
             # histogram honest across conflict retries.
             pi.pod.__dict__["_enqueued_at"] = eq
         self._in_flight[qpi.uid] = len(self._event_log)
-        return qpi
+
+    def pop_run(self, limit: int, accept: Callable[[object], Optional[bool]]
+                ) -> Tuple[List, Optional[object], float]:
+        """A run of the queue's own pop order: the entities that ``limit``
+        calls of ``pop()`` would hand out, in that order and stamped as
+        ``pop()`` stamps them, on ONE reading of the clock. The order is
+        that of the single pops while the clock stands still, which is how
+        a run sees it: the backoffQ is flushed once, against that reading,
+        so an entity whose backoff runs out while the run is popped is
+        promoted by the next flush (the next run, the next ``pop()``) and
+        not in the middle of this one. ``accept(entity)`` decides each as
+        it leaves: True and it joins the run; None and it is dropped (the
+        caller has settled it, ``done()`` included) and does not count
+        against ``limit``; False ends the run, and that entity, popped,
+        stamped and in flight like the others, is returned apart. Returns
+        (run, the refused entity or None, the clock reading)."""
+        now = self.now()
+        self.flush_backoff_completed(now)
+        active_pop = self.active_q.pop
+        backoff_pop = self.backoff_q.pop if self.pop_from_backoff_q else None
+        hand_out = self._hand_out
+        run: List = []
+        while len(run) < limit:
+            qpi = active_pop()
+            if qpi is None:
+                qpi = backoff_pop() if backoff_pop is not None else None
+                if qpi is None:
+                    break
+            hand_out(qpi, now)
+            ok = accept(qpi)
+            if ok:
+                run.append(qpi)
+            elif ok is not None:
+                return run, qpi, now
+        return run, None, now
 
     def done(self, uid: str) -> None:
         """Done (scheduling_queue.go:1326) — scheduling attempt finished."""
@@ -987,11 +1029,13 @@ class PriorityQueue:
         if self._in_flight:
             self._event_log.append(ev)
 
-    def flush_backoff_completed(self) -> None:
-        """backoffQ flush loop (scheduling_queue.go Run :503)."""
+    def flush_backoff_completed(self, now: Optional[float] = None) -> None:
+        """backoffQ flush loop (scheduling_queue.go Run :503), against the
+        clock or against the reading the caller holds."""
         while True:
             qpi = self.backoff_q.peek()
-            if qpi is None or self.backoff_expiry(qpi) > self.now():
+            if qpi is None or self.backoff_expiry(qpi) > (
+                    self.now() if now is None else now):
                 return
             self.backoff_q.pop()
             self.active_q.push(qpi)

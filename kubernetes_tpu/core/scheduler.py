@@ -276,6 +276,13 @@ class Handle:
         return self._scheduler.clientset.device_classes
 
 
+def queue_wait(qpi, now: float) -> float:
+    """Seconds from the entity's queue admission to ``now``, its pop (0
+    without an admission instant, and never negative)."""
+    start = getattr(qpi, "enqueued_at", None)
+    return now - start if start is not None and now > start else 0.0
+
+
 class Scheduler:
     # Queue wait past this horizon force-samples the pod's trace and emits
     # a queue.starved event (overload plane, docs/RESILIENCE.md).
@@ -1895,13 +1902,16 @@ class Scheduler:
         if getattr(qpi, "_qwait_recorded", False):
             return
         qpi._qwait_recorded = True
-        start = getattr(qpi, "enqueued_at", None)
-        wait = max(0.0, self.now() - start) if start is not None else 0.0
+        wait = queue_wait(qpi, self.now())
         self.metrics.pod_stage_duration.observe(wait, "queue.wait")
+        if self.tracer.wants(ctx):
+            self.trace_queue_wait(qpi, ctx, wait, time.time())
+
+    def trace_queue_wait(self, qpi, ctx, wait: float, wall_pop: float) -> None:
+        """A sampled pod's two rows of its pop: the retroactive
+        queue.admission event and the queue.wait span that ends at
+        ``wall_pop``."""
         tr = self.tracer
-        if not tr.wants(ctx):
-            return
-        wall_pop = time.time()
         tr.record("queue.admission", ctx, start=wall_pop - wait)
         tr.record("queue.wait", ctx, wait, start=wall_pop - wait,
                   attempts=qpi.attempts)

@@ -396,6 +396,14 @@ class _Stage:
         self._child_s = 0.0
         self._parts: Optional[dict] = None  # a root's: self time by stage
 
+    def say(self, **stats) -> None:
+        """What is known of the stage only as it ends (the pods a pop
+        accepted): onto ``attrs``, and onto the open annotation as further
+        stats of the event."""
+        self.attrs.update(stats)
+        if self._ann is not None:
+            self._ann.set_metadata(**stats)
+
     def __enter__(self) -> "_Stage":
         ledger = self._ledger
         if self._ann is not None:

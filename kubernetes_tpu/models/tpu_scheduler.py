@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time as _time
+from contextlib import contextmanager
 from functools import partial
 from typing import List, Optional, Tuple
 
@@ -40,7 +41,8 @@ from ..core.framework import OK as _OK_STATUS
 from ..core.framework import WAIT, Framework
 from ..core.queue import (QueuedCompositeGroupInfo, QueuedPodGroupInfo,
                           QueuedPodInfo)
-from ..core.scheduler import QueuedBind, Scheduler, ScheduleResult
+from ..core.scheduler import (QueuedBind, Scheduler, ScheduleResult,
+                              queue_wait)
 from ..ops.device_state import NodeStateMirror, enable_persistent_compilation_cache
 from ..ops.features import Unsupported, batch_supported, build_batch
 from ..ops.kernel import schedule_batch
@@ -49,6 +51,8 @@ from ..ops.kernel import schedule_batch
 # Sentinel fallback_reason: the popped entity is a pod GROUP that can ride a
 # device gang session (schedule_one routes it to run_gang_device_session).
 _GANG_SESSION = "__gang_device_session__"
+# A session whose head is no plain template clone: a holder no pod carries.
+_NO_TEMPLATE = (object(), 0, "")
 
 # Batches that may be in flight on the device while the host commits retired
 # ones (2 = double buffering).
@@ -159,6 +163,10 @@ class TPUScheduler(Scheduler):
         # for group entities and with tracing off): the batch collectors
         # keep it, so a batch's sampled members are found once.
         self._popped_ctx = None
+        # What a refill may take on the template's verdict (_refill): the
+        # shared signature holder, priority and scheduler name of the
+        # session's head, or _NO_TEMPLATE where the head is no plain clone.
+        self._session_template = _NO_TEMPLATE
         # Terminal-failure memos: state key -> (unschedulable plugins,
         # message) for side-effect-free host diagnoses (see _fail_from_memo).
         # A small keyed LRU, not a single slot: two ALTERNATING unschedulable
@@ -243,6 +251,14 @@ class TPUScheduler(Scheduler):
         return {tail: int(series.value(tail)) for tail in ("batch", "single")}
 
     @property
+    def popped_pods(self) -> dict:
+        """Pods the device path's pops accepted into a batch, by how the
+        verdict was reached: `scheduler_queue_popped_pods_total{how}`
+        (`_pop_stage`)."""
+        series = self.metrics.queue_popped_pods
+        return {how: int(series.value(how)) for how in ("run", "single")}
+
+    @property
     def device_wait_s(self) -> float:
         """Time blocked on a device result fetch (`device.wait`)."""
         return self.stages.seconds["device.wait"]
@@ -267,14 +283,9 @@ class TPUScheduler(Scheduler):
                 return None
             if not isinstance(qpi, (QueuedPodGroupInfo,
                                     QueuedCompositeGroupInfo)):
-                if (qpi.pod.deletion_ts is not None
-                        or qpi.pod.uid in self.cache.pod_states):
-                    # skipPodSchedule: deleting pods never dispatch to
-                    # device, and neither do pods the cache already placed
-                    # (a reconcile unwind raced the bind confirm — see core
-                    # process_one). (Group/composite entities are never
-                    # skipped whole — their .pod is just the first member.)
-                    self.queue.done(qpi.pod.uid)
+                if self._skip_pod_schedule(qpi.pod):
+                    # (Group/composite entities are never skipped whole —
+                    # their .pod is just the first member.)
                     continue
                 # queue.wait ends here for device-path pods (host-path
                 # pods record in process_one; the qpi guard dedups).
@@ -284,6 +295,16 @@ class TPUScheduler(Scheduler):
                 self.record_queue_wait(qpi, ctx)
             return qpi
 
+    def _skip_pod_schedule(self, pod) -> bool:
+        """skipPodSchedule: deleting pods never dispatch to device, and
+        neither do pods the cache already placed (a reconcile unwind raced
+        the bind confirm — see core process_one). Such a pod's attempt is
+        settled here (`queue.done`) and the caller drops it."""
+        if pod.deletion_ts is not None or pod.uid in self.cache.pod_states:
+            self.queue.done(pod.uid)
+            return True
+        return False
+
     def _take(self, batch: "_Batch", qpi: QueuedPodInfo) -> None:
         """Accept the entity _pop just handed out into `batch`."""
         ctx = self._popped_ctx
@@ -292,19 +313,39 @@ class TPUScheduler(Scheduler):
             batch.sampled_at.append(len(batch))
         batch.append(qpi)
 
+    @contextmanager
     def _pop_stage(self):
         """The `queue.pop` stage of one batch, opened with the active
         queue's depth as the pop begins (attr `backlog`: on the span and,
-        in a profiler session, a stat of the event). One `len()` a batch;
-        the hint walk's per-pod pops are leaves of the table and say
-        nothing."""
-        return self.stages.stage(
-            "queue.pop", backlog=len(self.queue.active_q))
+        in a profiler session, a stat of the event) and closed with what
+        the collector inside it says it ``took`` into a device batch:
+        `pods`, and `run`, those of them on the template's verdict
+        (`_refill`), which also move
+        `scheduler_queue_popped_pods_total{how}`. One `len()` a batch; the
+        hint walk's per-pod pops are leaves of the table and say nothing."""
+        pods = run = 0
 
-    def _collect_batch(self) -> Tuple[Optional[Framework], List[QueuedPodInfo], Optional[str]]:
+        def took(n: int, on_template: int = 0) -> None:
+            nonlocal pods, run
+            pods += n
+            run += on_template
+
+        with self.stages.stage(
+                "queue.pop", backlog=len(self.queue.active_q)) as stage:
+            yield took
+            stage.say(pods=pods, run=run)
+            count = self.metrics.queue_popped_pods.inc
+            if run:
+                count("run", value=float(run))
+            if pods > run:
+                count("single", value=float(pods - run))
+
+    def _collect_batch(self, took) -> Tuple[Optional[Framework], List[QueuedPodInfo], Optional[str]]:
         """Pop a maximal run of consecutive identical-signature pods.
         Returns (framework, batch, fallback_reason); fallback_reason set when
-        the batch head must take the host path (batch will be length 1)."""
+        the batch head must take the host path (batch will be length 1).
+        ``took`` is the open `queue.pop` stage's (`_pop_stage`): told the
+        pods that go to a device batch."""
         head = self._pop()
         if head is None:
             return None, [], None
@@ -315,6 +356,7 @@ class TPUScheduler(Scheduler):
         if isinstance(head, QueuedPodGroupInfo):
             fw, sig = self._gang_device_eligible(head)
             if fw is not None:
+                took(len(head.members))
                 return fw, [head], _GANG_SESSION
             return self.framework_for_pod(head.pod), [head], "pod group entity"
         fw = self.framework_for_pod(head.pod)
@@ -341,18 +383,20 @@ class TPUScheduler(Scheduler):
             for n in getattr(head.pod, "resource_claims", ()) or ())
         self._session_aux_shape = self._aux_shape(head.pod)
         self._session_neutral_sig = self._neutral_sig(fw, head.pod, sig)
+        pod = head.pod
+        # The head's answers are its template's where nothing but the
+        # template went into them: the memo answered batch_supported (no
+        # volumes, no claims: _batch_supported_memo), the aux shape is the
+        # plain one, and its signature IS the session's.
+        shared = pod.__dict__.get("_sig_shared")
+        self._session_template = (
+            (shared, pod.priority, pod.scheduler_name)
+            if shared is not None and not pod.volumes
+            and not pod.resource_claims else _NO_TEMPLATE)
         batch = _Batch()
         self._take(batch, head)  # still the last entity _pop handed out
-        while len(batch) < self.max_batch:
-            nxt = self._pop()
-            if nxt is None:
-                break
-            if self._session_compatible(nxt, fw, sig):
-                self._take(batch, nxt)
-            else:
-                self._holdover = nxt
-                break
-        return fw, batch, None
+        took(1)
+        return fw, self._refill(batch, fw, sig, took), None
 
     # -- gang device sessions ----------------------------------------------
     #
@@ -462,7 +506,7 @@ class TPUScheduler(Scheduler):
         dirty_rows: List[int] = []
         invalidated = False
 
-        def collect_pack() -> List[QueuedPodGroupInfo]:
+        def collect_pack(took) -> List[QueuedPodGroupInfo]:
             groups: List[QueuedPodGroupInfo] = []
             total = 0
             while True:
@@ -477,6 +521,7 @@ class TPUScheduler(Scheduler):
                             and total + len(nxt.members) <= self.max_batch):
                         groups.append(nxt)
                         total += len(nxt.members)
+                        took(len(nxt.members))
                         self._session_claims.update(
                             c for m in nxt.members
                             for c in self._claims_of(m.pod))
@@ -495,8 +540,8 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if pack is None:
-                    with self._pop_stage():
-                        pack = collect_pack() or None
+                    with self._pop_stage() as took:
+                        pack = collect_pack(took) or None
                     if pack is None:
                         break
                     pending.append(pack)
@@ -1849,20 +1894,94 @@ class TPUScheduler(Scheduler):
             self._session_claims.update(dra_claims)
         return True
 
-    def _collect_session_batch(self, fw: Framework, sig) -> List[QueuedPodInfo]:
+    def _refill(self, batch: "_Batch", fw: Framework, sig,
+                took) -> "_Batch":
+        """Fill ``batch`` up to max_batch with the run the queue hands out
+        next (PriorityQueue.pop_run): the maximal prefix of its pop order
+        that the session accepts. The entity that ends the run goes to the
+        holdover slot, popped and in flight; a deleting or already placed
+        pod is settled and dropped, as `_pop` does.
+
+        A pod joins on the session template's verdict, without
+        `_session_compatible`, where what can be observed says it must get
+        the head's answers: the head's own shared signature holder (a clone
+        of the same template: one signature, one memoized batch_supported),
+        its priority and scheduler name, and nothing per pod that any of
+        the checks reads (a nomination, a node name, volumes, claims).
+        Every other entity takes `_session_compatible` as the head's
+        successors always did. The pop's records are written in one pass
+        after the run, on the run's one clock reading: `queue.wait` for all
+        of it, a sampled pod's two rows and its place in the batch; the open
+        `queue.pop` stage is told what the refill ``took`` (`_pop_stage`)."""
+        holder, priority, scheduler_name = self._session_template
+        queue = self.queue
+        skip, compatible = self._skip_pod_schedule, self._session_compatible
+        on_template = 0
+
+        def accept(qpi) -> Optional[bool]:
+            nonlocal on_template
+            if type(qpi) is QueuedPodInfo:
+                pod = qpi.pod_info.pod
+                if skip(pod):
+                    return None
+                if (pod.__dict__.get("_sig_shared") is holder
+                        and pod.priority == priority
+                        and pod.scheduler_name == scheduler_name
+                        and not pod.nominated_node_name
+                        and not pod.node_name
+                        and not pod.volumes and not pod.resource_claims):
+                    on_template += 1
+                    return True
+            return compatible(qpi, fw, sig)
+
+        run: List = []
+        held, self._holdover = self._holdover, None
+        if held is not None:
+            ok = accept(held)
+            if ok:
+                run.append(held)
+            elif ok is not None:
+                self._holdover = held
+                return batch
+        more, refused, now = queue.pop_run(
+            self.max_batch - len(batch) - len(run), accept)
+        self._holdover = refused
+        run += more
+        tracer = self.tracer if self.tracer.enabled else None
+        if type(refused) is QueuedPodInfo:
+            # the holdover's wait ends at its pop, as a batch member's does
+            self.record_queue_wait(
+                refused, tracer and tracer.context_for(refused.pod.uid))
+        if tracer is not None:
+            context_for = tracer.context_for
+            wall_pop = _time.time()
+            for at, qpi in enumerate(run, len(batch)):
+                if type(qpi) is not QueuedPodInfo:
+                    continue  # a group entity records as one, elsewhere
+                ctx = context_for(qpi.pod_info.pod.uid)
+                if ctx is None:
+                    continue
+                if "_qwait_recorded" not in qpi.__dict__:
+                    self.trace_queue_wait(
+                        qpi, ctx, queue_wait(qpi, now), wall_pop)
+                batch.sampled.append(ctx)
+                batch.sampled_at.append(at)
+        waits: List[float] = []
+        for qpi in run:
+            if (type(qpi) is QueuedPodInfo
+                    and "_qwait_recorded" not in qpi.__dict__):
+                qpi._qwait_recorded = True
+                waits.append(queue_wait(qpi, now))
+        self.metrics.pod_stage_duration.observe_many(waits, "queue.wait")
+        batch += run
+        took(len(run), on_template)
+        return batch
+
+    def _collect_session_batch(self, fw: Framework, sig,
+                               took) -> List[QueuedPodInfo]:
         """Pop up to max_batch pods matching the session signature; an
         incompatible entity goes to the holdover slot and ends the refill."""
-        batch = _Batch()
-        while len(batch) < self.max_batch:
-            nxt = self._pop()
-            if nxt is None:
-                break
-            if self._session_compatible(nxt, fw, sig):
-                self._take(batch, nxt)
-            else:
-                self._holdover = nxt
-                break
-        return batch
+        return self._refill(_Batch(), fw, sig, took)
 
     def run_device_session(self, fw: Framework, first_batch: List[QueuedPodInfo]) -> None:
         """Crash-proof wrapper: an unexpected device failure mid-session
@@ -1927,8 +2046,9 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if batch is None:
-                    with self._pop_stage():
-                        batch = self._collect_session_batch(fw, sig) or None
+                    with self._pop_stage() as took:
+                        batch = self._collect_session_batch(
+                            fw, sig, took) or None
                     if batch is None and self._event_inbox:
                         # A concurrent client (threaded watch feed) may have
                         # parked pod-add events while this session ran: drain
@@ -1943,9 +2063,9 @@ class TPUScheduler(Scheduler):
                         elif sd.patch_pending:
                             continue  # patch (or drain) before collecting
                         else:
-                            with self._pop_stage():
+                            with self._pop_stage() as took:
                                 batch = self._collect_session_batch(
-                                    fw, sig) or None
+                                    fw, sig, took) or None
                     if batch is None:
                         break
                     pending.append(batch)
@@ -2584,8 +2704,8 @@ class TPUScheduler(Scheduler):
         # path below (the popped entity waits in the holdover slot).
         if self._hints.entry is not None and self._try_hint_binds():
             return True
-        with self._pop_stage():
-            fw, batch, fallback_reason = self._collect_batch()
+        with self._pop_stage() as took:
+            fw, batch, fallback_reason = self._collect_batch(took)
         if not batch:
             return False
         if fallback_reason is _GANG_SESSION:

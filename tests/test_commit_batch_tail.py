@@ -4,7 +4,6 @@ store, one settle) instead of one `_commit` call a pod, to the per-pod tail's
 outcome. The per-pod tail is forced the thread-safe way: a clientset without
 the bulk verb. No timing is asserted."""
 
-from contextlib import nullcontext
 
 import pytest
 
@@ -13,6 +12,7 @@ from kubernetes_tpu.core.metrics import Histogram
 from kubernetes_tpu.models import TPUScheduler
 from kubernetes_tpu.plugins.basic import DefaultBinder
 from kubernetes_tpu.testing import make_node, make_pod
+from kubernetes_tpu.testing.annotations import StageAnnotations
 
 
 class _NoBulkVerb(FakeClientset):
@@ -63,11 +63,10 @@ def _cluster(cs_class, nodes=40, max_batch=16):
 def _run_and_read_tails(monkeypatch, cs_class, pods, **cluster):
     """Schedule `pods` and return the `tail` each `sched.host.commit` stage
     opened with (the annotation's stats, as a profiler session gets them)."""
-    opened = []
+    annotations = StageAnnotations()
+    opened = annotations.opened
     sched, cs, _clock, _seen = _cluster(cs_class, **cluster)
-    monkeypatch.setattr(
-        sched.stages, "_annotation",
-        lambda name, **stats: opened.append((name, stats)) or nullcontext())
+    monkeypatch.setattr(sched.stages, "_annotation", annotations)
     for p in pods:
         cs.create_pod(p.obj())
     sched.run_until_idle()

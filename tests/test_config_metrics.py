@@ -309,6 +309,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_device_batches_total",
                      "scheduler_device_scan_steps_total",
                      "scheduler_commit_pods_total",
+                     "scheduler_queue_popped_pods_total",
                      "scheduler_hint_cache_hits_total",
                      "scheduler_hint_cache_misses_total",
                      "scheduler_hint_cache_invalidations_total",

@@ -10,6 +10,7 @@ import pytest
 from kubernetes_tpu.core import spans
 from kubernetes_tpu.models import TPUScheduler
 from kubernetes_tpu.testing import make_node, make_pod
+from kubernetes_tpu.testing.annotations import StageAnnotations
 
 HOSTNAME = "kubernetes.io/hostname"
 ZONE = "topology.kubernetes.io/zone"
@@ -271,20 +272,10 @@ def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
     """What is known of a stage as it opens rides its annotation, so a
     profiler session holds each `sched.device.dispatch` with the engine
     that placed the batch as a stat of the event."""
-    opened = []
-
-    class Annotation:
-        def __init__(self, name, **stats):
-            opened.append((name, stats))
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
+    annotations = StageAnnotations()
+    opened = annotations.opened
     sched, cs = _cluster()
-    monkeypatch.setattr(sched.stages, "_annotation", Annotation)
+    monkeypatch.setattr(sched.stages, "_annotation", annotations)
     for i in range(3):
         cs.create_pod(_red(f"r{i}", weight=1))
     sched.run_until_idle()

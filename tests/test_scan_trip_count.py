@@ -6,7 +6,6 @@ because a step past `n_active` is inert by `step`'s own masks.
 The fixed-length scan lives HERE, not in the package: `_FixedLength` runs the
 package's own loop body `batch_pad` times whatever `n_active` says."""
 
-from contextlib import nullcontext
 from functools import partial, wraps
 
 import numpy as np
@@ -19,6 +18,7 @@ from kubernetes_tpu.core import FakeClientset
 from kubernetes_tpu.models import TPUScheduler
 from kubernetes_tpu.ops import kernel
 from kubernetes_tpu.testing import make_node, make_pod
+from kubernetes_tpu.testing.annotations import StageAnnotations
 
 ZONE = "topology.kubernetes.io/zone"
 BATCH = 64
@@ -177,13 +177,12 @@ def test_a_dispatch_of_5_pods_on_a_1024_wide_plan_runs_5_steps(site, monkeypatch
     both on the `sched.device.dispatch` stage as it opens."""
     from kubernetes_tpu.api.types import PodGroup
 
-    opened = []
+    annotations = StageAnnotations()
+    opened = annotations.opened
     cs = FakeClientset()
     sched = TPUScheduler(clientset=cs)
     assert sched.max_batch == 1024
-    monkeypatch.setattr(
-        sched.stages, "_annotation",
-        lambda name, **stats: opened.append((name, stats)) or nullcontext())
+    monkeypatch.setattr(sched.stages, "_annotation", annotations)
     for i in range(40):
         cs.create_node(make_node().name(f"n{i}").capacity(
             {"cpu": 8, "memory": "16Gi", "pods": 110}).zone(f"z{i % 4}").obj())

@@ -23,6 +23,7 @@ from kubernetes_tpu.core.spans import (FlightRecorder, SpanRecorder,
                                        format_ctx, parse_ctx, sampled_uid,
                                        trace_id_for, write_jsonl)
 from kubernetes_tpu.testing.wrappers import make_node, make_pod
+from kubernetes_tpu.testing.annotations import StageAnnotations
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -419,7 +420,6 @@ class TestStageLedger:
         `device.dispatch` opens with the scheduler's next `seq` and the
         depth of the pipeline it found, and the `device.wait` that retires
         a batch opens with that batch's `seq`."""
-        from contextlib import nullcontext
         from kubernetes_tpu.models import TPUScheduler
 
         class Refusing(FakeClientset):
@@ -429,13 +429,11 @@ class TestStageLedger:
                     raise KeyError("refused once")
                 return super().bind(pod, node_name)
 
-        opened = []
+        annotations = StageAnnotations()
+        opened = annotations.opened
         cs = Refusing()
         s = TPUScheduler(clientset=cs, max_batch=16)
-        monkeypatch.setattr(
-            s.stages, "_annotation",
-            lambda name, **stats: opened.append((name, stats))
-            or nullcontext())
+        monkeypatch.setattr(s.stages, "_annotation", annotations)
         for i in range(8):
             cs.create_node(_node(f"n{i}", cpu="32"))
         for i in range(48):
