@@ -15,25 +15,39 @@ fractions in millionths) and two guarantees on the order of work. Controls
   scheduling in creation order" broken);
 - `last_maximum`: ties broken the other way (the guarantee "first maximum in
   walk order" broken);
+- `node_add_ignored`, `node_delete_ignored`, `tree_order_stale`: the three
+  things a cache can get wrong about a cluster event (the node never joins;
+  the removed node's pods still count and its row still takes pods; the new
+  node put at the end of the flat list and not into its zone's turn). They
+  are read on a log WITH events (`event_log`: init pods, a third of the
+  measured pods, a node removed, a third, a node added to its zone, the
+  rest, one pod no node can hold, then deletes and creates), since the plain
+  log has none;
 - `<key>.<name>`: for each pod feature that the configuration's templates use,
   the controls its own file states (`reference_features/<key>.py`,
   `CONTROLS`): the feature's state with one guarantee broken, put in its
   place. Found by name: this file names no feature.
 
 Reading, NOT a control: `float32`, the score terms in float32 and floored
-where the reference floors. On these uniform clusters (equal nodes, equal
+where the reference floors. On the uniform clusters (equal nodes, equal
 pods, power-of-two sizes) it places every pod where int64 does, so exact
-placements do not detect float32 scoring here, and `correct` would pass it. A
+placements do not detect float32 scoring there, and `correct` would pass it. A
 later PR that lowers the score path's precision must bring a configuration
-with unequal nodes or pods on which this reading differs (PERF.md).
+with unequal nodes or pods on which this reading differs: node groups of
+several sizes (`tests/benchmark/toy_bench/configs/unequal-toy.json`; PERF.md).
 
     python3 benchmark/control.py --config spread-5k --seeds 11 12 13
+    python3 benchmark/control.py --config spread-5k --seeds 11 12 13 --events
+    python3 benchmark/control.py --bench-dir tests/benchmark/toy_bench \
+        --config unequal-toy --seeds 11 12 13
 
 schedules the init pods and one wave at the configuration's own size with the
 reference and with each control, and prints how many placements differ (the
-limit of the comparison is 0 differing; a control must differ). It needs no
-chip: both sides are numpy. `tests/benchmark/test_benchmark_reference.py`
-keeps it as a test at toy size.
+limit of the comparison is 0 differing; a control must differ); `--events`
+does the same over the log with cluster events, with the event controls
+beside the others. It needs no chip: both sides are numpy.
+`tests/benchmark/test_benchmark_reference.py` and `test_benchmark_events.py`
+keep it as tests at toy size.
 """
 
 from __future__ import annotations
@@ -110,7 +124,9 @@ class StaleBatch(reference.Reference):
     GROUP = 64
 
     def resource_scores(self, shape, rows):
-        if len(self.placed) % self.GROUP == 0 or not hasattr(self, "_frozen"):
+        # (a cluster event makes the rows anew: the group starts again)
+        if (len(self.placed) % self.GROUP == 0 or not hasattr(self, "_frozen")
+                or len(self._frozen[0]) != self.n):
             self._frozen = (self.nz_cpu.copy(), self.nz_mem.copy())
         live = self.nz_cpu, self.nz_mem
         self.nz_cpu, self.nz_mem = self._frozen
@@ -128,11 +144,46 @@ class LastMaximum(reference.Reference):
         return s * len(s) + np.arange(len(s))
 
 
+class NodeAddIgnored(reference.Reference):
+    """A node created in the middle of the run never joins the cluster."""
+
+    def add_node(self, node):
+        pass
+
+
+class NodeDeleteIgnored(reference.Reference):
+    """A removed node stays: its pods still count and its row still takes
+    pods."""
+
+    def remove_node(self, name):
+        pass
+
+
+class TreeOrderStale(reference.Reference):
+    """A node created in the middle of the run is put at the end of the flat
+    list, not into its zone's turn of the node tree."""
+
+    _late = frozenset()           # the nodes that joined after the first
+
+    def add_node(self, node):
+        self._late = self._late | {node["name"]}
+        super().add_node(node)
+
+    def _walk_order(self):
+        order = super()._walk_order()
+        return ([n for n in order if n["name"] not in self._late]
+                + [n for n in order if n["name"] in self._late])
+
+
 # A control has to differ on every seed. The float32 reading is kept beside
 # them as found (see the module's docstring): an identical result, which a
 # guarantee on placements cannot refuse.
 CONTROLS = {"int32": Int32Scores, "stale_batch": StaleBatch,
             "last_maximum": LastMaximum}
+# read on a log with cluster events (`event_log`): the plain log has none
+EVENT_CONTROLS = {"node_add_ignored": NodeAddIgnored,
+                  "node_delete_ignored": NodeDeleteIgnored,
+                  "tree_order_stale": TreeOrderStale}
 READINGS = {"float32": Float32Scores}
 
 
@@ -175,19 +226,100 @@ def differing(cfg: dict, seed: int, control: type) -> tuple:
     return total, differ
 
 
+PENDING = "cannotFit"      # the template group of `event_log`'s large pod
+
+
+def event_log(cfg: dict, seed: int) -> dict:
+    """A run with cluster events, as a driver would hand it to `run.py`
+    (`nodes`, `templates`, `log`, `may_pend`, `placements`), made with the
+    reference itself: the init pods, a third of the measured pods, the fullest node of
+    the first zone removed, another third, a node of the first group's
+    template added to that zone, the rest, one pod that asks for more cpu
+    than any node has, and the first third deleted and created again."""
+    nodes = objects.cluster(cfg, seed)
+    group = objects.node_groups(cfg)[0]
+    largest = max(reference.milli_cpu(g["template"]["cpu"])
+                  for g in objects.node_groups(cfg))
+    templates = {"initPods": cfg["initPods"]["template"],
+                 "measurePods": cfg["measurePods"]["template"],
+                 PENDING: {"cpu": f"{largest + 1000}m", "memory": "1Gi"}}
+    ref = reference.Reference(nodes)
+    log, placements = [], {}
+
+    def create(names, which):
+        for name in names:
+            log.append(("create", name, which))
+            placements[name] = ref.schedule(name, templates[which],
+                                            may_pend=which == PENDING)
+
+    per = int(cfg["measurePods"]["count"])
+    third = [range(0, per // 3), range(per // 3, 2 * per // 3),
+             range(2 * per // 3, per)]
+    create([f"init-{i}" for i in range(int(cfg["initPods"]["count"]))],
+           "initPods")
+    create([f"m-{i}" for i in third[0]], "measurePods")
+    # both events in the zone whose turn comes first: the node that joins it
+    # then stands ahead of the other zones' last nodes, not at the list's end
+    first = np.flatnonzero(ref.zone_of == 0)
+    fullest = ref.names[int(first[np.argmax(ref.n_pods[first])])]
+    log.append(("node_delete", fullest, None))
+    ref.remove_node(fullest)
+    create([f"m-{i}" for i in third[1]], "measurePods")
+    zones = int(group["template"]["zones"])
+    index = next(i for i in range(len(nodes), len(nodes) + zones)
+                 if f"zone-{i % zones}" == ref.zones[0])
+    added = reference.node_description("node-added-0", index,
+                                       group["template"])
+    log.append(("node_add", added["name"], added))
+    ref.add_node(added)
+    create([f"m-{i}" for i in third[2]], "measurePods")
+    create(["large-0"], PENDING)
+    for i in third[0]:
+        log.append(("delete", f"m-{i}", None))
+        ref.delete(f"m-{i}")
+    create([f"again-{i}" for i in third[0]], "measurePods")
+    return {"nodes": nodes, "templates": templates, "log": log,
+            "may_pend": [PENDING], "placements": placements}
+
+
+def differing_on_events(cfg: dict, seed: int, control: type) -> tuple:
+    """(pods compared, placements on which the control differs) over
+    `event_log`. A control that cannot finish the log (no feasible node for
+    a pod, a pending pod that fits, a node it never had) has failed: every
+    pod counts as differing."""
+    run = event_log(cfg, seed)
+    sound = run["placements"]
+    try:
+        other = reference.replay(control(run["nodes"]), run["templates"],
+                                 run["log"], run["may_pend"])
+    except (reference.Unschedulable, reference.Unmodelled, KeyError):
+        return len(sound), len(sound)
+    return len(sound), sum(other[p] != node for p, node in sound.items())
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", required=True)
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--events", action="store_true",
+                    help="over the log with cluster events, the event "
+                         "controls beside the others")
+    ap.add_argument("--bench-dir", default=HERE,
+                    help="the directory whose configs/ holds the "
+                         "configuration")
     args = ap.parse_args(argv)
     cfg = objects.load_config(
-        os.path.join(HERE, "configs", args.config + ".json"), args.rehearse)
+        os.path.join(args.bench_dir, "configs", args.config + ".json"),
+        args.rehearse)
     controls = {**CONTROLS, **feature_controls(cfg)}
+    count = differing
+    if args.events:
+        controls, count = {**controls, **EVENT_CONTROLS}, differing_on_events
     held = set(controls)          # the controls that differed on every seed
     for seed in args.seeds:
         for name, control in {**controls, **READINGS}.items():
-            total, differ = differing(cfg, seed, control)
+            total, differ = count(cfg, seed, control)
             if not differ:
                 held.discard(name)
             print(f"{'control' if name in controls else 'reading'} {name} "

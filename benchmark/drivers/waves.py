@@ -1,6 +1,6 @@
 """Traffic driver `waves`: the library surface, closed loop, in process.
 
-The surface `bench.py` and `python -m kubernetes_tpu.perf` drive: a default
+The surface `python -m kubernetes_tpu.perf` drives: a default
 `TPUScheduler` on this thread, creates from a client thread through the
 in-process clientset, `schedule_one` until drained. One wave is the
 configuration's measured phase: create its measured pods, drain until all are

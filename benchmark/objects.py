@@ -13,6 +13,12 @@ states; a key without both files is refused (``features.py``).
 ``--seed`` changes the order in which the nodes are created (names keep their
 zone): the node tree, every tie-break and the rotating start index then see
 another cluster, while sizes and shapes stay the configuration's.
+
+A configuration's ``nodes`` is one group (``count``, ``template``) or a list
+of groups, each of its own size; a group of one may give its node a ``name``.
+Nodes are numbered ``node-<i>`` across the groups and the seed permutes the
+whole creation order (``reference.group_descriptions``). ``rehearse`` then
+gives the toy counts of ``nodes`` as a list, one for each group.
 """
 
 from __future__ import annotations
@@ -32,8 +38,20 @@ def load_config(path: str, rehearse: bool) -> dict:
     if rehearse:
         # Toy counts for the CPU rehearsal, stated in the file itself.
         for group, count in cfg["rehearse"].items():
-            cfg[group]["count"] = int(count)
+            if isinstance(cfg[group], list):
+                if len(count) != len(cfg[group]):
+                    raise ValueError(f"{path}: rehearse gives {len(count)} "
+                                     f"counts for {len(cfg[group])} {group}")
+                for g, c in zip(cfg[group], count):
+                    g["count"] = int(c)
+            else:
+                cfg[group]["count"] = int(count)
     return cfg
+
+
+def node_groups(cfg: dict) -> List[dict]:
+    nodes = cfg["nodes"]
+    return nodes if isinstance(nodes, list) else [nodes]
 
 
 def node_order(count: int, seed: int) -> List[int]:
@@ -43,9 +61,9 @@ def node_order(count: int, seed: int) -> List[int]:
 
 def cluster(cfg: dict, seed: int) -> List[dict]:
     """Plain node descriptions in creation order."""
-    n = int(cfg["nodes"]["count"])
-    return reference.node_descriptions(
-        cfg["nodes"]["template"], n, node_order(n, seed))
+    groups = node_groups(cfg)
+    n = sum(int(g["count"]) for g in groups)
+    return reference.group_descriptions(groups, node_order(n, seed))
 
 
 def make_node(desc: dict):

@@ -3,9 +3,10 @@
 Independent of the package under test: it imports nothing of
 ``kubernetes_tpu`` and takes nothing the program has made. Its inputs are the
 plain node and pod descriptions of a configuration file (``configs/*.json``)
-in the order the run created them; its output is the node each pod must land
+in the order the run created them, and the run's log of what happened to
+them afterwards (``replay``); its output is the node each pod must land
 on when pods are scheduled one after the other in creation order with
-deterministic ties, which is the guarantee both configurations state.
+deterministic ties, which is the guarantee the configurations state.
 
 Semantics, per pod (kube-scheduler ``schedule_one.go``, default plugin set):
 
@@ -24,6 +25,32 @@ Semantics, per pod (kube-scheduler ``schedule_one.go``, default plugin set):
   node for the core's pods and cannot move the maximum, so a pod or node that
   would make them vary is refused unless a feature file models it;
 - the first maximum in walk order wins.
+
+Cluster events (``backend/cache/node_tree.go``, ``cache.go``,
+``schedule_one.go``), each applied where the log has it:
+
+- ``add_node``: the node joins the end of its zone's list, a zone first met
+  the end of the zones; ``remove_node``: the node leaves its zone's list, and
+  a zone left empty leaves the zones. The walk order is the zone-interleaved
+  list made anew from that tree, the adaptive sample follows the new count,
+  and the rotating start index is kept and taken modulo the new count;
+- pods on a removed node stay bound where they were (scheduler_perf runs no
+  kubelet and no pod garbage collector) and leave every count: the cache
+  drops the node from the tree and the snapshot list, so its pods leave fit,
+  spread and term tables alike, and a later delete of one accounts nothing.
+  Departure: a node created again under the name of a removed node that
+  still holds such pods would get them back in the source's cache
+  (``cache.AddNode`` reuses the NodeInfo); that is refused as ``Unmodelled``;
+- a pod that must stay pending: for a pod of a template group that the run
+  names as one that may pend, no feasible node is an answer (``None``) and
+  not ``Unschedulable``. Its failed cycle walks every node, so the start
+  index stays where it was. Preemption (PostFilter) is NOT modelled: the run
+  names only groups whose pods can evict nothing. Nor is the order in which
+  a requeued pod would meet the pods created meanwhile: after every event
+  that could admit a pending pod (a node added or removed, a bound pod
+  deleted, and a pod placed while a pod feature has a state) every pending
+  pod is checked again, and one that has become feasible is refused as
+  ``Unmodelled``. A configuration that needs the retry brings it.
 
 This file is the core: it knows the pod template's ``cpu``, ``memory`` and
 ``labels`` and nothing else. Every further key of a pod template is a pod
@@ -45,6 +72,8 @@ numpy only, which states its own semantics and refusals and supplies:
   before the first maximum is taken), ``account(row, pod, sign)`` (a pod
   landed on, +1, or left, -1, that row). ``ref`` offers ``n``, ``names``,
   ``zones``, ``zone_of``, ``n_zones`` and ``placed`` (pod name -> (row, pod));
+  when a node is added or removed the rows change, so every ``State`` is made
+  anew over the new rows and told again of every pod on a live node;
 - optionally ``CONTROLS``: name -> a ``State`` with one guarantee broken,
   which ``control.py`` puts in the feature's place and which has to come out
   as not correct.
@@ -102,22 +131,59 @@ def num_feasible_nodes_to_find(num_nodes: int) -> int:
     return max(num_nodes * pct // 100, 100)
 
 
-def node_descriptions(template: dict, count: int,
-                      order: Sequence[int]) -> List[dict]:
-    """The cluster as plain data: node ``i`` is ``node-<i>`` in zone
-    ``zone-<i % zones>``, listed in the order the run creates them."""
+def node_description(name: str, index: int, template: dict) -> dict:
+    """One node of ``template`` as plain data, in zone ``zone-<index % zones>``
+    (what a driver that adds a node in the middle of a run describes it
+    with, to the program through ``objects.make_node`` and in its log)."""
     unknown = set(template) - NODE_KEYS
     if unknown:
+        # taints, labels and every other node key wait for the PR that
+        # needs them: the fit filter and the two resource scores read
+        # nothing else of a node
         raise Unmodelled(f"node template keys {sorted(unknown)}")
     zones = int(template["zones"])
     if zones < 1:
         raise Unmodelled("nodes without a zone label")
-    if sorted(order) != list(range(count)):
+    return {"name": name, "zone": f"zone-{index % zones}",
+            "cpu": milli_cpu(template["cpu"]),
+            "memory": quantity(template["memory"]),
+            "pods": int(template["pods"])}
+
+
+def group_descriptions(groups: Sequence[dict],
+                       order: Sequence[int]) -> List[dict]:
+    """The cluster as plain data, listed in the order the run creates it.
+    ``groups`` are a configuration's node groups, each ``count`` nodes of one
+    ``template``. Positions run across the groups in the order given: the
+    node at position ``k`` is in zone ``zone-<k % zones>`` of its own group's
+    template and is named ``node-<i>``, ``i`` counting the unnamed nodes
+    before it, so one group reads as it always did. A group of one may give
+    its node a ``name`` of its own."""
+    described = []
+    unnamed = 0
+    for g in groups:
+        unknown = set(g) - {"count", "template", "name"}
+        if unknown:
+            raise Unmodelled(f"node group keys {sorted(unknown)}")
+        count = int(g["count"])
+        if "name" in g and count != 1:
+            raise ValueError(f"node group {g['name']!r}: a name is for a "
+                             f"group of one, not of {count}")
+        for _ in range(count):
+            name = g.get("name")
+            if name is None:
+                name, unnamed = f"node-{unnamed}", unnamed + 1
+            described.append(
+                node_description(name, len(described), g["template"]))
+    if sorted(order) != list(range(len(described))):
         raise ValueError("node order is not a permutation of the nodes")
-    return [{"name": f"node-{i}", "zone": f"zone-{i % zones}",
-             "cpu": milli_cpu(template["cpu"]),
-             "memory": quantity(template["memory"]),
-             "pods": int(template["pods"])} for i in order]
+    return [described[i] for i in order]
+
+
+def node_descriptions(template: dict, count: int,
+                      order: Sequence[int]) -> List[dict]:
+    """One group: node ``i`` is ``node-<i>`` in zone ``zone-<i % zones>``."""
+    return group_descriptions([{"count": count, "template": template}], order)
 
 
 class PodShape:
@@ -133,6 +199,9 @@ class PodShape:
         self.features: Dict[str, object] = {}
 
 
+DESCRIPTION_KEYS = {"name", "zone", "cpu", "memory", "pods"}
+
+
 class Reference:
     """Sequential scheduler over plain arrays, nodes in node-tree order."""
 
@@ -140,38 +209,122 @@ class Reference:
                  bench_dir: Optional[str] = None):
         # where pod features are looked for ahead of this file's directory
         self.bench_dir = bench_dir
-        by_zone: Dict[str, List[dict]] = {}
+        # the node tree: zone -> its nodes in creation order; the dict's own
+        # order is the zones' (first appearance, a zone left empty removed)
+        self._tree: Dict[str, List[dict]] = {}
+        self._zone_of_node: Dict[str, str] = {}
+        self.names: List[str] = []
+        self.start = 0
+        self.placed: Dict[str, tuple] = {}      # pod name -> (row, pod)
+        self.pending: Dict[str, PodShape] = {}  # pods that found no node
+        self._gone: Dict[str, str] = {}         # pod -> the removed node it is on
+        self._modules: Dict[str, object] = {}   # feature key -> its module
+        self._states: Dict[str, object] = {}    # feature key -> its State
+        self._shapes: Dict[int, PodShape] = {}
         for n in nodes:
-            by_zone.setdefault(n["zone"], []).append(n)
-        zones = list(by_zone)
+            self._join(n)
+        self._rebuild()
+
+    # -- the cluster -------------------------------------------------------
+
+    def _join(self, node: dict) -> None:
+        unknown = set(node) - DESCRIPTION_KEYS
+        if unknown:
+            raise Unmodelled(f"node description keys {sorted(unknown)}")
+        if node["name"] in self._zone_of_node:
+            raise ValueError(f"duplicate node name {node['name']}")
+        if node["name"] in self._gone.values():
+            raise Unmodelled(
+                f"node {node['name']} created again while pods bound to the "
+                f"removed node of that name remain")
+        if node["cpu"] <= 0 or node["memory"] <= 0:
+            raise Unmodelled("a node without cpu or memory")
+        self._tree.setdefault(node["zone"], []).append(node)
+        self._zone_of_node[node["name"]] = node["zone"]
+
+    def _walk_order(self) -> List[dict]:
+        """The zone-interleaved list (``node_tree.go list()``): zones in
+        their order, one node of each in turn, nodes of a zone in the order
+        they joined it."""
         ordered: List[dict] = []
+        total = sum(len(v) for v in self._tree.values())
         depth = 0
-        while len(ordered) < sum(len(v) for v in by_zone.values()):
-            for z in zones:
-                if depth < len(by_zone[z]):
-                    ordered.append(by_zone[z][depth])
+        while len(ordered) < total:
+            for nodes in self._tree.values():
+                if depth < len(nodes):
+                    ordered.append(nodes[depth])
             depth += 1
+        return ordered
+
+    def _rebuild(self) -> None:
+        """The rows anew from the node tree: the walk order, the arrays over
+        it, the sample's size, and every count told again of the pods on the
+        nodes that are there."""
+        zones = list(self._tree)
+        ordered = self._walk_order()
+        was = self.names
         self.names = [n["name"] for n in ordered]
-        if len(set(self.names)) != len(self.names):
-            raise ValueError("duplicate node names")
         self.n = len(ordered)
         self.zones = zones
-        self.zone_of = np.array([zones.index(n["zone"]) for n in ordered])
+        index = {z: i for i, z in enumerate(zones)}
+        self.zone_of = np.array([index[n["zone"]] for n in ordered], np.int64)
         self.n_zones = len(zones)
         self.alloc_cpu = np.array([n["cpu"] for n in ordered], np.int64)
         self.alloc_mem = np.array([n["memory"] for n in ordered], np.int64)
         self.alloc_pods = np.array([n["pods"] for n in ordered], np.int64)
-        if (self.alloc_cpu <= 0).any() or (self.alloc_mem <= 0).any():
-            raise Unmodelled("a node without cpu or memory")
-        z = np.zeros(self.n, np.int64)
-        self.req_cpu, self.req_mem = z.copy(), z.copy()
-        self.nz_cpu, self.nz_mem = z.copy(), z.copy()
-        self.n_pods = z.copy()
-        self.start = 0
         self.to_find = num_feasible_nodes_to_find(self.n)
-        self.placed: Dict[str, tuple] = {}      # pod name -> (row, pod)
-        self._states: Dict[str, object] = {}    # feature key -> its State
-        self._shapes: Dict[int, PodShape] = {}
+        # a pod keeps its node: its row is wherever that node now is, and a
+        # pod whose node has left stays bound there and leaves every count
+        row_of = {name: i for i, name in enumerate(self.names)}
+        here = {}
+        for pod, (row, shape) in self.placed.items():
+            if was[row] in row_of:
+                here[pod] = (row_of[was[row]], shape)
+            else:
+                self._gone[pod] = was[row]
+        self.placed = here
+        rows = np.array([row for row, _ in here.values()], np.int64)
+        shapes = [shape for _, shape in here.values()]
+        for held, field in (("req_cpu", "cpu"), ("req_mem", "memory"),
+                            ("nz_cpu", "nz_cpu"), ("nz_mem", "nz_memory")):
+            summed = np.zeros(self.n, np.int64)
+            np.add.at(summed, rows,
+                      np.array([getattr(s, field) for s in shapes], np.int64))
+            setattr(self, held, summed)
+        self.n_pods = np.bincount(rows, minlength=self.n).astype(np.int64)
+        self._states = {}
+        for key, module in self._modules.items():
+            state = self._states[key] = self.feature_state(key, module)
+            for row, shape in here.values():
+                state.account(row, shape, +1)
+
+    def add_node(self, node: dict) -> None:
+        """A node (one plain description) joins the cluster."""
+        self._join(dict(node))
+        self._rebuild()
+        self._nothing_pending_fits(f"node {node['name']} was added")
+
+    def remove_node(self, name: str) -> None:
+        """A node leaves; the pods on it stay bound and leave every count."""
+        if name not in self._zone_of_node:
+            raise KeyError(f"no node {name} to remove")
+        zone = self._zone_of_node.pop(name)
+        self._tree[zone] = [n for n in self._tree[zone] if n["name"] != name]
+        if not self._tree[zone]:
+            del self._tree[zone]
+        self._rebuild()
+        self._nothing_pending_fits(f"node {name} was removed")
+
+    def _nothing_pending_fits(self, after: str) -> None:
+        seen = set()
+        for pod, shape in self.pending.items():
+            if id(shape) in seen:
+                continue
+            seen.add(id(shape))
+            if self.n and self.feasible(shape).any():
+                raise Unmodelled(
+                    f"pending pod {pod} has a feasible node after {after}: "
+                    f"the retry of a requeued pod is not modelled")
 
     # -- bookkeeping -------------------------------------------------------
 
@@ -196,6 +349,7 @@ class Reference:
         for key, module in modules.items():
             s.features[key] = module.parse(template[key], template)
             if key not in self._states and hasattr(module, "State"):
+                self._modules[key] = module
                 state = self._states[key] = self.feature_state(key, module)
                 for row, pod in self.placed.values():
                     state.account(row, pod, +1)
@@ -253,18 +407,25 @@ class Reference:
                 total = total + more
         return total
 
-    def schedule(self, name: str, template: dict) -> str:
-        """Place one pod; returns the node's name."""
-        if name in self.placed:
+    def schedule(self, name: str, template: dict,
+                 may_pend: bool = False) -> Optional[str]:
+        """Place one pod; returns the node's name. A pod that finds no
+        feasible node raises ``Unschedulable`` unless it ``may_pend``: then
+        it is held as pending and the answer is ``None``."""
+        if name in self.placed or name in self.pending or name in self._gone:
             raise ValueError(f"pod {name} scheduled twice")
         shape = self._shape(template)
-        start = self.start % self.n
+        start = self.start % self.n if self.n else 0
         ok = self.feasible(shape)
         # the walk: rows start, start+1, ..., n-1, 0, ..., start-1
         walked = np.concatenate((ok[start:], ok[:start]))
         found = np.cumsum(walked)
-        if found[-1] == 0:
-            raise Unschedulable(f"pod {name}: no feasible node")
+        if not self.n or found[-1] == 0:
+            if not may_pend:
+                raise Unschedulable(f"pod {name}: no feasible node")
+            # every node was walked: (start + n) % n, the index stays
+            self.pending[name] = shape
+            return None
         if found[-1] >= self.to_find:
             evaluated = int(np.searchsorted(found, self.to_find)) + 1
         else:
@@ -275,11 +436,22 @@ class Reference:
                   else rows[np.argmax(self.scores(shape, rows))])
         self._account(row, shape, +1)
         self.placed[name] = (row, shape)
+        if self._states:
+            # a pod that landed can admit another only through a feature
+            self._nothing_pending_fits(f"pod {name} was placed")
         return self.names[row]
 
     def delete(self, name: str) -> None:
-        row, shape = self.placed.pop(name)
-        self._account(row, shape, -1)
+        """A pod leaves: a bound one frees its node, a pending one is
+        forgotten, one on a removed node accounts nothing."""
+        if name in self.pending:
+            del self.pending[name]
+        elif name in self._gone:
+            del self._gone[name]
+        else:
+            row, shape = self.placed.pop(name)
+            self._account(row, shape, -1)
+            self._nothing_pending_fits(f"pod {name} was deleted")
 
     def over_allocatable(self) -> List[str]:
         bad = ((self.req_cpu > self.alloc_cpu) | (self.req_mem > self.alloc_mem)
@@ -287,13 +459,49 @@ class Reference:
         return [self.names[i] for i in np.flatnonzero(bad)]
 
 
-def compare(expected: Dict[str, str], got: Dict[str, Optional[str]]) -> dict:
+def replay(ref: Reference, templates: Dict[str, dict], log: Iterable[tuple],
+           may_pend: Iterable[str] = ()) -> Dict[str, Optional[str]]:
+    """``ref`` over what a run did, in the order it did it: the node each
+    pod created must be bound to, ``None`` for one that must stay pending.
+    ``log`` entries, by name: ``("create", pod, group)`` (``templates[group]``
+    is its template; a group named in ``may_pend`` may find no node),
+    ``("delete", pod, None)``, ``("node_add", name, description)``,
+    ``("node_delete", name, None)``. Any other operation is an error."""
+    may_pend = set(may_pend)
+    unknown = may_pend - set(templates)
+    if unknown:
+        raise ValueError(f"may_pend names no template: {sorted(unknown)}")
+    expected: Dict[str, Optional[str]] = {}
+    for op, name, arg in log:
+        if op == "create":
+            expected[name] = ref.schedule(name, templates[arg],
+                                          may_pend=arg in may_pend)
+        elif op == "delete":
+            ref.delete(name)
+        elif op == "node_add":
+            if arg["name"] != name:
+                raise ValueError(f"node_add {name}: described as "
+                                 f"{arg['name']}")
+            ref.add_node(arg)
+        elif op == "node_delete":
+            ref.remove_node(name)
+        else:
+            raise ValueError(f"log operation {op!r} ({name}) is none of "
+                             f"create, delete, node_add, node_delete")
+    return expected
+
+
+def compare(expected: Dict[str, Optional[str]],
+            got: Dict[str, Optional[str]]) -> dict:
     """Every placement equal, every pod bound once: the exact comparison
-    (limit 0 differing placements, 0 unbound, 0 unexpected)."""
+    (limit 0 differing placements, 0 unbound, 0 unexpected). A pod expected
+    to stay pending (``None``) must be unbound in the run: bound anywhere it
+    is a differing placement, and unbound it is not counted as unbound."""
     differ = [(p, n, got.get(p)) for p, n in expected.items()
-              if got.get(p) != n]
-    unbound = [p for p in expected if not got.get(p)]
+              if (got.get(p) or None) != n]
+    unbound = [p for p, n in expected.items() if n and not got.get(p)]
     extra = [p for p in got if p not in expected]
     return {"compared": len(expected), "differing": len(differ),
             "unbound": len(unbound), "unexpected": len(extra),
+            "pending": sum(1 for n in expected.values() if n is None),
             "examples": differ[:3]}

@@ -96,15 +96,15 @@ def read_layer_metrics(bench_dir: str, metrics: list, obs: dict) -> dict:
 
 
 def replay(result: dict, bench_dir: str) -> tuple:
-    """The reference over what the run did, in the order it did it."""
+    """The reference over what the run did, in the order it did it: pods
+    created and deleted, nodes added and removed, by the operation's name
+    (`reference.replay`; an operation it does not know fails the run).
+    `may_pend`, where the driver gives it, names the template groups whose
+    pods may find no node and must then stay unbound."""
     import reference
     ref = reference.Reference(result["nodes"], bench_dir)
-    expected = {}
-    for op, name, group in result["log"]:
-        if op == "create":
-            expected[name] = ref.schedule(name, result["templates"][group])
-        else:
-            ref.delete(name)
+    expected = reference.replay(ref, result["templates"], result["log"],
+                                result.get("may_pend", ()))
     return reference.compare(expected, result["placements"]), \
         ref.over_allocatable()
 
@@ -216,7 +216,9 @@ def main(argv=None) -> int:
     t_ref = time.perf_counter()
     cmp_, over = replay(result, ctx.bench_dir)
     say(f"reference replayed {cmp_['compared']} pods in "
-        f"{time.perf_counter() - t_ref:.2f}s")
+        f"{time.perf_counter() - t_ref:.2f}s"
+        + (f" ({cmp_['pending']} of them expected to stay pending)"
+           if cmp_["pending"] else ""))
     # a program first met inside the window shows as a compile or as a load
     # from the persistent cache: either is set-up that leaked into the window
     compiled = sum(marks["compile_close"][k] - marks["compile_open"][k]

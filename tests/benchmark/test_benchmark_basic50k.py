@@ -42,7 +42,11 @@ LISTED = {
     "host_commit_share", "gc_pause_share", "device_wait_share",
     "hint_hit_rate", "plan_build_share", "loop_unnamed_share",
     "queue_pop_share", "inbox_drain_share", "device_dispatch_share",
-    "kernel_ms_per_batch", "schedule_batch_roofline", "backlog_at_pop_mean"}
+    "kernel_ms_per_batch", "schedule_batch_roofline", "backlog_at_pop_mean",
+    # appended by PR 38: the readers of PRs 35-37
+    "commit_batch_share", "pop_run_share", "kernel_hidden_share",
+    "fetch_tail_ms", "launch_gap_ms", "collector_pause_share",
+    "plan_adopt_share", "cycle_self_share"}
 SEEDS = (7, 3000000019)          # the driver's seeds exceed 32 signed bits
 # BENCHMARK.json at the parent commit (a7e9e14): its sha256, how many
 # entries each list had, and its cells
@@ -206,7 +210,10 @@ def test_rehearsal_of_the_cell_reads_every_listed_metric():
     assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-3000:]
     line = json.loads(proc.stdout.strip().splitlines()[-1])
     assert line["correct"] is True and line["failed"] == 0
-    assert set(line["metrics"]) == LISTED - {"schedule_batch_roofline"}
+    listed = {m["name"] for m in MANIFEST["per_layer"]
+              if CELL in m.get("workloads", ())}
+    assert LISTED - {"schedule_batch_roofline"} <= set(line["metrics"]) \
+        <= listed
     from kubernetes_tpu.models import TPUScheduler
     pods = _config()["rehearse"]["measurePods"]
     assert line["attempted"] % pods == 0 and line["attempted"] >= pods
@@ -278,7 +285,7 @@ def test_what_this_pr_appended_to_the_manifest():
             if w["config"] == CONFIG] == [CELL]
     listed = {m["name"] for m in MANIFEST["per_layer"]
               if CELL in m.get("workloads", ())}
-    assert listed == LISTED
+    assert LISTED <= listed       # a later PR may list the cell under more
     metrics = {m["name"]: m for m in MANIFEST["per_layer"]}
     assert metrics["backlog_at_pop_mean"] == {
         "name": "backlog_at_pop_mean", "unit": "pods", "better": "higher",

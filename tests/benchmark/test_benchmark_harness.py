@@ -88,6 +88,13 @@ def test_rehearsal_prints_the_contracts_line(cell, trace):
         # a share of the chip's roofline needs the chip's peak: a rehearsal
         # reads nothing there (benchmark/peaks.json has no CPU row)
         want = {m for m in want if not m.endswith("_roofline")}
+        # a toy restore that fits the program's 4,096-event journal keeps
+        # the score hint, so every wave is hint-bound (`basic-5k.waves`):
+        # no batch retires, no pop takes a pod, and the two readers of
+        # retired batches find nothing to read
+        waves = [l for l in proc.stdout.splitlines() if "] wave " in l]
+        if waves and all(" batches 0 " in l for l in waves):
+            want -= {"commit_batch_share", "pop_run_share"}
         assert dev["window_s"] > 0 and "busy_s" in dev
         assert set(line["breakdown"]) == {"device_ops", "idle_gaps"}
         assert all(len(v) <= 10 for v in line["breakdown"].values())
@@ -326,12 +333,13 @@ def test_manifest_keeps_to_the_contract():
         if m["name"].endswith("_roofline"):
             assert m["unit"] == "%"
         layers.add(m["layer"])
+    cells = [w["name"] for w in MANIFEST["workloads"]]
     for m in MANIFEST["end_to_end"] + MANIFEST["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"])
         assert m["better"] in ("lower", "higher")
         for w in m.get("workloads", ()):
-            assert w in CELLS
-    for cell in CELLS:
+            assert w in cells
+    for cell in cells:
         assert len(_expected_metrics(cell, "end_to_end")) >= 2
         assert _expected_metrics(cell, "per_layer")
     with open(os.path.join(ROOT, "PERF.md")) as f:

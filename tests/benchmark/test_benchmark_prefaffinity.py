@@ -43,12 +43,17 @@ PREF_LISTED = {
     "kernel_ms_per_batch",
     "host_commit_share", "gc_pause_share", "device_wait_share",
     "plan_build_share", "hint_hit_rate", "queue_pop_share",
-    "inbox_drain_share", "loop_unnamed_share", "device_dispatch_share"}
+    "inbox_drain_share", "loop_unnamed_share", "device_dispatch_share",
+    # appended by PR 38: the readers of PRs 35-37
+    "commit_batch_share", "pop_run_share", "kernel_hidden_share",
+    "fetch_tail_ms", "launch_gap_ms", "scan_step_us",
+    "collector_pause_share", "plan_adopt_share", "cycle_self_share"}
 OPEN_LISTED = {
     "generator_lag_p99_ms", "bind_tail_p99_ms", "sched_e2e_p99_ms",
     "hint_hit_rate.open", "queue_wait_p99_ms", "bind_post_p99_ms",
     "loop_idle_share.open", "gc_pause_share.open",
-    "device_batch_pods_mean.open", "kernel_ms_per_batch.open"}
+    "device_batch_pods_mean.open", "kernel_ms_per_batch.open",
+    "inbox_oldest_wait_p50_ms.open"}              # appended by PR 38
 SEEDS = (7, 3000000019)          # the driver's seeds exceed 32 signed bits
 # BENCHMARK.json at the parent commit (e4e3f1e): its sha256, how many
 # entries each list had, and its cells
@@ -297,8 +302,8 @@ def test_rehearsal_of_the_prefaffinity_cell_reads_every_listed_metric():
     assert line["correct"] is True and line["failed"] == 0
     # every reader PR 31 listed the cell under returns a number, but the
     # share of the chip's roofline, which needs the chip: None in a rehearsal
-    assert PREF_LISTED == _listed(PREF_CELL)
-    assert PREF_LISTED - {"ipa_scan_roofline"} == set(line["metrics"])
+    assert PREF_LISTED - {"ipa_scan_roofline"} <= set(line["metrics"]) \
+        <= _listed(PREF_CELL)
     assert line["metrics"]["ipa_score_share"]["value"] > 0
     # the normalising scan placed every batch of the traced waves
     assert line["metrics"]["scan_normalised_share"]["value"] == 100.0
@@ -314,8 +319,7 @@ def test_rehearsal_of_the_served_open_spread_cell():
     line, out = _run(["--workload", OPEN_CELL, "--seed", "12",
                       "--seconds", "1", "--trace", "1", "--rehearse"])
     assert line["correct"] is True and line["failed"] == 0
-    assert OPEN_LISTED == _listed(OPEN_CELL)
-    assert OPEN_LISTED == set(line["metrics"])
+    assert OPEN_LISTED <= set(line["metrics"]) <= _listed(OPEN_CELL)
     assert line["metrics"]["kernel_ms_per_batch.open"]["value"] > 0
     # no hint can bind a hard-spread pod: the chip placed every arrival
     assert line["metrics"]["hint_hit_rate.open"]["value"] == 0
@@ -511,12 +515,17 @@ def test_what_this_pr_appended_to_the_manifest():
     assert entry["reduced"] == [] and entry["file"] == (
         "benchmark/configs/prefaffinity-5k.json")
     metrics = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert metrics["ipa_score_share"]["workloads"] == [PREF_CELL]
-    assert metrics["ipa_scan_roofline"]["workloads"] == [PREF_CELL]
-    assert metrics["device_batch_pods_mean.open"]["workloads"] == [OPEN_CELL]
-    assert metrics["kernel_ms_per_batch.open"]["workloads"] == [OPEN_CELL]
+    # the cells listed under at least what is named here, and this PR's own
+    # metrics with its cell first: a later PR may append to either
+    assert PREF_LISTED <= _listed(PREF_CELL)
+    assert OPEN_LISTED <= _listed(OPEN_CELL)
+    assert metrics["ipa_score_share"]["workloads"][:1] == [PREF_CELL]
+    assert metrics["ipa_scan_roofline"]["workloads"][:1] == [PREF_CELL]
+    assert metrics["device_batch_pods_mean.open"]["workloads"][:1] == [
+        OPEN_CELL]
+    assert metrics["kernel_ms_per_batch.open"]["workloads"][:1] == [OPEN_CELL]
     assert metrics["kernel_ms_per_batch.open"]["moves"] == "bind_p50_ms"
-    assert metrics["scan_normalised_share"]["workloads"] == [PREF_CELL]
+    assert metrics["scan_normalised_share"]["workloads"][:1] == [PREF_CELL]
     assert metrics["ipa_scan_roofline"]["unit"] == "%"
     reports = {}
     for e in MANIFEST["end_to_end"]:

@@ -286,21 +286,30 @@ def test_the_recordings_batches_join_by_seq(recorded):
 
 # -- the cells the metrics are listed under, rehearsed -----------------------
 
+# PR 36's five cells, and the three that PR 38 appended to the eight lists
 LISTED = {
     "spread-5k.waves": set(NEW[:7]),
     "antiaffinity-5k.waves": set(NEW[:7]) - {"scan_step_us"},
     "basic-5k.waves": set(NEW[4:7]),
     "basic-5k.served-waves": set(NEW[4:7]),
     "basic-5k.served-open": {NEW[7]},
+    "prefaffinity-5k.waves": set(NEW[:7]),
+    "basic-5k-50k.waves": set(NEW[:7]) - {"scan_step_us"},
+    "spread-5k.served-open": {NEW[7]},
 }
 
 
 def test_the_manifest_lists_the_eight_where_the_issue_says():
+    """The eight in PR 36's order from wherever the first stands, each
+    listing at least the cells named here: what a later PR appends after
+    them, an entry or a cell's name, is not this test's to hold."""
     entries = {m["name"]: m for m in MANIFEST["per_layer"]}
-    assert [m["name"] for m in MANIFEST["per_layer"]][-8:] == list(NEW)
+    order = [m["name"] for m in MANIFEST["per_layer"]]
+    first = order.index(NEW[0])
+    assert order[first:first + len(NEW)] == list(NEW)
     for name in NEW:
         cells = {c for c, names in LISTED.items() if name in names}
-        assert set(entries[name]["workloads"]) == cells, name
+        assert cells <= set(entries[name]["workloads"]), name
     assert entries["scan_step_us"]["layer"] == "kernels"
     assert entries["plan_adopt_share"]["layer"] == "feature build and mirror"
 
