@@ -240,6 +240,14 @@ class SchedulerMetrics:
             "observation a drain. It lies before queue admission, so the "
             "e2e histogram does not hold it.",
             buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
+        self.cluster_event_wait = r(Histogram(
+            "scheduler_cluster_event_wait_seconds",
+            "Stage inbox.wait of one event: how long a cluster event that "
+            "another thread parked (kind=node: a node added, updated or "
+            "deleted) had waited when the loop replayed it; one observation "
+            "an event, where the oldest-wait series has one a drain and "
+            "mostly meets pods.", ("kind",),
+            buckets=DURATION_BUCKETS + (32.768, 65.536, 131.072)))
         self.bind_requests = r(Counter(
             "scheduler_bind_requests_total",
             "Binding requests sent to the apiserver: single (one pod: an "
@@ -388,6 +396,15 @@ class SchedulerMetrics:
             "a mesh 'full' tears down and re-uploads the whole sharded "
             "state, the cost the delta patches exist to avoid.",
             ("kind", "plane")))
+        self.plan_rebuild_cause = r(Counter(
+            "scheduler_plan_rebuild_cause_total",
+            "Full plan rebuilds by what made them full: 'first' (no plan "
+            "kept to resume), 'other_pod' (the kept plan is another "
+            "template's, profile's or attempt count's), 'journal_overrun' "
+            "(more events since it than the journal retains), 'structural' "
+            "(a node added or removed since), 'unpatchable' (an event of "
+            "another kind no row patch covers), 'patch_failed'.",
+            ("cause",)))
         self.plan_ipa_terms = r(Counter(
             "scheduler_plan_ipa_terms_total",
             "What the required inter-pod term tables of the plans built "

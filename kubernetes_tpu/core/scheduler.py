@@ -184,6 +184,14 @@ class Handle:
             return None
         return fn(fw, state, pod, node_to_status, num_candidates, start)
 
+    def say_stage(self, name: str, **stats) -> None:
+        """Stats onto the loop stage ``name`` if that is the one open around
+        the caller and somebody listens (``StageLedger.heard``: the
+        ``postfilter.preempt`` of the attempt a plugin is running under)."""
+        st = self._scheduler.stages.heard(name)
+        if st is not None:
+            st.say(**stats)
+
     def on_async_bind_done(self, pod, acked_at: float) -> None:
         """Async dispatcher bind acknowledged (loop thread, from the done
         inbox): settle the pod as of ``acked_at`` (time.perf_counter)."""
@@ -517,6 +525,12 @@ class Scheduler:
         # are atomic under the GIL, so no lock is needed.
         from collections import deque
         self._event_inbox = deque()
+        # Parked cluster events (`cluster_events_parked`): parks counted by
+        # the threads that park, under their lock; replays by the loop.
+        self._cluster_parks = 0
+        self._cluster_replays = 0
+        self._cluster_park_lock = threading.Lock()
+        self._cluster_replay_fns = set()
         # When the oldest event now parked was parked (perf_counter; 0.0:
         # nothing stamped): the park that finds the inbox empty stamps it,
         # the drain reads it and takes it away (stage inbox.wait).
@@ -531,7 +545,7 @@ class Scheduler:
     def _wire_event_handlers(self) -> None:
         self.clientset.on_pod_event(self._pod_events())
         self.clientset.on_node_event(self._threaded(
-            self._timed_event("node", self._on_node_event)))
+            self._timed_event("node", self._on_node_event), clocked="node"))
         self.clientset.on_namespace_event(self._threaded(self._bump(
             self.cache.add_namespace, EV_NAMESPACE,
             keyfn=lambda ns: ns.name)))
@@ -569,7 +583,7 @@ class Scheduler:
             handler(*args)
         return h
 
-    def _threaded(self, handler):
+    def _threaded(self, handler, clocked: str = ""):
         """Watch events raised off the scheduling thread (e.g. the thread-mode
         dispatcher's bind fanning out through the clientset) are parked in an
         inbox and replayed by the scheduling loop — the DeltaFIFO seam
@@ -578,7 +592,13 @@ class Scheduler:
         synchronous semantics tests rely on. The park that finds the inbox
         empty stamps the clock: that event is the oldest the next drain
         meets (a park that loses the race with a drain between its look and
-        its append leaves no stamp, and that drain observes nothing)."""
+        its append leaves no stamp, and that drain observes nothing).
+        ``clocked`` names a kind whose every parked event carries its own
+        park time and observes its wait as it is replayed
+        (``scheduler_cluster_event_wait_seconds{kind}``): the few events
+        that move the cluster under the pods, not the pods. They are
+        counted while they are parked (``cluster_events_parked``), and a
+        drain that is asked to hold them stops in front of the first."""
         loop_ident = threading.get_ident()  # get_ident beats current_thread
         inbox = self._event_inbox
 
@@ -589,7 +609,37 @@ class Scheduler:
                 if not inbox:
                     self._inbox_oldest_at = time.perf_counter()
                 inbox.append((handler, args))
-        return dispatch
+        if not clocked:
+            return dispatch
+        observe = self.metrics.cluster_event_wait.observe
+
+        def replay(parked_at, *args):
+            self._cluster_replays += 1
+            observe(time.perf_counter() - parked_at, clocked)
+            handler(*args)
+
+        self._cluster_replay_fns.add(replay)
+
+        def dispatch_clocked(*args):
+            if threading.get_ident() == loop_ident:
+                handler(*args)
+            else:
+                with self._cluster_park_lock:  # parkers only; the loop
+                    self._cluster_parks += 1   # writes the other count
+                now = time.perf_counter()
+                if not inbox:
+                    self._inbox_oldest_at = now
+                inbox.append((replay, (now,) + args))
+        return dispatch_clocked
+
+    @property
+    def cluster_events_parked(self) -> int:
+        """Cluster events (``_threaded``'s clocked kinds: a node added,
+        updated or deleted) that another thread has parked and the loop has
+        not replayed yet. A device session that finds one stops refilling,
+        retires what is in flight and ends, so that the next turn replays
+        the event with an empty pipeline (models/tpu_scheduler.py)."""
+        return self._cluster_parks - self._cluster_replays
 
     def _pod_events(self):
         """The pod handler the clientset is given: ``_threaded`` over
@@ -618,9 +668,16 @@ class Scheduler:
             confirms.append(clock() - t0)
         return dispatch
 
-    def drain_event_inbox(self) -> int:
-        """Replay off-thread watch events on the scheduling loop."""
-        if not self._event_inbox:
+    def drain_event_inbox(self, hold_cluster_events: bool = False) -> int:
+        """Replay off-thread watch events on the scheduling loop.
+        ``hold_cluster_events`` (a device session's drain, batches in
+        flight): stop in front of the first parked cluster event and leave
+        it, and what was parked behind it, for the turn's own drain."""
+        inbox = self._event_inbox
+        if not inbox:
+            return 0
+        held = self._cluster_replay_fns if hold_cluster_events else ()
+        if held and inbox[0][0] in held:
             return 0
         parked_at, self._inbox_oldest_at = self._inbox_oldest_at, 0.0
         if parked_at:
@@ -628,9 +685,12 @@ class Scheduler:
                 time.perf_counter() - parked_at)
         n = 0
         with self.stages.stage("inbox.drain"):
-            while self._event_inbox:
+            while inbox:
+                if held and inbox[0][0] in held:
+                    self._inbox_oldest_at = inbox[0][1][0]  # its own park
+                    break
                 try:
-                    handler, args = self._event_inbox.popleft()
+                    handler, args = inbox.popleft()
                 except IndexError:
                     break
                 handler(*args)
@@ -1169,8 +1229,11 @@ class Scheduler:
         pod = qpi.pod
         if fw.post_filter_plugins:
             _t = time.perf_counter()
-            result, post_st = fw.run_post_filter_plugins(
-                state, pod, fe.diagnosis.node_to_status)
+            # The stage of a failed attempt's PostFilter: what runs under it
+            # says the rest (Handle.say_stage: the dry run's engine, its parts).
+            with self.stages.stage("postfilter.preempt"):
+                result, post_st = fw.run_post_filter_plugins(
+                    state, pod, fe.diagnosis.node_to_status)
             self._observe_point("PostFilter", _t, post_st.is_success())
             nominated = getattr(result, "nominating_info", None) if result else None
             if post_st.is_success() and nominated:

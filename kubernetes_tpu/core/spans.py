@@ -78,11 +78,12 @@ TRACE_HEADER = "X-Trace-Context"
 STAGES = (
     "queue.admission",   # pod entered this scheduler's queue (event)
     "queue.wait",        # admission → pop
-    "plan.build",        # session plan acquisition (attrs: kind=full|delta|resume)
+    "plan.build",        # session plan acquisition (attrs: kind=full|delta|resume, cause)
     "device.dispatch",   # kernel dispatch enqueue (attrs: batch, engine)
     "device.wait",       # blocked on the device result fetch
     "host.commit",       # assume/reserve/permit/bind host tail
     "bind.post",         # binding POST leaves the scheduler (attrs: bulk)
+    "postfilter.preempt",  # PostFilter of a failed attempt (attrs: engine, parts)
     "api.bind",          # apiserver binding subresource commit
     "wal.append",        # durable WAL append of the BOUND event
     "bound.fanout",      # BOUND event fanout to watch streams
@@ -110,7 +111,7 @@ LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
                "hint.validate", "plan.build", "plan.ipa", "plan.ipa_score",
                "plan.patch", "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
-               "loop.idle", "gc.settle", "gc.pause")
+               "postfilter.preempt", "loop.idle", "gc.settle", "gc.pause")
 # A bound pod's minimal complete chain. Device stages are optional (host-
 # path pods legitimately skip them); observe spans prove the fanout landed.
 CORE_CHAIN = ("queue.wait", "host.commit", "bind.post", "api.bind",
@@ -467,6 +468,18 @@ class StageLedger:
     def stage(self, name: str, ctxs: Sequence[SpanContext] = (),
               point: str = "", annotate: bool = True, **attrs) -> _Stage:
         return _Stage(self, name, ctxs, point, annotate, attrs)
+
+    def heard(self, name: str) -> Optional[_Stage]:
+        """The innermost open stage, for code that runs under a stage
+        somebody else opened (the dry run under PostFilter's) and has stats
+        for it: that stage if it is ``name`` and a profiler or a recorder
+        holds its annotation, else None, and the caller takes no clocks for
+        stats that nobody reads or that would land on another stage."""
+        if self._stack:
+            st = self._stack[-1]
+            if st.name == name and st._ann is not None:
+                return st
+        return None
 
     def leaf(self, name: str, seconds: float, per_pod: bool = True,
              **attrs) -> None:

@@ -153,6 +153,10 @@ class TPUScheduler(Scheduler):
         self.plan_rebuilds_full = 0
         self.plan_rebuilds_delta = 0
         self.plan_rebuilds_resume = 0
+        # Why the last plan acquisition was a full rebuild ("" where it was
+        # none): plan.build and plan.adopt say it (`cause`), and
+        # scheduler_plan_rebuild_cause_total counts it.
+        self.plan_build_cause = ""
         self.delta_dirty_rows = 0
         # Stacked placement evaluations that ran on device (one per group
         # cycle whose candidate set was kernel-evaluated).
@@ -495,9 +499,11 @@ class TPUScheduler(Scheduler):
         # targets plain-pod namespace sweeps, not group entities.
         stages = self.stages
         with stages.stage("plan.build") as st:
-            state, plan, carry, node_names, st.attrs["kind"] = \
+            state, plan, carry, node_names, kind = \
                 self._resume_or_rebuild(fw, first.members[0].pod, sig, None,
                                         aux_shape, claims_rv)
+            st.say(kind=kind, cause=self.plan_build_cause)
+        built = {"kind": kind, "cause": self.plan_build_cause}
         sd = _SessionDelta(state, carry, self.cluster_event_seq)
         del state, carry
         start_unwinds = self.state_unwinds
@@ -623,7 +629,7 @@ class TPUScheduler(Scheduler):
             if pack in pending:
                 pending.remove(pack)
 
-        with stages.stage("plan.adopt"):
+        with stages.stage("plan.adopt", **built):  # its session's build
             self.cache.update_snapshot(self.snapshot)
             dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
             if invalidated:
@@ -997,6 +1003,12 @@ class TPUScheduler(Scheduler):
 
     def _device_dry_run_preemption(self, fw: Framework, pod, node_to_status,
                                    num_candidates: int, start: int):
+        # The parts are clocked only for a postfilter.preempt stage that is
+        # open around this call and listened to (StageLedger.heard); for
+        # nobody, every reading is float(), 0.0.
+        st = self.stages.heard("postfilter.preempt")
+        clock = _time.perf_counter if st is not None else float
+        t0 = clock()
         self.cache.update_snapshot(self.snapshot)
         nodes = self.snapshot.node_info_list
         if any(ni.pods_with_required_anti_affinity for ni in nodes):
@@ -1009,6 +1021,7 @@ class TPUScheduler(Scheduler):
         if built is None:
             return None
         vic_req, vic_valid, potential = built
+        t_victims = clock()
         dstate, plan = self.build_plan(fw, pod, 1)
         if vic_req.shape[2] != self.mirror.r_slots:
             # build_plan interned the preemptor's never-seen scalar slots
@@ -1028,9 +1041,22 @@ class TPUScheduler(Scheduler):
         from ..core.framework import UNSCHEDULABLE_AND_UNRESOLVABLE
         from ..ops.kernel import dry_run_preemption
         from ..plugins.preemption import Candidate
-        res = np.asarray(dry_run_preemption(
+        t_plan = clock()
+        on_device = dry_run_preemption(
             dstate, plan.features, jnp.asarray(vic_req),
-            jnp.asarray(vic_valid), vic_valid.shape[1]))
+            jnp.asarray(vic_valid), vic_valid.shape[1])
+        t_dispatch = clock()
+        res = np.asarray(on_device)
+        t_fetch = clock()
+        if st is not None:
+            # the stage's parts, and the shapes the kernel's cost is reckoned
+            # from (rows, victim slots, resource slots)
+            st.say(victims_ms=round(1e3 * (t_victims - t0), 3),
+                   plan_ms=round(1e3 * (t_plan - t_victims), 3),
+                   dispatch_ms=round(1e3 * (t_dispatch - t_plan), 3),
+                   fetch_ms=round(1e3 * (t_fetch - t_dispatch), 3),
+                   rows=int(vic_valid.shape[0]), k=int(vic_valid.shape[1]),
+                   r=int(vic_req.shape[2]))
         self.preemption_device_evals += 1
         self._note_device_success()
         feasible, vmask = res[:, 0], res[:, 1:]
@@ -1712,12 +1738,15 @@ class TPUScheduler(Scheduler):
         """Session-start plan acquisition: exact/neutral resume, journal
         delta patch, or full rebuild. Returns (state, plan, carry,
         node_names, kind)."""
+        from ..core.cache import EV_STRUCTURAL
         carry = None
         resume, self._resume = self._resume, None
         kind = "full"
+        cause = "first"  # why a full rebuild is one, if this is
         state = plan = node_names = None
         _t_hint = _time.perf_counter()
         if resume is not None:
+            cause = "other_pod"
             rkey, rseq, payload, rnom = resume
             sig_ok = (rkey[1] == sig) if rkey[0] == "exact" else (
                 nsig is not None and rkey[1] == nsig)
@@ -1740,6 +1769,14 @@ class TPUScheduler(Scheduler):
                         if patched is not None:
                             state, carry = patched
                             kind = "delta"
+                        else:
+                            cause = "patch_failed"
+                    elif events is None:
+                        cause = "journal_overrun"
+                    elif any(ev.kind == EV_STRUCTURAL for ev in events):
+                        cause = "structural"
+                    else:
+                        cause = "unpatchable"
                 if kind == "full":
                     carry = None
         # get_node_hint_duration (runtime/batch.go GetNodeHint analogue):
@@ -1749,6 +1786,8 @@ class TPUScheduler(Scheduler):
         if kind == "full":
             state, plan = self.build_plan(fw, head_pod, self.max_batch)
             node_names = [ni.name for ni in self.snapshot.node_info_list]
+            self.metrics.plan_rebuild_cause.inc(cause)
+        self.plan_build_cause = cause if kind == "full" else ""
         self._count_rebuild(kind)
         return state, plan, carry, node_names, kind
 
@@ -2021,9 +2060,13 @@ class TPUScheduler(Scheduler):
         # tagged with the acquisition kind (full/delta/resume).
         with stages.stage("plan.build", first_batch.sampled, "DevicePlan",
                           batch=len(first_batch)) as st:
-            state, plan, carry, node_names, st.attrs["kind"] = \
+            state, plan, carry, node_names, kind = \
                 self._resume_or_rebuild(fw, first_batch[0].pod, sig, nsig,
                                         aux_shape, claims_rv)
+            # said, not only kept: a profiler trace holds them as the
+            # event's stats, beside `batch`
+            st.say(kind=kind, cause=self.plan_build_cause)
+        built = {"kind": kind, "cause": self.plan_build_cause}
         sd = _SessionDelta(state, carry, self.cluster_event_seq)
         del state, carry
         start_unwinds = self.state_unwinds
@@ -2046,6 +2089,15 @@ class TPUScheduler(Scheduler):
                         invalidated = True
                         break
                 if batch is None:
+                    if self.cluster_events_parked:
+                        # A node was added, changed or removed under the
+                        # backlog: no refill. What is in flight retires and
+                        # the session ends as one that ran dry (adopted,
+                        # resumable), so the turn that follows replays the
+                        # event with an empty pipeline. It used to wait
+                        # for the whole backlog, and the batch in flight
+                        # when it was seen took the host path.
+                        break
                     with self._pop_stage() as took:
                         batch = self._collect_session_batch(
                             fw, sig, took) or None
@@ -2053,10 +2105,12 @@ class TPUScheduler(Scheduler):
                         # A concurrent client (threaded watch feed) may have
                         # parked pod-add events while this session ran: drain
                         # them HERE so a creation burst doesn't end the
-                        # session early. Cluster-state events patch the live
-                        # plan+carry when the journal classifies them, and
-                        # invalidate exactly as before when it can't.
-                        self.drain_event_inbox()
+                        # session early, up to the first parked cluster
+                        # event (held for the next turn, as above). Events
+                        # raised on this thread patch the live plan+carry
+                        # when the journal classifies them, and invalidate
+                        # exactly as before when it can't.
+                        self.drain_event_inbox(hold_cluster_events=True)
                         if not self._note_session_events(
                                 sd, plan, node_names, busy=bool(inflight)):
                             invalidated = True
@@ -2136,7 +2190,7 @@ class TPUScheduler(Scheduler):
             if batch in pending:
                 pending.remove(batch)
 
-        with stages.stage("plan.adopt"):
+        with stages.stage("plan.adopt", **built):  # its session's build
             self.cache.update_snapshot(self.snapshot)
             dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
             if invalidated:

@@ -18,6 +18,7 @@ loop later without changing this control flow.
 
 from __future__ import annotations
 
+import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -58,6 +59,14 @@ class Evaluator:
         self._offset = 0  # rotating start, GetOffsetAndNumCandidates
         self._last_start = None  # start used by the most recent dry run
         self.last_from_device = False  # candidates came from the kernel
+
+    def say_stage(self, **stats) -> None:
+        """What the evaluation was, onto the scheduler's ``postfilter.preempt``
+        stage where that is the stage open around it (a handle without one
+        says nothing)."""
+        say = getattr(self.handle, "say_stage", None)
+        if say is not None:
+            say("postfilter.preempt", **stats)
 
     # -- eligibility (default_preemption.go PodEligibleToPreemptOthers) ----
 
@@ -166,7 +175,9 @@ class Evaluator:
                                   num_candidates, start)
                 if cands is not None:
                     self.last_from_device = True
+                    self.say_stage(engine="device", candidates=len(cands))
                     return cands
+        _t_host = time.perf_counter()
         candidates: List[Candidate] = []
         for i in range(n):
             ni = nodes[(start + i) % n]
@@ -180,6 +191,8 @@ class Evaluator:
                 candidates.append(cand)
                 if len(candidates) >= num_candidates:
                     break
+        self.say_stage(engine="host", candidates=len(candidates),
+                       host_ms=round(1e3 * (time.perf_counter() - _t_host), 3))
         return candidates
 
     # -- selection (preemption.go pickOneNodeForPreemption) ----------------
@@ -228,8 +241,7 @@ class Evaluator:
         def _delete(p):
             # preemption_goroutines_* (executor.go:171 prepareCandidateAsync
             # analogue): each victim deletion is one unit of async work.
-            import time as _time
-            _t0 = _time.perf_counter()
+            _t0 = time.perf_counter()
             try:
                 cs.delete_pod(p)
             except Exception:
@@ -239,7 +251,7 @@ class Evaluator:
             if metrics is not None:
                 metrics.preemption_goroutines_execution_total.inc("success")
                 metrics.preemption_goroutines_duration.observe(
-                    _time.perf_counter() - _t0)
+                    time.perf_counter() - _t0)
 
         for pi in cand.victims:
             if dispatcher is not None and async_ok:
@@ -339,16 +351,16 @@ class DefaultPreemption:
         snapshot = self.handle.snapshot() if callable(self.handle.snapshot) else self.handle.snapshot
         ok, msg = self.evaluator.pod_eligible(pod, snapshot)
         if not ok:
+            self.evaluator.say_stage(engine="none")
             return None, Status.unresolvable(f"preemption: {msg}")
         metrics = getattr(self.handle, "metrics", None)
         if metrics is not None:
             metrics.preemption_attempts.inc()
-        import time as _time
-        _t_eval = _time.perf_counter()
+        _t_eval = time.perf_counter()
         candidates = self.evaluator.find_candidates(state, pod, filtered_status_map)
         if metrics is not None:
             metrics.preemption_evaluation_duration.observe(
-                _time.perf_counter() - _t_eval)
+                time.perf_counter() - _t_eval)
         if not candidates:
             return None, Status.unresolvable(
                 "preemption: 0/%d nodes are available" % max(1, snapshot.num_nodes()))
@@ -401,11 +413,11 @@ class DefaultPreemption:
                 best = Candidate(node_name=best.node_name,
                                  victims=verified.victims,
                                  num_pdb_violations=best.num_pdb_violations)
-        _t_exec = _time.perf_counter()
+        _t_exec = time.perf_counter()
         self.evaluator.prepare_candidate(best, pod)
         if metrics is not None:
             metrics.preemption_execution_duration.observe(
-                _time.perf_counter() - _t_exec)
+                time.perf_counter() - _t_exec)
             if best.num_pdb_violations:
                 metrics.preemption_pdb_violations.inc(
                     value=best.num_pdb_violations)

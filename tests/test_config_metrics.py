@@ -304,6 +304,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_device_path_breaker_open",
                      "scheduler_plan_rebuild_total",
                      "scheduler_plan_rebuild_dirty_rows_total",
+                     "scheduler_plan_rebuild_cause_total",
                      "scheduler_plan_ipa_terms_total",
                      "scheduler_plan_anti_lane_total",
                      "scheduler_device_batches_total",
@@ -318,6 +319,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_bind_requests_total",
                      "scheduler_bind_request_pods_total",
                      "scheduler_inbox_oldest_wait_seconds",
+                     "scheduler_cluster_event_wait_seconds",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",

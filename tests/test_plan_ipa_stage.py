@@ -286,5 +286,7 @@ def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
                      "batch_pad": 1024, "steps": 3, "seq": seq,
                      "inflight": 0}
                for seq, d in enumerate(dispatches, 1))
-    # what is filled in while the stage is open (a plan's kind) is not there
-    assert ("sched.plan.build", {"batch": 3}) in opened
+    # what a stage says while it is open rides the annotation too: a plan's
+    # kind, and why a full build is one (PR 40)
+    assert ("sched.plan.build", {"batch": 3, "kind": "full",
+                                 "cause": "first"}) in opened
