@@ -482,6 +482,15 @@ class SchedulerMetrics:
             "transition disables hints cluster-wide), 'bind_conflict' "
             "(single-NODE invalidation, the hint survives), "
             "'device_failure'.", ("reason",)))
+        self.hint_sibling_absorbed = r(Counter(
+            "scheduler_hint_sibling_absorbed_total",
+            "Live hints of ANOTHER pod signature that absorbed a clean "
+            "device session at its hint install (two pod templates taking "
+            "turns): 'siblings' = entries kept, 'rows' = node rows whose "
+            "pod state they took from the session's carry and re-evaluated, "
+            "in one array pass. A sibling whose rows are not the session's "
+            "is dropped instead: invalidations, reason 'cross_reencode'.",
+            ("what",)))
         self.hint_validation_duration = r(Histogram(
             "scheduler_hint_validation_duration_seconds",
             "Host-side hint validate+select latency per consulted pod "

@@ -389,6 +389,65 @@ class HintEntry:
         self.ok[row] = (bool(self.static_ok[row]) and fit_ok
                         and not self.blocked[row])
 
+    def _reval_rows(self, rows: slice) -> None:
+        """``_reval_row`` over a run of rows in one pass of int64 array
+        arithmetic: the same integers to the bit (numpy's ``//`` is the
+        floor Python's is; a lane whose guard is false is computed and
+        discarded, never read; int64 as in the kernel and ``from_session``,
+        where the scalar form's Python ints would only part from it past
+        8 TiB of memory on a node). For the caller that holds a session, where
+        ``_reval_row`` is for the one that holds a row: a one-row call of
+        this costs more than the scalar form, so both stay, and
+        tests/test_hint_reval_rows.py holds them to each other."""
+        alloc = self.alloc_r[rows]
+        req_r = self.req_r[rows]
+        request = self.request
+        if self.enable[4] == 0:
+            fit_ok = True
+        else:
+            fit_ok = self.pod_count[rows] + 1 <= self.alloc_pods[rows]
+            if self.has_request != 0:
+                fit_ok &= ~((request > 0)
+                            & (request > alloc - req_r)).any(axis=1)
+        used = self.nonzero[rows] + self.nz_request
+        num_ = den = 0
+        for slot, wj in zip(self.fit_slots.tolist(),
+                            self.fit_weights.tolist()):
+            a = alloc[:, slot]
+            u = used[:, slot] if slot < 2 else req_r[:, slot] + request[slot]
+            held = a > 0
+            if self.fit_strategy == 0:  # LeastAllocated
+                rscore = np.where(
+                    held & (u <= a),
+                    (a - u) * MAX_NODE_SCORE // np.maximum(a, 1), 0)
+            else:  # MostAllocated
+                rscore = np.where(
+                    held,
+                    np.minimum(u, a) * MAX_NODE_SCORE // np.maximum(a, 1), 0)
+            num_ = num_ + rscore * wj
+            den = den + held * wj
+        fit_sc = np.where(den > 0, num_ // np.maximum(den, 1), 0)
+        if self.ba_skip == 1:
+            ba = 0
+        else:
+            a_cpu, a_mem = alloc[:, 0], alloc[:, 1]
+            q_cpu = np.minimum(
+                used[:, 0] * _BA_SCALE // np.maximum(a_cpu, 1), _BA_SCALE)
+            q_mem = np.minimum(
+                used[:, 1] * _BA_SCALE // np.maximum(a_mem, 1), _BA_SCALE)
+            ba = np.where(
+                (a_cpu > 0) & (a_mem > 0),
+                (MAX_NODE_SCORE * _BA_SCALE
+                 - 50 * np.abs(q_cpu - q_mem)) // _BA_SCALE,
+                MAX_NODE_SCORE)
+        self.fit_ok[rows] = fit_ok
+        self.fit_sc[rows] = fit_sc
+        self.ba[rows] = ba
+        self.total[rows] = (self.w_tt * MAX_NODE_SCORE + self.w_fit * fit_sc
+                            + self.w_ba * ba
+                            + self.w_il * self.il_score[rows])
+        self.ok[rows] = self.static_ok[rows] & fit_ok & ~self.blocked[rows]
+
     # -- event-driven freshness (the journal replay) ------------------------
 
     def block_row(self, node: str) -> bool:
@@ -438,18 +497,35 @@ class HintEntry:
         self._pending = []  # a row moved outside the walk: re-segment
         return None
 
-    def resync_rows(self, cache) -> Optional[str]:
-        """Re-encode EVERY row's dynamic pod state from cache truth: a
-        device session this entry did not watch just committed placements
-        (own binds are journal-benign, so there is no event stream to
-        replay). One full pass of the journal pod re-encode, blocked rows
-        kept blocked. O(rows) host work — paid once per install, only when
-        a sibling entry survives, never on the single-shape steady state."""
-        for name in self.node_names:
-            reason = self._reencode_pod_row(cache, name, unblock=False)
-            if reason:
-                return reason
-        return None
+    def resync_rows(self, fresh: "HintEntry") -> bool:
+        """Absorb a device session this entry did not watch (own binds are
+        journal-benign, so there is no event stream to replay): take EVERY
+        row's dynamic pod state from `fresh`, the entry captured from that
+        session's final carry, and re-evaluate every row once
+        (``_reval_rows``). The session ended clean, so the carry's
+        req_r/nonzero/pod_count ARE cache truth for every row, the ones it
+        placed on and the ones it left alone: what ``fresh`` itself serves
+        from. This entry keeps its own alloc_r and static verdicts (the
+        journal re-validates a row of those at its next serve) and its
+        blocked rows (a 409 block must outlive a sibling's session).
+
+        Three lane copies and one array evaluation, whatever the session
+        placed; paid at every clean session end while two pod templates
+        take turns (it was a scalar pass over every row by name, 72 ms of
+        a 93 ms adoption at 5,000 nodes; PERF.md, PR 41). False when this entry's rows are not the session's (a node
+        came or went since it was captured, or a capacity tier grew): the
+        journal's structural record would end it at its next serve anyway,
+        so the caller drops it now."""
+        n = len(self.node_names)
+        if (self.req_r.shape != fresh.req_r.shape
+                or self.node_names != fresh.node_names):
+            return False
+        self.req_r[:n] = fresh.req_r[:n]
+        self.nonzero[:n] = fresh.nonzero[:n]
+        self.pod_count[:n] = fresh.pod_count[:n]
+        self._reval_rows(slice(0, n))
+        self._pending = []  # rows moved outside the walk: re-segment
+        return True
 
     def _revalidate_node_row(self, cache, key: str) -> Optional[str]:
         """EV_NODE_UPDATE: taints/allocatable/unschedulable moved on one
@@ -522,7 +598,10 @@ class ScoreHintCache:
     entry's attempt watermark, and a committed bind re-encodes the landed
     node's row on the non-serving entries from cache truth
     (``note_own_attempt``), so a sibling's placements can never make an
-    entry serve a stale row."""
+    entry serve a stale row. A clean device session of one shape is pushed
+    whole: at its install every entry of another shape takes all rows' pod
+    state from the fresh carry in one array pass (``resync_rows``), or is
+    dropped where its rows are not the session's."""
 
     def __init__(self, sched, enabled: bool = True):
         self.sched = sched
@@ -572,7 +651,8 @@ class ScoreHintCache:
         # the newer truth for that shape); a genuinely new shape pushes the
         # coldest entry out. Surviving siblings ABSORB the device session
         # that just ended — its attempts bump and its committed placements
-        # (re-encoded from cache truth) — or the attempts fence would read
+        # (every row's pod state from the fresh carry, which is cache truth
+        # after a clean end: resync_rows) — or the attempts fence would read
         # every sibling as foreign next serve and alternating shapes would
         # thrash the cache one install per pod. unwinds/nomination fences
         # are deliberately NOT absorbed: a session that moved those leaves
@@ -581,13 +661,21 @@ class ScoreHintCache:
         for x in self.entries:
             if x.keys & e.keys:
                 continue
-            x.attempts = self.sched.attempts
-            if x.resync_rows(self.sched.cache) is None:
+            if x.resync_rows(e):
+                x.attempts = self.sched.attempts
                 kept.append(x)
             else:
                 self.sched.hint_invalidations += 1
                 self.sched.metrics.hint_cache_invalidations.inc(
                     "cross_reencode")
+        rows = sum(len(x.node_names) for x in kept)
+        if kept:
+            absorbed = self.sched.metrics.hint_sibling_absorbed
+            absorbed.inc("siblings", value=len(kept))
+            absorbed.inc("rows", value=rows)
+        st = self.sched.stages.heard("plan.adopt")
+        if st is not None:
+            st.say(siblings=len(kept), sibling_rows=rows)
         self.entries = [e] + kept
         while len(self.entries) > HINT_LRU_SLOTS:
             self._drop(self.entries[-1], "lru_evict")

@@ -1,8 +1,9 @@
 """The tracing a churn wave needs (PR 40): the loop stage
 `postfilter.preempt` around a failed attempt's PostFilter with the dry run's
 engine and parts said on it, the `cause` of a full plan build on `plan.build`
-and on its session's `plan.adopt`, and the wait of a node event that another
-thread parked. No timing is asserted."""
+and on its session's `plan.adopt`, the wait of a node event that another
+thread parked, and (PR 41) the sibling score hints that took a session in at
+its adoption. No timing is asserted."""
 
 import threading
 
@@ -141,6 +142,68 @@ def test_a_full_build_says_why_it_is_full_and_its_adoption_says_it_too():
         assert (f'scheduler_plan_rebuild_cause_total{{cause="{cause}"}} '
                 f'{float(builds_of)}') in text, cause
     assert sched.plan_rebuilds_full == 4
+
+
+def test_an_adoption_says_the_sibling_hints_that_absorbed_its_session():
+    """A churn wave's turns at toy size (PR 41): plain pods, then a node and
+    a pod no node can hold, plain pods, both deleted, plain pods. Two pod
+    templates take turns, so at most session ends the other template's
+    score hint is live: `sched.plan.adopt` says how many such siblings took
+    the session in (`siblings`) and over how many rows (`sibling_rows`),
+    and `scheduler_hint_sibling_absorbed_total` counts the same. A sibling
+    captured on another row set (the node came or went in between) is
+    dropped instead, reason `cross_reencode`; there is no scalar pass left
+    to fall back to, so nothing counts one."""
+    from kubernetes_tpu.models import TPUScheduler
+    sched = TPUScheduler()
+    rec = sched.stages._annotation = StageAnnotations()
+    cs = sched.clientset
+    for i in range(8):
+        cs.create_node(_node(f"n{i}"))
+
+    def plain(prefix):
+        for i in range(4):
+            cs.create_pod(_pod(f"{prefix}{i}"))
+        sched.run_until_idle()
+
+    def said():
+        return [(a.get("siblings"), a.get("sibling_rows"))
+                for name, a in rec.opened if name == "sched.plan.adopt"]
+
+    plain("a")                                  # the only hint: no sibling
+    for step in (1, 3):
+        cs.create_node(_node(f"churn-node-{step}"))
+        cs.create_pod(_pod(f"churn-pod-{step}", cpu="9", priority=10))
+        sched.run_until_idle()                  # its session places nothing
+        assert not cs.pods[f"churn-pod-{step}"].node_name
+        plain(f"b{step}")                       # 9 rows, as the churn pod's
+        cs.delete_node(f"churn-node-{step}")
+        cs.delete_pod(cs.pods[f"churn-pod-{step}"])
+        plain(f"c{step}")                       # 8 rows again
+    assert sched.failures == 2 and sched.host_path_pods == 0
+    assert said() == [
+        (0, 0),             # a
+        (0, 0), (1, 9),     # the churn pod drops a's hint (8 rows); b1 is taken
+        (0, 0),             # c1 drops the churn pod's (9 rows)
+        (0, 0), (1, 9),     # the same, second step
+        (0, 0)]
+    absorbed = sched.metrics.hint_sibling_absorbed
+    assert absorbed.value("siblings") == 2 and absorbed.value("rows") == 18
+    inv = sched.metrics.hint_cache_invalidations
+    assert inv.value("cross_reencode") == 4
+    text = sched.expose_metrics()
+    assert 'scheduler_hint_sibling_absorbed_total{what="rows"} 18.0' in text
+    assert 'scheduler_hint_sibling_absorbed_total{what="siblings"} 2.0' in text
+    assert sorted(absorbed._values) == [("rows",), ("siblings",)]
+    # nobody listens: the series still counts, the stage is told nothing
+    quiet = TPUScheduler()
+    for i in range(8):
+        quiet.clientset.create_node(_node(f"n{i}"))
+    for prefix, cpu in (("a", "100m"), ("b", "200m")):
+        for i in range(4):
+            quiet.clientset.create_pod(_pod(f"{prefix}{i}", cpu=cpu))
+        quiet.run_until_idle()
+    assert quiet.metrics.hint_sibling_absorbed.value("rows") == 8
 
 
 def test_a_parked_node_event_observes_its_own_wait_as_it_is_replayed():

@@ -314,6 +314,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_hint_cache_hits_total",
                      "scheduler_hint_cache_misses_total",
                      "scheduler_hint_cache_invalidations_total",
+                     "scheduler_hint_sibling_absorbed_total",
                      "scheduler_hint_validation_duration_seconds",
                      "scheduler_bind_conflict_total",
                      "scheduler_bind_requests_total",

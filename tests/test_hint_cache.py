@@ -15,6 +15,7 @@ Also here: the requeue_conflict enqueued_at regression (conflict retries
 must not restart the scheduler_e2e_scheduling_duration_seconds clock).
 """
 
+import copy
 import random
 
 import pytest
@@ -44,7 +45,7 @@ def _pod(name, ns="default", cpu="200m", labels=None, anti=None):
     return b.obj()
 
 
-def _pair(n_nodes=24, max_batch=64, oracle_hints=False):
+def _pair(n_nodes=24, max_batch=64, oracle_hints=False, journal_cap=None):
     """(always-dispatch oracle, hint-enabled device scheduler) over
     identical clusters. The oracle is a TPUScheduler with the hint cache
     disabled — the exact code path every pod takes today. mesh=None keeps
@@ -56,6 +57,8 @@ def _pair(n_nodes=24, max_batch=64, oracle_hints=False):
     dev = TPUScheduler(max_batch=max_batch, mesh=None)
     assert dev._hints.enabled
     for s in (oracle, dev):
+        if journal_cap:     # before the first record: a journal never shrinks
+            s.journal.cap = journal_cap
         for i in range(n_nodes):
             s.clientset.create_node(_node(f"node-{i}"))
     return oracle, dev
@@ -705,3 +708,165 @@ class TestHintLru:
         for e in es:
             row = e.row_of["node-3"]
             assert e.blocked[row] and not e.ok[row]
+
+
+_DYNAMIC_LANES = ("req_r", "nonzero", "pod_count")
+_VERDICT_LANES = ("fit_ok", "fit_sc", "ba", "total", "ok")
+
+
+def _old_scalar_pass(entry, cache):
+    """What `HintEntry.resync_rows` did until PR 41, restated: the journal's
+    pod re-encode from cache truth (`NodeInfo.requested`, the pods it
+    holds) on every row by name, a scalar `_reval_row` each, blocked rows
+    kept blocked. On a copy, so the entry under test stays as it is."""
+    want = copy.copy(entry)
+    for lane in _DYNAMIC_LANES + _VERDICT_LANES + ("blocked",):
+        setattr(want, lane, getattr(entry, lane).copy())
+    for name in want.node_names:
+        assert want._reencode_pod_row(cache, name, unblock=False) is None
+    return want
+
+
+def _check_siblings_at_install(dev):
+    """Wrap the hint install: after each, every entry behind the fresh one
+    (a sibling that absorbed the session) is held, lane by lane, to the old
+    scalar pass over cache truth. Returns the list of rows checked."""
+    checked = []
+    install = dev._hints.install
+
+    def checking(*args, **kwargs):
+        install(*args, **kwargs)
+        for x in dev._hints.entries[1:]:
+            want = _old_scalar_pass(x, dev.cache)
+            for lane in _DYNAMIC_LANES + _VERDICT_LANES + ("blocked",):
+                got = getattr(x, lane)
+                assert got.dtype == getattr(want, lane).dtype, lane
+                assert (got == getattr(want, lane)).all(), (
+                    f"sibling lane {lane} is not cache truth after install "
+                    f"{len(checked)}")
+            assert x.attempts == dev.attempts and x._pending == []
+            checked.append(len(x.node_names))
+
+    dev._hints.install = checking
+    return checked
+
+
+class TestSiblingAbsorbsSession:
+    """A clean device session of one template ends while the hint of ANOTHER
+    is live (two Deployments through one queue): the sibling takes every
+    row's pod state from the session's carry and re-evaluates all rows in
+    one array pass (ISSUE 41). It must then serve exactly as one re-encoded
+    row by row from cache truth would."""
+
+    A, B = "200m", "400m"
+
+    def _seed_two(self, n_nodes=32, journal_cap=None):
+        oracle, dev = _pair(n_nodes=n_nodes, journal_cap=journal_cap)
+        checked = _check_siblings_at_install(dev)
+        _both(oracle, dev, lambda s: [s.clientset.create_pod(
+            _pod(f"seed-a-{i}", cpu=self.A)) for i in range(12)])
+        _both(oracle, dev, lambda s: [s.clientset.create_pod(
+            _pod(f"seed-b-{i}", cpu=self.B)) for i in range(12)])
+        assert len(dev._hints.entries) == 2 and checked == [n_nodes]
+        return oracle, dev, checked
+
+    def test_sessions_take_turns_and_the_sibling_serves(self):
+        """Deletes that outrun the journal void the hint that is served
+        (and only that one: `serve` validates the matching entry), so each
+        template's device sessions take turns with the other's hint live."""
+        oracle, dev, checked = self._seed_two(journal_cap=16)
+        hits = {"a": 0, "b": 0}
+        for rnd in range(3):
+            def delete_oldest(s):
+                bound = sorted((p for p in s.clientset.pods.values()
+                                if p.node_name), key=lambda p: p.name)
+                for p in bound[:20]:
+                    s.clientset.delete_pod(p)
+            _both(oracle, dev, delete_oldest)
+            for shape, cpu in (("a", self.A), ("b", self.B)):
+                # the served entry meets the journal's gap: a device session,
+                # and at its end the OTHER template's entry absorbs it
+                b0, n0 = dev.device_batches, len(checked)
+                _both(oracle, dev, lambda s, shape=shape, cpu=cpu: [
+                    s.clientset.create_pod(
+                        _pod(f"r{rnd}-{shape}-{i}", cpu=cpu))
+                    for i in range(10)])
+                assert dev.device_batches == b0 + 1
+                assert len(checked) == n0 + 1, "no sibling absorbed"
+            # B's session just ended: A is the SIBLING now, and serves
+            for shape, cpu in (("a", self.A), ("b", self.B)):
+                b0, h0 = dev.device_batches, dev.hint_hits
+                _both(oracle, dev, lambda s, shape=shape, cpu=cpu: [
+                    s.clientset.create_pod(
+                        _pod(f"h{rnd}-{shape}-{i}", cpu=cpu))
+                    for i in range(6)])
+                assert dev.device_batches == b0, f"{shape} was dispatched"
+                hits[shape] += dev.hint_hits - h0
+            _assert_identical(oracle, dev, ctx=f"(round {rnd})")
+        assert hits == {"a": 18, "b": 18}
+        absorbed = dev.metrics.hint_sibling_absorbed
+        assert absorbed.value("siblings") == len(checked) == 7
+        assert absorbed.value("rows") == sum(checked) == 7 * 32
+        assert dev.metrics.hint_cache_invalidations.value(
+            "cross_reencode") == 0
+
+    @pytest.mark.parametrize("event", ["node_added", "node_removed"])
+    def test_a_sibling_of_another_row_set_is_dropped_at_install(self, event):
+        """A node comes or goes between the sibling's capture and the next
+        session's end: its rows are not the session's, and it is dropped
+        where the scalar pass either kept it for the journal's structural
+        record to end at its next serve (an add) or dropped it too (a
+        remove). Either way it never serves a row again."""
+        oracle, dev, checked = self._seed_two()
+        if event == "node_added":
+            _both(oracle, dev, lambda s: s.clientset.create_node(
+                _node("node-late")))
+        else:
+            _both(oracle, dev, lambda s: s.clientset.delete_node("node-31"))
+        sibling = next(e for e in dev._hints.entries
+                       if e.pod.name.startswith("seed-b"))
+        b0, inv0 = dev.device_batches, dev.hint_invalidations
+        _both(oracle, dev, lambda s: [s.clientset.create_pod(
+            _pod(f"after-a-{i}", cpu=self.A)) for i in range(8)])
+        # A met the structural record at its serve and ran a session; at
+        # its end B's rows (the old cluster's) were not the session's
+        assert dev.device_batches == b0 + 1
+        assert sibling not in dev._hints.entries
+        assert len(dev._hints.entries) == 1 and len(checked) == 1
+        inv = dev.metrics.hint_cache_invalidations
+        assert inv.value("structural") == 1 and inv.value(
+            "cross_reencode") == 1
+        assert dev.hint_invalidations == inv0 + 2
+        # B's next pods are a session's, not the dropped hint's
+        h0 = dev.hint_hits
+        _both(oracle, dev, lambda s: [s.clientset.create_pod(
+            _pod(f"after-b-{i}", cpu=self.B)) for i in range(8)])
+        assert dev.device_batches == b0 + 2
+        assert dev.hint_hits - h0 < 8
+        # ... at whose end A, captured on the same rows, absorbs it
+        assert len(checked) == 2 and len(dev._hints.entries) == 2
+        _both(oracle, dev, lambda s: [s.clientset.create_pod(
+            _pod(f"last-{i}", cpu=(self.A if i % 2 else self.B)))
+            for i in range(12)])
+        assert dev.device_batches == b0 + 2
+        _assert_identical(oracle, dev)
+
+    def test_a_blocked_row_stays_blocked_through_an_absorbed_session(self):
+        _oracle, dev, checked = self._seed_two(journal_cap=16)
+        sibling = next(e for e in dev._hints.entries
+                       if e.pod.name.startswith("seed-b"))
+        node = "node-3"
+        row = sibling.row_of[node]
+        dev._note_bind_conflict("OutOfCapacity", _pod("x"), node)
+        assert sibling.blocked[row] and not sibling.ok[row]
+        # A's hint meets a journal gap, A runs a session, B absorbs it
+        for _ in range(dev.journal.cap + 8):
+            dev._record_event("queue", "x")
+        for i in range(8):
+            dev.clientset.create_pod(_pod(f"after-a-{i}", cpu=self.A))
+        dev.run_until_idle()
+        assert len(checked) == 2 and sibling in dev._hints.entries
+        assert sibling.blocked[row] and not sibling.ok[row]
+        assert sibling.blocked.sum() == 1
+        # and the fresh entry knows of no conflict
+        assert not dev._hints.entries[0].blocked.any()
