@@ -28,6 +28,16 @@ fractions in millionths) and two guarantees on the order of work. Controls
   `CONTROLS`): the feature's state with one guarantee broken, put in its
   place. Found by name: this file names no feature.
 
+- over a log that preempts (`--preemption`, `preemption_log`: the init pods,
+  then the measured pods in 24 parts, before each part one pod of the
+  configuration's `preemptors` or `churn.pod` templates in turn, its victims
+  deleted where the reference evicts them, and after the part its `retry`),
+  the same controls, held to placements, evictions and nominations alike. A
+  pod feature's controls are looked for in EVERY pod template of the
+  configuration, the churn and preemptor templates too, and the plain log
+  creates one pod of each such template, which may pend, a third into the
+  wave.
+
 Reading, NOT a control: `float32`, the score terms in float32 and floored
 where the reference floors. On the uniform clusters (equal nodes, equal
 pods, power-of-two sizes) it places every pod where int64 does, so exact
@@ -38,6 +48,8 @@ several sizes (`tests/benchmark/toy_bench/configs/unequal-toy.json`; PERF.md).
 
     python3 benchmark/control.py --config spread-5k --seeds 11 12 13
     python3 benchmark/control.py --config spread-5k --seeds 11 12 13 --events
+    python3 benchmark/control.py --bench-dir tests/benchmark/toy_bench \
+        --config preempt-toy --seeds 11 12 13 --preemption
     python3 benchmark/control.py --bench-dir tests/benchmark/toy_bench \
         --config unequal-toy --seeds 11 12 13
 
@@ -199,12 +211,40 @@ def _swapped(key: str, broken_state: type) -> type:
     return Swapped
 
 
+def every_template(cfg: dict) -> list:
+    """Every template of the configuration, wherever it stands (init and
+    measured pods, a churn op's objects, preemptors, node groups)."""
+    found = []
+
+    def walk(value):
+        if isinstance(value, dict):
+            if isinstance(value.get("template"), dict):
+                found.append(value["template"])
+            for v in value.values():
+                walk(v)
+        elif isinstance(value, list):
+            for v in value:
+                walk(v)
+
+    walk(cfg)
+    return found
+
+
+def may_pend_templates(cfg: dict) -> list:
+    """The templates of the pods that may find no node: the configuration's
+    `preemptors`, or the pod of its `churn` op."""
+    if "preemptors" in cfg:
+        return [g["template"] for g in objects.groups(cfg, "preemptors")]
+    pod = cfg.get("churn", {}).get("pod", {})
+    return [pod["template"]] if "template" in pod else []
+
+
 def feature_controls(cfg: dict) -> dict:
-    """As `<key>.<name>`, the controls of every pod feature that the
-    configuration's templates use."""
+    """As `<key>.<name>`, the controls of every pod feature that any of the
+    configuration's templates uses."""
     out = {}
-    keys = {k for group in ("initPods", "measurePods")
-            for k in cfg[group]["template"]} - reference.CORE_POD_KEYS
+    keys = ({k for template in every_template(cfg) for k in template}
+            - reference.CORE_POD_KEYS - reference.NODE_KEYS)
     for key in sorted(keys):
         module = features.load("reference", key)
         for name, broken in getattr(module, "CONTROLS", {}).items():
@@ -213,16 +253,35 @@ def feature_controls(cfg: dict) -> dict:
 
 
 def differing(cfg: dict, seed: int, control: type) -> tuple:
-    """(pods compared, placements on which the control differs)."""
+    """(pods compared, placements on which the control differs): the init
+    pods and one wave, and a third into the wave one pod of each template
+    that may pend."""
     nodes = objects.cluster(cfg, seed)
     sound, other = reference.Reference(nodes), control(nodes)
     differ = total = 0
-    for group in ("initPods", "measurePods"):
-        tpl = cfg[group]["template"]
-        for i in range(int(cfg[group]["count"])):
-            name = f"{group}-{i}"
-            differ += sound.schedule(name, tpl) != other.schedule(name, tpl)
-            total += 1
+    died = False        # a control that cannot go on differs from there on
+
+    def place(name, tpl, may_pend=False):
+        nonlocal differ, total, died
+        want = sound.schedule(name, tpl, may_pend)
+        if not died:
+            try:
+                differ += other.schedule(name, tpl, may_pend) != want
+            except (reference.Unschedulable, reference.Unmodelled):
+                died = True
+        differ += died
+        total += 1
+
+    for g, group in enumerate(objects.groups(cfg, "initPods")):
+        for i in range(int(group["count"])):
+            place(f"initPods-{g}-{i}" if g else f"initPods-{i}",
+                  group["template"])
+    per = int(cfg["measurePods"]["count"])
+    for i in range(per):
+        if i == per // 3:
+            for k, tpl in enumerate(may_pend_templates(cfg)):
+                place(f"mayPend-{k}", tpl, may_pend=True)
+        place(f"measurePods-{i}", cfg["measurePods"]["template"])
     return total, differ
 
 
@@ -282,19 +341,83 @@ def event_log(cfg: dict, seed: int) -> dict:
             "may_pend": [PENDING], "placements": placements}
 
 
-def differing_on_events(cfg: dict, seed: int, control: type) -> tuple:
-    """(pods compared, placements on which the control differs) over
-    `event_log`. A control that cannot finish the log (no feasible node for
-    a pod, a pending pod that fits, a node it never had) has failed: every
-    pod counts as differing."""
-    run = event_log(cfg, seed)
-    sound = run["placements"]
+def _held_to(run: dict, sound, control: type) -> tuple:
+    """(pods compared, what differs between `sound` and the control's replay
+    of `run`'s log: placements, evictions, nominations). A control that
+    cannot finish the log (no feasible node for a pod, a pending pod that
+    fits, a node it never had, a retry of a pod it has bound) has failed
+    from there on: every pod it did not reach counts as differing."""
+    broken, other = control(run["nodes"]), reference.Expected()
     try:
-        other = reference.replay(control(run["nodes"]), run["templates"],
-                                 run["log"], run["may_pend"])
-    except (reference.Unschedulable, reference.Unmodelled, KeyError):
-        return len(sound), len(sound)
-    return len(sound), sum(other[p] != node for p, node in sound.items())
+        reference.replay(broken, run["templates"], run["log"],
+                         run["may_pend"], other)
+    except (reference.Unschedulable, reference.Unmodelled, KeyError,
+            ValueError):
+        other.evictions = dict(broken.evicted)
+        other.nominations = dict(broken.nominations)
+    cmp_ = reference.compare(sound, other, other.evictions,
+                             other.nominations)
+    return len(sound), (
+        sum(1 for p, node in sound.items() if other.get(p, p) != node)
+        + cmp_["evictions_differing"] + cmp_["nominations_differing"])
+
+
+def differing_on_events(cfg: dict, seed: int, control: type) -> tuple:
+    """`_held_to` over `event_log`."""
+    run = event_log(cfg, seed)
+    return _held_to(run, run["placements"], control)
+
+
+ROUNDS = 24                # the preemptors of `preemption_log`
+
+
+def preemption_log(cfg: dict, seed: int) -> dict:
+    """A run that preempts, as a driver that deletes victims inside the
+    cycle would hand it to `run.py`, made with the reference itself: the
+    init pods (every group in turn), then the measured pods in `ROUNDS`
+    parts; before each part one pod of the templates that may pend, in
+    turn, and the `delete` of each victim the reference evicts for it;
+    after the part its `retry`."""
+    nodes = objects.cluster(cfg, seed)
+    templates = {"measurePods": cfg["measurePods"]["template"]}
+    ref = reference.Reference(nodes)
+    log = []
+
+    def create(name, which, may_pend=False):
+        log.append(("create", name, which))
+        ref.schedule(name, templates[which], may_pend)
+
+    for g, group in enumerate(objects.groups(cfg, "initPods")):
+        templates[f"initPods-{g}"] = group["template"]
+        for i in range(int(group["count"])):
+            create(f"init-{g}-{i}", f"initPods-{g}")
+    kinds = may_pend_templates(cfg)
+    for k, template in enumerate(kinds):
+        templates[f"preemptor-{k}"] = template
+    per = int(cfg["measurePods"]["count"])
+    for r in range(ROUNDS):
+        which = f"preemptor-{r % len(kinds)}"
+        evicted = set(ref.evicted)
+        create(f"high-{r}", which, may_pend=True)
+        for victim in ref.evicted:
+            if victim not in evicted:
+                log.append(("delete", victim, None))
+                ref.delete(victim)
+        for i in range(r * per // ROUNDS, (r + 1) * per // ROUNDS):
+            create(f"m-{i}", "measurePods")
+        if f"high-{r}" in ref.pending:
+            log.append(("retry", f"high-{r}", None))
+            ref.retry(f"high-{r}")
+    return {"nodes": nodes, "templates": templates, "log": log,
+            "may_pend": [f"preemptor-{k}" for k in range(len(kinds))]}
+
+
+def differing_on_preemption(cfg: dict, seed: int, control: type) -> tuple:
+    """`_held_to` over `preemption_log`."""
+    run = preemption_log(cfg, seed)
+    sound = reference.replay(reference.Reference(run["nodes"]),
+                             run["templates"], run["log"], run["may_pend"])
+    return _held_to(run, sound, control)
 
 
 def main(argv=None) -> int:
@@ -305,6 +428,9 @@ def main(argv=None) -> int:
     ap.add_argument("--events", action="store_true",
                     help="over the log with cluster events, the event "
                          "controls beside the others")
+    ap.add_argument("--preemption", action="store_true",
+                    help="over a log that preempts, held to placements, "
+                         "evictions and nominations")
     ap.add_argument("--bench-dir", default=HERE,
                     help="the directory whose configs/ holds the "
                          "configuration")
@@ -316,6 +442,8 @@ def main(argv=None) -> int:
     count = differing
     if args.events:
         controls, count = {**controls, **EVENT_CONTROLS}, differing_on_events
+    elif args.preemption:
+        count = differing_on_preemption
     held = set(controls)          # the controls that differed on every seed
     for seed in args.seeds:
         for name, control in {**controls, **READINGS}.items():
@@ -324,8 +452,8 @@ def main(argv=None) -> int:
                 held.discard(name)
             print(f"{'control' if name in controls else 'reading'} {name} "
                   f"config {args.config} seed {seed}: {differ} of {total} "
-                  f"placements differ from the reference (limit of the "
-                  f"comparison: 0)", flush=True)
+                  f"placements{', evictions and nominations' * args.preemption} "
+                  f"differ from the reference (limit of the comparison: 0)", flush=True)
     for name in sorted(set(controls) - held):
         print(f"control {name} placed every pod of some seed where the "
               f"reference does: on {args.config} it is a reading, and "

@@ -49,9 +49,15 @@ def load_config(path: str, rehearse: bool) -> dict:
     return cfg
 
 
+def groups(cfg: dict, key: str) -> List[dict]:
+    """``cfg[key]`` as a list of groups (``count``, ``template``): one group
+    or several, none where the key is absent."""
+    found = cfg.get(key, [])
+    return found if isinstance(found, list) else [found]
+
+
 def node_groups(cfg: dict) -> List[dict]:
-    nodes = cfg["nodes"]
-    return nodes if isinstance(nodes, list) else [nodes]
+    return groups(cfg, "nodes")
 
 
 def node_order(count: int, seed: int) -> List[int]:

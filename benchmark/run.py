@@ -97,15 +97,20 @@ def read_layer_metrics(bench_dir: str, metrics: list, obs: dict) -> dict:
 
 def replay(result: dict, bench_dir: str) -> tuple:
     """The reference over what the run did, in the order it did it: pods
-    created and deleted, nodes added and removed, by the operation's name
-    (`reference.replay`; an operation it does not know fails the run).
+    created, retried and deleted, nodes added and removed, by the operation's
+    name (`reference.replay`; an operation it does not know fails the run).
     `may_pend`, where the driver gives it, names the template groups whose
-    pods may find no node and must then stay unbound."""
+    pods may find no node and must then stay unbound (PostFilter runs for
+    them); `evictions` (victim -> preemptor) and `nominations` (preemptor ->
+    node), where it gives them, are what the run's PostFilter did, and a run
+    that gives none must have had none to give."""
     import reference
     ref = reference.Reference(result["nodes"], bench_dir)
     expected = reference.replay(ref, result["templates"], result["log"],
                                 result.get("may_pend", ()))
-    return reference.compare(expected, result["placements"]), \
+    return reference.compare(expected, result["placements"],
+                             result.get("evictions"),
+                             result.get("nominations")), \
         ref.over_allocatable()
 
 
@@ -214,11 +219,27 @@ def main(argv=None) -> int:
 
     # -- correct: every placement against the reference, and the chip did it
     t_ref = time.perf_counter()
-    cmp_, over = replay(result, ctx.bench_dir)
+    refused = None
+    try:
+        cmp_, over = replay(result, ctx.bench_dir)
+    except (ValueError, RuntimeError, KeyError) as e:
+        # a log the reference cannot follow (a retry of a pod it has bound,
+        # a pending pod the log never retries, no node for a pod that must
+        # have one) is a run that is not correct, and says why, not a crash
+        refused = f"{type(e).__name__}: {e}"
+        cmp_ = dict.fromkeys(
+            ("unbound", "unexpected", "pending", "evictions",
+             "evictions_differing", "nominations_differing"), 0)
+        cmp_.update(compared=len(result["placements"]),
+                    differing=len(result["placements"]),
+                    examples=[], preemption_examples=[])
+        over = []
     say(f"reference replayed {cmp_['compared']} pods in "
         f"{time.perf_counter() - t_ref:.2f}s"
         + (f" ({cmp_['pending']} of them expected to stay pending)"
-           if cmp_["pending"] else ""))
+           if cmp_["pending"] else "")
+        + (f"; {cmp_['evictions']} evictions expected"
+           if cmp_["evictions"] else ""))
     # a program first met inside the window shows as a compile or as a load
     # from the persistent cache: either is set-up that leaked into the window
     compiled = sum(marks["compile_close"][k] - marks["compile_open"][k]
@@ -226,8 +247,14 @@ def main(argv=None) -> int:
     checks = [("placements_differing", cmp_["differing"], 0),
               ("pods_unbound", cmp_["unbound"], 0),
               ("pods_unexpected", cmp_["unexpected"], 0),
+              ("evictions_differing", cmp_["evictions_differing"], 0),
+              ("nominations_differing", cmp_["nominations_differing"], 0),
               ("nodes_over_allocatable", len(over), 0),
               ("compiles_in_window", compiled, 0)] + list(result["guards"])
+    if refused:
+        say(f"the reference refuses the log, every placement counts as "
+            f"differing: {refused}")
+        checks.insert(0, ("log_refused_by_the_reference", 1, 0))
     correct = True
     compared = {}
     for name, got, limit in checks:
@@ -239,6 +266,9 @@ def main(argv=None) -> int:
     if cmp_["examples"]:
         say(f"differing placements, e.g. (pod, reference, run): "
             f"{cmp_['examples']}")
+    if cmp_["preemption_examples"]:
+        say(f"differing evictions or nominations, e.g. (pod, reference, "
+            f"run): {cmp_['preemption_examples']}")
 
     # -- metrics
     device = device_report(args.rehearse)

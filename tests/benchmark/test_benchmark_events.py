@@ -178,36 +178,71 @@ def test_only_a_group_the_run_names_may_pend():
     assert ref.pending == {}
 
 
-@pytest.mark.parametrize("event", ("node_add", "delete", "node_delete"))
+SPREAD = dict(POD, labels={"app": "s"}, topologySpreadConstraints=[
+    {"maxSkew": 1, "labelSelector": {"app": "s"}}])
+# for each event that can admit a pending pod: the nodes, the log up to and
+# with the event, the pending pod, and where its retry must land
+ADMITTED = {
+    # 5 cpu fit no node of 4; b, as small, changes nothing; c holds 8
+    "node_add": (
+        [_node("a", "z0")],
+        [("create", "big", "large"), ("node_add", "b", _node("b", "z0")),
+         ("node_add", "c", _node("c", "z0", cpu=8000))], "big", "c"),
+    # a holds four pods of 1 cpu; the fifth waits for the first to go
+    "delete": (
+        [_node("a", "z0")],
+        [("create", f"p{i}", "pod") for i in range(4)]
+        + [("create", "p4", "waits"), ("delete", "p0", None)], "p4", "a"),
+    # z0 is full and two pods ahead of z1: the spread pod fits nowhere
+    # until z1 leaves the zones with its node, and c (z0) has room
+    "node_delete": (
+        [_node("a", "z0", cpu=2000), _node("b", "z1", cpu=1000)],
+        [("create", "p0", "spread"), ("create", "p1", "spread"),
+         ("create", "p2", "spread"), ("delete", "p1", None),
+         ("create", "plain", "one"), ("create", "p3", "spreadwaits"),
+         ("node_add", "c", _node("c", "z0", cpu=1000)),
+         ("node_delete", "b", None)], "p3", "c"),
+}
+TEMPLATES = {"pod": POD, "waits": dict(POD), "large": LARGE,
+             "spread": SPREAD, "spreadwaits": dict(SPREAD),
+             "one": {"cpu": 1}}
+MAY_PEND = ["large", "waits", "spreadwaits"]
+
+
+@pytest.mark.parametrize("event", sorted(ADMITTED))
 def test_a_pending_pod_that_becomes_feasible_is_unmodelled(event):
-    spread = dict(POD, labels={"app": "s"}, topologySpreadConstraints=[
-        {"maxSkew": 1, "labelSelector": {"app": "s"}}])
-    if event == "node_add":
-        ref = reference.Reference([_node("a", "z0")])
-        assert ref.schedule("big", LARGE, may_pend=True) is None
-        ref.add_node(_node("b", "z0"))                   # as small: fine
-        with pytest.raises(reference.Unmodelled, match="big"):
-            ref.add_node(_node("c", "z0", cpu=8000))
-    elif event == "delete":
-        ref = reference.Reference([_node("a", "z0")])
-        for i in range(4):
-            ref.schedule(f"p{i}", POD)
-        assert ref.schedule("p4", POD, may_pend=True) is None
-        with pytest.raises(reference.Unmodelled, match="p4"):
-            ref.delete("p0")
-    else:
-        # z0 is full and two pods ahead of z1: the spread pod fits nowhere
-        # until z1 leaves the zones with its node
-        ref = reference.Reference([_node("a", "z0", cpu=2000),
-                                   _node("b", "z1", cpu=1000)])
-        assert [ref.schedule(f"p{i}", spread) for i in range(3)] == [
-            "a", "b", "a"]
-        ref.delete("p1")
-        ref.schedule("plain", {"cpu": 1})
-        assert ref.schedule("p3", spread, may_pend=True) is None
-        ref.add_node(_node("c", "z0", cpu=1000))         # z0: still 2 ahead
-        with pytest.raises(reference.Unmodelled, match="p3"):
-            ref.remove_node("b")
+    """No `retry` in the log: the refusal stands, and names what is
+    missing."""
+    nodes, log, pod, _ = ADMITTED[event]
+    with pytest.raises(reference.Unmodelled,
+                       match=f"pending pod {pod} .* no `retry`"):
+        reference.replay(reference.Reference(nodes), TEMPLATES, log,
+                         MAY_PEND)
+    # up to the event the log is sound, the pod pending
+    expected = reference.replay(reference.Reference(nodes), TEMPLATES,
+                                log[:-1], MAY_PEND)
+    assert expected[pod] is None
+
+
+@pytest.mark.parametrize("event", sorted(ADMITTED))
+def test_a_pending_pod_that_the_log_retries_lands_where_it_is_retried(event):
+    """The same logs with the `retry` the scheduler made once the event had
+    requeued the pod: it lands, and not before."""
+    nodes, log, pod, node = ADMITTED[event]
+    ref = reference.Reference(nodes)
+    expected = reference.replay(ref, TEMPLATES,
+                                log + [("retry", pod, None)], MAY_PEND)
+    assert expected[pod] == node and ref.pending == {}
+    assert ref.over_allocatable() == []
+    # a retry ahead of the event finds no node and changes nothing; the one
+    # behind it lands as before
+    early = log[:-1] + [("retry", pod, None), log[-1], ("retry", pod, None)]
+    assert reference.replay(reference.Reference(nodes), TEMPLATES, early,
+                            MAY_PEND) == expected
+    # and a retry of a pod that is not pending is an error
+    with pytest.raises(ValueError, match="not pending"):
+        reference.replay(reference.Reference(nodes), TEMPLATES,
+                         log + [("retry", pod, None)] * 2, MAY_PEND)
 
 
 def test_what_a_node_event_must_not_pass():
@@ -331,14 +366,17 @@ def test_the_event_log_has_every_operation_and_the_reference_agrees_with_itself(
         reference.Reference(made["nodes"]), made["templates"], made["log"],
         made["may_pend"])
     assert [p for p, n in expected.items() if n is None] == ["large-0"]
-    # a control that cannot finish the log has failed on every pod
+    # a control that cannot finish the log has failed on every pod it did
+    # not reach: here, all that the log creates behind its first node event
 
     class Dies(reference.Reference):
         def remove_node(self, name):
             raise reference.Unmodelled("no")
 
+    behind = ops[ops.index("node_delete"):].count("create")
+    assert 0 < behind < len(expected)
     assert control.differing_on_events(cfg, 11, Dies) == (
-        len(expected), len(expected))
+        len(expected), behind)
 
 
 @pytest.mark.parametrize("seed", SEEDS)
