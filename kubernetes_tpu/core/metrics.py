@@ -282,6 +282,14 @@ class SchedulerMetrics:
             "Pods added to queues by event and queue.", ("queue", "event")))
         self.preemption_attempts = r(Counter(
             "scheduler_preemption_attempts_total", "Preemption attempts."))
+        self.preemption_dry_runs = r(Counter(
+            "scheduler_preemption_dry_runs_total",
+            "Candidate searches of DefaultPreemption by what ran the "
+            "what-if: 'device' (one dry_run_preemption kernel call over "
+            "every node) or 'host' (the Evaluator's per-node loop: no device "
+            "backend, a preemptor the kernel does not cover, or the "
+            "recompute after a device candidate the host verify refused).",
+            ("engine",)))
         self.preemption_victims = r(Histogram(
             "scheduler_preemption_victims", "Victims per preemption.",
             buckets=(1, 2, 4, 8, 16, 32, 64)))
@@ -400,11 +408,21 @@ class SchedulerMetrics:
             "scheduler_plan_rebuild_cause_total",
             "Full plan rebuilds by what made them full: 'first' (no plan "
             "kept to resume), 'other_pod' (the kept plan is another "
-            "template's, profile's or attempt count's), 'journal_overrun' "
+            "template's, profile's or attempt count's), 'nomination' (the "
+            "kept plan is this template's and only the nomination set it was "
+            "built under has moved), 'journal_overrun' "
             "(more events since it than the journal retains), 'structural' "
             "(a node added or removed since), 'unpatchable' (an event of "
             "another kind no row patch covers), 'patch_failed'.",
             ("cause",)))
+        self.nominated_evaluations = r(Counter(
+            "scheduler_nominated_evaluations_total",
+            "Device-path evaluations of a nominated pod's own node, first "
+            "and alone (evaluateNominatedNode): 'bound' there, or "
+            "'fell_through' to the ordinary cycle (the node is gone or no "
+            "longer takes the pod), 'bind_refused' (the node took the pod "
+            "and the apiserver refused the bind: unwound and requeued).",
+            ("outcome",)))
         self.plan_ipa_terms = r(Counter(
             "scheduler_plan_ipa_terms_total",
             "What the required inter-pod term tables of the plans built "

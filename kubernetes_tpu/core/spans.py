@@ -84,6 +84,7 @@ STAGES = (
     "host.commit",       # assume/reserve/permit/bind host tail
     "bind.post",         # binding POST leaves the scheduler (attrs: bulk)
     "postfilter.preempt",  # PostFilter of a failed attempt (attrs: engine, parts)
+    "nominated.eval",    # a nominated pod's own node, first and alone (attrs: outcome, engine)
     "api.bind",          # apiserver binding subresource commit
     "wal.append",        # durable WAL append of the BOUND event
     "bound.fanout",      # BOUND event fanout to watch streams
@@ -111,7 +112,8 @@ LOOP_STAGES = ("cycle", "queue.pop", "inbox.drain", "hint.walk",
                "hint.validate", "plan.build", "plan.ipa", "plan.ipa_score",
                "plan.patch", "plan.adopt",
                "device.dispatch", "device.wait", "host.commit", "bind.post",
-               "postfilter.preempt", "loop.idle", "gc.settle", "gc.pause")
+               "postfilter.preempt", "nominated.eval", "loop.idle",
+               "gc.settle", "gc.pause")
 # A bound pod's minimal complete chain. Device stages are optional (host-
 # path pods legitimately skip them); observe spans prove the fanout landed.
 CORE_CHAIN = ("queue.wait", "host.commit", "bind.post", "api.bind",

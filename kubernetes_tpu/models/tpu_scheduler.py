@@ -51,6 +51,9 @@ from ..ops.kernel import schedule_batch
 # Sentinel fallback_reason: the popped entity is a pod GROUP that can ride a
 # device gang session (schedule_one routes it to run_gang_device_session).
 _GANG_SESSION = "__gang_device_session__"
+# _collect_batch's word for a head that holds a nomination: a batch of one,
+# whose nominated node is evaluated first and alone (_run_nominated).
+_NOMINATED = "__nominated_pod__"
 # A session whose head is no plain template clone: a holder no pod carries.
 _NO_TEMPLATE = (object(), 0, "")
 
@@ -163,6 +166,8 @@ class TPUScheduler(Scheduler):
         self.placement_device_evals = 0
         # DryRunPreemption kernel calls (one per device-evaluated PostFilter).
         self.preemption_device_evals = 0
+        self._empty_nom_key = None  # _empty_nom_lane: shapes it was made at
+        self._empty_nom = None
         # The sampled span context of the entity _pop handed out last (None
         # for group entities and with tracing off): the batch collectors
         # keep it, so a batch's sampled members are found once.
@@ -364,7 +369,7 @@ class TPUScheduler(Scheduler):
                 return fw, [head], _GANG_SESSION
             return self.framework_for_pod(head.pod), [head], "pod group entity"
         fw = self.framework_for_pod(head.pod)
-        reason = self._batch_supported_memo(head.pod, fw)
+        reason = self._batch_supported_memo(head.pod, fw, as_head=True)
         if reason is None:
             reason = self._nominated_device_block(fw, head.pod)
         if reason is None and self.extenders:
@@ -400,6 +405,10 @@ class TPUScheduler(Scheduler):
         batch = _Batch()
         self._take(batch, head)  # still the last entity _pop handed out
         took(1)
+        if pod.nominated_node_name:
+            # evaluateNominatedNode comes before the cycle that could share
+            # a batch: the head stays alone until its node has answered
+            return fw, batch, _NOMINATED
         return fw, self._refill(batch, fw, sig, took), None
 
     # -- gang device sessions ----------------------------------------------
@@ -967,6 +976,60 @@ class TPUScheduler(Scheduler):
             self.host_path_pods += len(remaining)
             self.process_one(qpi)
 
+    # -- a nominated pod's own node, first and alone ------------------------
+
+    def _run_nominated(self, fw: Framework, batch: "_Batch") -> bool:
+        """evaluateNominatedNode (schedule_one.go:722) on the device path,
+        for a head that holds a nomination: its nominated node alone, by a
+        batch of one of the pod's own scheduling program whose plan keeps
+        that row only (build_batch ``only_row``). The fit there counts the
+        other nominations of equal or higher priority (the plan's nominated
+        lane, which leaves the pod's own out), nothing is scored, and the
+        start index stays where it was: the walk finds fewer rows than it
+        looks for and passes the whole cluster. True: the pod is dealt with,
+        bound there with its nomination cleared (``_commit``). False: the
+        node is gone or no longer takes the pod, nothing was changed, and
+        the caller runs the ordinary cycle with the pod at the head of its
+        batch. The stage ``nominated.eval`` and
+        ``scheduler_nominated_evaluations_total{outcome}`` say which."""
+        with self.stages.stage("nominated.eval", batch.sampled,
+                               engine="device") as st:
+            try:
+                outcome = self._evaluate_nominated_node(fw, batch[0])
+            except Unsupported:
+                outcome = "fell_through"
+            st.say(outcome=outcome)
+        self.metrics.nominated_evaluations.inc(outcome)
+        # a bind the apiserver refused was unwound and requeued by _commit:
+        # the pod is dealt with, and no ordinary cycle follows either
+        return outcome != "fell_through"
+
+    def _evaluate_nominated_node(self, fw: Framework,
+                                 qpi: QueuedPodInfo) -> str:
+        """``bound``, ``fell_through`` or ``bind_refused``."""
+        pod = qpi.pod
+        self.cache.update_snapshot(self.snapshot)
+        row = self._snapshot_rows().get(pod.nominated_node_name)
+        if row is None:
+            return "fell_through"  # the node left: the ordinary cycle
+        start = self.next_start_node_index
+        state, plan = self.build_plan(fw, pod, self.max_batch, only_row=row)
+        attrs = self._dispatch_attrs(plan, 1, 0)
+        with self.stages.stage("device.dispatch", **attrs):
+            results, _carry = self._dispatch(state, plan, 1, None)
+        self._count_dispatch(attrs)
+        with self.stages.stage("device.wait", batch=1, seq=attrs["seq"]):
+            res = np.asarray(results)
+        self._note_device_success()
+        if int(res[0, 0]) != row:
+            return "fell_through"
+        with self.stages.stage("host.commit", batch=1, tail="single"):
+            bound = self._commit(fw, qpi, pod.nominated_node_name)
+        self.metrics.commit_pods.inc("single")
+        # findNodesThatFitPod returns before it advances the index
+        self.next_start_node_index = start
+        return "bound" if bound else "bind_refused"
+
     # -- device preemption dry run -----------------------------------------
 
     def device_dry_run_preemption(self, fw: Framework, state, pod,
@@ -1041,10 +1104,18 @@ class TPUScheduler(Scheduler):
         from ..core.framework import UNSCHEDULABLE_AND_UNRESOLVABLE
         from ..ops.kernel import dry_run_preemption
         from ..plugins.preemption import Candidate
+        # The plan's nominated lane (what the nominated pods of equal or
+        # higher priority hold, this preemptor's own nomination left out)
+        # counts in every fit of the what-if; zeros of the same shape where
+        # nobody is nominated, so both are one compiled program.
+        f = plan.features
+        if not plan.has_nom:
+            nom_req, nom_pods = self._empty_nom_lane()
+            f = f._replace(nom_req=nom_req, nom_pods=nom_pods)
         t_plan = clock()
         on_device = dry_run_preemption(
-            dstate, plan.features, jnp.asarray(vic_req),
-            jnp.asarray(vic_valid), vic_valid.shape[1])
+            dstate, f, jnp.asarray(vic_req), jnp.asarray(vic_valid),
+            vic_valid.shape[1])
         t_dispatch = clock()
         res = np.asarray(on_device)
         t_fetch = clock()
@@ -1056,7 +1127,9 @@ class TPUScheduler(Scheduler):
                    dispatch_ms=round(1e3 * (t_dispatch - t_plan), 3),
                    fetch_ms=round(1e3 * (t_fetch - t_dispatch), 3),
                    rows=int(vic_valid.shape[0]), k=int(vic_valid.shape[1]),
-                   r=int(vic_req.shape[2]))
+                   r=int(vic_req.shape[2]),
+                   nom_rows=len({row for row, _ in
+                                 self._nominated_lane(pod) or ()}))
         self.preemption_device_evals += 1
         self._note_device_success()
         feasible, vmask = res[:, 0], res[:, 1:]
@@ -1139,6 +1212,16 @@ class TPUScheduler(Scheduler):
             return "counted claims"
         return None
 
+    def _snapshot_rows(self) -> dict:
+        """Node name -> row of the snapshot's node_info_list (call AFTER
+        update_snapshot): the snapshot's own index where it covers the
+        list, else made from it."""
+        index = self.snapshot._index
+        nodes = self.snapshot.node_info_list
+        if len(index) != len(nodes):
+            index = {ni.name: i for i, ni in enumerate(nodes)}
+        return index
+
     def _nominated_lane(self, pod) -> Optional[list]:
         """[(snapshot row, PodInfo)] for the lane: nominated pods with
         priority >= the batch pod's, on rows present in the snapshot.
@@ -1146,10 +1229,7 @@ class TPUScheduler(Scheduler):
         nom = self.queue.nominator
         if not nom.has_nominated_pods():
             return None
-        index = self.snapshot._index
-        if len(index) != len(self.snapshot.node_info_list):
-            index = {ni.name: i
-                     for i, ni in enumerate(self.snapshot.node_info_list)}
+        index = self._snapshot_rows()
         out = []
         for node_name, pis in nom._node_to_pods.items():
             row = index.get(node_name)
@@ -1186,10 +1266,12 @@ class TPUScheduler(Scheduler):
                 return "extended resources backed by DRA"
         return None
 
-    def build_plan(self, fw: Framework, pod, batch_size: int):
+    def build_plan(self, fw: Framework, pod, batch_size: int,
+                   only_row: Optional[int] = None):
         """Snapshot → mirror sync → batch feature build → device flush.
         Returns (device_state, BatchPlan). Also the graft/bench entry's way
-        to produce kernel inputs.
+        to produce kernel inputs. ``only_row``: the one snapshot row the
+        plan may land on (build_batch; a nominated pod's own node).
 
         Mesh-first: under a mesh the mirror's RESIDENT copy is committed to
         mesh_state_shardings, so flush() uploads host staging straight to
@@ -1228,6 +1310,7 @@ class TPUScheduler(Scheduler):
             dra_enabled=dra_enabled,
             dra_in_use=dra_in_use,
             nominated=self._nominated_lane(pod),
+            only_row=only_row,
             stages=self.stages,
         )
         self._count_ipa(plan)
@@ -1303,21 +1386,40 @@ class TPUScheduler(Scheduler):
             # first nomination would otherwise compile inside the measured
             # window): warm the has_nom variant with an empty lane — shapes
             # and statics are identical to the live nominated plan.
-            import jax.numpy as jnp
-            nom_req = jnp.zeros((self.mirror.np_cap, self.mirror.r_slots),
-                                jnp.int64)
-            nom_pods = jnp.zeros(self.mirror.np_cap, jnp.int32)
+            nom_req, nom_pods = self._empty_nom_lane()
+            nf = plan.features._replace(nom_req=nom_req, nom_pods=nom_pods)
+            warm(dataclasses.replace(plan, features=nf, has_nom=True))
+
+    def _empty_nom_lane(self):
+        """A nominated lane that holds nothing, at the live lane's shapes and
+        (under a mesh) committed shardings, which jit keys on:
+        shard_features puts the lane on the node axis. Kept per shape."""
+        import jax.numpy as jnp
+        key = (self.mirror.np_cap, self.mirror.r_slots, self.mesh)
+        if self._empty_nom_key != key:
+            nom_req = jnp.zeros(key[:2], jnp.int64)
+            nom_pods = jnp.zeros(key[0], jnp.int32)
             if self.mesh is not None:
-                # Match the live dispatch's committed shardings (jit keys on
-                # them): shard_features puts nom arrays on the node axis.
                 import jax
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 nom_req = jax.device_put(
                     nom_req, NamedSharding(self.mesh, P("nodes", None)))
                 nom_pods = jax.device_put(
                     nom_pods, NamedSharding(self.mesh, P("nodes")))
-            nf = plan.features._replace(nom_req=nom_req, nom_pods=nom_pods)
-            warm(dataclasses.replace(plan, features=nf, has_nom=True))
+            self._empty_nom_key, self._empty_nom = key, (nom_req, nom_pods)
+        return self._empty_nom
+
+    def warm_for_preemption(self, pod) -> None:
+        """Compile the dry-run program a preemptor shaped like ``pod`` will
+        meet, at the victim width the cluster has now, WITHOUT evicting
+        anybody: the what-if itself, its answer dropped. Measuring harnesses
+        call it beside ``warm_for(pod, nominated=True)`` (the scheduling
+        program of the failed attempt and of the nominated retry, with and
+        without a lane), so no compile lands inside the measured window."""
+        fw = self.framework_for_pod(pod)
+        evals = self.preemption_device_evals
+        self.device_dry_run_preemption(fw, None, pod, {}, 1, 0)
+        self.preemption_device_evals = evals
 
     def warm_for_placements(self, pod, group_size: int,
                             n_placements: int) -> None:
@@ -1750,10 +1852,15 @@ class TPUScheduler(Scheduler):
             rkey, rseq, payload, rnom = resume
             sig_ok = (rkey[1] == sig) if rkey[0] == "exact" else (
                 nsig is not None and rkey[1] == nsig)
-            if (sig_ok
-                    and rkey[2:] == (id(fw), aux_shape, claims_rv,
-                                     self.attempts, self.state_unwinds)
-                    and rnom == self._nom_resume_key(head_pod.priority)):
+            rest_ok = sig_ok and rkey[2:] == (
+                id(fw), aux_shape, claims_rv, self.attempts,
+                self.state_unwinds)
+            if rest_ok and rnom != self._nom_resume_key(head_pod.priority):
+                # the kept plan is this template's and nothing but the
+                # nomination set (or the lane's priority threshold) has
+                # moved since: its nominated lane is stale
+                cause = "nomination"
+            elif rest_ok:
                 state, plan, carry, node_names = payload
                 if rseq == self.cluster_event_seq:
                     kind = "resume"
@@ -1854,14 +1961,18 @@ class TPUScheduler(Scheduler):
             limited_drivers=self.limited_drivers())
         return ((vol_d, vol_inc) if vol_d else None, self._claim_shape(pod))
 
-    def _batch_supported_memo(self, pod, fw: Framework):
+    def _batch_supported_memo(self, pod, fw: Framework,
+                              as_head: bool = False):
         """batch_supported with the verdict memoized on the pod's shared
         template-signature holder (clone_from_template invariant: clones
         never mutate spec), so a 50k-pod workload computes it once, not 50k
-        times. The one per-INSTANCE field the verdict reads —
-        nominated_node_name — is checked outside the memo."""
-        if pod.nominated_node_name:
-            return "nominated node fast path"
+        times. The one per-INSTANCE field read here —
+        nominated_node_name — is checked outside the memo: a pod that holds
+        a nomination joins nobody's batch (its node is evaluated first and
+        alone), it only ever heads one (``as_head``: _collect_batch, which
+        hands it to _run_nominated)."""
+        if pod.nominated_node_name and not as_head:
+            return "nominated pod heads a batch of its own"
         shared = pod.__dict__.get("_sig_shared")
         if (shared is None or any(v.pvc_name for v in pod.volumes)
                 or getattr(pod, "resource_claims", None)):
@@ -2173,13 +2284,10 @@ class TPUScheduler(Scheduler):
                     start_nom = self.queue.nominator.version
             else:
                 # A previous batch diverged: every later device choice is
-                # stale. Host-path the pods and charge their rows dirty.
+                # stale. Rerun the pods and charge their rows dirty.
                 for i, qpi in enumerate(b):
-                    row = int(res[0, i])
-                    if row >= 0:
-                        dirty_rows.append(row)
-                    self.host_path_pods += 1
-                    self.process_one(qpi)
+                    self._rerun_after_divergence(fw, qpi, int(res[0, i]),
+                                                 dirty_rows)
             if b in pending:
                 pending.remove(b)  # fully handled: out of crash recovery
 
@@ -2362,10 +2470,7 @@ class TPUScheduler(Scheduler):
             row = rows[i]
             self.next_start_node_index = starts[i]
             if invalidated:
-                if row >= 0:
-                    dirty_rows.append(row)
-                self.host_path_pods += 1
-                self.process_one(qpi)
+                self._rerun_after_divergence(fw, qpi, row, dirty_rows)
                 continue
             if row < 0:
                 if self._fail_from_memo(fw, qpi):
@@ -2404,6 +2509,25 @@ class TPUScheduler(Scheduler):
         if single:
             self.metrics.commit_pods.inc("single", value=float(single))
         return invalidated
+
+    def _rerun_after_divergence(self, fw: Framework, qpi: QueuedPodInfo,
+                                row: int, dirty_rows: List[int]) -> None:
+        """A pod whose device answer is stale: an earlier pod of its batch,
+        or of a batch ahead of it in the pipeline, moved state the carry
+        does not hold. Where the device had placed it (``row``) the row is
+        charged dirty and the host cycle places it anew. Where the device
+        had found it no node (several preemptors behind one another: the
+        first one's nomination is what moved the state), the diagnosis is
+        made anew from the mirror's staging arrays and the nominations as
+        they stand NOW (``_fail_with_vector_diagnosis`` answers only if
+        every node still fails), so its PostFilter meets what the host
+        cycle's would, without the host cycle's walk."""
+        if row >= 0:
+            dirty_rows.append(row)
+        elif self._fail_with_vector_diagnosis(fw, qpi):
+            return
+        self.host_path_pods += 1
+        self.process_one(qpi)
 
     def _fail_state_key(self, fw: Framework, pod) -> tuple:
         """Everything a scheduling outcome can depend on, versioned: the pod
@@ -2445,14 +2569,18 @@ class TPUScheduler(Scheduler):
         from ..core.framework import CycleState, FitError
         from ..ops.features import diagnose_unschedulable
 
-        if self.queue.nominator.has_nominated_pods():
-            # The vectorized diagnosis doesn't model the two-pass nominated
-            # filter; the exact host rerun owns the Diagnosis.
+        if self._nominated_device_block(fw, qpi.pod) is not None:
+            # A nomination that touches this pod by more than its requests
+            # (_nominated_device_block): the vectorized diagnosis models the
+            # two-pass filter for the resource fit only, and the exact host
+            # rerun owns the Diagnosis.
             return False
         t0 = _t.perf_counter()
         self.cache.update_snapshot(self.snapshot)
         self.mirror.sync(self.snapshot.node_info_list)
-        diag = diagnose_unschedulable(qpi.pod, self.mirror, self.snapshot, fw)
+        diag = diagnose_unschedulable(
+            qpi.pod, self.mirror, self.snapshot, fw,
+            nominated=self._nominated_lane(qpi.pod))
         if diag is None:
             return False
         self.attempts += 1
@@ -2771,10 +2899,16 @@ class TPUScheduler(Scheduler):
                     self.host_path_pods += len(getattr(qpi, "members", ()) or (1,))
                     self.process_one(qpi)
             return True
+        nominated = fallback_reason is _NOMINATED
+        if nominated:
+            fallback_reason = None
         if fallback_reason is None and len(batch) >= 1:
             pr = self._device_unsupported_profile(fw, batch[0].pod)
             if pr is not None:
                 fallback_reason = pr
+        if fallback_reason is None and nominated \
+                and self._run_nominated(fw, batch):
+            return True
         if fallback_reason is not None:
             for qpi in batch:
                 self.host_path_pods += 1

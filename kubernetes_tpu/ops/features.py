@@ -417,9 +417,11 @@ def batch_supported(pod: Pod, snapshot, fit_plugin=None, ba_plugin=None,
     counted CSI attach limit) are covered on device via host-evaluated
     static per-node vectors (sel_match / extra_ok / na_raw / il_score /
     aux_room) — only genuinely stateful host machinery (unbound volume
-    binding, DRA allocation, nominated-pod two-pass) still falls back."""
-    if pod.nominated_node_name:
-        return "nominated node fast path"
+    binding, DRA allocation) still falls back. A nomination on the pod is
+    no reason: its node is evaluated first and alone by a batch of one
+    whose plan keeps that row only (build_batch `only_row`;
+    models/tpu_scheduler.py _evaluate_nominated_node), and the pod never
+    joins another pod's batch (_batch_supported_memo)."""
     aff = pod.affinity
     na = aff.node_affinity if aff is not None else None
     if na is not None and na.required is not None:
@@ -488,6 +490,7 @@ def build_batch(
     dra_enabled=False,
     dra_in_use=None,
     nominated=None,
+    only_row: Optional[int] = None,
     stages=None,
 ) -> BatchPlan:
     """Build kernel inputs for a batch of `batch_size` pods identical to `pod`.
@@ -509,6 +512,11 @@ def build_batch(
     gate guarantees the batch pod carries no feature a nominated pod could
     interact with beyond resources — models/tpu_scheduler.py
     _nominated_device_block).
+
+    `only_row`: the one snapshot row the batch may land on (a nominated
+    pod's evaluation of its nominated node, evaluateNominatedNode): every
+    other row fails the static mask, so the walk passes the whole cluster,
+    finds at most that row, and leaves the start index where it was.
     """
     verdict = volume_device_support(
         pod, clientset, pvc_refs=pvc_refs, limited_drivers=limited_drivers)
@@ -609,6 +617,9 @@ def build_batch(
         for r_i, ni in enumerate(nodes):
             if host_ports_conflict(ports, ni.used_ports):
                 extra_ok_host[r_i] = False
+
+    if only_row is not None:
+        extra_ok_host &= np.arange(len(nodes)) == only_row
 
     # -- ImageLocality static score (imagelocality.go scaledImageScore) -----
     il_host = None
@@ -1174,7 +1185,7 @@ def build_preemption_victims(pod: Pod, snapshot, mirror: NodeStateMirror):
 
 
 def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
-                           fw) -> Optional["object"]:
+                           fw, nominated=None) -> Optional["object"]:
     """Per-node failure Diagnosis for a pod the device found infeasible
     EVERYWHERE — vectorized over the mirror's staging arrays instead of the
     pure-Python per-node filter loop (which costs ~0.3s at 5k nodes and used
@@ -1185,6 +1196,15 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
     pod affinity — those return None and take the exact host rerun). The
     verdict codes and plugin attributions match the host plugins in profile
     filter order; messages are the plugins' standard texts.
+
+    `nominated`: the plan's nominated lane, [(node_row, PodInfo)] as
+    build_batch takes it (pods of equal or higher priority nominated to a
+    row, the pod's own nomination left out). Their requests and count join
+    what the row holds in the resource fit, which is pass one of the
+    two-pass filter; a row that fails there has that status on the host too,
+    and one that passes it passes pass two (the caller's gate,
+    _nominated_device_block, keeps away every pod a nominated pod touches
+    by more than its requests).
     """
     if (pod.topology_spread_constraints
             or (pod.affinity is not None
@@ -1238,10 +1258,16 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
         req_vec = _resource_vec(mirror, req)
         alloc = mirror.h_alloc_r[:n]
         used = mirror.h_req_r[:n]
+        count = mirror.h_pod_count[:n]
+        if nominated:
+            used, count = used.copy(), count.copy()
+            for row, npi in nominated:
+                used[row] += _resource_vec(mirror, npi.pod.resource_request())
+                count[row] += 1
         pos = req_vec > 0
         insufficient = (req_vec[None, :] > (alloc - used)) & pos[None, :]
         over_capacity = (req_vec[None, :] > alloc) & pos[None, :]
-        pods_full = (mirror.h_pod_count[:n] + 1) > mirror.h_alloc_pods[:n]
+        pods_full = (count + 1) > mirror.h_alloc_pods[:n]
         # Unresolvable when the request exceeds allocatable outright
         # (fit.go fitsRequest Unresolvable flag) — preemption can't help.
         checks.append(("NodeResourcesFit", True,

@@ -880,6 +880,15 @@ def dry_run_preemption(
     the per-victim feasibility check reduces to the fit arithmetic of
     _resource_eval — bit-identical to the host oracle's filter verdicts.
 
+    The nominated lane of the preemptor's plan (`f.nom_req` / `f.nom_pods`:
+    per row, what the nominated pods of equal or higher priority hold there,
+    the preemptor's own nomination left out) counts in every fit of the
+    what-if, as the host's two-pass filter counts it (SelectVictimsOnNode →
+    RunFilterPluginsWithNominatedPods). The scheduler hands the lane at
+    full width always, zeros where nobody is nominated, so an empty and a
+    filled lane are ONE compiled program; features whose lane has no rows
+    (a plan built without one, handed in as it is) are read as no lane.
+
     Returns one stacked bool array [NP, 1+K] (a single device→host fetch):
     column 0 = feasible (non-empty minimal victim set), columns 1..K = the
     victim mask; scores/PDBs/selection stay host-side
@@ -891,6 +900,10 @@ def dry_run_preemption(
     static_ok = (state.valid & name_ok & unsched_ok & taint_ok & sel_ok
                  & exist_anti_ok & f.extra_ok & (idx < num))
 
+    has_lane = f.nom_req.shape[0] == NP
+    nom_req = f.nom_req if has_lane else None
+    nom_pods = f.nom_pods if has_lane else None
+
     n_pot = vic_valid.sum(axis=1).astype(jnp.int32)          # [NP]
     sum_vic = (vic_req * vic_valid[:, :, None]).sum(axis=1)  # [NP, R]
     base_req = state.req_r - sum_vic
@@ -898,11 +911,12 @@ def dry_run_preemption(
 
     def fit(req_r, pod_cnt):
         # The scheduling kernel's exact fit filter; scores are dead code
-        # under jit (XLA eliminates them). No nominated lane: the host dry
-        # run ignores nominations too (run_filter_plugins, not two-pass).
+        # under jit (XLA eliminates them). The nominated lane counts, as in
+        # the host dry run's two-pass filter (Evaluator.dry_run_on_node).
         ok, _sc, _ba = _resource_eval(
             f, 0, state.alloc_r, state.alloc_pods, req_r,
-            jnp.zeros_like(req_r[..., :2]), pod_cnt)
+            jnp.zeros_like(req_r[..., :2]), pod_cnt,
+            nom_r=nom_req, nom_p=nom_pods)
         return ok
 
     feasible0 = static_ok & fit(base_req, cnt0) & (n_pot > 0)

@@ -8,12 +8,20 @@ Reference anchors:
 - plugins/defaultpreemption/default_preemption.go — PostFilter → Evaluator,
   victim ordering (lower priority first, then earlier start later),
   PodEligibleToPreemptOthers;
-- async victim deletion (executor.go:171) is synchronous here; the
-  APIDispatcher integration arrives with the async-writes subsystem.
+- async victim deletion (executor.go:171 prepareCandidateAsync): the victims'
+  deletes go through the APIDispatcher, inline over an in-process clientset
+  (inside the preemptor's cycle) and off the loop in thread mode.
 
-The dry run is the host-side "what-if" path; its device-batched analogue
-(DryRunPreemption as a second kernel, SURVEY.md §7.7) can replace the inner
-loop later without changing this control flow.
+The what-if counts nominated room (SelectVictimsOnNode runs
+RunFilterPluginsWithNominatedPods): both filter calls of ``dry_run_on_node``
+go through the framework's two-pass filter with the nominator, so the room
+held for another preemptor of equal or higher priority is taken, and the
+preemptor's own nomination and every nomination of lower priority are not.
+The device-batched form of the same what-if (``ops/kernel.py``
+``dry_run_preemption``, SURVEY.md §7.7) takes the same lane and answers
+``find_candidates`` where the handle has a device backend; the candidate
+picked from it is verified by ``dry_run_on_node``. The node is picked by the
+source's criteria in the source's order (``select_candidate``).
 """
 
 from __future__ import annotations
@@ -31,6 +39,9 @@ from ..core.framework import (
     UNSCHEDULABLE_AND_UNRESOLVABLE,
 )
 from ..core.node_info import NodeInfo, PodInfo
+
+
+PRIORITY_OFFSET = 1 << 31  # MaxInt32 + 1 (pickOneNodeForPreemption)
 
 
 @dataclass
@@ -67,6 +78,14 @@ class Evaluator:
         say = getattr(self.handle, "say_stage", None)
         if say is not None:
             say("postfilter.preempt", **stats)
+
+    def stage_clock(self):
+        """``time.perf_counter`` where the ``postfilter.preempt`` stage open
+        around the caller is listened to, else ``float`` (every reading 0.0):
+        the attempt's parts are clocked for somebody or not at all."""
+        stages = getattr(self.handle, "stages", None)
+        heard = stages.heard("postfilter.preempt") if stages is not None else None
+        return time.perf_counter if heard is not None else float
 
     # -- eligibility (default_preemption.go PodEligibleToPreemptOthers) ----
 
@@ -113,11 +132,19 @@ class Evaluator:
                     return False
             return True
 
+        # RunFilterPluginsWithNominatedPods, as SelectVictimsOnNode runs it:
+        # the room held for a nominated pod of equal or higher priority is
+        # taken (the preemptor's own nomination is not)
+        nominator = getattr(self.handle, "nominator", None)
+
+        def passes() -> bool:
+            return self.fw.run_filter_plugins_with_nominated_pods(
+                sim_state, pod, ni, nominator).is_success()
+
         for pi in potential:
             if not remove_pod(pi):
                 return None
-        st = self.fw.run_filter_plugins(sim_state, pod, ni)
-        if not st.is_success():
+        if not passes():
             return None
 
         # Reprieve: re-add victims most-important first — higher priority,
@@ -128,8 +155,7 @@ class Evaluator:
         for pi in potential:
             if not add_pod(pi):
                 return None
-            st = self.fw.run_filter_plugins(sim_state, pod, ni)
-            if not st.is_success():
+            if not passes():
                 # can't keep it: evict for real
                 if not remove_pod(pi):
                     return None
@@ -175,6 +201,7 @@ class Evaluator:
                                   num_candidates, start)
                 if cands is not None:
                     self.last_from_device = True
+                    self._count_dry_run("device")
                     self.say_stage(engine="device", candidates=len(cands))
                     return cands
         _t_host = time.perf_counter()
@@ -191,14 +218,37 @@ class Evaluator:
                 candidates.append(cand)
                 if len(candidates) >= num_candidates:
                     break
+        self._count_dry_run("host")
         self.say_stage(engine="host", candidates=len(candidates),
                        host_ms=round(1e3 * (time.perf_counter() - _t_host), 3))
         return candidates
+
+    def _count_dry_run(self, engine: str) -> None:
+        """scheduler_preemption_dry_runs_total{engine}: every candidate
+        search by what ran it, the host recompute after a device candidate
+        that the host verify refused among them (whether or not a stage
+        listens: a run's guard reads it)."""
+        metrics = getattr(self.handle, "metrics", None)
+        if metrics is not None:
+            metrics.preemption_dry_runs.inc(engine)
 
     # -- selection (preemption.go pickOneNodeForPreemption) ----------------
 
     @staticmethod
     def select_candidate(candidates: List[Candidate]) -> Optional[Candidate]:
+        """pickOneNodeForPreemption, criterion by criterion, each over the
+        candidates the one before left tied:
+
+        1. the fewest PDB violations;
+        2. the lowest priority of the node's most important victim;
+        3. the smallest sum over its victims of ``priority + 2**31`` (the
+           source's ``MaxInt32 + 1``: every term is positive, so fewer
+           victims beat a smaller plain sum of negative priorities);
+        4. the fewest victims;
+        5. the LATEST of the nodes' earliest start times, each taken among
+           the node's victims of its highest priority
+           (``GetEarliestPodStartTime``);
+        6. the first found (``min`` keeps the first of equal keys)."""
         if not candidates:
             return None
         if len(candidates) == 1:
@@ -206,14 +256,13 @@ class Evaluator:
 
         def key(c: Candidate):
             highest = max(pi.pod.priority for pi in c.victims)
-            prio_sum = sum(pi.pod.priority for pi in c.victims)
-            latest_start = max(pi.pod.creation_ts for pi in c.victims)
             return (
-                c.num_pdb_violations,   # fewest PDB violations
-                highest,                # lowest highest-victim priority
-                prio_sum,               # lowest priority sum
-                len(c.victims),         # fewest victims
-                -latest_start,          # latest victim start time survives
+                c.num_pdb_violations,
+                highest,
+                sum(pi.pod.priority + PRIORITY_OFFSET for pi in c.victims),
+                len(c.victims),
+                -min(pi.pod.creation_ts for pi in c.victims
+                     if pi.pod.priority == highest),
             )
 
         return min(candidates, key=key)
@@ -391,7 +440,10 @@ class DefaultPreemption:
             if not candidates:
                 return None, Status.unresolvable(
                     "preemption: extenders rejected all candidates")
+        clock = self.evaluator.stage_clock()
+        _t_select = clock()
         best = self.evaluator.select_candidate(candidates)
+        _t_verify = clock()
         if self.evaluator.last_from_device and best is not None:
             # Host-verify the device-selected candidate: the exact per-node
             # dry run must reproduce the victim set. On divergence (a kernel
@@ -415,9 +467,18 @@ class DefaultPreemption:
                                  num_pdb_violations=best.num_pdb_violations)
         _t_exec = time.perf_counter()
         self.evaluator.prepare_candidate(best, pod)
+        _t_done = time.perf_counter()
+        if clock is not float:
+            # the attempt's tail beside the dry run's parts: picking the
+            # node, the host verify of the device's candidate, the evictions
+            self.evaluator.say_stage(
+                select_ms=round(1e3 * (_t_verify - _t_select), 3),
+                verify_ms=round(1e3 * (_t_exec - _t_verify), 3),
+                evict_ms=round(1e3 * (_t_done - _t_exec), 3),
+                victims=len(best.victims), nominated=1)
         if metrics is not None:
             metrics.preemption_execution_duration.observe(
-                time.perf_counter() - _t_exec)
+                _t_done - _t_exec)
             if best.num_pdb_violations:
                 metrics.preemption_pdb_violations.inc(
                     value=best.num_pdb_violations)

@@ -321,6 +321,8 @@ def test_metric_name_parity_with_reference():
                      "scheduler_bind_request_pods_total",
                      "scheduler_inbox_oldest_wait_seconds",
                      "scheduler_cluster_event_wait_seconds",
+                     "scheduler_nominated_evaluations_total",
+                     "scheduler_preemption_dry_runs_total",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",

@@ -178,3 +178,43 @@ def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, 
     assert prims["div"] == 0 and prims["rem"] == 0, prims
     assert 200 < len(body) <= ceiling, len(body)
     assert any("resource_fit" in l for l in body), "the scope names left the HLO"
+
+
+def test_the_preemption_cells_programs_compile_for_the_chip(one_chip):
+    """`preempt-5k.waves` (PR 43) meets two programs no cell met before: the
+    dry run with a nominated lane in its fit (one program for an empty and
+    a filled lane: the lane is always at full width) and the lap kernel of a
+    plan built under nominations (`has_nom`). Both compile for the chip, and
+    the dry run's reprieve loop holds no division expansion either."""
+    from kubernetes_tpu.ops.kernel import dry_run_preemption, schedule_batch
+
+    state, plan = _small_plan(128, False)
+    NP, R, K = state.valid.shape[0], plan.features.request.shape[0], 8
+    assert not plan.has_nom and plan.features.nom_req.shape[0] == 0
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def sds(x):
+        return S(x.shape, x.dtype)
+
+    f = jax.tree_util.tree_map(sds, plan.features)._replace(
+        nom_req=S((NP, R), jnp.int64), nom_pods=S((NP,), jnp.int32))
+    state = jax.tree_util.tree_map(sds, state)
+    hlo = dry_run_preemption.lower(
+        state, f, S((NP, K, R), jnp.int64), S((NP, K), jnp.bool_), K,
+    ).compile().as_text()
+    body = _instructions(hlo, under="/while/body/") or _instructions(hlo)
+    prims = _primitives(body)
+    assert prims["div"] == 0 and prims["rem"] == 0, prims
+    assert any("resource_fit" in l or "sub" in l for l in body)
+    lap = schedule_batch.lower(
+        state, f, plan.batch_pad, plan.fit_strategy, plan.vmax,
+        n_active=S((), jnp.int32), carry_in=None, has_pns=plan.has_pns,
+        has_ipa_base=plan.has_ipa_base, anti_rowlocal=plan.anti_rowlocal,
+        has_na_pref=plan.has_na_pref, port_selfblock=plan.port_selfblock,
+        has_aux=plan.has_aux, has_nom=True).compile().as_text()
+    body = _instructions(lap, under="/while/body/")
+    prims = _primitives(body)
+    assert prims["div"] == 0 and prims["rem"] == 0, prims
+    assert 200 < len(body) <= LAP_BODY_CEILING + 500, len(body)

@@ -456,9 +456,13 @@ def test_a_priority_change_between_two_runs_takes_the_full_check():
         cs.create_pod(pod)
     dev.run_until_idle()
     assert all(p.node_name for p in cs.pods.values())
-    # the nominated pod ends the run and takes the host path, alone
-    assert dev.host_path_pods == 1
-    assert dev.popped_pods == {"run": 17, "single": 2}
+    # the nominated pod ends the run and heads a batch of its own: its node
+    # first and alone, on the device path since PR 43 (it took the host path
+    # until then)
+    assert dev.host_path_pods == 0
+    assert dev.metrics.nominated_evaluations.value("bound") == 1
+    assert {p.name: p.node_name for p in cs.pods.values()}["p13"] == "n3"
+    assert dev.popped_pods == {"run": 17, "single": 3}
 
 
 def test_sampled_pods_get_the_same_rows_after_the_run():
