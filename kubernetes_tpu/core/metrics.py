@@ -290,6 +290,13 @@ class SchedulerMetrics:
             "backend, a preemptor the kernel does not cover, or the "
             "recompute after a device candidate the host verify refused).",
             ("engine",)))
+        self.preemption_victim_rows = r(Counter(
+            "scheduler_preemption_victim_rows_total",
+            "Node rows of the device what-if's victim tensors, by what a "
+            "dry run did with them: 'rebuilt' (derived again from the "
+            "node's pods: its NodeInfo generation, its place in the list, "
+            "or the holder's key had changed) or 'kept' (the row of the "
+            "last dry run, as it was).", ("how",)))
         self.preemption_victims = r(Histogram(
             "scheduler_preemption_victims", "Victims per preemption.",
             buckets=(1, 2, 4, 8, 16, 32, 64)))

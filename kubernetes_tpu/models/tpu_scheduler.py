@@ -44,7 +44,8 @@ from ..core.queue import (QueuedCompositeGroupInfo, QueuedPodGroupInfo,
 from ..core.scheduler import (QueuedBind, Scheduler, ScheduleResult,
                               queue_wait)
 from ..ops.device_state import NodeStateMirror, enable_persistent_compilation_cache
-from ..ops.features import Unsupported, batch_supported, build_batch
+from ..ops.features import (PreemptionVictims, Unsupported, batch_supported,
+                            build_batch)
 from ..ops.kernel import schedule_batch
 
 
@@ -144,6 +145,9 @@ class TPUScheduler(Scheduler):
         else:
             self.mesh = mesh  # explicit Mesh, or None to force single-device
         self.mirror = NodeStateMirror()
+        # the preemption what-if's victim tensors, kept from one preemptor
+        # to the next and patched by the snapshot's generations
+        self._victims = PreemptionVictims(self.mirror)
         self._holdover: Optional[QueuedPodInfo] = None
         # metrics
         self.device_scheduled = 0
@@ -917,6 +921,7 @@ class TPUScheduler(Scheduler):
         self._hints.invalidate("device_failure")
         self._placement_plan_cache = None
         self._placement_mask_cache = None
+        self._victims.drop()
         self._fail_memo.clear()
         self.metrics.batch_cache_flushed.inc("device_path_failure")
         self._after_flush = True
@@ -1079,8 +1084,17 @@ class TPUScheduler(Scheduler):
             # the kernel treats as static.
             return None
         self.mirror.sync(nodes)
-        from ..ops.features import build_preemption_victims
-        built = build_preemption_victims(pod, self.snapshot, self.mirror)
+        # The arrays are the holder's own and the next call patches them in
+        # place. That is safe because this method fetches the what-if's
+        # answer (np.asarray(on_device)) before it returns: no dispatch that
+        # reads them is in flight by then (the CPU backend's jnp.asarray may
+        # alias host memory instead of copying it).
+        victims = self._victims
+        built = victims.build(pod, self.snapshot)
+        self.metrics.preemption_victim_rows.inc(
+            "rebuilt", value=victims.rebuilt)
+        self.metrics.preemption_victim_rows.inc(
+            "kept", value=len(nodes) - victims.rebuilt)
         if built is None:
             return None
         vic_req, vic_valid, potential = built
@@ -1126,6 +1140,7 @@ class TPUScheduler(Scheduler):
                    plan_ms=round(1e3 * (t_plan - t_victims), 3),
                    dispatch_ms=round(1e3 * (t_dispatch - t_plan), 3),
                    fetch_ms=round(1e3 * (t_fetch - t_dispatch), 3),
+                   victim_rows_rebuilt=victims.rebuilt,
                    rows=int(vic_valid.shape[0]), k=int(vic_valid.shape[1]),
                    r=int(vic_req.shape[2]),
                    nom_rows=len({row for row, _ in

@@ -1147,41 +1147,118 @@ def _batch_tier(n: int) -> int:
 PREEMPT_K_CAP = 256  # victims-per-node tier ceiling (recompile guard)
 
 
-def build_preemption_victims(pod: Pod, snapshot, mirror: NodeStateMirror):
+def _reprieve_order(pi: PodInfo):
+    return -pi.pod.priority, pi.pod.creation_ts
+
+
+class PreemptionVictims:
     """Victim tensors for the dry-run kernel: per node, every lower-priority
     pod in MoreImportantPod reprieve order (higher priority first, then
     earlier start — preemption.go:480-520 / the host Evaluator's sort).
-    Returns (vic_req [npc, K, R] i64, vic_valid [npc, K] bool,
-    potential [n] list-of-PodInfo in the same order) or None when some node
-    exceeds the K cap (host path owns it)."""
-    nodes = snapshot.node_info_list
-    prio = pod.priority
-    potential = []
-    kmax = 0
-    for ni in nodes:
-        pis = [pi for pi in ni.pods if pi.pod.priority < prio]
-        pis.sort(key=lambda pi: (-pi.pod.priority, pi.pod.creation_ts))
-        potential.append(pis)
-        if len(pis) > kmax:
-            kmax = len(pis)
-    if kmax == 0 or kmax > PREEMPT_K_CAP:
-        return None
-    k = _pow2(kmax, 8)
-    npc = mirror.np_cap
-    # Intern every victim scalar-resource slot BEFORE allocating (interning
-    # can grow r_slots; the caller's build_plan re-syncs the mirror after).
-    reqs = [[pi.pod.resource_request() for pi in pis] for pis in potential]
-    for rs in reqs:
-        for r in rs:
-            for name in r.scalar_resources:
-                mirror.scalar_slot(name)
-    vic_req = np.zeros((npc, k, mirror.r_slots), np.int64)
-    vic_valid = np.zeros((npc, k), bool)
-    for r_i, rs in enumerate(reqs):
-        for j, r in enumerate(rs):
-            vic_req[r_i, j] = _resource_vec(mirror, r)
-            vic_valid[r_i, j] = True
-    return vic_req, vic_valid, potential
+
+    They are state that follows the snapshot by its generations, as the
+    mirror's rows do (`NodeStateMirror._sync_rows`): per row the (node name,
+    `NodeInfo.generation`) it was derived from is kept, and a call derives
+    again only the rows whose pair differs. Every mutation of a NodeInfo
+    bumps its generation and `cache.update_snapshot` re-clones only nodes
+    whose generation advanced, so an unchanged pair means the row's `ni.pods`
+    holds the same PodInfos in the same order. What the pair does not cover
+    is the key (the preemptor's priority, `np_cap`, `r_slots`): a change of
+    it drops everything, and the build from nothing is the same per-row code
+    with every row stale."""
+
+    def __init__(self, mirror: NodeStateMirror):
+        self.mirror = mirror
+        self.rebuilt = 0    # rows the last call derived again
+        self.drop()
+
+    def drop(self) -> None:
+        self._key = None
+        self._names: List[str] = []
+        self._gens: List[int] = []
+        self._potential: List[list] = []
+        self._vic_req = self._vic_valid = None
+
+    def build(self, pod: Pod, snapshot):
+        """(vic_req [npc, K, R] i64, vic_valid [npc, K] bool, potential [n]
+        list-of-PodInfo in the same order) or None when no node holds a
+        victim or some node exceeds the K cap (host path owns it). The
+        arrays and the list are the holder's own, patched in place by the
+        next call: the caller is done with them before it returns."""
+        try:
+            return self._build(pod, snapshot.node_info_list)
+        except BaseException:
+            self.drop()     # half a walk is no state to patch from
+            raise
+
+    def _build(self, pod: Pod, nodes):
+        mirror = self.mirror
+        prio = pod.priority
+        key = (prio, mirror.np_cap, mirror.r_slots)
+        if key != self._key:
+            self.drop()
+            self._key = key
+        names, gens, potential = self._names, self._gens, self._potential
+        n = len(nodes)
+        stale = []
+        for i, ni in enumerate(nodes):
+            if i < len(names) and names[i] == ni.name \
+                    and gens[i] == ni.generation:
+                continue
+            pis = [pi for pi in ni.pods if pi.pod.priority < prio]
+            pis.sort(key=_reprieve_order)
+            if i < len(names):
+                names[i], gens[i], potential[i] = ni.name, ni.generation, pis
+            else:
+                names.append(ni.name)
+                gens.append(ni.generation)
+                potential.append(pis)
+            stale.append(i)
+        self.rebuilt = len(stale)
+        # shrink: the tail rows hold nobody now
+        emptied = stale + list(range(n, len(names)))
+        del names[n:], gens[n:], potential[n:]
+        kmax = max(map(len, potential), default=0)
+        if kmax == 0 or kmax > PREEMPT_K_CAP:
+            self.drop()
+            return None
+        # Intern every victim scalar-resource slot BEFORE touching the arrays
+        # (interning can grow r_slots; the caller's build_plan re-syncs the
+        # mirror after): at a new width everything starts again.
+        reqs = [[pi.pod.resource_request() for pi in potential[i]]
+                for i in stale]
+        for rs in reqs:
+            for r in rs:
+                for name in r.scalar_resources:
+                    mirror.scalar_slot(name)
+        if mirror.r_slots != key[2]:
+            return self._build(pod, nodes)
+        # k, and with it the shapes and the compiled program, is what a build
+        # from nothing gives: a kept row holds at most kmax <= k victims, so
+        # crossing a tier is a copy of the columns both widths have.
+        k = _pow2(kmax, 8)
+        vic_req, vic_valid = self._vic_req, self._vic_valid
+        if vic_valid is None or vic_valid.shape[1] != k:
+            self._vic_req = np.zeros((mirror.np_cap, k, key[2]), np.int64)
+            self._vic_valid = np.zeros((mirror.np_cap, k), bool)
+            if vic_valid is not None:
+                m = min(k, vic_valid.shape[1])
+                self._vic_req[:, :m] = vic_req[:, :m]
+                self._vic_valid[:, :m] = vic_valid[:, :m]
+            vic_req, vic_valid = self._vic_req, self._vic_valid
+        vic_req[emptied] = 0
+        vic_valid[emptied] = False
+        for r_i, rs in zip(stale, reqs):
+            for j, r in enumerate(rs):
+                vic_req[r_i, j] = _resource_vec(mirror, r)
+            vic_valid[r_i, :len(rs)] = True
+        return vic_req, vic_valid, potential
+
+
+def build_preemption_victims(pod: Pod, snapshot, mirror: NodeStateMirror):
+    """The victim tensors built from nothing: `PreemptionVictims` with every
+    row stale."""
+    return PreemptionVictims(mirror).build(pod, snapshot)
 
 
 def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
