@@ -297,6 +297,16 @@ class SchedulerMetrics:
             "node's pods: its NodeInfo generation, its place in the list, "
             "or the holder's key had changed) or 'kept' (the row of the "
             "last dry run, as it was).", ("how",)))
+        self.preemptor_plans = r(Counter(
+            "scheduler_preemptor_plan_total",
+            "Plans acquired for one pod of a template planned for before, "
+            "by site ('dry_run': the device what-if of a preemption; "
+            "'nominated': the evaluation of a nominated pod's own node) and "
+            "by how: 'kept' (the template's kept plan, with the nominated "
+            "lane, the row mask and the start index derived again) or "
+            "'built' (no plan was kept, or the events since, the shapes or "
+            "the bound pods' terms voided it: a full build, kept in turn).",
+            ("site", "how")))
         self.preemption_victims = r(Histogram(
             "scheduler_preemption_victims", "Victims per preemption.",
             buckets=(1, 2, 4, 8, 16, 32, 64)))

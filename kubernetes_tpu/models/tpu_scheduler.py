@@ -44,8 +44,9 @@ from ..core.queue import (QueuedCompositeGroupInfo, QueuedPodGroupInfo,
 from ..core.scheduler import (QueuedBind, Scheduler, ScheduleResult,
                               queue_wait)
 from ..ops.device_state import NodeStateMirror, enable_persistent_compilation_cache
-from ..ops.features import (PreemptionVictims, Unsupported, batch_supported,
-                            build_batch)
+from ..ops.features import (KeptPlan, PreemptionVictims, Unsupported,
+                            lane_requests, batch_supported, build_batch,
+                            plan_shape)
 from ..ops.kernel import schedule_batch
 
 
@@ -61,6 +62,10 @@ _NO_TEMPLATE = (object(), 0, "")
 # Batches that may be in flight on the device while the host commits retired
 # ones (2 = double buffering).
 PIPELINE_DEPTH = 2
+# Templates whose built plan is kept for _preemptor_plan, the oldest leaving
+# first: a bound on memory (a plan is some 40 bytes a node row on the
+# device), not a choice of path.
+_KEPT_PLANS = 8
 # Device-path circuit breaker (core/backoff.py CircuitBreaker): consecutive
 # failures that open it, and the seconds it then pins the host path.
 DEVICE_BREAKER_THRESHOLD = 3
@@ -148,6 +153,9 @@ class TPUScheduler(Scheduler):
         # the preemption what-if's victim tensors, kept from one preemptor
         # to the next and patched by the snapshot's generations
         self._victims = PreemptionVictims(self.mirror)
+        # built plans kept per pod template (_preemptor_plan): template key
+        # -> KeptPlan, the oldest entry leaving at _KEPT_PLANS
+        self._kept_plans: dict = {}
         self._holdover: Optional[QueuedPodInfo] = None
         # metrics
         self.device_scheduled = 0
@@ -922,6 +930,7 @@ class TPUScheduler(Scheduler):
         self._placement_plan_cache = None
         self._placement_mask_cache = None
         self._victims.drop()
+        self._kept_plans.clear()
         self._fail_memo.clear()
         self.metrics.batch_cache_flushed.inc("device_path_failure")
         self._after_flush = True
@@ -1000,7 +1009,7 @@ class TPUScheduler(Scheduler):
         with self.stages.stage("nominated.eval", batch.sampled,
                                engine="device") as st:
             try:
-                outcome = self._evaluate_nominated_node(fw, batch[0])
+                outcome = self._evaluate_nominated_node(fw, batch[0], st)
             except Unsupported:
                 outcome = "fell_through"
             st.say(outcome=outcome)
@@ -1009,16 +1018,20 @@ class TPUScheduler(Scheduler):
         # the pod is dealt with, and no ordinary cycle follows either
         return outcome != "fell_through"
 
-    def _evaluate_nominated_node(self, fw: Framework,
-                                 qpi: QueuedPodInfo) -> str:
-        """``bound``, ``fell_through`` or ``bind_refused``."""
+    def _evaluate_nominated_node(self, fw: Framework, qpi: QueuedPodInfo,
+                                 st) -> str:
+        """``bound``, ``fell_through`` or ``bind_refused``; the open
+        ``nominated.eval`` stage `st` is told where the plan came from
+        (``plan`` = ``kept`` / ``built``)."""
         pod = qpi.pod
         self.cache.update_snapshot(self.snapshot)
         row = self._snapshot_rows().get(pod.nominated_node_name)
         if row is None:
             return "fell_through"  # the node left: the ordinary cycle
         start = self.next_start_node_index
-        state, plan = self.build_plan(fw, pod, self.max_batch, only_row=row)
+        state, plan, how = self._preemptor_plan(
+            fw, pod, self.max_batch, "nominated", only_row=row)
+        st.say(plan=how)
         attrs = self._dispatch_attrs(plan, 1, 0)
         with self.stages.stage("device.dispatch", **attrs):
             results, _carry = self._dispatch(state, plan, 1, None)
@@ -1099,7 +1112,9 @@ class TPUScheduler(Scheduler):
             return None
         vic_req, vic_valid, potential = built
         t_victims = clock()
-        dstate, plan = self.build_plan(fw, pod, 1)
+        # snapshot and mirror are as the lines above left them
+        dstate, plan, how = self._preemptor_plan(fw, pod, 1, "dry_run",
+                                                 synced=True)
         if vic_req.shape[2] != self.mirror.r_slots:
             # build_plan interned the preemptor's never-seen scalar slots
             # AFTER the victim tensors were built, growing the mirror's
@@ -1137,7 +1152,7 @@ class TPUScheduler(Scheduler):
             # the stage's parts, and the shapes the kernel's cost is reckoned
             # from (rows, victim slots, resource slots)
             st.say(victims_ms=round(1e3 * (t_victims - t0), 3),
-                   plan_ms=round(1e3 * (t_plan - t_victims), 3),
+                   plan_ms=round(1e3 * (t_plan - t_victims), 3), plan=how,
                    dispatch_ms=round(1e3 * (t_dispatch - t_plan), 3),
                    fetch_ms=round(1e3 * (t_fetch - t_dispatch), 3),
                    victim_rows_rebuilt=victims.rebuilt,
@@ -1281,6 +1296,13 @@ class TPUScheduler(Scheduler):
                 return "extended resources backed by DRA"
         return None
 
+    def _commit_mirror_shardings(self) -> None:
+        if self.mesh is not None:
+            from ..parallel import mesh_state_shardings
+            self.mirror.commit_shardings(mesh_state_shardings(self.mesh))
+        else:
+            self.mirror.commit_shardings(None)
+
     def build_plan(self, fw: Framework, pod, batch_size: int,
                    only_row: Optional[int] = None):
         """Snapshot → mirror sync → batch feature build → device flush.
@@ -1294,11 +1316,7 @@ class TPUScheduler(Scheduler):
         pinned jits on the resident itself — no per-session single-device
         copy + device_put round-trip of the whole state."""
         self.cache.update_snapshot(self.snapshot)
-        if self.mesh is not None:
-            from ..parallel import mesh_state_shardings
-            self.mirror.commit_shardings(mesh_state_shardings(self.mesh))
-        else:
-            self.mirror.commit_shardings(None)
+        self._commit_mirror_shardings()
         self.mirror.sync(self.snapshot.node_info_list)
         ipa = fw.plugin("InterPodAffinity")
         dra_enabled, dra_in_use = self._dra_ctx(fw)
@@ -1334,6 +1352,99 @@ class TPUScheduler(Scheduler):
             from ..parallel import shard_features
             plan.features = shard_features(plan.features, self.mesh)
         return state, plan
+
+    def _kept_plan_guard(self) -> tuple:
+        """What a kept plan holds that neither its key nor a journal event
+        says: the shapes its arrays were built at, the mesh they are placed
+        on, the sample size behind `to_find`, and the count of bound pods
+        that carry inter-pod terms (this scheduler's own binds are not
+        journalled, the carry holds them; one of a pod with terms moves
+        `exist_anti` / `ipa_base` of a plan that has neither, and moves this
+        count, as it gates `_neutral_sig`)."""
+        return plan_shape(self.mirror) + (
+            self.mesh, self.percentage_of_nodes_to_score,
+            self.cache.affinity_pod_refs)
+
+    def _keep_plan(self, key: tuple, pod, plan) -> Optional[KeptPlan]:
+        """Keep under `key` (`_template_key`) a plan just built for `pod`'s
+        template with ``only_row`` None (`build_plan`; snapshot and mirror
+        are the build's), for `_preemptor_plan` to find. Only a pod whose
+        filters read other pods by their requests alone
+        (`_resources_only_block`, the precondition of both sites that ask)
+        has a plan worth keeping: nothing a pod brings to a node moves its
+        features. None where it has not. Nobody writes to a built plan, so
+        the session's own object is kept."""
+        if self._resources_only_block(pod) is not None:
+            return None
+        kept = self._kept_plans
+        kept.pop(key, None)
+        if len(kept) >= _KEPT_PLANS:
+            kept.pop(next(iter(kept)))
+        entry = kept[key] = KeptPlan(plan, self.cluster_event_seq,
+                                     self._kept_plan_guard())
+        return entry
+
+    def _preemptor_plan(self, fw: Framework, pod, batch_size: int, site: str,
+                        only_row: Optional[int] = None, synced: bool = False):
+        """(device state, plan, ``kept`` | ``built``) for ONE pod of a
+        template that was planned for before: the what-if of its preemption
+        (`site` ``dry_run``) and, once it is nominated, the evaluation of
+        its own node (``nominated``, with ``only_row``). Such a plan differs
+        from the template's last one in the nominated lane, the row mask,
+        the start index and the result width, which `KeptPlan.derive` makes
+        again. The template's plan is kept (`_keep_plan`: by the session
+        that built it, or by the build here) and stays the template's while
+        the journal's events since classify under `_classify_delta`, the
+        rule by which a session resumes its plan: a `pod_local` plan and
+        plain pods' events dirty mirror rows, never features. A node update
+        (taints, allocatable) keeps the features too and has `has_pns` read
+        again from the synced mirror, both ways, where a session's row patch
+        only refuses the flag's rise. Anything else, or a changed guard
+        (`_kept_plan_guard`), drops the entry and builds. The device state
+        is the mirror's flush, as in `build_plan`: the rows the events
+        dirtied are scattered, the truth the what-if reads. ``synced``: the
+        caller has just refreshed the snapshot and synced the mirror to it.
+        `scheduler_preemptor_plan_total{site, how}` counts every call;
+        session starts keep their own counters."""
+        sig = fw.sign_pod(pod)
+        aux_shape = self._aux_shape(pod)
+        key = self._template_key(fw, pod, sig, aux_shape)
+        if not synced:
+            self.cache.update_snapshot(self.snapshot)
+        # the lane's scalar slots first: a never-seen one grows r_slots,
+        # and with it the guard
+        lane = lane_requests(self.mirror, self._nominated_lane(pod))
+        entry = self._kept_plans.get(key) if sig is not None else None
+        if entry is not None:
+            # and the mirror's sync before the guard is read, for the same
+            # reason: a bound pod's never-seen slot is interned there
+            self._commit_mirror_shardings()
+            if not synced or self.mirror._full_flush:
+                self.mirror.sync(self.snapshot.node_info_list)
+            _events, cls = self._classify_since(entry.seq, entry.plan)
+            if cls is None or entry.guard != self._kept_plan_guard():
+                del self._kept_plans[key]
+                entry = None
+        how = "built" if entry is None else "kept"
+        self.metrics.preemptor_plans.inc(site, how)
+        if entry is None:
+            state, plan = self.build_plan(fw, pod, batch_size)
+            # (a plan that is not one to keep is still derived from, once)
+            entry = (sig is not None and self._keep_plan(key, pod, plan)
+                     or KeptPlan(plan, self.cluster_event_seq, ()))
+        else:
+            if not cls[3]:  # a node update among the events
+                entry.taints_moved(self.mirror, self.snapshot.num_nodes())
+            entry.seq = self.cluster_event_seq
+            state = self.mirror.flush()
+        plan = entry.derive(
+            self.mirror, self.snapshot.num_nodes(), batch_size=batch_size,
+            start_index=self.next_start_node_index, nom_reqs=lane,
+            only_row=only_row)
+        if self.mesh is not None:
+            from ..parallel import shard_features
+            plan.features = shard_features(plan.features, self.mesh)
+        return state, plan, how
 
     def _count_ipa(self, plan) -> None:
         """What a built plan's required inter-pod term tables cost
@@ -1708,6 +1819,16 @@ class TPUScheduler(Scheduler):
             level = max(level, 1 if ev.shrink else 2)
         return ("benign", "safe", "strict")[level], names, node_only, pod_only
 
+    def _classify_since(self, seq: int, plan):
+        """(events, classification) of what the journal holds since `seq`
+        under `plan`: the one answer to whether a kept plan outlived them.
+        The events are None where the journal no longer reaches back to
+        `seq`, the classification None there and wherever `_classify_delta`
+        says so."""
+        events = self.journal.since(seq)
+        return events, (self._classify_delta(events, plan)
+                        if events is not None else None)
+
     def _note_session_events(self, sd, plan, node_names, busy: bool) -> bool:
         """The ONE journal-consumption protocol both session kinds run at
         their invalidation checks. `sd` is the session's mutable delta view
@@ -1722,10 +1843,7 @@ class TPUScheduler(Scheduler):
 
     def _consume_session_events(self, sd, plan, node_names,
                                 busy: bool) -> bool:
-        events = self.journal.since(sd.start_seq)
-        if events is None:
-            return False
-        cls = self._classify_delta(events, plan)
+        _events, cls = self._classify_since(sd.start_seq, plan)
         if cls is None:
             return False
         level, names, _node_only, pod_only = cls
@@ -1880,9 +1998,7 @@ class TPUScheduler(Scheduler):
                 if rseq == self.cluster_event_seq:
                     kind = "resume"
                 else:
-                    events = self.journal.since(rseq)
-                    cls = (self._classify_delta(events, plan)
-                           if events is not None else None)
+                    events, cls = self._classify_since(rseq, plan)
                     if cls is not None:
                         # No pipeline is in flight at session start: every
                         # level (benign/safe/strict) may patch here.
@@ -1907,11 +2023,25 @@ class TPUScheduler(Scheduler):
             _time.perf_counter() - _t_hint)
         if kind == "full":
             state, plan = self.build_plan(fw, head_pod, self.max_batch)
+            self._keep_plan(
+                self._template_key(fw, head_pod, sig, aux_shape), head_pod,
+                plan)
             node_names = [ni.name for ni in self.snapshot.node_info_list]
             self.metrics.plan_rebuild_cause.inc(cause)
         self.plan_build_cause = cause if kind == "full" else ""
         self._count_rebuild(kind)
         return state, plan, carry, node_names, kind
+
+    def _template_key(self, fw: Framework, pod, sig, aux_shape,
+                      neutral_ok: bool = True) -> tuple:
+        """What a built plan is kept under, for a session to resume
+        (`_save_resume`) or for `_preemptor_plan`: the pod's signature, the
+        neutral (namespace-erased) one where the pod is eligible, the
+        profile, the counted-constraint shape and the claims' version."""
+        nsig = self._neutral_sig(fw, pod, sig) if neutral_ok else None
+        mode = ("neutral", nsig) if nsig is not None else ("exact", sig)
+        return mode + (id(fw), aux_shape,
+                       getattr(self.clientset, "resource_claims_rv", 0))
 
     def _save_resume(self, fw: Framework, head_pod, sig, aux_shape,
                      state, plan, carry, node_names,
@@ -1919,12 +2049,9 @@ class TPUScheduler(Scheduler):
         """Capture a clean session's end state for the next resume check.
         Saved under the neutral (namespace-erased) signature when eligible,
         so a stream of label/namespace-only-different sessions chains."""
-        nsig = self._neutral_sig(fw, head_pod, sig) if neutral_ok else None
-        mode = ("neutral", nsig) if nsig is not None else ("exact", sig)
         self._resume = (
-            mode + (id(fw), aux_shape,
-                    getattr(self.clientset, "resource_claims_rv", 0),
-                    self.attempts, self.state_unwinds),
+            self._template_key(fw, head_pod, sig, aux_shape, neutral_ok)
+            + (self.attempts, self.state_unwinds),
             self.cluster_event_seq,
             (state, plan, carry, node_names),
             self._nom_resume_key(head_pod.priority))

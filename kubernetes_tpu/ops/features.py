@@ -18,7 +18,7 @@ from __future__ import annotations
 
 import contextlib
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
@@ -547,11 +547,7 @@ def build_batch(
     # Nominated pods' scalar slots intern HERE, before the re-sync point —
     # a grow later would orphan every feature vector already built at the
     # old r_slots width.
-    nom_reqs = [(row, npi.pod.resource_request())
-                for row, npi in (nominated or ())]
-    for _row, r in nom_reqs:
-        for name in r.scalar_resources:
-            mirror.scalar_slot(name)
+    nom_reqs = lane_requests(mirror, nominated)
     if fit_plugin is not None:
         specs = fit_plugin.resources
         strategy = {"LeastAllocated": 0, "MostAllocated": 1}[fit_plugin.scoring_strategy]
@@ -617,9 +613,6 @@ def build_batch(
         for r_i, ni in enumerate(nodes):
             if host_ports_conflict(ports, ni.used_ports):
                 extra_ok_host[r_i] = False
-
-    if only_row is not None:
-        extra_ok_host &= np.arange(len(nodes)) == only_row
 
     # -- ImageLocality static score (imagelocality.go scaledImageScore) -----
     il_host = None
@@ -695,7 +688,7 @@ def build_batch(
         [req.milli_cpu or NodeInfo.DEFAULT_MILLI_CPU,
          req.memory or NodeInfo.DEFAULT_MEMORY], i64)
 
-    vmax = _pow2(max((len(ax.values) for ax in mirror.axes.values()), default=1) + 1, 64)
+    vmax = _plan_vmax(mirror)
 
     # ---- DNS tables ------------------------------------------------------
     c1 = _pow2(len(dns))
@@ -995,15 +988,7 @@ def build_batch(
     # ---- nominated-pod lane (two-pass filter pass 1, resources only) -----
     # (scalar slots were interned at the top of build_batch, before re-sync)
     has_nom = bool(nominated)
-    if has_nom:
-        nom_req = np.zeros((npc, mirror.r_slots), i64)
-        nom_pods = np.zeros(npc, i32)
-        for row, r in nom_reqs:
-            nom_req[row] += _resource_vec(mirror, r)
-            nom_pods[row] += 1
-    else:
-        nom_req = np.zeros((0, mirror.r_slots), i64)
-        nom_pods = np.zeros(0, i32)
+    nom_req, nom_pods = _lane_arrays(mirror, nom_reqs)
 
     # ---- counted aux constraint: CSI attach room / DRA free devices ------
     AUX_BIG = (1 << 30)
@@ -1053,7 +1038,8 @@ def build_batch(
         node_name_id=jnp.asarray(node_name_id),
         tolerates_unsched=jnp.asarray(tolerates_unsched),
         sel_match=jnp.asarray(_pad_bool(sel_match_host, npc)),
-        extra_ok=jnp.asarray(_pad_bool(extra_ok_host, npc, default=True)),
+        extra_ok=jnp.asarray(_only_row(
+            _pad_bool(extra_ok_host, npc, default=True), n, only_row)),
         il_score=jnp.asarray(_pad_i64(il_host, npc)),
         na_raw=jnp.asarray(_pad_i64(na_host, npc)),
         dns_axis=jnp.asarray(dns_axis), dns_active=jnp.asarray(dns_active),
@@ -1089,7 +1075,7 @@ def build_batch(
         batch_pad=_batch_tier(batch_size),
         fit_strategy=strategy,
         vmax=vmax,
-        has_pns=bool((mirror.h_taint_eff[:n] == EFFECT_PREFER_NO_SCHEDULE).any()),
+        has_pns=_has_pns(mirror, n),
         # With a landing axis the kernel reads ipa_base whatever this says
         # (`if KD or has_ipa_base`), so the flag follows the axis: an empty
         # cluster's plan and a loaded one's are then one compiled program,
@@ -1132,6 +1118,54 @@ def _pad_i64(vals, npc: int) -> np.ndarray:
     return out
 
 
+def lane_requests(mirror: NodeStateMirror, nominated) -> list:
+    """[(row, request)] of the nominated lane's pods, their scalar slots
+    interned: a slot that grows `r_slots` must do so before any vector of
+    that width is built, or kept."""
+    nom_reqs = [(row, npi.pod.resource_request())
+                for row, npi in (nominated or ())]
+    for _row, r in nom_reqs:
+        for name in r.scalar_resources:
+            mirror.scalar_slot(name)
+    return nom_reqs
+
+
+def _lane_arrays(mirror: NodeStateMirror, nom_reqs) -> tuple:
+    """(nom_req, nom_pods) of the nominated lane: per row what its nominated
+    pods ask and how many they are; zero rows where nobody is nominated."""
+    if not nom_reqs:
+        return (np.zeros((0, mirror.r_slots), np.int64),
+                np.zeros(0, np.int32))
+    nom_req = np.zeros((mirror.np_cap, mirror.r_slots), np.int64)
+    nom_pods = np.zeros(mirror.np_cap, np.int32)
+    for row, r in nom_reqs:
+        nom_req[row] += _resource_vec(mirror, r)
+        nom_pods[row] += 1
+    return nom_req, nom_pods
+
+
+def _only_row(extra_ok: np.ndarray, n: int, only_row: Optional[int]):
+    """The padded `extra_ok` with every node's row but `only_row` refused
+    (the padding stays as it is); itself where `only_row` is None."""
+    if only_row is None:
+        return extra_ok
+    out = extra_ok.copy()
+    out[:n] = False
+    out[only_row] = extra_ok[only_row]
+    return out
+
+
+def _plan_vmax(mirror: NodeStateMirror) -> int:
+    """The width of a plan's count tables: the tier of the most values any
+    topology axis of the mirror holds."""
+    return _pow2(max((len(ax.values) for ax in mirror.axes.values()),
+                     default=1) + 1, 64)
+
+
+def _has_pns(mirror: NodeStateMirror, n: int) -> bool:
+    return bool((mirror.h_taint_eff[:n] == EFFECT_PREFER_NO_SCHEDULE).any())
+
+
 def _batch_tier(n: int) -> int:
     """Coarse tiers of the result buffer's width: each distinct tier is a
     separate XLA compile (~1 min on first use), so bound them to {8, 64,
@@ -1142,6 +1176,60 @@ def _batch_tier(n: int) -> int:
     if n <= 64:
         return 64
     return _pow2(n, 512)
+
+
+def plan_shape(mirror: NodeStateMirror) -> tuple:
+    """What of the mirror sizes a plan's arrays: a plan built at one shape
+    is no plan at another."""
+    return mirror.np_cap, mirror.r_slots, _plan_vmax(mirror)
+
+
+@dataclass
+class KeptPlan:
+    """A built plan kept for its pod template (models/tpu_scheduler.py
+    `_preemptor_plan`: the preemption what-if and a nominated pod's own node
+    each plan for ONE pod of a template they have planned for before).
+    `plan` is what `build_batch` gave with ``only_row`` None; `seq` the
+    journal's sequence up to which it is known to hold; `guard` what the key
+    does not say and no event announces (the owner's to compose, with
+    `plan_shape` in it). What a kept plan cannot hold is derived again at
+    every use (`derive`): the nominated lane, the ``only_row`` mask, the
+    start index, the width of the results. Everything else in it is the
+    template's and the nodes' own (labels, images, declared features), which
+    the events that keep it valid do not touch."""
+
+    plan: BatchPlan
+    seq: int
+    guard: tuple
+    _extra_ok: Optional[np.ndarray] = None  # the plan's, on the host
+
+    def taints_moved(self, mirror: NodeStateMirror, n: int) -> None:
+        """A node update lies behind the plan (taints, allocatable or the
+        unschedulable flag of a row; labels, images and declared features
+        intact): of the plan only `has_pns` reads those rows, and the
+        synced mirror says it again as `build_batch` would."""
+        self.plan = replace(self.plan, has_pns=_has_pns(mirror, n))
+
+    def derive(self, mirror: NodeStateMirror, n: int, *, batch_size: int,
+               start_index: int, nom_reqs,
+               only_row: Optional[int] = None) -> BatchPlan:
+        """The plan `build_batch` would give now for `batch_size` pods of the
+        template, with `nom_reqs` (`lane_requests`) the nominated lane and
+        ``only_row`` the one row it may land on: at most four uploads and no
+        pass over the nodes. The mirror is synced to the `n` nodes."""
+        feats = self.plan.features
+        nom_req, nom_pods = _lane_arrays(mirror, nom_reqs)
+        again = {"nom_req": jnp.asarray(nom_req),
+                 "nom_pods": jnp.asarray(nom_pods),
+                 "start_index": jnp.asarray(np.int32(start_index % max(1, n)))}
+        if only_row is not None:
+            if self._extra_ok is None:
+                self._extra_ok = np.asarray(feats.extra_ok)
+            again["extra_ok"] = jnp.asarray(
+                _only_row(self._extra_ok, n, only_row))
+        return replace(self.plan, features=feats._replace(**again),
+                       has_nom=bool(nom_reqs),
+                       batch_pad=_batch_tier(batch_size))
 
 
 PREEMPT_K_CAP = 256  # victims-per-node tier ceiling (recompile guard)
