@@ -28,26 +28,50 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import math
 import time as _time
 from contextlib import contextmanager
 from functools import partial
 from typing import List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 _log = logging.getLogger(__name__)
 
+from ..compile_cache import watch_compiles
+from ..core import spans as _spans
+from ..core.backoff import CircuitBreaker
+from ..core.cache import (EV_NAMESPACE, EV_NODE_UPDATE, EV_POD_ADD,
+                          EV_POD_REMOVE, EV_POD_UPDATE, EV_QUEUE,
+                          EV_STRUCTURAL)
+from ..core.features import TPU_BATCH_SCHEDULING
 from ..core.framework import OK as _OK_STATUS
-from ..core.framework import WAIT, Framework
+from ..core.framework import (UNSCHEDULABLE_AND_UNRESOLVABLE, WAIT, CycleState,
+                              FitError, Framework, PlacementProgress,
+                              PodGroupAssignments, Status)
 from ..core.queue import (QueuedCompositeGroupInfo, QueuedPodGroupInfo,
                           QueuedPodInfo)
 from ..core.scheduler import (QueuedBind, Scheduler, ScheduleResult,
                               queue_wait)
-from ..ops.device_state import NodeStateMirror, enable_persistent_compilation_cache
-from ..ops.features import (KeptPlan, PreemptionVictims, Unsupported,
+from ..ops.codebook import EFFECT_PREFER_NO_SCHEDULE
+from ..ops.device_state import (NodeStateMirror, patch_tier,
+                                enable_persistent_compilation_cache)
+from ..ops.features import (KeptPlan, PreemptionVictims, Unsupported, _pow2,
                             lane_requests, batch_supported, build_batch,
-                            plan_shape)
-from ..ops.kernel import schedule_batch
+                            diagnose_unschedulable, plan_shape,
+                            volume_device_support)
+from ..ops.kernel import (dry_run_preemption, patch_carry_rows,
+                          patch_carry_rows_pinned, schedule_batch,
+                          schedule_placements)
+from ..parallel.mesh import (collective_report, make_mesh, mesh_host_split,
+                             mesh_shard_count, mesh_state_shardings,
+                             shard_features, sharded_lap_schedule)
+from ..plugins.basic import DefaultBinder
+from ..plugins.preemption import Candidate
+from .score_hints import ScoreHintCache, hint_eligible
 
 
 # Sentinel fallback_reason: the popped entity is a pod GROUP that can ride a
@@ -62,9 +86,10 @@ _NO_TEMPLATE = (object(), 0, "")
 # Batches that may be in flight on the device while the host commits retired
 # ones (2 = double buffering).
 PIPELINE_DEPTH = 2
-# Templates whose built plan is kept for _preemptor_plan, the oldest leaving
-# first: a bound on memory (a plan is some 40 bytes a node row on the
-# device), not a choice of path.
+# Templates whose built plan is kept to derive from (_preemptor_plan), the
+# oldest leaving first: a bound on memory (a plan is some 40 bytes a node
+# row on the device), not a choice of path. A plan kept for its session's
+# tail alone is not counted: at most one tail lives.
 _KEPT_PLANS = 8
 # Device-path circuit breaker (core/backoff.py CircuitBreaker): consecutive
 # failures that open it, and the seconds it then pins the host path.
@@ -88,33 +113,43 @@ class _Batch(list):
 
 
 class _SessionDelta:
-    """A live session's journal-patchable view: the device state + carry the
-    delta patches rewrite, the seq watermark already consumed, and whether a
-    shrink patch is parked waiting for the pipeline to drain. One protocol
-    (TPUScheduler._note_session_events) mutates it for both session kinds."""
+    """A live session's mutable view, of both session kinds, from where it
+    opens (TPUScheduler._open_session) to where it closes (_close_session):
+    what it plans for (`fw`, the head `pod`, `sig`, `aux_shape`, and the
+    namespace-erased `nsig` where sessions chain on it: plain pods', not
+    gangs'), the `plan`, the `node_names` of its rows, how the plan was come
+    by (`built`: kind, cause); what the journal patches
+    (_note_session_events): the device `state` + `carry`, the seq watermark
+    consumed, whether a shrink patch waits for the pipeline to drain; the
+    batches `inflight` (entities, results, seq), the rows that took a pod
+    (`ok_rows`) and those where carry and host disagree (`dirty_rows`)."""
 
-    __slots__ = ("state", "carry", "start_seq", "patch_pending",
-                 "busy_patch_rows")
+    __slots__ = ("fw", "pod", "sig", "nsig", "neutral_ok", "aux_shape", "plan",
+                 "node_names", "built", "state", "carry", "start_seq",
+                 "start_unwinds", "patch_pending", "busy_patch_rows",
+                 "inflight", "ok_rows", "dirty_rows")
 
-    def __init__(self, state, carry, start_seq):
-        self.state = state
-        self.carry = carry
-        self.start_seq = start_seq
+    def __init__(self, fw, pod, sig, nsig, neutral_ok, aux_shape):
+        self.fw = fw
+        self.pod = pod
+        self.sig = sig
+        self.nsig = nsig
+        self.neutral_ok = neutral_ok
+        self.aux_shape = aux_shape
         self.patch_pending = False
-        # Rows patched while the pipeline was BUSY (shard-plane foreign-bind
-        # feed): an in-flight batch may have placed onto one of them after
-        # dispatch, and that placement's aggregate is not in mirror staging
-        # yet — so the patch can understate the row until the batch retires.
-        # The session end charges these rows dirty, and adopt() re-encodes
-        # them from post-commit staging truth; in between, the binding
-        # subresource's capacity re-validation bounds the damage to a 409.
+        self.inflight: list = []
+        self.ok_rows: List[int] = []
+        self.dirty_rows: List[int] = []
+        # Rows patched while the pipeline was BUSY (_consume_session_events):
+        # an in-flight batch may have placed onto one after dispatch, which
+        # mirror staging does not hold yet, so the session end charges them
+        # dirty and adopt() re-encodes them from post-commit staging truth.
         self.busy_patch_rows: list = []
 
 
 def _pow2_pad(n: int) -> int:
     """Placement-axis pow2 tier (shared by warm + live paths so the warm
     compile always matches the live kernel shape)."""
-    from ..ops.features import _pow2
     return _pow2(max(1, n))
 
 
@@ -127,12 +162,9 @@ class TPUScheduler(Scheduler):
                  **kwargs):
         kwargs.setdefault("deterministic_ties", True)
         super().__init__(*args, **kwargs)
-        self._mesh_arg = mesh
-        from ..core.features import TPU_BATCH_SCHEDULING
         self.device_enabled = self.gates.enabled(TPU_BATCH_SCHEDULING)
         self.max_batch = max_batch if max_batch is not None else self.config.max_batch
         enable_persistent_compilation_cache()
-        from ..compile_cache import watch_compiles
         watch_compiles()  # a slow stage says whether a compile ran inside it
         # Multi-chip: with >1 device the node axis shards over a
         # ("cells", "nodes") mesh and the SAME jitted kernel compiles SPMD
@@ -143,9 +175,7 @@ class TPUScheduler(Scheduler):
         # never a silent single-device run.
         self.mesh = None
         if mesh == "auto":
-            import jax
             if len(jax.devices()) > 1:
-                from ..parallel import make_mesh
                 self.mesh = make_mesh(n_cells=1)
         else:
             self.mesh = mesh  # explicit Mesh, or None to force single-device
@@ -153,10 +183,12 @@ class TPUScheduler(Scheduler):
         # the preemption what-if's victim tensors, kept from one preemptor
         # to the next and patched by the snapshot's generations
         self._victims = PreemptionVictims(self.mirror)
-        # built plans kept per pod template (_preemptor_plan): template key
-        # -> KeptPlan, the oldest entry leaving at _KEPT_PLANS
-        self._kept_plans: dict = {}
+        self._plans: dict = {}  # "the keeper of built plans", below
+        # _evaluate_placements: the last group cycle's plan, and its masks
+        self._placement_plan_cache: dict = {}
+        self._placement_mask_cache: dict = {}
         self._holdover: Optional[QueuedPodInfo] = None
+        self._after_flush = False  # a flush since the last committed batch
         # metrics
         self.device_scheduled = 0
         self.dispatch_seq = 0  # the last live dispatch's ordinal (`seq`)
@@ -188,21 +220,10 @@ class TPUScheduler(Scheduler):
         # shared signature holder, priority and scheduler name of the
         # session's head, or _NO_TEMPLATE where the head is no plain clone.
         self._session_template = _NO_TEMPLATE
-        # Terminal-failure memos: state key -> (unschedulable plugins,
-        # message) for side-effect-free host diagnoses (see _fail_from_memo).
-        # A small keyed LRU, not a single slot: two ALTERNATING unschedulable
-        # signatures must each stay memoized or every miss tears down the
-        # live device session (VERDICT r3 weakness 6).
+        # Terminal-failure memos, a small keyed LRU (_fail_from_memo): state
+        # key -> (unschedulable plugins, message)
         self._fail_memo: "dict" = {}
         self._fail_memo_cap = 64
-        # Session-resume cache: (fw id, sig, cluster_event_seq, attempts) →
-        # (state, plan, carry) captured at the end of a clean device session.
-        # When the next session starts with an identical signature and NO
-        # intervening activity (no host attempts, no cluster events), the
-        # snapshot/mirror/feature rebuild is skipped entirely and the carry
-        # chains on — the cross-session generalization of the in-session
-        # chained carry (plan_build was ~1s of the r03 measured window).
-        self._resume = None
         # Live session's namespace-erased signature (None = exact-sig only)
         # and the node-name→row map behind journal delta patches.
         self._session_neutral_sig = None
@@ -215,16 +236,11 @@ class TPUScheduler(Scheduler):
         self._limited_drivers_n = -1
         # Claims referenced by pods already accepted into the CURRENT device
         # session (committed or in flight): a second pod sharing one of them
-        # must not join — the kernel counts attach units per landing, the
-        # host per distinct claim (see ops/features.py volume_device_support).
+        # must not join (_session_compatible).
         self._session_claims: set = set()
-        # Device-path circuit breaker (core/backoff.py; docs/RESILIENCE.md):
-        # any unexpected exception from the device path is caught ONCE, the
-        # work reruns on the host Evaluator, and after N consecutive
-        # failures the breaker pins the host path for a cool-down. The host
-        # path produces identical assignments (the repo's core equivalence
-        # invariant), so degradation is graceful, never a crashed cycle.
-        from ..core.backoff import CircuitBreaker
+        # Device-path circuit breaker (core/backoff.py; docs/RESILIENCE.md;
+        # _note_device_failure): after N consecutive failures it pins the
+        # host path, which produces identical assignments, for a cool-down.
         self.device_breaker = CircuitBreaker(
             failure_threshold=DEVICE_BREAKER_THRESHOLD,
             cooldown=DEVICE_BREAKER_COOLDOWN_S)
@@ -232,15 +248,23 @@ class TPUScheduler(Scheduler):
         # device kernel boundary crossing; may raise.
         self._fault_hook = None
         # Signature-keyed score-hint fast path (models/score_hints.py;
-        # KEP-5598 OpportunisticBatch, cross-cycle): a clean session's end
-        # carry seeds a host-side walk that binds the NEXT identical pods
-        # without any device dispatch. Event-driven freshness rides the
-        # journal.
-        from .score_hints import ScoreHintCache
+        # _try_hint_binds)
         self._hints = ScoreHintCache(self, enabled=self.device_enabled)
         self.hint_hits = 0
         self.hint_misses = 0
         self.hint_invalidations = 0
+        # Everything kept that was derived from device state, with how it is
+        # dropped. _note_device_failure runs the list: a holder registered
+        # HERE cannot outlive a fault (tests/test_plan_keeper.py walks it).
+        self._device_holders = {
+            "mirror": self.mirror.invalidate,
+            "plans": self._plans.clear,
+            "victims": self._victims.drop,
+            "hints": partial(self._hints.invalidate, "device_failure"),
+            "placement_plans": self._placement_plan_cache.clear,
+            "placement_masks": self._placement_mask_cache.clear,
+            "fail_memo": self._fail_memo.clear,
+        }
 
     # Host/device time split (schedule_one.go:574-style step accounting,
     # re-shaped for the batch pipeline), exported by the perf harness and
@@ -488,49 +512,26 @@ class TPUScheduler(Scheduler):
         return sorted(qgpi.members, key=lambda m: (-m.pod.priority, m.timestamp))
 
     def run_gang_device_session(self, fw: Framework, first: QueuedPodGroupInfo) -> None:
-        """Crash-proof wrapper — see run_device_session: stranded packs
-        rerun on the host group cycle on an unexpected device failure."""
-        pending: List[List[QueuedPodGroupInfo]] = []
-        try:
-            self._run_gang_device_session(fw, first, pending)
-        except Unsupported:
-            raise
-        except Exception as e:  # noqa: BLE001 - device→host fallback
-            self._note_device_failure(e, "gang_device_session")
-            for pk in pending:
-                for g in pk:
-                    self._recover_qpi(g)
+        """A session of pod groups from `first` on (`_run_session`)."""
+        self._run_session(self._run_gang_device_session, fw, [first],
+                          "gang_device_session")
 
     def _run_gang_device_session(self, fw: Framework,
-                                 first: QueuedPodGroupInfo,
+                                 pack: Optional[List[QueuedPodGroupInfo]],
                                  pending: List[List[QueuedPodGroupInfo]]) -> None:
-        pack: Optional[List[QueuedPodGroupInfo]] = [first]
-        pending.append(pack)  # crash-recovery registry (wrapper);
-        # registered BEFORE build_plan so a plan-build crash recovers too.
-        sig = fw.sign_pod(first.members[0].pod)
-        aux_shape = self._aux_shape(first.members[0].pod)
+        first = pack[0]
         # Claims already accepted into this session (all members' PVCs):
         # collect_pack rejects groups re-using any of them — the kernel's
         # per-landing attach count assumes distinct claims, like the host's
         # distinct-claim NodeVolumeLimits count.
         self._session_claims = {
             c for m in first.members for c in self._claims_of(m.pod)}
-        claims_rv = getattr(self.clientset, "resource_claims_rv", 0)
-        # Gang resumes stay exact-signature (nsig=None): the neutral erasure
-        # targets plain-pod namespace sweeps, not group entities.
+        # Gang resumes stay exact-signature: the neutral erasure targets
+        # plain-pod namespace sweeps, not group entities.
+        sd = self._open_session(fw, first.members[0].pod, neutral_ok=False)
+        sig, aux_shape, node_names = sd.sig, sd.aux_shape, sd.node_names
+        inflight, ok_rows, dirty_rows = sd.inflight, sd.ok_rows, sd.dirty_rows
         stages = self.stages
-        with stages.stage("plan.build") as st:
-            state, plan, carry, node_names, kind = \
-                self._resume_or_rebuild(fw, first.members[0].pod, sig, None,
-                                        aux_shape, claims_rv)
-            st.say(kind=kind, cause=self.plan_build_cause)
-        built = {"kind": kind, "cause": self.plan_build_cause}
-        sd = _SessionDelta(state, carry, self.cluster_event_seq)
-        del state, carry
-        start_unwinds = self.state_unwinds
-        inflight: List[Tuple[List[QueuedPodGroupInfo], object, int]] = []
-        ok_rows: List[int] = []
-        dirty_rows: List[int] = []
         invalidated = False
 
         def collect_pack(took) -> List[QueuedPodGroupInfo]:
@@ -562,8 +563,7 @@ class TPUScheduler(Scheduler):
                 if sd.patch_pending:
                     if inflight:
                         break  # retire dispatched packs before patching
-                    if not self._note_session_events(sd, plan, node_names,
-                                                     busy=False):
+                    if not self._note_session_events(sd, busy=False):
                         invalidated = True
                         break
                 if pack is None:
@@ -572,35 +572,16 @@ class TPUScheduler(Scheduler):
                     if pack is None:
                         break
                     pending.append(pack)
-                members = [m for g in pack for m in self._sorted_members(g)]
-                attrs = self._dispatch_attrs(plan, len(members),
-                                             len(inflight))
-                with stages.stage("device.dispatch", **attrs):
-                    results, sd.carry = self._dispatch(
-                        sd.state, plan, len(members), sd.carry)
-                    results.copy_to_host_async()
-                self._count_dispatch(attrs)
-                inflight.append((pack, results, attrs["seq"]))
-                stages.inflight = len(inflight)
-                self.metrics.goroutines.set(float(len(inflight)),
-                                            "device_dispatch")
+                self._dispatch_next(
+                    sd, pack, sum(len(g.members) for g in pack))
                 pack = None
             if not inflight:
                 break
-            groups, results, seq = inflight.pop(0)
-            stages.inflight = len(inflight)
-            self.metrics.goroutines.set(float(len(inflight)),
-                                        "device_dispatch")
-            with stages.stage("device.wait", seq=seq):
-                res = np.asarray(results)
-            if (invalidated or self.state_unwinds != start_unwinds
-                    or not self._note_session_events(sd, plan, node_names,
-                                                     busy=True)):
+            groups, res = self._retire_oldest(sd)
+            if (invalidated or self.state_unwinds != sd.start_unwinds
+                    or not self._note_session_events(sd, busy=True)):
                 invalidated = True
-                for g in groups:
-                    for m in self._sorted_members(g):
-                        self.host_path_pods += 1
-                    self.process_one(g)
+                self._to_host_path(groups)
                 if groups in pending:
                     pending.remove(groups)
                 continue
@@ -619,53 +600,28 @@ class TPUScheduler(Scheduler):
                         for r in rows:
                             if r >= 0:
                                 dirty_rows.append(int(r))
-                        for _ in ms:
-                            self.host_path_pods += 1
-                        self.process_one(g)
+                        self._to_host_path([g])
                         invalidated = True
                         continue
                     if not self._commit_gang_group(fw, g, ms, rows, node_names,
                                                    ok_rows, dirty_rows):
                         invalidated = True  # a member's host commit rejected a
                         # placement the carry already applied
-                    if (self.state_unwinds != start_unwinds
-                            or not self._note_session_events(sd, plan, node_names,
-                                                             busy=True)):
+                    if (self.state_unwinds != sd.start_unwinds
+                            or not self._note_session_events(sd, busy=True)):
                         invalidated = True
                         sd.start_seq = self.cluster_event_seq
-                        start_unwinds = self.state_unwinds
-            if getattr(self, "_after_flush", False):
-                # First retired pack after a flush (pod_scheduled_after_flush
-                # consumption for gang sessions).
-                self.metrics.pod_scheduled_after_flush.inc(value=len(ok_rows))
-                self._after_flush = False
+                        sd.start_unwinds = self.state_unwinds
+            self._note_after_flush(sd)
             if groups in pending:
                 pending.remove(groups)  # fully handled: out of crash recovery
 
         if pack:
-            for g in pack:
-                for _ in g.members:
-                    self.host_path_pods += 1
-                self.process_one(g)
+            self._to_host_path(pack)
             if pack in pending:
                 pending.remove(pack)
 
-        with stages.stage("plan.adopt", **built):  # its session's build
-            self.cache.update_snapshot(self.snapshot)
-            dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
-            if invalidated:
-                self.mirror.invalidate()
-                self.metrics.batch_cache_flushed.inc("gang_session_invalidated")
-                self._after_flush = True
-            else:
-                self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
-                                  sd.carry.req_r, sd.carry.nonzero,
-                                  sd.carry.pod_count, dirty_rows=dirty_rows)
-                if sd.carry is not None and not dirty_rows:
-                    self._save_resume(fw, first.members[0].pod, sig, aux_shape,
-                                      sd.state, plan, sd.carry, node_names,
-                                      neutral_ok=False)
-        self._note_device_success()
+        self._close_session(sd, invalidated, "gang_session_invalidated")
 
     def _commit_gang_group(self, fw: Framework, qgpi: QueuedPodGroupInfo,
                            members: List[QueuedPodInfo], rows, node_names,
@@ -675,8 +631,6 @@ class TPUScheduler(Scheduler):
         binding cycle per member, group bookkeeping). Returns False when any
         member's host commit rejected its placement — the device carry has
         that placement applied, so the caller must invalidate."""
-        from ..core.framework import CycleState
-
         self.attempts += 1
         committed = 0
         attempted_uids = set()
@@ -707,10 +661,10 @@ class TPUScheduler(Scheduler):
     @staticmethod
     def _placement_plan_restriction_invariant(plan) -> bool:
         """True when the plan can be evaluated per-placement on device.
-        Topology-SPREAD tables are no longer a blocker: the host oracle
-        computes them over the restricted list (cache.py assume_placement),
-        and _placement_spread_overrides rebuilds each placement's restricted
-        tables from the plan's per-node columns. Still host-only:
+        Topology-SPREAD tables are: the host oracle computes them over the
+        restricted list (cache.py assume_placement), and
+        _placement_spread_overrides rebuilds each placement's restricted
+        tables from the plan's per-node columns. Host-only:
         inter-pod-affinity tables (term matches against restricted pod sets)
         and image-locality (its spread discount divides by the restricted
         node count). Static row-local terms (fit, balance, taints,
@@ -727,12 +681,10 @@ class TPUScheduler(Scheduler):
         match-count columns over each placement's rows. Returns the
         spread_overrides tuple for ops/kernel.py schedule_placements, or
         None when the plan carries no spread features."""
-        import jax.numpy as jnp
         f = plan.features
         c1p, c2p = f.dns_axis.shape[0], f.sa_axis.shape[0]
         if c1p == 0 and c2p == 0:
             return None
-        import math
         vmax = plan.vmax
         p_pad = _pow2_pad(len(placements))
         n = len(self.snapshot.node_info_list)
@@ -779,12 +731,11 @@ class TPUScheduler(Scheduler):
         kernel call (ops/kernel.py schedule_placements) — the TPU form of
         the per-placement simulation loop. Falls back to the host loop when
         any member or the plan is outside the device ring."""
-        from ..core.framework import (CycleState, PlacementProgress,
-                                      PodGroupAssignments)
 
+        host_loop = partial(super()._evaluate_placements, fw, pg_state, group,
+                            members, placements, start_index)
         if not self.device_enabled or self.queue.nominator.has_nominated_pods():
-            return super()._evaluate_placements(
-                fw, pg_state, group, members, placements, start_index)
+            return host_loop()
         p0 = members[0].pod
         sig = fw.sign_pod(p0)
         if sig is None or any(
@@ -794,47 +745,38 @@ class TPUScheduler(Scheduler):
                 # claim-carrying members: host sims (no intra-sim claim dedup)
                 or any(v.pvc_name for v in m.pod.volumes)
                 for m in members):
-            return super()._evaluate_placements(
-                fw, pg_state, group, members, placements, start_index)
+            return host_loop()
         # Plan cache across group cycles: restriction-invariant, port-free
         # plans depend only on NODE state + the pod spec — our own commits
         # between cycles only move per-node aggregates, which flow through
         # the mirror's dirty-row scatter, NOT the feature tables. A stream
         # of identical gangs (the perf shape) then builds features once.
-        cache = getattr(self, "_placement_plan_cache", None)
         ckey = (id(fw), sig, len(members), self.cluster_event_seq,
                 self.mirror.np_cap)
-        if cache is not None and cache[0] == ckey:
-            plan = cache[1]
-            self.cache.update_snapshot(self.snapshot)
-            self.mirror.sync(self.snapshot.node_info_list)
+        plan = self._placement_plan_cache.get(ckey)
+        if plan is not None:
+            self._sync_mirror()
             state = self.mirror.flush()  # resident stays mesh-committed
         else:
             try:
                 state, plan = self.build_plan(fw, p0, len(members))
             except Unsupported:
-                return super()._evaluate_placements(
-                    fw, pg_state, group, members, placements, start_index)
+                return host_loop()
             if not self._placement_plan_restriction_invariant(plan):
-                return super()._evaluate_placements(
-                    fw, pg_state, group, members, placements, start_index)
+                return host_loop()
             # Spread-carrying plans are NOT cached across group cycles: the
             # per-node match-count columns change with every commit of a
             # matching pod, unlike the node-state aggregates that flow
             # through the mirror's dirty rows.
-            self._placement_plan_cache = (
-                (id(fw), sig, len(members), self.cluster_event_seq,
-                 self.mirror.np_cap),
-                plan) if not (plan.port_selfblock or plan.has_aux
-                              or plan.dns_node_counts is not None
-                              or plan.sa_node_counts is not None) else None
+            self._placement_plan_cache.clear()
+            if not (plan.port_selfblock or plan.has_aux
+                    or plan.dns_node_counts is not None
+                    or plan.sa_node_counts is not None):
+                self._placement_plan_cache[
+                    (id(fw), sig, len(members), self.cluster_event_seq,
+                     self.mirror.np_cap)] = plan
 
-        import jax.numpy as jnp
-        from ..ops.kernel import schedule_placements
-        index = self.snapshot._index
-        if len(index) != len(self.snapshot.node_info_list):
-            index = {ni.name: i
-                     for i, ni in enumerate(self.snapshot.node_info_list)}
+        index = self._snapshot_rows()
         npc = self.mirror.np_cap
         # Pad the placement axis to a pow2 tier so XLA compiles once per
         # (placement tier, batch tier), not once per candidate count.
@@ -843,10 +785,8 @@ class TPUScheduler(Scheduler):
         # across a stream of identical groups (same domains, same rows).
         mkey = (self.cluster_event_seq, p_pad, npc,
                 tuple(tuple(p.node_names) for p in placements))
-        mcache = getattr(self, "_placement_mask_cache", None)
-        if mcache is not None and mcache[0] == mkey:
-            masks_dev = mcache[1]
-        else:
+        masks_dev = self._placement_mask_cache.get(mkey)
+        if masks_dev is None:
             masks = np.zeros((p_pad, npc), bool)
             for pi, placement in enumerate(placements):
                 for name in placement.node_names:
@@ -854,7 +794,8 @@ class TPUScheduler(Scheduler):
                     if row is not None:
                         masks[pi, row] = True
             masks_dev = jnp.asarray(masks)
-            self._placement_mask_cache = (mkey, masks_dev)
+            self._placement_mask_cache.clear()
+            self._placement_mask_cache[mkey] = masks_dev
         _t_pe = _time.perf_counter()
         res = np.asarray(schedule_placements(
             state, plan.features, plan.batch_pad, plan.fit_strategy,
@@ -902,8 +843,9 @@ class TPUScheduler(Scheduler):
     def _note_device_failure(self, exc: BaseException, where: str) -> None:
         """One unexpected device-path exception: log it, count it, charge
         the breaker, and discard every piece of device-resident state the
-        failure may have poisoned (mirror, resume carry, plan caches). The
-        caller reroutes the affected work to the host Evaluator."""
+        failure may have poisoned (every holder registered in
+        `_device_holders`). The caller reroutes the affected work to the
+        host Evaluator."""
         reason = type(exc).__name__
         _log.error("device path failed in %s (%s: %s) — falling back to the "
                    "host path", where, reason, exc, exc_info=True)
@@ -913,7 +855,6 @@ class TPUScheduler(Scheduler):
         # forensic artifact the breaker incidents need.
         self.tracer.record("device.fallback", self.tracer.proc_ctx(),
                            where=where, reason=reason)
-        from ..core import spans as _spans
         _spans.request_dump("device_fallback")
         opened = self.device_breaker.record_failure()
         if opened:
@@ -924,14 +865,8 @@ class TPUScheduler(Scheduler):
                 self.device_breaker.cooldown)
         self.metrics.device_breaker_state.set(
             0.0 if self.device_breaker.allows() else 1.0)
-        self.mirror.invalidate()
-        self._resume = None
-        self._hints.invalidate("device_failure")
-        self._placement_plan_cache = None
-        self._placement_mask_cache = None
-        self._victims.drop()
-        self._kept_plans.clear()
-        self._fail_memo.clear()
+        for drop in self._device_holders.values():
+            drop()
         self.metrics.batch_cache_flushed.inc("device_path_failure")
         self._after_flush = True
 
@@ -1024,7 +959,7 @@ class TPUScheduler(Scheduler):
         ``nominated.eval`` stage `st` is told where the plan came from
         (``plan`` = ``kept`` / ``built``)."""
         pod = qpi.pod
-        self.cache.update_snapshot(self.snapshot)
+        self._sync_mirror()
         row = self._snapshot_rows().get(pod.nominated_node_name)
         if row is None:
             return "fell_through"  # the node left: the ordinary cycle
@@ -1074,10 +1009,9 @@ class TPUScheduler(Scheduler):
         except Unsupported:
             return None
         except Exception as e:  # noqa: BLE001 - crash-proof fallback
-            # A shape error (once: victim tensors at one r_slots width,
-            # the plan at another) lands here if a new variant ever
-            # appears: one count, one breaker charge, and the host
-            # Evaluator reruns the dry run exactly — never a crashed
+            # A shape error (victim tensors at one r_slots width, the plan
+            # at another) lands here: one count, one breaker charge, and the
+            # host Evaluator reruns the dry run exactly — never a crashed
             # PostFilter cycle.
             self._note_device_failure(e, "preemption_dry_run")
             return None
@@ -1090,13 +1024,12 @@ class TPUScheduler(Scheduler):
         st = self.stages.heard("postfilter.preempt")
         clock = _time.perf_counter if st is not None else float
         t0 = clock()
-        self.cache.update_snapshot(self.snapshot)
+        self._sync_mirror()
         nodes = self.snapshot.node_info_list
         if any(ni.pods_with_required_anti_affinity for ni in nodes):
             # Removing an anti-carrying victim could clear exist_anti, which
             # the kernel treats as static.
             return None
-        self.mirror.sync(nodes)
         # The arrays are the holder's own and the next call patches them in
         # place. That is safe because this method fetches the what-if's
         # answer (np.asarray(on_device)) before it returns: no dispatch that
@@ -1112,9 +1045,7 @@ class TPUScheduler(Scheduler):
             return None
         vic_req, vic_valid, potential = built
         t_victims = clock()
-        # snapshot and mirror are as the lines above left them
-        dstate, plan, how = self._preemptor_plan(fw, pod, 1, "dry_run",
-                                                 synced=True)
+        dstate, plan, how = self._preemptor_plan(fw, pod, 1, "dry_run")
         if vic_req.shape[2] != self.mirror.r_slots:
             # build_plan interned the preemptor's never-seen scalar slots
             # AFTER the victim tensors were built, growing the mirror's
@@ -1129,10 +1060,6 @@ class TPUScheduler(Scheduler):
             vic_req = grown
         if self._fault_hook is not None:
             self._fault_hook("preempt")
-        import jax.numpy as jnp
-        from ..core.framework import UNSCHEDULABLE_AND_UNRESOLVABLE
-        from ..ops.kernel import dry_run_preemption
-        from ..plugins.preemption import Candidate
         # The plan's nominated lane (what the nominated pods of equal or
         # higher priority hold, this preemptor's own nomination left out)
         # counts in every fit of the what-if; zeros of the same shape where
@@ -1296,12 +1223,16 @@ class TPUScheduler(Scheduler):
                 return "extended resources backed by DRA"
         return None
 
-    def _commit_mirror_shardings(self) -> None:
+    def _sync_mirror(self) -> None:
+        """The snapshot refreshed from the cache and the mirror's staging
+        rows synced to it, the resident copy committed to the mesh's
+        shardings (or none): what every reader of either starts from."""
+        self.cache.update_snapshot(self.snapshot)
         if self.mesh is not None:
-            from ..parallel import mesh_state_shardings
             self.mirror.commit_shardings(mesh_state_shardings(self.mesh))
         else:
             self.mirror.commit_shardings(None)
+        self.mirror.sync(self.snapshot.node_info_list)
 
     def build_plan(self, fw: Framework, pod, batch_size: int,
                    only_row: Optional[int] = None):
@@ -1315,9 +1246,7 @@ class TPUScheduler(Scheduler):
         the sharded placement and later dirty scatters / delta patches ride
         pinned jits on the resident itself — no per-session single-device
         copy + device_put round-trip of the whole state."""
-        self.cache.update_snapshot(self.snapshot)
-        self._commit_mirror_shardings()
-        self.mirror.sync(self.snapshot.node_info_list)
+        self._sync_mirror()
         ipa = fw.plugin("InterPodAffinity")
         dra_enabled, dra_in_use = self._dra_ctx(fw)
         plan = build_batch(
@@ -1349,9 +1278,27 @@ class TPUScheduler(Scheduler):
         self._count_ipa(plan)
         state = self.mirror.flush()  # committed to the mesh placement
         if self.mesh is not None:
-            from ..parallel import shard_features
             plan.features = shard_features(plan.features, self.mesh)
         return state, plan
+
+    # -- the keeper of built plans -------------------------------------------
+    #
+    # `self._plans`: template key -> KeptPlan. A built plan is asked for
+    # again by a session that starts where its template's last clean session
+    # ended (the entry's tail; at most one lives) and by a preemptor that
+    # derives from it (an entry with a guard; at most _KEPT_PLANS). Both go
+    # one way: `_kept_plan` (look it up; ask the journal, once, whether it
+    # outlived it; drop or use it), then `_build_kept_plan` if it did not.
+
+    def _template_key(self, fw: Framework, pod, sig, aux_shape,
+                      neutral_ok: bool = True) -> tuple:
+        """What a built plan is kept under: the pod's signature, the neutral
+        (namespace-erased) one where the pod is eligible, the profile, the
+        counted-constraint shape and the claims' version."""
+        nsig = self._neutral_sig(fw, pod, sig) if neutral_ok else None
+        mode = ("neutral", nsig) if nsig is not None else ("exact", sig)
+        return mode + (id(fw), aux_shape,
+                       getattr(self.clientset, "resource_claims_rv", 0))
 
     def _kept_plan_guard(self) -> tuple:
         """What a kept plan holds that neither its key nor a journal event
@@ -1365,76 +1312,126 @@ class TPUScheduler(Scheduler):
             self.mesh, self.percentage_of_nodes_to_score,
             self.cache.affinity_pod_refs)
 
-    def _keep_plan(self, key: tuple, pod, plan) -> Optional[KeptPlan]:
-        """Keep under `key` (`_template_key`) a plan just built for `pod`'s
-        template with ``only_row`` None (`build_plan`; snapshot and mirror
-        are the build's), for `_preemptor_plan` to find. Only a pod whose
-        filters read other pods by their requests alone
-        (`_resources_only_block`, the precondition of both sites that ask)
-        has a plan worth keeping: nothing a pod brings to a node moves its
-        features. None where it has not. Nobody writes to a built plan, so
-        the session's own object is kept."""
-        if self._resources_only_block(pod) is not None:
-            return None
-        kept = self._kept_plans
-        kept.pop(key, None)
-        if len(kept) >= _KEPT_PLANS:
-            kept.pop(next(iter(kept)))
-        entry = kept[key] = KeptPlan(plan, self.cluster_event_seq,
-                                     self._kept_plan_guard())
-        return entry
+    def _kept_plan(self, key, tail: bool):
+        """(entry, events, classification): the template's entry where it
+        holds what the caller needs (``tail``: a session's tail; else a
+        plan to derive from) and outlived the journal's events since (the
+        tail's end; else the plan's last use): they classify under
+        `_classify_delta`, the one rule, and a plan to derive from kept its
+        guard. Else the entry is None and what was voided is dropped."""
+        entry = self._plans.get(key)
+        if entry is None or (entry.tail_seq if tail else entry.guard) is None:
+            return None, (), None
+        events, cls = self._classify_since(
+            entry.tail_seq if tail else entry.seq, entry.plan)
+        if cls is None or not (
+                tail or entry.guard == self._kept_plan_guard()):
+            self._drop_kept(key, entry, tail)
+            entry = None
+        return entry, events, cls
+
+    def _drop_kept(self, key, entry: KeptPlan, tail: bool) -> None:
+        """The entry's tail (``tail``), or its guard, goes: a plan that is
+        not to be derived from any more stays only where a live tail needs
+        it. An entry left with neither leaves the map."""
+        if tail:
+            entry.drop_tail()
+        else:
+            entry.guard = None
+        if entry.guard is None and entry.tail_seq is None:
+            del self._plans[key]
+
+    def _build_kept_plan(self, fw: Framework, pod, key, batch_size: int):
+        """(device state, entry) of a full build for `pod`'s template
+        (`build_plan`, ``only_row`` None), kept under `key` to derive from
+        where the pod's filters read other pods by their requests alone
+        (`_resources_only_block`, the precondition of both sites that
+        derive): nothing a pod brings to a node moves such a plan's
+        features. Else (or with no key: an unsignable pod; or where a live
+        tail's plan holds the key) the entry is the caller's alone. Nobody
+        writes to a built plan: the session's own object is kept."""
+        state, plan = self.build_plan(fw, pod, batch_size)
+        plans = self._plans
+        held = plans.get(key)
+        if key is None or self._resources_only_block(pod) is not None or (
+                held is not None and held.guard is None):
+            return state, KeptPlan(plan, self.cluster_event_seq, None)
+        plans.pop(key, None)
+        derivable = [k for k, e in plans.items() if e.guard is not None]
+        if len(derivable) >= _KEPT_PLANS:  # the oldest leaves
+            self._drop_kept(derivable[0], plans[derivable[0]], tail=False)
+        entry = plans[key] = KeptPlan(plan, self.cluster_event_seq,
+                                      self._kept_plan_guard())
+        return state, entry
+
+    def _claim_tail(self, keys, priority: int):
+        """A session starts: the one tail that may live is this session's
+        to resume, or it is dropped. Returns (its key, "") where a session
+        of one of `keys` left it with `attempts`, `state_unwinds` and the
+        nomination key as they are now; else (None, why a full build
+        follows): ``first`` (no tail lived), ``other_pod`` (another
+        template's, profile's or attempt count's), ``nomination`` (only
+        the nominations moved: the plan's nominated lane is stale)."""
+        mine, cause = None, "first"
+        for key, entry in list(self._plans.items()):
+            if entry.tail_seq is None:
+                continue
+            cause = "other_pod"
+            if key in keys and (entry.attempts, entry.state_unwinds) == (
+                    self.attempts, self.state_unwinds):
+                if entry.nom_key == self._nom_resume_key(priority):
+                    mine, cause = key, ""
+                    continue
+                cause = "nomination"
+            self._drop_kept(key, entry, tail=True)
+        return mine, cause
+
+    def _hand_back_tail(self, sd: "_SessionDelta") -> None:
+        """A clean session's end state, for the template's next session to
+        resume from: under the neutral (namespace-erased) signature when
+        eligible, so label/namespace-only-different sessions chain. The
+        template's entry takes it where it holds this session's plan; else
+        an entry of its own does, from which nobody derives."""
+        key = self._template_key(sd.fw, sd.pod, sd.sig, sd.aux_shape,
+                                 sd.neutral_ok)
+        entry = self._plans.get(key)
+        if entry is None or entry.plan is not sd.plan:
+            entry = self._plans[key] = KeptPlan(
+                sd.plan, self.cluster_event_seq, None)
+        entry.state, entry.carry = sd.state, sd.carry
+        entry.node_names = sd.node_names
+        entry.tail_seq = self.cluster_event_seq
+        entry.attempts, entry.state_unwinds = self.attempts, self.state_unwinds
+        entry.nom_key = self._nom_resume_key(sd.pod.priority)
 
     def _preemptor_plan(self, fw: Framework, pod, batch_size: int, site: str,
-                        only_row: Optional[int] = None, synced: bool = False):
+                        only_row: Optional[int] = None):
         """(device state, plan, ``kept`` | ``built``) for ONE pod of a
         template that was planned for before: the what-if of its preemption
         (`site` ``dry_run``) and, once it is nominated, the evaluation of
-        its own node (``nominated``, with ``only_row``). Such a plan differs
-        from the template's last one in the nominated lane, the row mask,
-        the start index and the result width, which `KeptPlan.derive` makes
-        again. The template's plan is kept (`_keep_plan`: by the session
-        that built it, or by the build here) and stays the template's while
-        the journal's events since classify under `_classify_delta`, the
-        rule by which a session resumes its plan: a `pod_local` plan and
-        plain pods' events dirty mirror rows, never features. A node update
-        (taints, allocatable) keeps the features too and has `has_pns` read
-        again from the synced mirror, both ways, where a session's row patch
-        only refuses the flag's rise. Anything else, or a changed guard
-        (`_kept_plan_guard`), drops the entry and builds. The device state
-        is the mirror's flush, as in `build_plan`: the rows the events
-        dirtied are scattered, the truth the what-if reads. ``synced``: the
-        caller has just refreshed the snapshot and synced the mirror to it.
-        `scheduler_preemptor_plan_total{site, how}` counts every call;
-        session starts keep their own counters."""
+        its own node (``nominated``, with ``only_row``). Call AFTER
+        `_sync_mirror`. The template's kept plan holds while the events
+        since classify (`_kept_plan`): a `pod_local` plan and plain pods'
+        events dirty mirror rows, never features, nor does a node update
+        (taints, allocatable); the rest `KeptPlan.derive` makes again. The
+        device state is the mirror's flush, as in `build_plan`: the dirtied
+        rows are scattered, the truth the what-if reads. Every call counts
+        in `scheduler_preemptor_plan_total{site, how}`; session starts keep
+        their own counters."""
         sig = fw.sign_pod(pod)
-        aux_shape = self._aux_shape(pod)
-        key = self._template_key(fw, pod, sig, aux_shape)
-        if not synced:
-            self.cache.update_snapshot(self.snapshot)
-        # the lane's scalar slots first: a never-seen one grows r_slots,
-        # and with it the guard
+        key = (self._template_key(fw, pod, sig, self._aux_shape(pod))
+               if sig is not None else None)
+        # the lane's scalar slots before the guard is read: a never-seen
+        # one grows r_slots, and with it the guard (so does a bound pod's,
+        # which the mirror's sync interns)
         lane = lane_requests(self.mirror, self._nominated_lane(pod))
-        entry = self._kept_plans.get(key) if sig is not None else None
-        if entry is not None:
-            # and the mirror's sync before the guard is read, for the same
-            # reason: a bound pod's never-seen slot is interned there
-            self._commit_mirror_shardings()
-            if not synced or self.mirror._full_flush:
-                self.mirror.sync(self.snapshot.node_info_list)
-            _events, cls = self._classify_since(entry.seq, entry.plan)
-            if cls is None or entry.guard != self._kept_plan_guard():
-                del self._kept_plans[key]
-                entry = None
+        entry, _events, _cls = self._kept_plan(key, tail=False)
         how = "built" if entry is None else "kept"
         self.metrics.preemptor_plans.inc(site, how)
         if entry is None:
-            state, plan = self.build_plan(fw, pod, batch_size)
             # (a plan that is not one to keep is still derived from, once)
-            entry = (sig is not None and self._keep_plan(key, pod, plan)
-                     or KeptPlan(plan, self.cluster_event_seq, ()))
+            state, entry = self._build_kept_plan(fw, pod, key, batch_size)
         else:
-            if not cls[3]:  # a node update among the events
-                entry.taints_moved(self.mirror, self.snapshot.num_nodes())
             entry.seq = self.cluster_event_seq
             state = self.mirror.flush()
         plan = entry.derive(
@@ -1442,7 +1439,6 @@ class TPUScheduler(Scheduler):
             start_index=self.next_start_node_index, nom_reqs=lane,
             only_row=only_row)
         if self.mesh is not None:
-            from ..parallel import shard_features
             plan.features = shard_features(plan.features, self.mesh)
         return state, plan, how
 
@@ -1520,14 +1516,11 @@ class TPUScheduler(Scheduler):
         """A nominated lane that holds nothing, at the live lane's shapes and
         (under a mesh) committed shardings, which jit keys on:
         shard_features puts the lane on the node axis. Kept per shape."""
-        import jax.numpy as jnp
         key = (self.mirror.np_cap, self.mirror.r_slots, self.mesh)
         if self._empty_nom_key != key:
             nom_req = jnp.zeros(key[:2], jnp.int64)
             nom_pods = jnp.zeros(key[0], jnp.int32)
             if self.mesh is not None:
-                import jax
-                from jax.sharding import NamedSharding, PartitionSpec as P
                 nom_req = jax.device_put(
                     nom_req, NamedSharding(self.mesh, P("nodes", None)))
                 nom_pods = jax.device_put(
@@ -1553,8 +1546,6 @@ class TPUScheduler(Scheduler):
         topology-constrained gang workload will hit (inert n_active=0
         dispatch), so XLA compilation lands outside the measured window —
         the placement analogue of warm_for."""
-        import jax.numpy as jnp
-        from ..ops.kernel import schedule_placements
         fw = self.framework_for_pod(pod)
         if self._batch_supported_memo(pod, fw) is not None:
             return
@@ -1596,7 +1587,6 @@ class TPUScheduler(Scheduler):
         programs, a CPU dry run — not a speed)."""
         if self.mesh is None or not (plan.rides_lap and plan.row_local):
             return None
-        from ..parallel.mesh import mesh_shard_count, sharded_lap_schedule
         if self.mirror.np_cap % mesh_shard_count(self.mesh):
             return None  # node tier not divisible across shards
         return sharded_lap_schedule(self.mesh, plan.batch_pad,
@@ -1647,11 +1637,13 @@ class TPUScheduler(Scheduler):
         m.batch_attempts.inc("dispatched")
         m.batch_size.observe(attrs["batch"])
 
-    def _gspmd_dispatch(self, state, plan, n_active: int, carry):
+    def _gspmd_dispatch(self, state, plan, n_active: int, carry,
+                        call=schedule_batch):
         """The GSPMD-compiled schedule_batch call — one kwargs set shared
-        by the live fallback dispatch and warm_for's fallback warming (a
-        differing kwarg pytree would be a separate jit cache entry)."""
-        return schedule_batch(
+        by the live fallback dispatch, warm_for's fallback warming (a
+        differing kwarg pytree would be a separate jit cache entry) and
+        collective_counts' lowering (``call``)."""
+        return call(
             state, plan.features, plan.batch_pad, plan.fit_strategy,
             plan.vmax, n_active=np.int32(n_active), carry_in=carry,
             has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
@@ -1667,7 +1659,6 @@ class TPUScheduler(Scheduler):
         at-or-below the GSPMD baseline per step)."""
         if self.mesh is None:
             return None
-        from ..parallel.mesh import collective_report, mesh_host_split
         fw = self.framework_for_pod(pod)
         bs = batch_size or self.max_batch
         state, plan = self.build_plan(fw, pod, bs)
@@ -1676,14 +1667,8 @@ class TPUScheduler(Scheduler):
             lowered = fn.lower(state, plan.features, np.int32(bs), None)
             path = "shard_map"
         else:
-            lowered = schedule_batch.lower(
-                state, plan.features, plan.batch_pad, plan.fit_strategy,
-                plan.vmax, n_active=np.int32(bs), carry_in=None,
-                has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
-                anti_rowlocal=plan.anti_rowlocal,
-                has_na_pref=plan.has_na_pref,
-                port_selfblock=plan.port_selfblock, has_aux=plan.has_aux,
-                has_nom=plan.has_nom)
+            lowered = self._gspmd_dispatch(state, plan, bs, None,
+                                           call=schedule_batch.lower)
             path = "gspmd"
         n_hosts, per_host = mesh_host_split(self.mesh)
         report = collective_report(lowered.compile().as_text(),
@@ -1704,23 +1689,21 @@ class TPUScheduler(Scheduler):
     # (Scheduler.cluster_event_seq).
 
     def _nom_resume_key(self, priority: int):
-        """Nomination component of the session-resume key: the set version
-        plus — only when a lane is live — the priority threshold the plan
-        was built with (an empty nominator makes priority irrelevant)."""
+        """Nomination component of a session's tail (KeptPlan.nom_key): the
+        set version plus — only when a lane is live — the priority threshold
+        the plan was built with (an empty nominator makes priority
+        irrelevant)."""
         nom = self.queue.nominator
         return (nom.version, priority if nom.has_nominated_pods() else None)
 
     # -- incremental session resume (typed event journal) -------------------
     #
-    # The resume cache used to be all-or-nothing: ANY cluster event bumped
-    # cluster_event_seq, missed the key, and forced a full snapshot→features
-    # teardown (plan_build dominated the WhileGated/DeletedPodsWithFinalizers
-    # perf rows). The journal (core/cache.py EventJournal) records what each
-    # bump WAS, so a session can classify the intervening events against its
-    # plan and patch exactly the rows they dirtied — mirror staging, resident
-    # device state, and the live carry — then keep (or resume) the session
-    # with the pipeline full. Unclassifiable events keep today's behavior:
-    # full rebuild / invalidation.
+    # The journal (core/cache.py EventJournal) records what each bump of
+    # cluster_event_seq WAS, so a session can classify the intervening
+    # events against its plan and patch exactly the rows they dirtied —
+    # mirror staging, resident device state, and the live carry — then keep
+    # (or resume) the session with the pipeline full. An event that does
+    # not classify is a full rebuild / an invalidation.
 
     def _count_rebuild(self, kind: str) -> None:
         if kind == "full":
@@ -1776,20 +1759,16 @@ class TPUScheduler(Scheduler):
 
     def _classify_delta(self, events, plan):
         """Map journal events to the feature blocks they dirty under `plan`.
-        Returns (level, dirty node names, node_only, pod_only): 'benign'
-        (nothing node-side moved), 'safe' (row patches whose events only
-        enlarge feasibility — in-flight device results stay committable),
-        'strict' (row patches that may shrink feasibility: applicable with
-        an empty pipeline, or while busy when pod_only and the bind path
-        re-validates capacity) — or None when any event needs the full
-        rebuild. node_only/pod_only say whether every dirtying event was a
-        taint/alloc node update resp. a plain-pod row event."""
-        from ..core.cache import (EV_NAMESPACE, EV_NODE_UPDATE, EV_POD_ADD,
-                                  EV_POD_REMOVE, EV_POD_UPDATE, EV_QUEUE)
+        Returns (level, dirty node names, pod_only): 'benign' (nothing
+        node-side moved), 'safe' (row patches whose events only enlarge
+        feasibility — in-flight device results stay committable), 'strict'
+        (row patches that may shrink feasibility: applicable with an empty
+        pipeline, or while busy when pod_only and the bind path re-validates
+        capacity) — or None when any event needs the full rebuild. pod_only:
+        every dirtying event was a plain-pod row event (no node update)."""
         level = 0
         names = set()
-        node_only = True  # every dirtying event is a taint/alloc node update
-        pod_only = True   # every dirtying event is a plain-pod row event
+        pod_only = True
         for ev in events:
             if ev.kind == EV_QUEUE:
                 continue
@@ -1808,7 +1787,6 @@ class TPUScheduler(Scheduler):
                     return None
                 if ev.pod_ports and plan.port_selfblock:
                     return None  # used_ports moved under a port-aware plan
-                node_only = False
             elif ev.kind == EV_NODE_UPDATE:
                 if not plan.pod_local:
                     return None  # honor-policy spread tables read taints
@@ -1817,7 +1795,7 @@ class TPUScheduler(Scheduler):
                 return None
             names.add(ev.key)
             level = max(level, 1 if ev.shrink else 2)
-        return ("benign", "safe", "strict")[level], names, node_only, pod_only
+        return ("benign", "safe", "strict")[level], names, pod_only
 
     def _classify_since(self, seq: int, plan):
         """(events, classification) of what the journal holds since `seq`
@@ -1829,9 +1807,9 @@ class TPUScheduler(Scheduler):
         return events, (self._classify_delta(events, plan)
                         if events is not None else None)
 
-    def _note_session_events(self, sd, plan, node_names, busy: bool) -> bool:
+    def _note_session_events(self, sd: _SessionDelta, busy: bool) -> bool:
         """The ONE journal-consumption protocol both session kinds run at
-        their invalidation checks. `sd` is the session's mutable delta view
+        their invalidation checks. `sd` is the session's mutable view
         (_SessionDelta); updated in place. Returns True when the session
         stays valid — benign advance, patch applied, or patch deferred
         until the pipeline drains — False when it must invalidate. `busy` =
@@ -1839,14 +1817,13 @@ class TPUScheduler(Scheduler):
         if self.cluster_event_seq == sd.start_seq and not sd.patch_pending:
             return True
         with self.stages.stage("inbox.drain"):  # the journal's half of it
-            return self._consume_session_events(sd, plan, node_names, busy)
+            return self._consume_session_events(sd, busy)
 
-    def _consume_session_events(self, sd, plan, node_names,
-                                busy: bool) -> bool:
-        _events, cls = self._classify_since(sd.start_seq, plan)
+    def _consume_session_events(self, sd: _SessionDelta, busy: bool) -> bool:
+        _events, cls = self._classify_since(sd.start_seq, sd.plan)
         if cls is None:
             return False
-        level, names, _node_only, pod_only = cls
+        level, names, pod_only = cls
         if not names:
             sd.start_seq = self.cluster_event_seq
             sd.patch_pending = False
@@ -1868,10 +1845,7 @@ class TPUScheduler(Scheduler):
                 # under a 1-shard one. The patched rows are charged dirty
                 # (_SessionDelta.busy_patch_rows) so session-end adoption
                 # re-encodes them from post-commit truth.
-                patched = self._apply_delta_patch(
-                    plan, node_names, names, sd.state, sd.carry, busy=True)
-                if patched is not None:
-                    sd.state, sd.carry = patched
+                if self._apply_delta_patch(sd, names, busy=True):
                     row_of = self._session_row_of[1]
                     sd.busy_patch_rows.extend(
                         row_of[nm] for nm in names if nm in row_of)
@@ -1886,22 +1860,19 @@ class TPUScheduler(Scheduler):
             # nothing re-validates taints at bind time.
             sd.patch_pending = True
             return True
-        patched = self._apply_delta_patch(
-            plan, node_names, names, sd.state, sd.carry)
-        if patched is None:
+        if not self._apply_delta_patch(sd, names):
             return False
-        sd.state, sd.carry = patched
         sd.start_seq = self.cluster_event_seq
         sd.patch_pending = False
         self._count_rebuild("delta")
         return True
 
-    def _apply_delta_patch(self, plan, node_names, names, state, carry,
-                           busy: bool = False):
+    def _apply_delta_patch(self, sd: _SessionDelta, names,
+                           busy: bool = False) -> bool:
         """Patch the journal's dirty rows into mirror staging, the resident
-        device state, and the session carry. Returns (state, carry) or None
-        when the patch can't apply — the caller's full-rebuild fallback
-        recovers from every None.
+        device state, and the session carry (`sd`'s, rebound to the
+        result). False when the patch can't apply — the caller's
+        full-rebuild fallback recovers from every False.
 
         Mesh sessions patch EVERY classifiable kind — POD-event aggregates
         (pod_add/pod_remove/pod_update) included, the events that dominate
@@ -1913,13 +1884,16 @@ class TPUScheduler(Scheduler):
         jits (reused in place) when no dispatched batch still reads them
         (`busy`)."""
         if not names:
-            return state, carry
+            return True
         with self.stages.stage("plan.patch", rows=len(names)):
-            return self._patch_rows(plan, node_names, names, state, carry,
-                                    busy)
+            patched = self._patch_rows(sd.plan, sd.node_names, names,
+                                       sd.state, sd.carry, busy)
+        if patched is not None:
+            sd.state, sd.carry = patched
+        return patched is not None
 
     def _patch_rows(self, plan, node_names, names, state, carry, busy: bool):
-        row_of = getattr(self, "_session_row_of", None)
+        row_of = self._session_row_of
         if row_of is None or row_of[0] is not node_names:
             row_of = (node_names, {n: i for i, n in enumerate(node_names)})
             self._session_row_of = row_of
@@ -1931,7 +1905,6 @@ class TPUScheduler(Scheduler):
                 return None  # row set changed shape: structural after all
             updates.append((row, ni))
         if self.mesh is not None:
-            from ..parallel import mesh_state_shardings
             new_state = self.mirror.patch_rows(
                 updates, sharded_state=state,
                 out_shardings=mesh_state_shardings(self.mesh),
@@ -1942,7 +1915,6 @@ class TPUScheduler(Scheduler):
             return None
         rows = sorted({r for r, _ in updates})
         if not plan.has_pns:
-            from ..ops.codebook import EFFECT_PREFER_NO_SCHEDULE
             if (self.mirror.h_taint_eff[rows]
                     == EFFECT_PREFER_NO_SCHEDULE).any():
                 # The plan compiled the no-PreferNoSchedule fast path;
@@ -1950,9 +1922,6 @@ class TPUScheduler(Scheduler):
                 # recomputes has_pns) resumes from truth.
                 return None
         if carry is not None:
-            import jax.numpy as jnp
-            from ..ops.device_state import patch_tier
-            from ..ops.kernel import patch_carry_rows, patch_carry_rows_pinned
             tier = patch_tier(len(rows))
             prows = rows + [rows[-1]] * (tier - len(rows))
             patch_fn = (patch_carry_rows_pinned if self.mesh is not None
@@ -1968,93 +1937,56 @@ class TPUScheduler(Scheduler):
         self.metrics.plan_rebuild_dirty_rows.inc(value=len(rows))
         return new_state, carry
 
-    def _resume_or_rebuild(self, fw: Framework, head_pod, sig, nsig,
-                           aux_shape, claims_rv):
-        """Session-start plan acquisition: exact/neutral resume, journal
-        delta patch, or full rebuild. Returns (state, plan, carry,
-        node_names, kind)."""
-        from ..core.cache import EV_STRUCTURAL
-        carry = None
-        resume, self._resume = self._resume, None
-        kind = "full"
-        cause = "first"  # why a full rebuild is one, if this is
-        state = plan = node_names = None
+    def _resume_or_rebuild(self, sd: "_SessionDelta") -> str:
+        """Session-start plan acquisition: the tail the template's last
+        clean session handed back, as it is (``resume``) or row-patched by
+        the journal's events since (``delta``), else a full build
+        (``full``). Fills `sd` with the plan, its rows' node names, the
+        device state and the carry (None after a full build)."""
+        fw, pod = sd.fw, sd.pod
         _t_hint = _time.perf_counter()
-        if resume is not None:
-            cause = "other_pod"
-            rkey, rseq, payload, rnom = resume
-            sig_ok = (rkey[1] == sig) if rkey[0] == "exact" else (
-                nsig is not None and rkey[1] == nsig)
-            rest_ok = sig_ok and rkey[2:] == (
-                id(fw), aux_shape, claims_rv, self.attempts,
-                self.state_unwinds)
-            if rest_ok and rnom != self._nom_resume_key(head_pod.priority):
-                # the kept plan is this template's and nothing but the
-                # nomination set (or the lane's priority threshold) has
-                # moved since: its nominated lane is stale
-                cause = "nomination"
-            elif rest_ok:
-                state, plan, carry, node_names = payload
-                if rseq == self.cluster_event_seq:
-                    kind = "resume"
+        filed = self._template_key(fw, pod, sd.sig, sd.aux_shape)
+        exact = self._template_key(fw, pod, sd.sig, sd.aux_shape, False)
+        mine, cause = self._claim_tail(
+            (exact, filed) if sd.neutral_ok else (exact,), pod.priority)
+        entry, events, cls = self._kept_plan(mine, tail=True)
+        kind = "full"
+        if entry is not None:
+            sd.plan, sd.node_names = entry.plan, entry.node_names
+            sd.state, sd.carry = entry.state, entry.carry
+            ended = entry.tail_seq
+            # taken: this session resumes from it, or nobody does
+            self._drop_kept(mine, entry, tail=True)
+            if ended == self.cluster_event_seq:
+                kind = "resume"
+            else:
+                # No pipeline is in flight at session start: every level
+                # (benign/safe/strict) may patch here.
+                _level, names, _pod_only = cls
+                if self._apply_delta_patch(sd, names):
+                    kind = "delta"
                 else:
-                    events, cls = self._classify_since(rseq, plan)
-                    if cls is not None:
-                        # No pipeline is in flight at session start: every
-                        # level (benign/safe/strict) may patch here.
-                        patched = self._apply_delta_patch(
-                            plan, node_names, cls[1], state, carry)
-                        if patched is not None:
-                            state, carry = patched
-                            kind = "delta"
-                        else:
-                            cause = "patch_failed"
-                    elif events is None:
-                        cause = "journal_overrun"
-                    elif any(ev.kind == EV_STRUCTURAL for ev in events):
-                        cause = "structural"
-                    else:
-                        cause = "unpatchable"
-                if kind == "full":
-                    carry = None
+                    cause = "patch_failed"
+        elif mine is not None:  # the journal voided it
+            if events is None:
+                cause = "journal_overrun"
+            elif any(ev.kind == EV_STRUCTURAL for ev in events):
+                cause = "structural"
+            else:
+                cause = "unpatchable"
         # get_node_hint_duration (runtime/batch.go GetNodeHint analogue):
         # the batch-reuse lookup is the session-resume key check.
         self.metrics.get_node_hint_duration.observe(
             _time.perf_counter() - _t_hint)
         if kind == "full":
-            state, plan = self.build_plan(fw, head_pod, self.max_batch)
-            self._keep_plan(
-                self._template_key(fw, head_pod, sig, aux_shape), head_pod,
-                plan)
-            node_names = [ni.name for ni in self.snapshot.node_info_list]
+            sd.state, entry = self._build_kept_plan(fw, pod, filed,
+                                                    self.max_batch)
+            sd.plan, sd.carry = entry.plan, None
+            sd.node_names = [ni.name for ni in self.snapshot.node_info_list]
             self.metrics.plan_rebuild_cause.inc(cause)
         self.plan_build_cause = cause if kind == "full" else ""
         self._count_rebuild(kind)
-        return state, plan, carry, node_names, kind
-
-    def _template_key(self, fw: Framework, pod, sig, aux_shape,
-                      neutral_ok: bool = True) -> tuple:
-        """What a built plan is kept under, for a session to resume
-        (`_save_resume`) or for `_preemptor_plan`: the pod's signature, the
-        neutral (namespace-erased) one where the pod is eligible, the
-        profile, the counted-constraint shape and the claims' version."""
-        nsig = self._neutral_sig(fw, pod, sig) if neutral_ok else None
-        mode = ("neutral", nsig) if nsig is not None else ("exact", sig)
-        return mode + (id(fw), aux_shape,
-                       getattr(self.clientset, "resource_claims_rv", 0))
-
-    def _save_resume(self, fw: Framework, head_pod, sig, aux_shape,
-                     state, plan, carry, node_names,
-                     neutral_ok: bool = True) -> None:
-        """Capture a clean session's end state for the next resume check.
-        Saved under the neutral (namespace-erased) signature when eligible,
-        so a stream of label/namespace-only-different sessions chains."""
-        self._resume = (
-            self._template_key(fw, head_pod, sig, aux_shape, neutral_ok)
-            + (self.attempts, self.state_unwinds),
-            self.cluster_event_seq,
-            (state, plan, carry, node_names),
-            self._nom_resume_key(head_pod.priority))
+        return kind
 
     def limited_drivers(self) -> frozenset:
         rv = getattr(self.clientset, "csi_nodes_rv", 0)
@@ -2097,7 +2029,6 @@ class TPUScheduler(Scheduler):
         pods (the >13k pods/s path) answer without the volume walk."""
         if not pod.volumes and not getattr(pod, "resource_claims", None):
             return (None, None)
-        from ..ops.features import volume_device_support
         _r, vol_d, vol_inc = volume_device_support(
             pod, self.clientset, pvc_refs=self.cache.pvc_refs,
             limited_drivers=self.limited_drivers())
@@ -2116,30 +2047,24 @@ class TPUScheduler(Scheduler):
         if pod.nominated_node_name and not as_head:
             return "nominated pod heads a batch of its own"
         shared = pod.__dict__.get("_sig_shared")
-        if (shared is None or any(v.pvc_name for v in pod.volumes)
-                or getattr(pod, "resource_claims", None)):
-            # PVC/claim verdicts depend on live claim/PV state — never
-            # memoized.
-            dra_enabled, dra_in_use = self._dra_ctx(fw)
-            return batch_supported(
-                pod, self.snapshot,
-                fit_plugin=fw.plugin("NodeResourcesFit"),
-                ba_plugin=fw.plugin("NodeResourcesBalancedAllocation"),
-                clientset=self.clientset, pvc_refs=self.cache.pvc_refs,
-                limited_drivers=self.limited_drivers(),
-                dra_enabled=dra_enabled, dra_in_use=dra_in_use,
-                session_claims=self._session_claims)
+        # PVC/claim verdicts depend on live claim/PV state — never memoized.
+        live = (shared is None or any(v.pvc_name for v in pod.volumes)
+                or getattr(pod, "resource_claims", None))
         key = ("_bsup", id(fw))
-        if key in shared:
+        if not live and key in shared:
             return shared[key]
-        reason = batch_supported(
-            pod, self.snapshot,
+        supported = partial(
+            batch_supported, pod, self.snapshot,
             fit_plugin=fw.plugin("NodeResourcesFit"),
             ba_plugin=fw.plugin("NodeResourcesBalancedAllocation"),
             clientset=self.clientset, pvc_refs=self.cache.pvc_refs,
             limited_drivers=self.limited_drivers())
-        shared[key] = reason
-        return reason
+        if live:
+            dra_enabled, dra_in_use = self._dra_ctx(fw)
+            return supported(dra_enabled=dra_enabled, dra_in_use=dra_in_use,
+                             session_claims=self._session_claims)
+        shared[key] = supported()
+        return shared[key]
 
     def _session_compatible(self, head: QueuedPodInfo, fw: Framework, sig) -> bool:
         if isinstance(head, QueuedPodGroupInfo):
@@ -2276,57 +2201,50 @@ class TPUScheduler(Scheduler):
         return self._refill(_Batch(), fw, sig, took)
 
     def run_device_session(self, fw: Framework, first_batch: List[QueuedPodInfo]) -> None:
-        """Crash-proof wrapper: an unexpected device failure mid-session
-        (kernel shape error, dispatch fault, poisoned carry) must not strand
-        the entities the session popped — every batch not yet fully
-        committed reruns on the host path, the mirror invalidates, and the
-        breaker is charged. Unsupported keeps its existing contract
-        (schedule_one host-paths first_batch)."""
-        pending: List[List[QueuedPodInfo]] = []
+        """A session of plain pods from `first_batch` on (`_run_session`)."""
+        self._run_session(self._run_device_session, fw, first_batch,
+                          "device_session")
+
+    def _run_session(self, ladder, fw: Framework, first, where: str) -> None:
+        """The crash-proof frame round a session ladder. An unexpected
+        device failure mid-session (kernel shape error, dispatch fault,
+        poisoned carry) must not strand what the session popped: every
+        batch not yet fully committed (`pending`; `first` is in it BEFORE
+        the plan is built) reruns on the host path, the mirror invalidates,
+        the breaker is charged. A template the device cannot plan for
+        (Unsupported, as the session opens) sends `first` to the host."""
+        pending = [first]
         try:
-            self._run_device_session(fw, first_batch, pending)
+            ladder(fw, first, pending)
         except Unsupported:
-            raise
+            self.metrics.device_path_fallback.inc("unsupported")
+            self._to_host_path(first)
         except Exception as e:  # noqa: BLE001 - device→host fallback
-            self._note_device_failure(e, "device_session")
+            self._note_device_failure(e, where)
             for b in pending:
                 for qpi in b:
                     self._recover_qpi(qpi)
 
+    def _to_host_path(self, entities) -> None:
+        """Entities popped for the device take the exact host cycle, a
+        group as one, its members counted."""
+        for qpi in entities:
+            self.host_path_pods += len(getattr(qpi, "members", ()) or (1,))
+            self.process_one(qpi)
+
     def _run_device_session(self, fw: Framework,
                             first_batch: List[QueuedPodInfo],
                             pending: List[List[QueuedPodInfo]]) -> None:
-        pending.append(first_batch)  # crash-recovery registry (wrapper);
-        # registered BEFORE build_plan so a plan-build crash recovers too.
-        sig = fw.sign_pod(first_batch[0].pod)
-        nsig = self._neutral_sig(fw, first_batch[0].pod, sig)
-        self._session_neutral_sig = nsig
-        # Signatures cover only the Sign plugins — NOT volumes/claims, whose
-        # counted-constraint shape changes the PLAN (aux_room semantics). A
-        # resume must match the aux shape too, or a claim-template session
-        # could chain onto a volume session's attach-room plan (fuzz-caught).
-        aux_shape = self._aux_shape(first_batch[0].pod)
-        claims_rv = getattr(self.clientset, "resource_claims_rv", 0)
-        stages = self.stages
         # Plan acquisition latency: the extension-point histogram gets
         # EVERY session (p50/p99 truth); sampled pods get plan.build spans
         # tagged with the acquisition kind (full/delta/resume).
-        with stages.stage("plan.build", first_batch.sampled, "DevicePlan",
-                          batch=len(first_batch)) as st:
-            state, plan, carry, node_names, kind = \
-                self._resume_or_rebuild(fw, first_batch[0].pod, sig, nsig,
-                                        aux_shape, claims_rv)
-            # said, not only kept: a profiler trace holds them as the
-            # event's stats, beside `batch`
-            st.say(kind=kind, cause=self.plan_build_cause)
-        built = {"kind": kind, "cause": self.plan_build_cause}
-        sd = _SessionDelta(state, carry, self.cluster_event_seq)
-        del state, carry
-        start_unwinds = self.state_unwinds
+        sd = self._open_session(fw, first_batch[0].pod, True,
+                                first_batch.sampled, "DevicePlan",
+                                batch=len(first_batch))
+        sig, node_names = sd.sig, sd.node_names
+        inflight, ok_rows, dirty_rows = sd.inflight, sd.ok_rows, sd.dirty_rows
+        stages = self.stages
         start_nom = self.queue.nominator.version
-        inflight: List[Tuple[List[QueuedPodInfo], object, int]] = []
-        ok_rows: List[int] = []
-        dirty_rows: List[int] = []
         invalidated = False
         batch: Optional[List[QueuedPodInfo]] = first_batch
 
@@ -2337,8 +2255,7 @@ class TPUScheduler(Scheduler):
                 if sd.patch_pending:
                     if inflight:
                         break  # retire dispatched work before patching
-                    if not self._note_session_events(sd, plan, node_names,
-                                                     busy=False):
+                    if not self._note_session_events(sd, busy=False):
                         invalidated = True
                         break
                 if batch is None:
@@ -2347,9 +2264,7 @@ class TPUScheduler(Scheduler):
                         # backlog: no refill. What is in flight retires and
                         # the session ends as one that ran dry (adopted,
                         # resumable), so the turn that follows replays the
-                        # event with an empty pipeline. It used to wait
-                        # for the whole backlog, and the batch in flight
-                        # when it was seen took the host path.
+                        # event with an empty pipeline.
                         break
                     with self._pop_stage() as took:
                         batch = self._collect_session_batch(
@@ -2362,10 +2277,10 @@ class TPUScheduler(Scheduler):
                         # event (held for the next turn, as above). Events
                         # raised on this thread patch the live plan+carry
                         # when the journal classifies them, and invalidate
-                        # exactly as before when it can't.
+                        # the session when it can't.
                         self.drain_event_inbox(hold_cluster_events=True)
                         if not self._note_session_events(
-                                sd, plan, node_names, busy=bool(inflight)):
+                                sd, busy=bool(inflight)):
                             invalidated = True
                         elif sd.patch_pending:
                             continue  # patch (or drain) before collecting
@@ -2376,31 +2291,15 @@ class TPUScheduler(Scheduler):
                     if batch is None:
                         break
                     pending.append(batch)
-                attrs = self._dispatch_attrs(plan, len(batch), len(inflight))
-                with stages.stage("device.dispatch", batch.sampled, **attrs):
-                    results, sd.carry = self._dispatch(
-                        sd.state, plan, len(batch), sd.carry)
-                    # Start the device→host copy NOW: issuing it at
-                    # dispatch time overlaps the fetch latency with the
-                    # host commit loop of the previous batch.
-                    results.copy_to_host_async()
-                self._count_dispatch(attrs)
-                inflight.append((batch, results, attrs["seq"]))
-                stages.inflight = len(inflight)
-                self.metrics.goroutines.set(float(len(inflight)),
-                                            "device_dispatch")
+                self._dispatch_next(sd, batch, len(batch), batch.sampled)
                 batch = None
             if not inflight:
                 break
             # Retire the oldest batch: block on its results (the device is
             # already computing the NEXT batch), then run the host tail.
-            b, results, seq = inflight.pop(0)
-            stages.inflight = len(inflight)
-            self.metrics.goroutines.set(float(len(inflight)),
-                                        "device_dispatch")
-            with stages.stage("device.wait", b.sampled, "DeviceWait",
-                              batch=len(b), seq=seq):
-                res = np.asarray(results)  # one device→host fetch
+            b = inflight[0][0]
+            b, res = self._retire_oldest(sd, b.sampled, "DeviceWait",
+                                         batch=len(b))
             if not invalidated:
                 run = self._batch_tail_run(b, res, fw)
                 with stages.stage("host.commit", b.sampled, "HostCommit",
@@ -2409,20 +2308,15 @@ class TPUScheduler(Scheduler):
                                         if run == len(b) else "mixed")):
                     invalidated = self._commit_batch(
                         b, res, fw, node_names, ok_rows, dirty_rows, run)
-                if getattr(self, "_after_flush", False):
-                    # First retired batch after a flush: its pods scheduled
-                    # from a fresh (non-chained) evaluation.
-                    self.metrics.pod_scheduled_after_flush.inc(
-                        value=len(ok_rows))
-                    self._after_flush = False
+                self._note_after_flush(sd)
                 if not invalidated and (
-                        self.state_unwinds != start_unwinds
+                        self.state_unwinds != sd.start_unwinds
                         or self.queue.nominator.version != start_nom
                         or not self._note_session_events(
-                            sd, plan, node_names, busy=bool(inflight))):
+                            sd, busy=bool(inflight))):
                     invalidated = True
                     sd.start_seq = self.cluster_event_seq
-                    start_unwinds = self.state_unwinds
+                    sd.start_unwinds = self.state_unwinds
                     start_nom = self.queue.nominator.version
             else:
                 # A previous batch diverged: every later device choice is
@@ -2434,41 +2328,105 @@ class TPUScheduler(Scheduler):
                 pending.remove(b)  # fully handled: out of crash recovery
 
         if batch:  # popped but never dispatched (invalidated mid-refill)
-            for qpi in batch:
-                self.host_path_pods += 1
-                self.process_one(qpi)
+            self._to_host_path(batch)
             if batch in pending:
                 pending.remove(batch)
 
-        with stages.stage("plan.adopt", **built):  # its session's build
+        # The hint (the cross-cycle OpportunisticBatch save): after a clean
+        # end the carry IS the kernel's sorted-score truth for the next
+        # identical pod.
+        self._close_session(sd, invalidated, "session_invalidated",
+                            install_hint=True)
+
+    # -- the frame round a device session, shared by both ladders -----------
+
+    def _open_session(self, fw: Framework, pod, neutral_ok: bool,
+                      sampled=(), point: str = "", **attrs) -> _SessionDelta:
+        """A session opens for `pod`'s template: `plan.build` (the caller's
+        span contexts, extension point and attrs) round the acquisition,
+        which says `kind` and `cause` (a profiler event's stats).
+        ``neutral_ok``: sessions of the template's namespace-erased
+        signature chain (plain pods only)."""
+        sig = fw.sign_pod(pod)
+        # Signatures cover only the Sign plugins — NOT volumes/claims, whose
+        # counted-constraint shape changes the PLAN (aux_room semantics). A
+        # resume must match the aux shape too, or a claim-template session
+        # could chain onto a volume session's attach-room plan (fuzz-caught).
+        sd = _SessionDelta(
+            fw, pod, sig,
+            self._neutral_sig(fw, pod, sig) if neutral_ok else None,
+            neutral_ok, self._aux_shape(pod))
+        with self.stages.stage("plan.build", sampled, point, **attrs) as st:
+            kind = self._resume_or_rebuild(sd)
+            sd.built = {"kind": kind, "cause": self.plan_build_cause}
+            st.say(**sd.built)
+        sd.start_seq = self.cluster_event_seq
+        sd.start_unwinds = self.state_unwinds
+        return sd
+
+    def _dispatch_next(self, sd: _SessionDelta, entities, n: int,
+                       sampled=()) -> None:
+        """`n` pods of `entities` (a batch, or a pack of groups) go to the
+        device, chained on the session's carry. The device→host copy starts
+        NOW: the fetch overlaps the host commit loop of the batch before."""
+        attrs = self._dispatch_attrs(sd.plan, n, len(sd.inflight))
+        with self.stages.stage("device.dispatch", sampled, **attrs):
+            results, sd.carry = self._dispatch(sd.state, sd.plan, n, sd.carry)
+            results.copy_to_host_async()
+        self._count_dispatch(attrs)
+        sd.inflight.append((entities, results, attrs["seq"]))
+        self._note_inflight(sd)
+
+    def _retire_oldest(self, sd: _SessionDelta, sampled=(), point: str = "",
+                       **attrs):
+        """(entities, results on the host) of the oldest dispatch in
+        flight: `device.wait` (with its `seq`) round the one fetch."""
+        entities, results, seq = sd.inflight.pop(0)
+        self._note_inflight(sd)
+        with self.stages.stage("device.wait", sampled, point, **attrs,
+                               seq=seq):
+            return entities, np.asarray(results)
+
+    def _note_inflight(self, sd: _SessionDelta) -> None:
+        self.stages.inflight = depth = len(sd.inflight)
+        self.metrics.goroutines.set(float(depth), "device_dispatch")
+
+    def _note_after_flush(self, sd: _SessionDelta) -> None:
+        """The first batch (or pack) committed after a flush: its pods were
+        scheduled from a fresh (non-chained) evaluation."""
+        if self._after_flush:
+            self.metrics.pod_scheduled_after_flush.inc(
+                value=len(sd.ok_rows))
+            self._after_flush = False
+
+    def _close_session(self, sd: _SessionDelta, invalidated: bool,
+                       flushed: str, install_hint: bool = False) -> None:
+        """A session closes: `plan.adopt`, opened with its session's build.
+        An invalidated session's carry charged host-diverged placements, so
+        staging is the authority again: a full re-encode + upload, counted
+        as a flush (`flushed`). A clean one keeps the device state resident
+        (the final carry holds every placement: the next flush uploads
+        nothing), hands its tail back and may install the score hint."""
+        with self.stages.stage("plan.adopt", **sd.built):
             self.cache.update_snapshot(self.snapshot)
-            dirty_rows.extend(sd.busy_patch_rows)  # re-encode busy-patched rows
+            dirty_rows = sd.dirty_rows + sd.busy_patch_rows  # re-encoded
             if invalidated:
-                # The carry charged host-diverged placements; staging is the
-                # authority again — force a full re-encode + upload.
                 self.mirror.invalidate()
-                self.metrics.batch_cache_flushed.inc("session_invalidated")
+                self.metrics.batch_cache_flushed.inc(flushed)
                 self._after_flush = True
             else:
-                # Keep the device state resident: the final carry reflects every
-                # successful placement, so the next flush uploads nothing.
-                self.mirror.adopt(self.snapshot.node_info_list, ok_rows,
-                                  sd.carry.req_r, sd.carry.nonzero,
-                                  sd.carry.pod_count, dirty_rows=dirty_rows)
-                if sd.carry is not None and not dirty_rows:
-                    self._save_resume(fw, first_batch[0].pod, sig, aux_shape,
-                                      sd.state, plan, sd.carry, node_names)
-                    # Score-hint install (the cross-cycle OpportunisticBatch
-                    # save): the final host-commit completed cleanly, so the
-                    # carry IS the kernel's sorted-score truth for the next
-                    # identical pod — persist it for the host-only bind loop.
-                    from .score_hints import hint_eligible
-                    if self._hints.enabled and hint_eligible(
-                            plan, aux_shape, first_batch[0].pod,
-                            self.extenders, self.queue.nominator,
+                carry = sd.carry
+                self.mirror.adopt(self.snapshot.node_info_list, sd.ok_rows,
+                                  carry.req_r, carry.nonzero,
+                                  carry.pod_count, dirty_rows=dirty_rows)
+                if not dirty_rows:
+                    self._hand_back_tail(sd)
+                    if install_hint and self._hints.enabled and hint_eligible(
+                            sd.plan, sd.aux_shape, sd.pod, self.extenders,
+                            self.queue.nominator,
                             self.cache.affinity_pod_refs):
-                        self._hints.install(fw, first_batch[0].pod, sig, nsig,
-                                            plan, node_names, sd.carry)
+                        self._hints.install(sd.fw, sd.pod, sd.sig, sd.nsig,
+                                            sd.plan, sd.node_names, carry)
         # The session ran to completion (invalidation included — that is a
         # NORMAL end, not a device failure): a half-open breaker closes.
         self._note_device_success()
@@ -2539,7 +2497,6 @@ class TPUScheduler(Scheduler):
             pod = b[i].pod
             self.cache.forget_pod(pod)
             pod.node_name = ""
-        from ..core.framework import CycleState
         for i in range(answered):
             if results[i] is None:
                 ok_rows.append(rows[i])
@@ -2696,7 +2653,6 @@ class TPUScheduler(Scheduler):
         plugins, message = memo
         self.attempts += 1
         qpi.unschedulable_plugins |= plugins
-        from ..core.framework import Status
         self.handle_scheduling_failure(fw, qpi, Status.unschedulable(message), None)
         self.queue.done(qpi.pod.uid)
         self.metrics.schedule_attempts.inc("unschedulable", fw.profile_name)
@@ -2707,9 +2663,6 @@ class TPUScheduler(Scheduler):
         mirror's staging arrays and run the standard fit-error tail
         (PostFilter/preemption included). Returns False when the pod's
         feature set needs the exact host rerun (topology features)."""
-        import time as _t
-        from ..core.framework import CycleState, FitError
-        from ..ops.features import diagnose_unschedulable
 
         if self._nominated_device_block(fw, qpi.pod) is not None:
             # A nomination that touches this pod by more than its requests
@@ -2717,9 +2670,8 @@ class TPUScheduler(Scheduler):
             # two-pass filter for the resource fit only, and the exact host
             # rerun owns the Diagnosis.
             return False
-        t0 = _t.perf_counter()
-        self.cache.update_snapshot(self.snapshot)
-        self.mirror.sync(self.snapshot.node_info_list)
+        t0 = _time.perf_counter()
+        self._sync_mirror()
         diag = diagnose_unschedulable(
             qpi.pod, self.mirror, self.snapshot, fw,
             nominated=self._nominated_lane(qpi.pod))
@@ -2755,7 +2707,6 @@ class TPUScheduler(Scheduler):
         goes through the single DefaultBinder."""
         ok = self._fast_tail.get(id(fw))
         if ok is None:
-            from ..plugins.basic import DefaultBinder
             ok = (
                 all(getattr(p, "state_driven_tail", False)
                     for p in fw.reserve_plugins)
@@ -2770,14 +2721,12 @@ class TPUScheduler(Scheduler):
             self._fast_tail[id(fw)] = ok
         return ok
 
-    _EMPTY_STATE = None  # shared CycleState for stateless fast commits
+    _EMPTY_STATE = CycleState()  # shared by the stateless fast commits
 
     def _commit(self, fw: Framework, qpi: QueuedPodInfo, node_name: str) -> bool:
         """assume → reserve → permit → binding cycle (the unchanged host tail
         of the scheduling cycle, schedule_one.go:315 onward). Returns False
         when the host rejected the placement (carry divergence)."""
-        from ..core.framework import CycleState
-
         pod = qpi.pod
         self.attempts += 1
         dra_state = None
@@ -2808,8 +2757,6 @@ class TPUScheduler(Scheduler):
             # and of a batch the batch tail refuses (_batch_tail_run) pays
             # it; a retired batch that qualifies is committed in passes
             # (_commit_run), to the same outcome.
-            if TPUScheduler._EMPTY_STATE is None:
-                TPUScheduler._EMPTY_STATE = CycleState()
             pod.node_name = node_name
             self.cache.assume_pod(pod, qpi.pod_info)
             st = fw.bind_plugins[0].bind(
@@ -3017,8 +2964,7 @@ class TPUScheduler(Scheduler):
             # schedule_one only pops the queue and would strand it forever.
             if self._holdover is not None:
                 qpi, self._holdover = self._holdover, None
-                self.host_path_pods += len(getattr(qpi, "members", ()) or (1,))
-                self.process_one(qpi)
+                self._to_host_path([qpi])
                 return True
             return super()._cycle()
         self.process_async_api_errors()
@@ -3033,21 +2979,14 @@ class TPUScheduler(Scheduler):
         if not batch:
             return False
         if fallback_reason is _GANG_SESSION:
-            try:
-                self.run_gang_device_session(fw, batch[0])
-            except Unsupported:
-                self.metrics.device_path_fallback.inc("unsupported")
-                for qpi in batch:
-                    self.host_path_pods += len(getattr(qpi, "members", ()) or (1,))
-                    self.process_one(qpi)
+            self.run_gang_device_session(fw, batch[0])
             return True
         nominated = fallback_reason is _NOMINATED
         if nominated:
             fallback_reason = None
-        if fallback_reason is None and len(batch) >= 1:
-            pr = self._device_unsupported_profile(fw, batch[0].pod)
-            if pr is not None:
-                fallback_reason = pr
+        if fallback_reason is None:
+            fallback_reason = self._device_unsupported_profile(
+                fw, batch[0].pod)
         if fallback_reason is None and nominated \
                 and self._run_nominated(fw, batch):
             return True
@@ -3056,11 +2995,5 @@ class TPUScheduler(Scheduler):
                 self.host_path_pods += 1
                 self.process_one(qpi)
             return True
-        try:
-            self.run_device_session(fw, batch)
-        except Unsupported:
-            self.metrics.device_path_fallback.inc("unsupported")
-            for qpi in batch:
-                self.host_path_pods += 1
-                self.process_one(qpi)
+        self.run_device_session(fw, batch)
         return True

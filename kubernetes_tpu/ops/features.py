@@ -46,7 +46,7 @@ from ..plugins.podtopologyspread import (
     PodTopologySpread,
 )
 from .codebook import EFFECT_IDS, EFFECT_PREFER_NO_SCHEDULE, OP_EQUAL, OP_EXISTS
-from .device_state import BASE_RESOURCES, NodeStateMirror
+from .device_state import BASE_RESOURCES, DeviceNodeState, NodeStateMirror
 
 _UNSCHED_TAINT = Taint(key=NodeUnschedulable.TAINT_KEY, effect=NO_SCHEDULE)
 
@@ -1186,29 +1186,44 @@ def plan_shape(mirror: NodeStateMirror) -> tuple:
 
 @dataclass
 class KeptPlan:
-    """A built plan kept for its pod template (models/tpu_scheduler.py
-    `_preemptor_plan`: the preemption what-if and a nominated pod's own node
-    each plan for ONE pod of a template they have planned for before).
-    `plan` is what `build_batch` gave with ``only_row`` None; `seq` the
-    journal's sequence up to which it is known to hold; `guard` what the key
-    does not say and no event announces (the owner's to compose, with
-    `plan_shape` in it). What a kept plan cannot hold is derived again at
-    every use (`derive`): the nominated lane, the ``only_row`` mask, the
-    start index, the width of the results. Everything else in it is the
-    template's and the nodes' own (labels, images, declared features), which
-    the events that keep it valid do not touch."""
+    """A built plan kept for its pod template (models/tpu_scheduler.py, "the
+    keeper of built plans"), for the two kinds of caller that plan for a
+    template planned for before.
+
+    A preemptor (`_preemptor_plan`: the preemption what-if, and a nominated
+    pod's own node) derives from it. `plan` is what `build_batch` gave with
+    ``only_row`` None; `seq` the journal's sequence up to which it is known
+    to hold; `guard` what the key does not say and no event announces (the
+    owner's to compose, with `plan_shape` in it), or None: nobody may
+    derive from a plan kept for its tail alone. What a kept plan cannot
+    hold is derived again at every use (`derive`): the nominated lane, the
+    ``only_row`` mask, the start index, the width of the results, `has_pns`.
+    Everything else in it is the template's and the nodes' own (labels,
+    images, declared features), which the events that keep it valid do not
+    touch.
+
+    A session (`_resume_or_rebuild`) resumes from its **tail**, what the
+    template's last clean session left: the device `state` and `carry` it
+    ended on, the `node_names` of their rows, the journal's sequence at its
+    end (`tail_seq`, None without a tail; not `seq`, which a preemptor's use
+    advances, while the session's row patch must see every event since its
+    end), and what must be as it was for the carry to chain on: `attempts`,
+    `state_unwinds`, the nomination key `nom_key`."""
 
     plan: BatchPlan
     seq: int
-    guard: tuple
+    guard: Optional[tuple]
+    state: Optional[DeviceNodeState] = None
+    carry: object = None
+    node_names: Optional[List[str]] = None
+    tail_seq: Optional[int] = None
+    attempts: int = 0
+    state_unwinds: int = 0
+    nom_key: Optional[tuple] = None
     _extra_ok: Optional[np.ndarray] = None  # the plan's, on the host
 
-    def taints_moved(self, mirror: NodeStateMirror, n: int) -> None:
-        """A node update lies behind the plan (taints, allocatable or the
-        unschedulable flag of a row; labels, images and declared features
-        intact): of the plan only `has_pns` reads those rows, and the
-        synced mirror says it again as `build_batch` would."""
-        self.plan = replace(self.plan, has_pns=_has_pns(mirror, n))
+    def drop_tail(self) -> None:
+        self.state = self.carry = self.node_names = self.tail_seq = None
 
     def derive(self, mirror: NodeStateMirror, n: int, *, batch_size: int,
                start_index: int, nom_reqs,
@@ -1216,7 +1231,11 @@ class KeptPlan:
         """The plan `build_batch` would give now for `batch_size` pods of the
         template, with `nom_reqs` (`lane_requests`) the nominated lane and
         ``only_row`` the one row it may land on: at most four uploads and no
-        pass over the nodes. The mirror is synced to the `n` nodes."""
+        pass over the nodes. The mirror is synced to the `n` nodes. A node
+        update may lie behind the plan (taints, allocatable or the
+        unschedulable flag of a row; labels, images and declared features
+        intact): of the plan only `has_pns` reads those rows, and the synced
+        mirror says it again as `build_batch` would."""
         feats = self.plan.features
         nom_req, nom_pods = _lane_arrays(mirror, nom_reqs)
         again = {"nom_req": jnp.asarray(nom_req),
@@ -1228,7 +1247,7 @@ class KeptPlan:
             again["extra_ok"] = jnp.asarray(
                 _only_row(self._extra_ok, n, only_row))
         return replace(self.plan, features=feats._replace(**again),
-                       has_nom=bool(nom_reqs),
+                       has_nom=bool(nom_reqs), has_pns=_has_pns(mirror, n),
                        batch_pad=_batch_tier(batch_size))
 
 

@@ -78,13 +78,19 @@ def _same_plan(got, want):
             assert getattr(got, f.name) == getattr(want, f.name), f.name
 
 
+def _ask(sched, fw, pre, batch, site, only_row=None):
+    """`_preemptor_plan` as its two sites call it: snapshot and mirror
+    brought up first (`_sync_mirror`)."""
+    sched._sync_mirror()
+    return sched._preemptor_plan(fw, pre, batch, site, only_row=only_row)
+
+
 def _acquire(sched, pre, site="dry_run", only_row=None):
     """The keeper's plan for `pre` beside a fresh `build_plan`'s, held equal;
     returns how the keeper came by it."""
     fw = sched.framework_for_pod(pre)
     batch = 1 if site == "dry_run" else sched.max_batch
-    state, got, how = sched._preemptor_plan(fw, pre, batch, site,
-                                            only_row=only_row)
+    state, got, how = _ask(sched, fw, pre, batch, site, only_row)
     want_state, want = sched.build_plan(fw, pre, batch, only_row=only_row)
     _same_plan(got, want)
     # the device state is the mirror's flush on both sides
@@ -195,10 +201,10 @@ def test_a_taint_that_comes_and_goes_moves_has_pns_both_ways():
     assert _acquire(sched, pre) == "built"
     _taint("PreferNoSchedule")(sched, cs, pre)
     assert _acquire(sched, pre) == "kept"
-    assert sched._preemptor_plan(fw, pre, 1, "dry_run")[1].has_pns
+    assert _ask(sched, fw, pre, 1, "dry_run")[1].has_pns
     cs.update_node(_node("n3"))
     assert _acquire(sched, pre) == "kept"
-    assert not sched._preemptor_plan(fw, pre, 1, "dry_run")[1].has_pns
+    assert not _ask(sched, fw, pre, 1, "dry_run")[1].has_pns
 
 
 def test_only_row_keeps_the_padding_and_the_rows_own_verdict():
@@ -210,8 +216,7 @@ def test_only_row_keeps_the_padding_and_the_rows_own_verdict():
     assert _acquire(sched, pre, "nominated", 5) == "built"
     for row in (0, 3, 5):
         assert _acquire(sched, pre, "nominated", row) == "kept"
-        plan = sched._preemptor_plan(fw, pre, sched.max_batch, "nominated",
-                                     only_row=row)[1]
+        plan = _ask(sched, fw, pre, sched.max_batch, "nominated", row)[1]
         ok = np.asarray(plan.features.extra_ok)
         assert ok[:6].tolist() == [r == row for r in range(6)]
         assert ok[6:].all()
@@ -295,7 +300,7 @@ def test_a_pod_whose_plan_other_pods_can_move_is_built_every_time(only_row):
         {"cpu": "3", "memory": "100Mi"}).priority(10).host_port(8080).obj()
     for _ in range(2):
         assert _acquire(sched, pre, "nominated", only_row) == "built"
-    assert not sched._kept_plans
+    assert not sched._plans
     assert _plans(sched) == {("nominated", "built"): 2}
 
 
@@ -311,7 +316,7 @@ def test_the_dry_run_ends_alike_whether_the_plan_was_kept_or_built():
             pre = _pod(f"pre-{step}", cpu="3", priority=10)
             fw = fw or sched.framework_for_pod(pre)
             if emptied:
-                sched._kept_plans.clear()
+                sched._plans.clear()
             found = sched.device_dry_run_preemption(fw, None, pre, {}, 6, 0)
             seen.append([(c.node_name, [v.pod.name for v in c.victims])
                          for c in found])
@@ -404,7 +409,7 @@ def test_a_batch_of_preemptors_ends_as_the_host_scheduler_ends_it(templates):
             1 for name, _ in rec.opened if name == "sched.plan.build")
     # each template has its own entry, holding its own request
     kept = {int(np.asarray(e.plan.features.request)[0])
-            for e in sched._kept_plans.values()}
+            for e in sched._plans.values()}
     assert {milli for _stem, milli in templates} <= kept
     assert sched.device_breaker.consecutive_failures == 0
 
@@ -415,7 +420,7 @@ def test_the_keeper_holds_a_bounded_number_of_templates():
     for i in range(tpu_scheduler._KEPT_PLANS + 3):
         pre = _pod(f"pre-{i}", cpu=f"{3000 + i}m", priority=10)
         assert _acquire(sched, pre) == "built"
-    assert len(sched._kept_plans) == tpu_scheduler._KEPT_PLANS
+    assert len(sched._plans) == tpu_scheduler._KEPT_PLANS
     # the newest are the ones kept
     assert _acquire(sched, pre) == "kept"
     assert _acquire(sched, _pod("pre-0", cpu="3000m", priority=10)) == "built"
