@@ -303,7 +303,7 @@ class SchedulerMetrics:
             "by site ('dry_run': the device what-if of a preemption; "
             "'nominated': the evaluation of a nominated pod's own node) and "
             "by how: 'kept' (the template's kept plan, with the nominated "
-            "lane, the row mask and the start index derived again) or "
+            "lane, the one-row plan and the start index derived again) or "
             "'built' (no plan was kept, or the events since, the shapes or "
             "the bound pods' terms voided it: a full build, kept in turn).",
             ("site", "how")))
@@ -481,6 +481,15 @@ class SchedulerMetrics:
             "change the answer), 'single' = through the full "
             "_session_compatible check, the head of each session and the "
             "members of a gang pack included.", ("how",)))
+        self.prefilter_narrowed_pods = r(Counter(
+            "scheduler_prefilter_narrowed_pods_total",
+            "Pods whose NodeAffinity PreFilterResult narrowed the nodes "
+            "their cycle evaluates (every required term pins metadata.name: "
+            "the DaemonSet controller's pods), by the path that placed "
+            "them: 'device' = dispatched in a batch of a plan over the "
+            "named nodes' rows only (models/tpu_scheduler.py "
+            "_dispatch_next), 'host' = the host cycle "
+            "(core/scheduler.py find_nodes_that_fit_pod).", ("path",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

@@ -1720,6 +1720,7 @@ class Scheduler:
 
         nodes = all_nodes
         if pre_res is not None and not pre_res.all_nodes():
+            self.metrics.prefilter_narrowed_pods.inc("host")
             if len(pre_res.node_names) == 1:
                 # The daemonset shape narrows 15k nodes to ONE per pod: a map
                 # lookup, not an O(all nodes) scan per pod.

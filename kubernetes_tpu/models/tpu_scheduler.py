@@ -61,15 +61,16 @@ from ..ops.device_state import (NodeStateMirror, patch_tier,
                                 enable_persistent_compilation_cache)
 from ..ops.features import (KeptPlan, PreemptionVictims, Unsupported, _pow2,
                             lane_requests, batch_supported, build_batch,
-                            diagnose_unschedulable, plan_shape,
-                            volume_device_support)
+                            diagnose_unschedulable, narrowed_rows,
+                            padded_rows, plan_shape, volume_device_support)
 from ..ops.kernel import (dry_run_preemption, patch_carry_rows,
                           patch_carry_rows_pinned, schedule_batch,
                           schedule_placements)
 from ..parallel.mesh import (collective_report, make_mesh, mesh_host_split,
                              mesh_shard_count, mesh_state_shardings,
-                             shard_features, sharded_lap_schedule)
-from ..plugins.basic import DefaultBinder
+                             shard_features, shard_node_state,
+                             sharded_lap_schedule)
+from ..plugins.basic import DefaultBinder, NodeAffinity
 from ..plugins.preemption import Candidate
 from .score_hints import ScoreHintCache, hint_eligible
 
@@ -122,7 +123,10 @@ class _SessionDelta:
     (_note_session_events): the device `state` + `carry`, the seq watermark
     consumed, whether a shrink patch waits for the pipeline to drain; the
     batches `inflight` (entities, results, seq), the rows that took a pod
-    (`ok_rows`) and those where carry and host disagree (`dirty_rows`)."""
+    (`ok_rows`) and those where carry and host disagree (`dirty_rows`).
+    Where the template's PreFilterResult narrows, the plan is over the named
+    nodes' rows only (`plan.rows`), and so are `state`, `carry`,
+    `node_names` and every row index above."""
 
     __slots__ = ("fw", "pod", "sig", "nsig", "neutral_ok", "aux_shape", "plan",
                  "node_names", "built", "state", "carry", "start_seq",
@@ -220,6 +224,9 @@ class TPUScheduler(Scheduler):
         # shared signature holder, priority and scheduler name of the
         # session's head, or _NO_TEMPLATE where the head is no plain clone.
         self._session_template = _NO_TEMPLATE
+        # The session's template has a PreFilterResult that narrows
+        # (_narrows): what its pops say as `narrowed`.
+        self._session_narrows = False
         # Terminal-failure memos, a small keyed LRU (_fail_from_memo): state
         # key -> (unschedulable plugins, message)
         self._fail_memo: "dict" = {}
@@ -366,19 +373,22 @@ class TPUScheduler(Scheduler):
         the collector inside it says it ``took`` into a device batch:
         `pods`, and `run`, those of them on the template's verdict
         (`_refill`), which also move
-        `scheduler_queue_popped_pods_total{how}`. One `len()` a batch; the
+        `scheduler_queue_popped_pods_total{how}`, and `narrowed`, those
+        taken into a batch of a template whose PreFilterResult narrows
+        (`_narrows`; 0 elsewhere). One `len()` a batch; the
         hint walk's per-pod pops are leaves of the table and say nothing."""
-        pods = run = 0
+        pods = run = narrowed = 0
 
-        def took(n: int, on_template: int = 0) -> None:
-            nonlocal pods, run
+        def took(n: int, on_template: int = 0, pinned: int = 0) -> None:
+            nonlocal pods, run, narrowed
             pods += n
             run += on_template
+            narrowed += pinned
 
         with self.stages.stage(
                 "queue.pop", backlog=len(self.queue.active_q)) as stage:
             yield took
-            stage.say(pods=pods, run=run)
+            stage.say(pods=pods, run=run, narrowed=narrowed)
             count = self.metrics.queue_popped_pods.inc
             if run:
                 count("run", value=float(run))
@@ -438,9 +448,11 @@ class TPUScheduler(Scheduler):
             (shared, pod.priority, pod.scheduler_name)
             if shared is not None and not pod.volumes
             and not pod.resource_claims else _NO_TEMPLATE)
+        # every pod of the session is the head's template, pin and all
+        self._session_narrows = self._narrows(pod)
         batch = _Batch()
         self._take(batch, head)  # still the last entity _pop handed out
-        took(1)
+        took(1, pinned=int(self._session_narrows))
         if pod.nominated_node_name:
             # evaluateNominatedNode comes before the cycle that could share
             # a batch: the head stays alone until its node has answered
@@ -496,7 +508,9 @@ class TPUScheduler(Scheduler):
                     or fw.sign_pod(m.pod) != sig
                     or self._batch_supported_memo(m.pod, fw) is not None
                     or self._device_unsupported_profile(fw, m.pod) is not None
-                    or getattr(m.pod, "resource_claims", None)):
+                    or getattr(m.pod, "resource_claims", None)
+                    # a narrowed member: the host group cycle, as before
+                    or self._narrows(m.pod)):
                 return None, None
             if self._aux_shape(m.pod) != aux_shape:
                 return None, None
@@ -742,6 +756,7 @@ class TPUScheduler(Scheduler):
                 fw.sign_pod(m.pod) != sig
                 or self._batch_supported_memo(m.pod, fw) is not None
                 or self._device_unsupported_profile(fw, m.pod) is not None
+                or self._narrows(m.pod)  # the host loop, as before
                 # claim-carrying members: host sims (no intra-sim claim dedup)
                 or any(v.pvc_name for v in m.pod.volumes)
                 for m in members):
@@ -930,12 +945,13 @@ class TPUScheduler(Scheduler):
     def _run_nominated(self, fw: Framework, batch: "_Batch") -> bool:
         """evaluateNominatedNode (schedule_one.go:722) on the device path,
         for a head that holds a nomination: its nominated node alone, by a
-        batch of one of the pod's own scheduling program whose plan keeps
-        that row only (build_batch ``only_row``). The fit there counts the
+        batch of one of the pod's own scheduling program whose plan is over
+        that row only (``KeptPlan.derive`` ``rows``: the narrowing a
+        PreFilterResult gets, to one node). The fit there counts the
         other nominations of equal or higher priority (the plan's nominated
         lane, which leaves the pod's own out), nothing is scored, and the
-        start index stays where it was: the walk finds fewer rows than it
-        looks for and passes the whole cluster. True: the pod is dealt with,
+        start index stays where it was: findNodesThatFitPod returns before
+        it advances it. True: the pod is dealt with,
         bound there with its nomination cleared (``_commit``). False: the
         node is gone or no longer takes the pod, nothing was changed, and
         the caller runs the ordinary cycle with the pod at the head of its
@@ -974,7 +990,7 @@ class TPUScheduler(Scheduler):
         with self.stages.stage("device.wait", batch=1, seq=attrs["seq"]):
             res = np.asarray(results)
         self._note_device_success()
-        if int(res[0, 0]) != row:
+        if int(res[0, 0]) != 0:  # the plan's one row
             return "fell_through"
         with self.stages.stage("host.commit", batch=1, tail="single"):
             bound = self._commit(fw, qpi, pod.nominated_node_name)
@@ -1234,12 +1250,74 @@ class TPUScheduler(Scheduler):
             self.mirror.commit_shardings(None)
         self.mirror.sync(self.snapshot.node_info_list)
 
+    def _narrows(self, pod) -> bool:
+        """The pod's NodeAffinity PreFilterResult narrows its cycle
+        (plugins/basic.py NodeAffinity.narrowed_node_names), so its
+        sessions plan over the named nodes' rows only. Memoized on the
+        template's shared holder, as its signature is: a clone never
+        mutates its spec."""
+        if pod.affinity is None:
+            return False
+        shared = pod.__dict__.get("_sig_shared")
+        if shared is not None and "_narrows" in shared:
+            return shared["_narrows"]
+        out = NodeAffinity.narrowed_node_names(pod) is not None
+        if shared is not None:
+            shared["_narrows"] = out
+        return out
+
+    def _plan_rows(self, pod, only_row: Optional[int] = None):
+        """The snapshot rows a plan for `pod` is over, or None for all of
+        them: ``only_row`` alone (a nominated pod's own node), else the
+        nodes its PreFilterResult names (ops/features.py narrowed_rows).
+        Call AFTER `_sync_mirror`."""
+        if only_row is not None:
+            return (only_row,)
+        if not self._narrows(pod):
+            return None
+        return narrowed_rows(pod, self._snapshot_rows())
+
+    def _narrowed(self, entry: KeptPlan, pod, rows, batch_size: int,
+                  lane=None):
+        """(device state, plan) over `rows` only, derived from `entry`'s
+        plan over every row (KeptPlan.derive): the staging rows uploaded as
+        a state of their own (NodeStateMirror.rows_state), placed as any
+        state and plan are. `lane`: the nominated lane's requests where the
+        caller has them (`lane_requests`)."""
+        if lane is None:
+            lane = lane_requests(self.mirror, self._nominated_lane(pod))
+        shards = mesh_shard_count(self.mesh) if self.mesh is not None else 1
+        plan = entry.derive(
+            self.mirror, self.snapshot.num_nodes(), batch_size=batch_size,
+            start_index=self.next_start_node_index, nom_reqs=lane,
+            rows=rows, shards=shards,
+            percentage_of_nodes_to_score=self.percentage_of_nodes_to_score)
+        state = self.mirror.rows_state(
+            padded_rows(rows, plan.plan_rows), len(rows))
+        if self.mesh is not None:
+            state = shard_node_state(state, self.mesh)
+            plan.features = shard_features(plan.features, self.mesh)
+        return state, plan
+
     def build_plan(self, fw: Framework, pod, batch_size: int,
                    only_row: Optional[int] = None):
-        """Snapshot → mirror sync → batch feature build → device flush.
-        Returns (device_state, BatchPlan). Also the graft/bench entry's way
-        to produce kernel inputs. ``only_row``: the one snapshot row the
-        plan may land on (build_batch; a nominated pod's own node).
+        """(device_state, BatchPlan) for `batch_size` pods like `pod`, as a
+        session of them would plan: over every row of the snapshot
+        (`_build_full_plan`), or, where the pod's PreFilterResult narrows
+        or ``only_row`` names the one snapshot row the plan may land on (a
+        nominated pod's own node), over those rows only (`_narrowed`). Also
+        the graft/bench entry's way to produce kernel inputs."""
+        state, plan = self._build_full_plan(fw, pod, batch_size)
+        rows = self._plan_rows(pod, only_row)
+        if rows is None:
+            return state, plan
+        return self._narrowed(
+            KeptPlan(plan, self.cluster_event_seq, None), pod, rows,
+            batch_size)
+
+    def _build_full_plan(self, fw: Framework, pod, batch_size: int):
+        """Snapshot → mirror sync → batch feature build → device flush, over
+        every row of the snapshot. Returns (device_state, BatchPlan).
 
         Mesh-first: under a mesh the mirror's RESIDENT copy is committed to
         mesh_state_shardings, so flush() uploads host staging straight to
@@ -1272,7 +1350,6 @@ class TPUScheduler(Scheduler):
             dra_enabled=dra_enabled,
             dra_in_use=dra_in_use,
             nominated=self._nominated_lane(pod),
-            only_row=only_row,
             stages=self.stages,
         )
         self._count_ipa(plan)
@@ -1343,14 +1420,14 @@ class TPUScheduler(Scheduler):
 
     def _build_kept_plan(self, fw: Framework, pod, key, batch_size: int):
         """(device state, entry) of a full build for `pod`'s template
-        (`build_plan`, ``only_row`` None), kept under `key` to derive from
+        (`_build_full_plan`, over every row), kept under `key` to derive from
         where the pod's filters read other pods by their requests alone
         (`_resources_only_block`, the precondition of both sites that
         derive): nothing a pod brings to a node moves such a plan's
         features. Else (or with no key: an unsignable pod; or where a live
         tail's plan holds the key) the entry is the caller's alone. Nobody
         writes to a built plan: the session's own object is kept."""
-        state, plan = self.build_plan(fw, pod, batch_size)
+        state, plan = self._build_full_plan(fw, pod, batch_size)
         plans = self._plans
         held = plans.get(key)
         if key is None or self._resources_only_block(pod) is not None or (
@@ -1409,7 +1486,9 @@ class TPUScheduler(Scheduler):
         """(device state, plan, ``kept`` | ``built``) for ONE pod of a
         template that was planned for before: the what-if of its preemption
         (`site` ``dry_run``) and, once it is nominated, the evaluation of
-        its own node (``nominated``, with ``only_row``). Call AFTER
+        its own node (``nominated``, with ``only_row``: the plan and its
+        state are over that row alone, `_narrowed`, and the mirror's flush
+        is left to whoever next needs the whole state). Call AFTER
         `_sync_mirror`. The template's kept plan holds while the events
         since classify (`_kept_plan`): a `pod_local` plan and plain pods'
         events dirty mirror rows, never features, nor does a node update
@@ -1433,11 +1512,13 @@ class TPUScheduler(Scheduler):
             state, entry = self._build_kept_plan(fw, pod, key, batch_size)
         else:
             entry.seq = self.cluster_event_seq
-            state = self.mirror.flush()
+            state = self.mirror.flush() if only_row is None else None
+        if only_row is not None:
+            return self._narrowed(entry, pod, (only_row,), batch_size,
+                                  lane) + (how,)
         plan = entry.derive(
             self.mirror, self.snapshot.num_nodes(), batch_size=batch_size,
-            start_index=self.next_start_node_index, nom_reqs=lane,
-            only_row=only_row)
+            start_index=self.next_start_node_index, nom_reqs=lane)
         if self.mesh is not None:
             plan.features = shard_features(plan.features, self.mesh)
         return state, plan, how
@@ -1503,20 +1584,33 @@ class TPUScheduler(Scheduler):
             # hostname-like value): warm the conservative fallback trace too
             # so the flip can't put a compile inside the measured window.
             warm(dataclasses.replace(plan, anti_rowlocal=False))
-        if nominated and not plan.has_nom:
+
+        def warm_with_a_lane(p):
             # Preemption workloads flip the nominated lane on mid-run (the
             # first nomination would otherwise compile inside the measured
             # window): warm the has_nom variant with an empty lane — shapes
             # and statics are identical to the live nominated plan.
-            nom_req, nom_pods = self._empty_nom_lane()
-            nf = plan.features._replace(nom_req=nom_req, nom_pods=nom_pods)
-            warm(dataclasses.replace(plan, features=nf, has_nom=True))
+            if not p.has_nom:
+                nom_req, nom_pods = self._empty_nom_lane(p.plan_rows)
+                nf = p.features._replace(nom_req=nom_req, nom_pods=nom_pods)
+                warm(dataclasses.replace(p, features=nf, has_nom=True))
 
-    def _empty_nom_lane(self):
-        """A nominated lane that holds nothing, at the live lane's shapes and
-        (under a mesh) committed shardings, which jit keys on:
-        shard_features puts the lane on the node axis. Kept per shape."""
-        key = (self.mirror.np_cap, self.mirror.r_slots, self.mesh)
+        if nominated:
+            warm_with_a_lane(plan)
+            if self.snapshot.num_nodes():
+                # The nominated retry's own program: a plan over one row
+                # (_evaluate_nominated_node), with and without a lane.
+                state, one = self.build_plan(fw, pod, self.max_batch,
+                                             only_row=0)
+                warm(one)
+                warm_with_a_lane(one)
+
+    def _empty_nom_lane(self, rows: Optional[int] = None):
+        """A nominated lane that holds nothing, at the live lane's shapes
+        (`rows` of them: the mirror's, or a narrowed plan's) and (under a
+        mesh) committed shardings, which jit keys on: shard_features puts
+        the lane on the node axis. Kept per shape."""
+        key = (rows or self.mirror.np_cap, self.mirror.r_slots, self.mesh)
         if self._empty_nom_key != key:
             nom_req = jnp.zeros(key[:2], jnp.int64)
             nom_pods = jnp.zeros(key[0], jnp.int32)
@@ -1587,7 +1681,7 @@ class TPUScheduler(Scheduler):
         programs, a CPU dry run — not a speed)."""
         if self.mesh is None or not (plan.rides_lap and plan.row_local):
             return None
-        if self.mirror.np_cap % mesh_shard_count(self.mesh):
+        if plan.plan_rows % mesh_shard_count(self.mesh):
             return None  # node tier not divisible across shards
         return sharded_lap_schedule(self.mesh, plan.batch_pad,
                                     plan.fit_strategy, plan.vmax)
@@ -1885,6 +1979,10 @@ class TPUScheduler(Scheduler):
         (`busy`)."""
         if not names:
             return True
+        if sd.plan.rows is not None:
+            # A narrowed session's rows are not the mirror's: it ends, and
+            # the template's next session builds from the patched truth.
+            return False
         with self.stages.stage("plan.patch", rows=len(names)):
             patched = self._patch_rows(sd.plan, sd.node_names, names,
                                        sd.state, sd.carry, busy)
@@ -1983,6 +2081,14 @@ class TPUScheduler(Scheduler):
                                                     self.max_batch)
             sd.plan, sd.carry = entry.plan, None
             sd.node_names = [ni.name for ni in self.snapshot.node_info_list]
+            rows = self._plan_rows(pod)
+            if rows is not None:
+                # The PreFilterResult narrows: the session runs over the
+                # named nodes' rows only. It hands back no tail (a session
+                # of the template always starts here) and installs no hint.
+                sd.state, sd.plan = self._narrowed(entry, pod, rows,
+                                                   self.max_batch)
+                sd.node_names = [sd.node_names[r] for r in rows]
             self.metrics.plan_rebuild_cause.inc(cause)
         self.plan_build_cause = cause if kind == "full" else ""
         self._count_rebuild(kind)
@@ -2191,7 +2297,8 @@ class TPUScheduler(Scheduler):
                 waits.append(queue_wait(qpi, now))
         self.metrics.pod_stage_duration.observe_many(waits, "queue.wait")
         batch += run
-        took(len(run), on_template)
+        took(len(run), on_template,
+             len(run) if self._session_narrows else 0)
         return batch
 
     def _collect_session_batch(self, fw: Framework, sig,
@@ -2359,7 +2466,7 @@ class TPUScheduler(Scheduler):
         with self.stages.stage("plan.build", sampled, point, **attrs) as st:
             kind = self._resume_or_rebuild(sd)
             sd.built = {"kind": kind, "cause": self.plan_build_cause}
-            st.say(**sd.built)
+            st.say(**sd.built, **sd.plan.narrowed_attrs())
         sd.start_seq = self.cluster_event_seq
         sd.start_unwinds = self.state_unwinds
         return sd
@@ -2374,6 +2481,8 @@ class TPUScheduler(Scheduler):
             results, sd.carry = self._dispatch(sd.state, sd.plan, n, sd.carry)
             results.copy_to_host_async()
         self._count_dispatch(attrs)
+        if sd.plan.rows is not None:  # a session narrows by PreFilterResult
+            self.metrics.prefilter_narrowed_pods.inc("device", value=float(n))
         sd.inflight.append((entities, results, attrs["seq"]))
         self._note_inflight(sd)
 
@@ -2406,7 +2515,12 @@ class TPUScheduler(Scheduler):
         staging is the authority again: a full re-encode + upload, counted
         as a flush (`flushed`). A clean one keeps the device state resident
         (the final carry holds every placement: the next flush uploads
-        nothing), hands its tail back and may install the score hint."""
+        nothing), hands its tail back and may install the score hint. A
+        clean session over a narrowed row set leaves the resident state as
+        it is: its carry holds other rows than the mirror's, so the rows it
+        landed on go the ordinary way (their NodeInfo generations moved:
+        the next sync re-encodes them, the next flush scatters them, one
+        row a pinned node), and nobody resumes or is served from it."""
         with self.stages.stage("plan.adopt", **sd.built):
             self.cache.update_snapshot(self.snapshot)
             dirty_rows = sd.dirty_rows + sd.busy_patch_rows  # re-encoded
@@ -2414,7 +2528,7 @@ class TPUScheduler(Scheduler):
                 self.mirror.invalidate()
                 self.metrics.batch_cache_flushed.inc(flushed)
                 self._after_flush = True
-            else:
+            elif sd.plan.rows is None:
                 carry = sd.carry
                 self.mirror.adopt(self.snapshot.node_info_list, sd.ok_rows,
                                   carry.req_r, carry.nonzero,
@@ -2674,7 +2788,8 @@ class TPUScheduler(Scheduler):
         self._sync_mirror()
         diag = diagnose_unschedulable(
             qpi.pod, self.mirror, self.snapshot, fw,
-            nominated=self._nominated_lane(qpi.pod))
+            nominated=self._nominated_lane(qpi.pod),
+            rows=self._plan_rows(qpi.pod))
         if diag is None:
             return False
         self.attempts += 1

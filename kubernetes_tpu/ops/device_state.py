@@ -434,6 +434,19 @@ class NodeStateMirror:
         return self._device
 
 
+    def rows_state(self, idx: np.ndarray, n: int) -> DeviceNodeState:
+        """The device state of a plan over a narrowed row set
+        (ops/features.py KeptPlan.derive `rows`): staging rows `idx` (the
+        first `n` the rows themselves, the rest padding) uploaded as a state
+        of their own, `valid` only in the first `n`. Staging is synced to
+        the snapshot by then (TPUScheduler._sync_mirror), so this is what a
+        flush holds in those rows; the resident copy is neither read nor
+        touched."""
+        arrays = [a[idx] for a in self._arrays()]
+        arrays[9][n:] = False  # h_valid: a gather is a copy
+        return DeviceNodeState(*[jnp.asarray(a) for a in arrays],
+                               jnp.asarray(self.h_topo[:, idx]))
+
     def patch_rows(self, updates, sharded_state=None,
                    out_shardings=None,
                    donate: bool = True) -> Optional[DeviceNodeState]:

@@ -38,7 +38,7 @@ from ..api.types import (
 )
 from ..core.node_info import NodeInfo, PodInfo
 from ..core.scheduler import num_feasible_nodes_to_find
-from ..plugins.basic import NodeUnschedulable
+from ..plugins.basic import NodeAffinity, NodeUnschedulable
 from ..plugins.helpers import compile_terms
 from ..plugins.podtopologyspread import (
     _compile_constraints,
@@ -199,6 +199,12 @@ class BatchPlan:
     # both 0 where the walk met no term to match.
     ipa_score_matches: int = 0
     ipa_pods_walked: int = 0
+    # A plan over a narrowed row set (KeptPlan.derive `rows`): the snapshot
+    # rows its rows stand for, in snapshot order (the nodes a NodeAffinity
+    # PreFilterResult names, or a nominated pod's own node); row i of the
+    # plan, of its device state and of its results is snapshot row rows[i].
+    # None: the plan's rows are the snapshot's.
+    rows: Optional[tuple] = None
 
     @property
     def coupling(self):
@@ -229,7 +235,22 @@ class BatchPlan:
                  "batch_pad": self.batch_pad}
         if engine != "lap":
             attrs["steps"] = n_active
+        attrs.update(self.narrowed_attrs())
         return attrs
+
+    def narrowed_attrs(self) -> dict:
+        """What a narrowed plan says of itself on `plan.build` and
+        `device.dispatch`: `narrowed_rows`, the nodes it may land on, and
+        `plan_rows`, the padded rows its arrays hold; nothing on a plan
+        over the snapshot's own rows."""
+        if self.rows is None:
+            return {}
+        return {"narrowed_rows": len(self.rows), "plan_rows": self.plan_rows}
+
+    @property
+    def plan_rows(self) -> int:
+        """The rows the plan's per-row arrays hold, padding included."""
+        return int(self.features.sel_match.shape[0])
 
     @property
     def rides_lap(self) -> bool:
@@ -417,21 +438,19 @@ def batch_supported(pod: Pod, snapshot, fit_plugin=None, ba_plugin=None,
     counted CSI attach limit) are covered on device via host-evaluated
     static per-node vectors (sel_match / extra_ok / na_raw / il_score /
     aux_room) — only genuinely stateful host machinery (unbound volume
-    binding, DRA allocation) still falls back. A nomination on the pod is
-    no reason: its node is evaluated first and alone by a batch of one
-    whose plan keeps that row only (build_batch `only_row`;
-    models/tpu_scheduler.py _evaluate_nominated_node), and the pod never
-    joins another pod's batch (_batch_supported_memo)."""
-    aff = pod.affinity
-    na = aff.node_affinity if aff is not None else None
-    if na is not None and na.required is not None:
-        # matchFields metadata.name pins trigger the NodeAffinity
-        # PreFilterResult narrowing (node_affinity.go PreFilter): the host
-        # cycle then rotates/samples over the NARROWED node list, which the
-        # kernel's full-cluster rotation cannot reproduce — and the narrowed
-        # universe is tiny, so the host cycle is already O(1) per pod.
-        if any(t.match_fields for t in na.required.terms):
-            return "node-affinity metadata.name narrowing"
+    binding, DRA allocation) still falls back. Neither a nomination on the
+    pod nor a NodeAffinity PreFilterResult (every required term pins
+    metadata.name) is a reason: both plan over a narrowed row set
+    (`KeptPlan.derive` `rows`), the nominated pod's own node first and
+    alone by a batch of one (models/tpu_scheduler.py
+    _evaluate_nominated_node; it never joins another pod's batch,
+    _batch_supported_memo), the pinned pods' named nodes by ordinary
+    sessions, over which the kernel's own rotation is the host's rotation
+    over the narrowed list (`narrowed_rows`). Only a pin that names nobody
+    (`In` with no values) stays on the host: its PreFilter rejects the pod
+    before the start index is read, which a dispatch would move."""
+    if NodeAffinity.narrowed_node_names(pod) == set():
+        return "node-affinity names no node"
     reason, vol_d, vol_inc = (_volume_verdict if _volume_verdict is not None
                               else volume_device_support(
                                   pod, clientset, pvc_refs=pvc_refs,
@@ -490,7 +509,6 @@ def build_batch(
     dra_enabled=False,
     dra_in_use=None,
     nominated=None,
-    only_row: Optional[int] = None,
     stages=None,
 ) -> BatchPlan:
     """Build kernel inputs for a batch of `batch_size` pods identical to `pod`.
@@ -513,10 +531,9 @@ def build_batch(
     interact with beyond resources — models/tpu_scheduler.py
     _nominated_device_block).
 
-    `only_row`: the one snapshot row the batch may land on (a nominated
-    pod's evaluation of its nominated node, evaluateNominatedNode): every
-    other row fails the static mask, so the walk passes the whole cluster,
-    finds at most that row, and leaves the start index where it was.
+    The plan is over every row of the snapshot, whatever the pod's
+    PreFilterResult says: a plan over a narrowed row set is derived from it
+    (`KeptPlan.derive` `rows`), never built.
     """
     verdict = volume_device_support(
         pod, clientset, pvc_refs=pvc_refs, limited_drivers=limited_drivers)
@@ -1038,8 +1055,7 @@ def build_batch(
         node_name_id=jnp.asarray(node_name_id),
         tolerates_unsched=jnp.asarray(tolerates_unsched),
         sel_match=jnp.asarray(_pad_bool(sel_match_host, npc)),
-        extra_ok=jnp.asarray(_only_row(
-            _pad_bool(extra_ok_host, npc, default=True), n, only_row)),
+        extra_ok=jnp.asarray(_pad_bool(extra_ok_host, npc, default=True)),
         il_score=jnp.asarray(_pad_i64(il_host, npc)),
         na_raw=jnp.asarray(_pad_i64(na_host, npc)),
         dns_axis=jnp.asarray(dns_axis), dns_active=jnp.asarray(dns_active),
@@ -1144,15 +1160,32 @@ def _lane_arrays(mirror: NodeStateMirror, nom_reqs) -> tuple:
     return nom_req, nom_pods
 
 
-def _only_row(extra_ok: np.ndarray, n: int, only_row: Optional[int]):
-    """The padded `extra_ok` with every node's row but `only_row` refused
-    (the padding stays as it is); itself where `only_row` is None."""
-    if only_row is None:
-        return extra_ok
-    out = extra_ok.copy()
-    out[:n] = False
-    out[only_row] = extra_ok[only_row]
-    return out
+# The BatchFeatures fields that hold one value a node row (the fields
+# parallel/mesh.py shards over the node axis; tests/test_narrowed_plan.py
+# holds the two lists equal): what a plan over a narrowed row set gathers.
+ROW_FIELDS = ("sel_match", "extra_ok", "il_score", "na_raw", "exist_anti",
+              "ipa_base", "aux_room")
+# The smallest row tier of a narrowed plan: the mirror's own smallest.
+NARROW_FLOOR = 64
+
+
+def narrowed_rows(pod: Pod, row_of: Dict[str, int]) -> Optional[List[int]]:
+    """The snapshot rows a NodeAffinity PreFilterResult narrows `pod`'s cycle
+    to, in snapshot order (the order the host walks the narrowed list in,
+    core/scheduler.py find_nodes_that_fit_pod), or None where it does not
+    narrow. `row_of`: node name -> snapshot row. A named node that is not
+    in the snapshot has no row; a pin to nodes that are all gone gives []."""
+    names = NodeAffinity.narrowed_node_names(pod)
+    if names is None:
+        return None
+    return sorted(row_of[nm] for nm in names if nm in row_of)
+
+
+def narrow_width(m: int, shards: int = 1) -> int:
+    """The padded row count of a plan narrowed to `m` rows: a power of two
+    from NARROW_FLOOR, a multiple of the mesh's `shards`."""
+    width = _pow2(max(m, shards), NARROW_FLOOR)
+    return -(-width // shards) * shards
 
 
 def _plan_vmax(mirror: NodeStateMirror) -> int:
@@ -1191,13 +1224,15 @@ class KeptPlan:
     template planned for before.
 
     A preemptor (`_preemptor_plan`: the preemption what-if, and a nominated
-    pod's own node) derives from it. `plan` is what `build_batch` gave with
-    ``only_row`` None; `seq` the journal's sequence up to which it is known
-    to hold; `guard` what the key does not say and no event announces (the
-    owner's to compose, with `plan_shape` in it), or None: nobody may
-    derive from a plan kept for its tail alone. What a kept plan cannot
-    hold is derived again at every use (`derive`): the nominated lane, the
-    ``only_row`` mask, the start index, the width of the results, `has_pns`.
+    pod's own node) derives from it. `plan` is what `build_batch` gave, over
+    every row of the snapshot; `seq` the journal's sequence up to which it
+    is known to hold; `guard` what the key does not say and no event
+    announces (the owner's to compose, with `plan_shape` in it), or None:
+    nobody may derive from a plan kept for its tail alone. What a kept plan
+    cannot hold is derived again at every use (`derive`): the nominated
+    lane, the narrowed row set (``rows``: a nominated pod's own node, or
+    the nodes a PreFilterResult names, with the sample and the start index
+    over them), the start index, the width of the results, `has_pns`.
     Everything else in it is the template's and the nodes' own (labels,
     images, declared features), which the events that keep it valid do not
     touch.
@@ -1220,35 +1255,68 @@ class KeptPlan:
     attempts: int = 0
     state_unwinds: int = 0
     nom_key: Optional[tuple] = None
-    _extra_ok: Optional[np.ndarray] = None  # the plan's, on the host
+    _host_rows: Optional[dict] = None  # the plan's ROW_FIELDS, on the host
 
     def drop_tail(self) -> None:
         self.state = self.carry = self.node_names = self.tail_seq = None
 
     def derive(self, mirror: NodeStateMirror, n: int, *, batch_size: int,
-               start_index: int, nom_reqs,
-               only_row: Optional[int] = None) -> BatchPlan:
+               start_index: int, nom_reqs, rows=None, shards: int = 1,
+               percentage_of_nodes_to_score: int = 0) -> BatchPlan:
         """The plan `build_batch` would give now for `batch_size` pods of the
-        template, with `nom_reqs` (`lane_requests`) the nominated lane and
-        ``only_row`` the one row it may land on: at most four uploads and no
-        pass over the nodes. The mirror is synced to the `n` nodes. A node
-        update may lie behind the plan (taints, allocatable or the
-        unschedulable flag of a row; labels, images and declared features
-        intact): of the plan only `has_pns` reads those rows, and the synced
-        mirror says it again as `build_batch` would."""
+        template, with `nom_reqs` (`lane_requests`) the nominated lane: a
+        few uploads and no pass over the nodes. The mirror is synced to the
+        `n` nodes. A node update may lie behind the plan (taints,
+        allocatable or the unschedulable flag of a row; labels, images and
+        declared features intact): of the plan only `has_pns` reads those
+        rows, and the synced mirror says it again as `build_batch` would.
+
+        ``rows``: the snapshot rows the batch may land on, in snapshot order
+        (`narrowed_rows`, or a nominated pod's one node). The plan is then
+        over THOSE rows only, padded to `narrow_width`: every per-row
+        feature gathered (the count tables stay the whole cluster's, as the
+        host's PreFilter state does), `num_nodes` their count, the sample
+        `num_feasible_nodes_to_find` of it and the start index modulo it, so
+        the kernel's rotation over its rows IS the host's rotation over the
+        narrowed list (core/scheduler.py find_nodes_that_pass_filters), and
+        a batch costs the device the rows that can matter. Its device state
+        is `NodeStateMirror.rows_state` of the same rows."""
         feats = self.plan.features
         nom_req, nom_pods = _lane_arrays(mirror, nom_reqs)
-        again = {"nom_req": jnp.asarray(nom_req),
-                 "nom_pods": jnp.asarray(nom_pods),
-                 "start_index": jnp.asarray(np.int32(start_index % max(1, n)))}
-        if only_row is not None:
-            if self._extra_ok is None:
-                self._extra_ok = np.asarray(feats.extra_ok)
+        again = {}
+        if rows is not None:
+            rows = tuple(rows)
+            n = len(rows)
+            idx = padded_rows(rows, narrow_width(n, shards))
+            if self._host_rows is None:
+                self._host_rows = {name: np.asarray(getattr(feats, name))
+                                   for name in ROW_FIELDS}
+            again = {name: jnp.asarray(a[idx])
+                     for name, a in self._host_rows.items()}
+            # the padding rows fail the static mask, whatever row 0 says
             again["extra_ok"] = jnp.asarray(
-                _only_row(self._extra_ok, n, only_row))
+                self._host_rows["extra_ok"][idx] & (np.arange(len(idx)) < n))
+            if nom_reqs:
+                nom_req, nom_pods = nom_req[idx], nom_pods[idx]
+            again["num_nodes"] = jnp.asarray(np.int32(n))
+            again["to_find"] = jnp.asarray(np.int32(num_feasible_nodes_to_find(
+                n, percentage_of_nodes_to_score)))
+        again.update(
+            nom_req=jnp.asarray(nom_req), nom_pods=jnp.asarray(nom_pods),
+            start_index=jnp.asarray(np.int32(start_index % max(1, n))))
         return replace(self.plan, features=feats._replace(**again),
-                       has_nom=bool(nom_reqs), has_pns=_has_pns(mirror, n),
-                       batch_pad=_batch_tier(batch_size))
+                       has_nom=bool(nom_reqs),
+                       has_pns=_has_pns(mirror, mirror.num_nodes),
+                       batch_pad=_batch_tier(batch_size), rows=rows)
+
+
+def padded_rows(rows, width: int) -> np.ndarray:
+    """`rows` as a gather index `width` long: the padding repeats row 0
+    (every staging array holds it), and is refused by `extra_ok` and by the
+    state's `valid`."""
+    idx = np.zeros(width, np.int64)
+    idx[:len(rows)] = rows
+    return idx
 
 
 PREEMPT_K_CAP = 256  # victims-per-node tier ceiling (recompile guard)
@@ -1369,7 +1437,7 @@ def build_preemption_victims(pod: Pod, snapshot, mirror: NodeStateMirror):
 
 
 def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
-                           fw, nominated=None) -> Optional["object"]:
+                           fw, nominated=None, rows=None) -> Optional["object"]:
     """Per-node failure Diagnosis for a pod the device found infeasible
     EVERYWHERE — vectorized over the mirror's staging arrays instead of the
     pure-Python per-node filter loop (which costs ~0.3s at 5k nodes and used
@@ -1389,6 +1457,12 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
     and one that passes it passes pass two (the caller's gate,
     _nominated_device_block, keeps away every pod a nominated pod touches
     by more than its requests).
+
+    `rows`: the snapshot rows a PreFilterResult narrowed the pod's cycle to
+    (`narrowed_rows`), or None. The host evaluates the narrowed list only,
+    so only those nodes have a status; a pin to nodes that are all gone
+    fails with no status at all (and no plugin to blame), as the host's
+    walk over an empty list does.
     """
     if (pod.topology_spread_constraints
             or (pod.affinity is not None
@@ -1397,9 +1471,17 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
     from ..core.framework import Diagnosis, Status
 
     nodes: List[NodeInfo] = snapshot.node_info_list
-    n = len(nodes)
-    if n == 0:
+    if len(nodes) == 0:
         return None
+    # `at`: the staging rows the statuses are for, all of them or the
+    # narrowed ones; `nodes` and `n` follow it.
+    at = slice(0, len(nodes))
+    if rows is not None:
+        if not rows:
+            return Diagnosis()
+        at = np.asarray(rows)
+        nodes = [nodes[r] for r in rows]
+    n = len(nodes)
     names = {p.name for p in fw.filter_plugins}
 
     # (plugin, unresolvable, fails[n] bool, message) in profile filter order.
@@ -1410,13 +1492,13 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
         checks.append(("NodeName", True, fails,
                        "node(s) didn't match the requested node name"))
     if "NodeUnschedulable" in names:
-        unsched = mirror.h_unsched[:n].copy()
+        unsched = mirror.h_unsched[at].copy()
         if any(t.tolerates(_UNSCHED_TAINT) for t in pod.tolerations):
             unsched[:] = False
         checks.append(("NodeUnschedulable", True, unsched,
                        "node(s) were unschedulable"))
     if "TaintToleration" in names:
-        tainted_rows = (mirror.h_taint_eff[:n] != 0).any(axis=1)
+        tainted_rows = (mirror.h_taint_eff[at] != 0).any(axis=1)
         fails = np.zeros(n, bool)
         for r_i in np.nonzero(tainted_rows)[0]:
             fails[r_i] = find_matching_untolerated_taint(
@@ -1440,18 +1522,19 @@ def diagnose_unschedulable(pod: Pod, mirror: NodeStateMirror, snapshot,
     if "NodeResourcesFit" in names:
         req = pod.resource_request()
         req_vec = _resource_vec(mirror, req)
-        alloc = mirror.h_alloc_r[:n]
-        used = mirror.h_req_r[:n]
-        count = mirror.h_pod_count[:n]
+        alloc = mirror.h_alloc_r[at]
+        used = mirror.h_req_r
+        count = mirror.h_pod_count
         if nominated:
             used, count = used.copy(), count.copy()
             for row, npi in nominated:
                 used[row] += _resource_vec(mirror, npi.pod.resource_request())
                 count[row] += 1
+        used, count = used[at], count[at]
         pos = req_vec > 0
         insufficient = (req_vec[None, :] > (alloc - used)) & pos[None, :]
         over_capacity = (req_vec[None, :] > alloc) & pos[None, :]
-        pods_full = (count + 1) > mirror.h_alloc_pods[:n]
+        pods_full = (count + 1) > mirror.h_alloc_pods[at]
         # Unresolvable when the request exceeds allocatable outright
         # (fit.go fitsRequest Unresolvable flag) — preemption can't help.
         checks.append(("NodeResourcesFit", True,

@@ -441,24 +441,34 @@ class NodeAffinity:
             return True
         return pod.required_node_selector_matches(new)
 
+    @staticmethod
+    def narrowed_node_names(pod: Pod) -> Optional[set]:
+        """The node names the PreFilterResult narrows the cycle to, or None
+        where it does not: every required term pins metadata.name via In
+        (a term without such a requirement means no narrowing). The one
+        rule of both paths: `pre_filter` answers with it, and the device
+        path plans over those rows only (ops/features.py `narrowed_rows`)."""
+        na = pod.affinity.node_affinity if pod.affinity else None
+        if na is None or na.required is None or not na.required.terms:
+            return None
+        node_names: set = set()
+        for term in na.required.terms:
+            term_names = None
+            for req in term.match_fields:
+                if req.key == "metadata.name" and req.operator == "In":
+                    term_names = set(req.values)
+            if term_names is None:
+                return None
+            node_names |= term_names
+        return node_names
+
     def pre_filter(self, state: CycleState, pod: Pod, nodes) -> Tuple[Optional[PreFilterResult], Status]:
         na = pod.affinity.node_affinity if pod.affinity else None
         if not pod.node_selector and (na is None or na.required is None):
             return None, Status.skip()
-        # Narrow to named nodes when every term pins metadata.name via In.
-        if na is not None and na.required is not None and na.required.terms:
-            node_names: Optional[set] = set()
-            for term in na.required.terms:
-                term_names = None
-                for req in term.match_fields:
-                    if req.key == "metadata.name" and req.operator == "In":
-                        term_names = set(req.values)
-                if term_names is None:
-                    node_names = None
-                    break
-                node_names |= term_names
-            if node_names is not None:
-                return PreFilterResult(node_names), OK
+        node_names = self.narrowed_node_names(pod)
+        if node_names is not None:
+            return PreFilterResult(node_names), OK
         return None, OK
 
     def filter(self, state: CycleState, pod: Pod, node_info: NodeInfo) -> Status:

@@ -40,11 +40,11 @@ def _assignments(sched):
     return {p.name: p.node_name for p in sched.clientset.pods.values()}
 
 
-def _run_pair(n_nodes, pods_fn, seed=0, **cluster_kw):
+def _run_pair(n_nodes, pods_fn, seed=0, cluster=_mk_cluster, **cluster_kw):
     host = Scheduler(deterministic_ties=True)
     dev = TPUScheduler()
-    _mk_cluster(host, n_nodes, seed=seed, **cluster_kw)
-    _mk_cluster(dev, n_nodes, seed=seed, **cluster_kw)
+    cluster(host, n_nodes, seed=seed, **cluster_kw)
+    cluster(dev, n_nodes, seed=seed, **cluster_kw)
     for p in pods_fn():
         host.clientset.create_pod(p)
     for p in pods_fn():
@@ -70,6 +70,121 @@ def _basic_pods(n, cpu="500m", mem="256Mi", labels=None, build=None):
             pods.append(b.obj())
         return pods
     return fn
+
+
+# -- pods whose NodeAffinity PreFilterResult narrows ---------------------------
+
+def _equal_cluster(sched, n_nodes, seed=0, pods=110):
+    """Nodes that tie on every score, so the rotating start index decides."""
+    for i in range(n_nodes):
+        sched.clientset.create_node(
+            make_node().name(f"node-{i}")
+            .capacity({"cpu": 8, "memory": "16Gi", "pods": pods})
+            .label("disk", "ssd" if i % 2 else "hdd").obj())
+
+
+def _pinned(name, nodes, cpu="100m", expressions=(), more_terms=()):
+    from kubernetes_tpu.api.labels import IN, Requirement
+    from kubernetes_tpu.api.types import (Affinity, NodeAffinity as NA,
+                                          NodeSelector, NodeSelectorTerm)
+    p = make_pod().name(name).req({"cpu": cpu}).obj()
+    term = NodeSelectorTerm(
+        match_expressions=tuple(expressions),
+        match_fields=(Requirement("metadata.name", IN, tuple(nodes)),))
+    p.affinity = Affinity(node_affinity=NA(
+        required=NodeSelector((term,) + tuple(more_terms))))
+    return p
+
+
+def _case_one_node():
+    return 12, lambda: [_pinned(f"ds-{i}", ["node-5"]) for i in range(30)], \
+        _mk_cluster, 30, 30
+
+
+def _case_four_nodes():
+    names = ["node-9", "node-2", "node-7", "node-4"]
+    return 12, lambda: [_pinned(f"ds-{i}", names) for i in range(30)], \
+        _equal_cluster, 30, 30
+
+
+def _case_120_nodes_the_sample_is_cut():
+    # 120 named nodes: the sample is 100 of them, so the window moves on
+    names = [f"node-{i}" for i in range(5, 125)]
+    return 130, lambda: [_pinned(f"ds-{i}", names) for i in range(12)], \
+        _equal_cluster, 12, 12
+
+
+def _case_a_node_that_does_not_exist():
+    def pods():
+        return ([_pinned(f"lost-{i}", ["node-404"]) for i in range(3)]
+                + [make_pod().name(f"plain-{i}").req({"cpu": "100m"}).obj()
+                   for i in range(4)])
+    return 12, pods, _equal_cluster, 4, 3
+
+
+def _case_a_full_node():
+    def cluster(sched, n_nodes, seed=0):
+        _equal_cluster(sched, n_nodes, pods=2)
+    return 6, lambda: [_pinned(f"ds-{i}", ["node-3"]) for i in range(4)], \
+        cluster, 2, 4
+
+
+def _case_a_term_without_match_fields_narrows_nothing():
+    from kubernetes_tpu.api.labels import IN, Requirement
+    from kubernetes_tpu.api.types import NodeSelectorTerm
+    other = NodeSelectorTerm(match_expressions=(
+        Requirement("disk", IN, ("ssd",)),))
+    return 12, lambda: [_pinned(f"p-{i}", ["node-2"], more_terms=(other,))
+                        for i in range(20)], _equal_cluster, 20, 0
+
+
+def _case_match_fields_with_match_expressions():
+    from kubernetes_tpu.api.labels import IN, Requirement
+    ssd = (Requirement("disk", IN, ("ssd",)),)  # the odd nodes
+    names = ["node-1", "node-2", "node-3", "node-6"]
+    return 12, lambda: [_pinned(f"p-{i}", names, expressions=ssd)
+                        for i in range(16)], _equal_cluster, 16, 16
+
+
+def _case_pinned_and_plain_pods_take_turns():
+    # the plain pods' nodes are the test of the start index a pinned pod
+    # leaves behind: every node ties, the first in walk order wins
+    def pods():
+        out = []
+        for i in range(24):
+            if i % 3 == 2:
+                out.append(_pinned(f"ds-{i}", ["node-3", "node-8", "node-10"]))
+            else:
+                out.append(make_pod().name(f"plain-{i}")
+                           .req({"cpu": "100m"}).obj())
+        return out
+    return 12, pods, _equal_cluster, 24, 8
+
+
+def _case_a_nominated_pinned_pod():
+    # node-3 is kept full by low-priority pods; the pinned pod of priority
+    # 10 evicts its way in, is nominated there, and binds at its retry
+    def pods():
+        fill = [make_pod().name(f"fill-{i}").req({"cpu": "3500m"})
+                .priority(-10).obj() for i in range(2)]
+        for p in fill:
+            p.node_name = "node-3"
+        pre = _pinned("pre", ["node-3"], cpu="6")
+        pre.priority = 10
+        return fill + [pre]
+    # (the retry's answer comes from the nominated node before the
+    # narrowing is looked at, on both paths: one narrowed attempt)
+    return 6, pods, _equal_cluster, 1, 1
+
+
+_NARROWED_CASES = {
+    f.__name__[len("_case_"):]: f for f in (
+        _case_one_node, _case_four_nodes, _case_120_nodes_the_sample_is_cut,
+        _case_a_node_that_does_not_exist, _case_a_full_node,
+        _case_a_term_without_match_fields_narrows_nothing,
+        _case_match_fields_with_match_expressions,
+        _case_pinned_and_plain_pods_take_turns,
+        _case_a_nominated_pinned_pod)}
 
 
 class TestFitEquivalence:
@@ -297,8 +412,8 @@ class TestWidenedCoverageEquivalence:
     def test_node_affinity_match_fields_narrowing(self):
         # Daemonset shape: matchFields metadata.name pin (daemonset-pod.yaml)
         # triggers the NodeAffinity PreFilterResult narrowing, which changes
-        # the rotation/sampling universe — these pods MUST take the host path
-        # (batch_supported), and assignments must still match the oracle.
+        # the rotation/sampling universe: the device path plans over the
+        # named nodes' rows only, and assignments must match the oracle.
         from kubernetes_tpu.api.labels import IN, Requirement
         from kubernetes_tpu.api.types import Affinity, NodeAffinity as NA, NodeSelector, NodeSelectorTerm
 
@@ -312,7 +427,47 @@ class TestWidenedCoverageEquivalence:
                 pods.append(p)
             return pods
         host, dev = _run_pair(12, fn)
-        assert dev.host_path_pods == 10  # PreFilterResult narrowing: host path
+        assert dev.host_path_pods == 0
+        assert dev.device_scheduled == 10
+        assert dev.metrics.prefilter_narrowed_pods.value("device") == 10
+        assert host.metrics.prefilter_narrowed_pods.value("host") == 10
+
+    @pytest.mark.parametrize("case", sorted(_NARROWED_CASES))
+    def test_narrowed_pods_are_placed_as_the_host_places_them(self, case):
+        """Every shape of a PreFilterResult that narrows, on the device path
+        and on the host's: the same node a pod, the same rotating start
+        index at the end (a plain pod behind a pinned one samples the same
+        window), the same failures with the same words and the same plugins
+        to blame, and no pod on the device scheduler's host path."""
+        n_nodes, pods_fn, cluster, bound, narrowed = _NARROWED_CASES[case]()
+        host, dev = _run_pair(n_nodes, pods_fn, cluster=cluster)
+        assert dev.host_path_pods == 0
+        assert host.scheduled == dev.scheduled == bound
+        assert host.failures == dev.failures
+        assert host.next_start_node_index == dev.next_start_node_index
+        # every attempt whose PreFilterResult narrowed, on either path
+        assert dev.metrics.prefilter_narrowed_pods.value("device") == narrowed
+        assert host.metrics.prefilter_narrowed_pods.value("host") == narrowed
+        assert dev.metrics.prefilter_narrowed_pods.value("host") == 0
+        for sched in (host, dev):
+            sched.said = {
+                p.name: [e.message for e in sched.recorder.for_object(
+                    f"{p.namespace}/{p.name}") if e.reason == "FailedScheduling"]
+                for p in sched.clientset.pods.values() if not p.node_name}
+            sched.blamed = {
+                q.pod.name: sorted(q.unschedulable_plugins)
+                for q in sched.queue.unschedulable.values()}
+        # (an identical pod that fails behind the first against the same
+        # state is parked from the failure memo, whose words are the short
+        # form: `_fail_from_memo`)
+        assert host.said.keys() == dev.said.keys()
+        for name, words in host.said.items():
+            assert len(words) == len(dev.said[name])
+            assert all(h == d or h.startswith(d + " for pod")
+                       for h, d in zip(words, dev.said[name])), name
+        assert any(host.said[n] == dev.said[n] for n in host.said) \
+            or not host.said
+        assert host.blamed == dev.blamed
 
     def test_preferred_node_affinity_scoring(self):
         host, dev = _run_pair(20, _basic_pods(

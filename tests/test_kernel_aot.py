@@ -218,3 +218,64 @@ def test_the_preemption_cells_programs_compile_for_the_chip(one_chip):
     prims = _primitives(body)
     assert prims["div"] == 0 and prims["rem"] == 0, prims
     assert 200 < len(body) <= LAP_BODY_CEILING + 500, len(body)
+
+
+def test_the_daemonset_cells_programs_compile_for_the_chip(one_chip):
+    """`daemonset-15k.waves` (PR 47) meets two shapes no cell met before:
+    the lap kernel of a plan over a narrowed row set (64 rows for the one
+    named node, batches of 1,024) and every program of the node state at
+    the 16,384-row tier (the lap kernel of a plain template, which
+    `warm_for` and the full build of a wave run, and the flush's one
+    scatter, 4,096 rows wide there). All compile for the chip; the narrowed
+    lap's body holds no division expansion and is no larger than the lap's
+    at any other tier."""
+    from kubernetes_tpu.core import FakeClientset
+    from kubernetes_tpu.models import TPUScheduler
+    from kubernetes_tpu.ops.device_state import _scatter_rows, patch_tier
+    from kubernetes_tpu.ops.kernel import schedule_batch
+    from kubernetes_tpu.testing.wrappers import make_node, make_pod
+
+    def S(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def sds(x):
+        return S(x.shape, x.dtype)
+
+    cs = FakeClientset()
+    s = TPUScheduler(clientset=cs, max_batch=1024, mesh=None)
+    for i in range(6):
+        cs.create_node(make_node().name(f"node-{i}").capacity(
+            {"cpu": "4", "memory": "32Gi", "pods": 110}).obj())
+    pinned = make_pod().name("ds").node_affinity_name("node-3").obj()
+    state, plan = s.build_plan(next(iter(s.profiles.values())), pinned, 1024)
+    assert plan.rows == (3,) and state.valid.shape == (64,)
+    assert plan.rides_lap and plan.batch_pad == 1024
+
+    def lap(state, plan, rows):
+        def at(x):  # a per-row array at another row tier
+            return S((rows,) + x.shape[1:], x.dtype)
+        f = jax.tree_util.tree_map(sds, plan.features)
+        from kubernetes_tpu.ops.features import ROW_FIELDS
+        f = f._replace(**{n: at(getattr(f, n)) for n in ROW_FIELDS})
+        st = jax.tree_util.tree_map(at, state)._replace(
+            topo=S((state.topo.shape[0], rows), state.topo.dtype))
+        return schedule_batch.lower(
+            st, f, plan.batch_pad, plan.fit_strategy, plan.vmax,
+            n_active=S((), jnp.int32), carry_in=None, has_pns=plan.has_pns,
+            has_ipa_base=plan.has_ipa_base, anti_rowlocal=plan.anti_rowlocal,
+            has_na_pref=plan.has_na_pref, port_selfblock=plan.port_selfblock,
+            has_aux=plan.has_aux, has_nom=plan.has_nom).compile().as_text(), st
+
+    for rows in (64, 16384):
+        hlo, st = lap(state, plan, rows)
+        body = _instructions(hlo, under="/while/body/")
+        prims = _primitives(body)
+        assert prims["div"] == 0 and prims["rem"] == 0, (rows, prims)
+        assert 200 < len(body) <= LAP_BODY_CEILING, (rows, len(body))
+    # the mirror's flush at the 16,384-row tier: one width, 4,096 rows
+    width = patch_tier(int(0.25 * 16384))
+    assert width == 4096
+    rows_of = jax.tree_util.tree_map(
+        lambda x: S((width,) + x.shape[1:], x.dtype), st)._replace(
+        topo=S((st.topo.shape[0], width), st.topo.dtype))
+    _scatter_rows.lower(st, S((width,), jnp.int32), rows_of).compile()

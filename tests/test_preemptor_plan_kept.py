@@ -207,19 +207,30 @@ def test_a_taint_that_comes_and_goes_moves_has_pns_both_ways():
     assert not _ask(sched, fw, pre, 1, "dry_run")[1].has_pns
 
 
-def test_only_row_keeps_the_padding_and_the_rows_own_verdict():
-    """The row mask leaves one node's row as the plan has it (a node the
-    pod's selector refuses stays refused) and the padding as it is."""
+def test_a_one_row_plan_keeps_the_rows_own_verdict_and_refuses_the_padding():
+    """A plan over one row (a nominated pod's own node) holds that node's
+    row as the kept plan has it, in row 0 of arrays of the smallest tier,
+    with a state of the same rows; the padding is refused."""
     sched, cs = _device()
     pre = _pod("pre", cpu="3", priority=10)
     fw = sched.framework_for_pod(pre)
     assert _acquire(sched, pre, "nominated", 5) == "built"
     for row in (0, 3, 5):
         assert _acquire(sched, pre, "nominated", row) == "kept"
-        plan = _ask(sched, fw, pre, sched.max_batch, "nominated", row)[1]
-        ok = np.asarray(plan.features.extra_ok)
-        assert ok[:6].tolist() == [r == row for r in range(6)]
-        assert ok[6:].all()
+        state, plan, _how = _ask(sched, fw, pre, sched.max_batch,
+                                 "nominated", row)
+        assert plan.rows == (row,)
+        assert plan.narrowed_attrs() == {"narrowed_rows": 1, "plan_rows": 64}
+        f = plan.features
+        assert (int(f.num_nodes), int(f.to_find), int(f.start_index)) \
+            == (1, 1, 0)
+        ok = np.asarray(f.extra_ok)
+        assert ok.shape == (64,) and ok[0] and not ok[1:].any()
+        valid = np.asarray(state.valid)
+        assert valid[0] and not valid[1:].any()
+        assert int(np.asarray(state.name_id)[0]) == sched.mirror.h_name_id[row]
+        assert np.array_equal(np.asarray(state.req_r)[0],
+                              sched.mirror.h_req_r[row])
 
 
 # -- (2) what drops it ---------------------------------------------------------

@@ -50,7 +50,8 @@ def test_a_session_of_several_batches_opens_each_pop_with_the_depth_it_met(
     assert sched.scheduled == 100 and sched.host_path_pods == 0
     assert sched.device_batches == 7
     pops = _pops(opened)
-    assert all(set(p) == {"backlog", "pods", "run"} for p in pops)
+    assert all(set(p) == {"backlog", "pods", "run", "narrowed"} for p in pops)
+    assert not any(p["narrowed"] for p in pops)  # nobody is pinned here
     met = [p["backlog"] for p in pops]
     assert met[:7] == [100, 84, 68, 52, 36, 20, 4]
     assert set(met[7:]) == {0} and len(met) >= 8
@@ -119,5 +120,6 @@ def test_the_hint_walks_pops_say_nothing(monkeypatch):
     # the walk's eight per-pod pops moved the table and opened no span; the
     # pops after it found an empty queue
     later = _pops(opened)[spans_before:]
-    assert all(p == {"backlog": 0, "pods": 0, "run": 0} for p in later)
+    assert all(p == {"backlog": 0, "pods": 0, "run": 0, "narrowed": 0}
+               for p in later)
     assert sched.stages.counts["queue.pop"] - stages_before >= 8 + len(later)
