@@ -490,6 +490,17 @@ class SchedulerMetrics:
             "named nodes' rows only (models/tpu_scheduler.py "
             "_dispatch_next), 'host' = the host cycle "
             "(core/scheduler.py find_nodes_that_fit_pod).", ("path",)))
+        self.host_to_device_transfers = r(Counter(
+            "scheduler_host_to_device_transfers_total",
+            "Host-to-device transfers of the feature build and the mirror "
+            "(ops/device_state.py NodeStateMirror.send: a payload's arrays "
+            "packed into one buffer, sent once, taken apart on the device), "
+            "by payload: 'features' = a full build's BatchFeatures, 'flush' "
+            "= the dirty rows of a flush's scatter or of a session's row "
+            "patch, 'rows_state' = the device state of a narrowed plan, "
+            "'derive' = what a kept plan derives again. One a payload; the "
+            "full upload after a restore (one array a transfer) is not "
+            "among them.", ("payload",)))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

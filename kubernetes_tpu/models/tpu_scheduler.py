@@ -184,6 +184,7 @@ class TPUScheduler(Scheduler):
         else:
             self.mesh = mesh  # explicit Mesh, or None to force single-device
         self.mirror = NodeStateMirror()
+        self.mirror.transfers = self.metrics.host_to_device_transfers
         # the preemption what-if's victim tensors, kept from one preemptor
         # to the next and patched by the snapshot's generations
         self._victims = PreemptionVictims(self.mirror)
@@ -1061,7 +1062,9 @@ class TPUScheduler(Scheduler):
             return None
         vic_req, vic_valid, potential = built
         t_victims = clock()
+        sent = self.mirror.transfers.total()
         dstate, plan, how = self._preemptor_plan(fw, pod, 1, "dry_run")
+        sent = int(self.mirror.transfers.total() - sent)
         if vic_req.shape[2] != self.mirror.r_slots:
             # build_plan interned the preemptor's never-seen scalar slots
             # AFTER the victim tensors were built, growing the mirror's
@@ -1096,6 +1099,7 @@ class TPUScheduler(Scheduler):
             # from (rows, victim slots, resource slots)
             st.say(victims_ms=round(1e3 * (t_victims - t0), 3),
                    plan_ms=round(1e3 * (t_plan - t_victims), 3), plan=how,
+                   transfers=sent,
                    dispatch_ms=round(1e3 * (t_dispatch - t_plan), 3),
                    fetch_ms=round(1e3 * (t_fetch - t_dispatch), 3),
                    victim_rows_rebuilt=victims.rebuilt,
@@ -2463,10 +2467,13 @@ class TPUScheduler(Scheduler):
             fw, pod, sig,
             self._neutral_sig(fw, pod, sig) if neutral_ok else None,
             neutral_ok, self._aux_shape(pod))
+        transfers = self.mirror.transfers
         with self.stages.stage("plan.build", sampled, point, **attrs) as st:
+            sent = transfers.total()
             kind = self._resume_or_rebuild(sd)
             sd.built = {"kind": kind, "cause": self.plan_build_cause}
-            st.say(**sd.built, **sd.plan.narrowed_attrs())
+            st.say(**sd.built, **sd.plan.narrowed_attrs(),
+                   transfers=int(transfers.total() - sent))
         sd.start_seq = self.cluster_event_seq
         sd.start_unwinds = self.state_unwinds
         return sd

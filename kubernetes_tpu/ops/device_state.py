@@ -18,7 +18,8 @@ and device agree bit-for-bit (see ops/kernel.py).
 
 from __future__ import annotations
 
-from typing import Dict, List, NamedTuple, Optional, Sequence
+import math
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -57,6 +58,7 @@ def enable_persistent_compilation_cache() -> None:
 import jax.numpy as jnp  # noqa: E402
 
 from ..api import resource as res  # noqa: E402
+from ..core.metrics import Counter  # noqa: E402
 from ..core.node_info import NodeInfo  # noqa: E402
 from .codebook import EFFECT_IDS, Codebook  # noqa: E402
 
@@ -107,6 +109,47 @@ def _pow2(n: int, floor: int) -> int:
     return c
 
 
+# -- one transfer a payload ---------------------------------------------------
+#
+# A host-to-device call costs the host its quarter of a millisecond whether it
+# carries a scalar or 8,192 rows, so a payload of many arrays (a plan's
+# features, a flush's dirty rows) goes up as ONE buffer and is taken apart on
+# the device: `pack` lays the named host arrays end to end, `unpack` (traced:
+# static offsets, slices, reshapes, casts) hands back the same names with the
+# same shapes and dtypes. Every value is a bool or an integer of at most 64
+# signed bits, so one int64 buffer holds them all and the casts are exact.
+
+
+def pack(named: Dict[str, np.ndarray]) -> Tuple[np.ndarray, tuple]:
+    """(buffer, layout) of the named host arrays (or numpy scalars): one
+    contiguous int64 buffer, and per array its (name, offset, shape, dtype
+    name): hashable, the static half of `unpack`."""
+    arrays = [np.asarray(a) for a in named.values()]
+    layout, size = [], 0
+    for name, a in zip(named, arrays):
+        if a.dtype.kind not in "biu" or a.dtype == np.uint64:
+            raise TypeError(f"{name}: {a.dtype} does not pack into int64")
+        layout.append((name, size, a.shape, a.dtype.name))
+        size += a.size
+    buf = np.empty(size, np.int64)
+    for (_name, off, _shape, _dtype), a in zip(layout, arrays):
+        buf[off:off + a.size] = a.reshape(-1)
+    return buf, tuple(layout)
+
+
+def unpack(buf, layout: tuple) -> Dict[str, jnp.ndarray]:
+    """The named arrays of a packed buffer (traced; `layout` static)."""
+    out = {}
+    for name, off, shape, dtype in layout:
+        size = math.prod(shape)
+        a = jax.lax.slice(buf, (off,), (off + size,)).reshape(shape)
+        out[name] = a if dtype == "int64" else a.astype(dtype)
+    return out
+
+
+_unpacked = jax.jit(unpack, static_argnames=("layout",))
+
+
 class TopoAxis:
     """One registered topology key (e.g. topology.kubernetes.io/zone):
     per-key value codebook + its row in the mirror's `topo` tensor.
@@ -131,15 +174,21 @@ class TopoAxis:
         return self.values.lookup(val if val != "" else self._EMPTY_TOKEN)
 
 
-def _scatter_rows_impl(state: DeviceNodeState, idx, rows: DeviceNodeState) -> DeviceNodeState:
-    """Dirty-row scatter as ONE compiled executable (13 per-array scatters
-    fused; a separate jit per array would compile 13 executables per tier)."""
-    updated = [arr.at[idx].set(r) for arr, r in zip(state[:-1], rows[:-1])]
-    topo = state.topo.at[:, idx].set(rows.topo)
+def _scatter_rows_impl(state: DeviceNodeState, packed, layout: tuple) -> DeviceNodeState:
+    """Dirty-row scatter as ONE compiled executable: the packed payload
+    (NodeStateMirror._dirty_payload: the row index and every column's rows
+    in one buffer) taken apart and 13 per-array scatters, fused; a program
+    of its own for the unpack, or a jit per array, would compile as many
+    executables per tier."""
+    rows = unpack(packed, layout)
+    idx = rows["idx"]
+    updated = [arr.at[idx].set(rows[name])
+               for name, arr in zip(state._fields[:-1], state[:-1])]
+    topo = state.topo.at[:, idx].set(rows["topo"])
     return DeviceNodeState(*updated, topo)
 
 
-_scatter_rows = jax.jit(_scatter_rows_impl)
+_scatter_rows = jax.jit(_scatter_rows_impl, static_argnames=("layout",))
 
 # Mesh variant: one jitted scatter per (out_shardings pytree, donation) —
 # parallel/mesh.py mesh_state_shardings caches the pytree, NamedSharding
@@ -162,7 +211,8 @@ def _sharded_scatter(out_shardings, donate: bool = False):
     key = (out_shardings, donate)
     fn = _SHARDED_SCATTER_CACHE.get(key)
     if fn is None:
-        fn = jax.jit(_scatter_rows_impl, out_shardings=out_shardings,
+        fn = jax.jit(_scatter_rows_impl, static_argnames=("layout",),
+                     out_shardings=out_shardings,
                      donate_argnums=(0,) if donate else ())
         _SHARDED_SCATTER_CACHE[key] = fn
     return fn
@@ -207,6 +257,28 @@ class NodeStateMirror:
         # single-device copy.
         self._shardings = None
         self.num_nodes = 0
+        # host-to-device transfers by payload kind, counted in `send`; the
+        # scheduler that owns the mirror puts its registry's series here
+        self.transfers = Counter(
+            "scheduler_host_to_device_transfers_total", "", ("payload",))
+
+    # -- one transfer a payload --------------------------------------------
+
+    def send(self, payload: str, named: Dict[str, np.ndarray]):
+        """(device buffer, layout) of the named host arrays, packed (`pack`)
+        and sent in ONE transfer, counted under `payload`; `unpack` inside
+        the program that reads it takes it apart."""
+        buf, layout = pack(named)
+        self.transfers.inc(payload)
+        return jnp.asarray(buf), layout
+
+    def upload(self, payload: str,
+               named: Dict[str, np.ndarray]) -> Dict[str, jnp.ndarray]:
+        """The named host arrays as device arrays of the same shapes and
+        dtypes: one transfer (`send`) and one jitted unpack, a program a
+        layout."""
+        buf, layout = self.send(payload, named)
+        return _unpacked(buf, layout=layout)
 
     # -- storage -----------------------------------------------------------
 
@@ -359,19 +431,22 @@ class NodeStateMirror:
             self.h_taint_eff, self.h_unsched, self.h_valid, self.h_name_id,
         )
 
+    def _staged_rows(self, idx: np.ndarray) -> Dict[str, np.ndarray]:
+        """Staging rows `idx` of every column, by DeviceNodeState field."""
+        named = {name: a[idx] for name, a in
+                 zip(DeviceNodeState._fields, self._arrays())}
+        named["topo"] = self.h_topo[:, idx]
+        return named
+
     def _dirty_payload(self, dirty, width: int):
-        """(idx, rows) scatter operands for the given staging rows, padded
-        to `width` by repeating the last index (scatter-set with duplicate
-        indices writes the same value): the jitted scatter compiles once
-        per width, not once per dirty-count."""
-        dirty = dirty + [dirty[-1]] * (width - len(dirty))
-        # int32 on the host: a Python list handed to jnp.asarray with a dtype
-        # is one more compiled program (a convert) for every width
-        idx = jnp.asarray(np.asarray(dirty, np.int32))
-        rows = DeviceNodeState(
-            *[jnp.asarray(a[dirty]) for a in self._arrays()],
-            jnp.asarray(self.h_topo[:, dirty]))
-        return idx, rows
+        """(packed, layout) scatter operand for the given staging rows: the
+        row index (`idx`) and every column's rows in one buffer, ONE
+        transfer (`send`), padded to `width` by repeating the last index
+        (scatter-set with duplicate indices writes the same value): the
+        jitted scatter compiles once per width, not once per dirty-count."""
+        idx = np.full(width, dirty[-1], np.int32)
+        idx[:len(dirty)] = dirty
+        return self.send("flush", {"idx": idx, **self._staged_rows(idx)})
 
     def commit_shardings(self, out_shardings) -> None:
         """Commit the resident device copy to these NamedShardings (None =
@@ -404,17 +479,19 @@ class NodeStateMirror:
         return self._device is not None and self._device.req_r.is_deleted()
 
     def _scatter_dirty(self, dirty) -> DeviceNodeState:
-        """Scatter the given staging rows into the resident device state,
-        at the ONE width a flush has: the tier of the most rows it may
-        scatter. How many rows a flush finds dirty is the workload's to
-        decide (a wave that packs its pods onto a few nodes dirties 25 rows
-        and the next one 250); were the width to follow that count, a width
-        first met mid-run would compile where work is being measured."""
-        idx, rows = self._dirty_payload(
+        """Scatter the given staging rows into the resident device state:
+        one transfer (`_dirty_payload`) and one program, at the ONE width a
+        flush has: the tier of the most rows it may scatter. How many rows
+        a flush finds dirty is the workload's to decide (a wave that packs
+        its pods onto a few nodes dirties 25 rows and the next one 250);
+        were the width to follow that count, a width first met mid-run
+        would compile where work is being measured."""
+        packed, layout = self._dirty_payload(
             dirty, patch_tier(int(self.scatter_threshold * self.np_cap)))
         if self._shardings is not None:
-            return _sharded_scatter(self._shardings)(self._device, idx, rows)
-        return _scatter_rows(self._device, idx, rows)
+            return _sharded_scatter(self._shardings)(
+                self._device, packed, layout=layout)
+        return _scatter_rows(self._device, packed, layout=layout)
 
     def flush(self) -> DeviceNodeState:
         """Upload pending changes; returns the device pytree (committed to
@@ -437,15 +514,14 @@ class NodeStateMirror:
     def rows_state(self, idx: np.ndarray, n: int) -> DeviceNodeState:
         """The device state of a plan over a narrowed row set
         (ops/features.py KeptPlan.derive `rows`): staging rows `idx` (the
-        first `n` the rows themselves, the rest padding) uploaded as a state
-        of their own, `valid` only in the first `n`. Staging is synced to
-        the snapshot by then (TPUScheduler._sync_mirror), so this is what a
-        flush holds in those rows; the resident copy is neither read nor
-        touched."""
-        arrays = [a[idx] for a in self._arrays()]
-        arrays[9][n:] = False  # h_valid: a gather is a copy
-        return DeviceNodeState(*[jnp.asarray(a) for a in arrays],
-                               jnp.asarray(self.h_topo[:, idx]))
+        first `n` the rows themselves, the rest padding) sent in one
+        transfer (`upload`) as a state of their own, `valid` only in the
+        first `n`. Staging is synced to the snapshot by then
+        (TPUScheduler._sync_mirror), so this is what a flush holds in those
+        rows; the resident copy is neither read nor touched."""
+        named = self._staged_rows(idx)
+        named["valid"][n:] = False  # a gather is a copy
+        return DeviceNodeState(**self.upload("rows_state", named))
 
     def patch_rows(self, updates, sharded_state=None,
                    out_shardings=None,
@@ -492,22 +568,24 @@ class NodeStateMirror:
         except _Regrown:
             return None  # staging reset: next flush rebuilds everything
         dirty = sorted({row for row, _ in updates})
-        idx, rows = self._dirty_payload(dirty, patch_tier(len(dirty)))
+        packed, layout = self._dirty_payload(dirty, patch_tier(len(dirty)))
         if sharded_state is not None and sharded_state is self._device:
             # Mesh-first steady state: session state == resident. One
             # pinned scatter patches it — DONATED (in-place buffer reuse)
             # unless the caller's dispatch pipeline still holds in-flight
             # reads of the old state (`donate=False`, the busy-patch seam).
             self._device = _sharded_scatter(out_shardings, donate=donate)(
-                sharded_state, idx, rows)
+                sharded_state, packed, layout=layout)
             self._dirty.difference_update(dirty)
             return self._device
         self._device = (_sharded_scatter(self._shardings)(
-            self._device, idx, rows) if self._shardings is not None
-            else _scatter_rows(self._device, idx, rows))
+            self._device, packed, layout=layout)
+            if self._shardings is not None
+            else _scatter_rows(self._device, packed, layout=layout))
         self._dirty.difference_update(dirty)
         if sharded_state is not None:
-            return _sharded_scatter(out_shardings)(sharded_state, idx, rows)
+            return _sharded_scatter(out_shardings)(
+                sharded_state, packed, layout=layout)
         return self._device
 
     def invalidate(self) -> None:

@@ -1045,47 +1045,36 @@ def build_batch(
                            if _claim_driver(key) == aux_driver)
             aux_room[r_i] = max(0, limit - existing)
 
-    feats = BatchFeatures(
-        request=jnp.asarray(request),
-        nz_request=jnp.asarray(nz_request),
-        has_request=jnp.asarray(has_request),
-        ba_skip=jnp.asarray(ba_skip),
-        tol_key=jnp.asarray(tol_key), tol_val=jnp.asarray(tol_val),
-        tol_eff=jnp.asarray(tol_eff), tol_op=jnp.asarray(tol_op),
-        node_name_id=jnp.asarray(node_name_id),
-        tolerates_unsched=jnp.asarray(tolerates_unsched),
-        sel_match=jnp.asarray(_pad_bool(sel_match_host, npc)),
-        extra_ok=jnp.asarray(_pad_bool(extra_ok_host, npc, default=True)),
-        il_score=jnp.asarray(_pad_i64(il_host, npc)),
-        na_raw=jnp.asarray(_pad_i64(na_host, npc)),
-        dns_axis=jnp.asarray(dns_axis), dns_active=jnp.asarray(dns_active),
-        dns_max_skew=jnp.asarray(dns_max_skew),
-        dns_self=jnp.asarray(dns_self), dns_forced0=jnp.asarray(dns_forced0),
-        dns_honor_aff=jnp.asarray(dns_honor_aff),
-        dns_honor_taints=jnp.asarray(dns_honor_taints),
-        dns_counts=jnp.asarray(dns_counts), dns_dom=jnp.asarray(dns_dom),
-        sa_axis=jnp.asarray(sa_axis), sa_wq=jnp.asarray(sa_wq),
-        sa_skew=jnp.asarray(sa_skew), sa_self=jnp.asarray(sa_self),
-        sa_counts=jnp.asarray(sa_counts),
-        anti_axis=jnp.asarray(anti_axis), anti_self=jnp.asarray(anti_self),
-        anti_counts=jnp.asarray(anti_counts),
-        exist_anti=jnp.asarray(exist_anti),
-        aff_axis=jnp.asarray(aff_axis), aff_self=jnp.asarray(aff_self),
-        aff_active=jnp.asarray(aff_active), aff_counts=jnp.asarray(aff_counts),
-        aff_own_all=jnp.asarray(aff_own_all),
-        ipa_base=jnp.asarray(ipa_base),
-        ipa_axis=jnp.asarray(ipa_axis), ipa_wland=jnp.asarray(ipa_wland),
-        fit_slots=jnp.asarray(fit_slots), fit_weights=jnp.asarray(fit_weights),
-        weights=jnp.asarray(np.array(weights, i64)),
-        enable=jnp.asarray(np.array([1 if b else 0 for b in filters_on], i32)),
-        aux_room=jnp.asarray(aux_room),
-        aux_inc=jnp.asarray(np.int32(aux_inc_n)),
-        nom_req=jnp.asarray(nom_req),
-        nom_pods=jnp.asarray(nom_pods),
-        num_nodes=jnp.asarray(np.int32(n)),
-        start_index=jnp.asarray(np.int32(start_index % max(1, n))),
-        to_find=jnp.asarray(np.int32(to_find)),
-    )
+    # every field in ONE transfer (NodeStateMirror.upload)
+    feats = BatchFeatures(**mirror.upload("features", dict(
+        request=request, nz_request=nz_request,
+        has_request=has_request, ba_skip=ba_skip,
+        tol_key=tol_key, tol_val=tol_val, tol_eff=tol_eff, tol_op=tol_op,
+        node_name_id=node_name_id,
+        tolerates_unsched=tolerates_unsched,
+        sel_match=_pad_bool(sel_match_host, npc),
+        extra_ok=_pad_bool(extra_ok_host, npc, default=True),
+        il_score=_pad_i64(il_host, npc), na_raw=_pad_i64(na_host, npc),
+        dns_axis=dns_axis, dns_active=dns_active, dns_max_skew=dns_max_skew,
+        dns_self=dns_self, dns_forced0=dns_forced0,
+        dns_honor_aff=dns_honor_aff, dns_honor_taints=dns_honor_taints,
+        dns_counts=dns_counts, dns_dom=dns_dom,
+        sa_axis=sa_axis, sa_wq=sa_wq, sa_skew=sa_skew, sa_self=sa_self,
+        sa_counts=sa_counts,
+        anti_axis=anti_axis, anti_self=anti_self, anti_counts=anti_counts,
+        exist_anti=exist_anti,
+        aff_axis=aff_axis, aff_self=aff_self, aff_active=aff_active,
+        aff_counts=aff_counts, aff_own_all=aff_own_all,
+        ipa_base=ipa_base, ipa_axis=ipa_axis, ipa_wland=ipa_wland,
+        fit_slots=fit_slots, fit_weights=fit_weights,
+        weights=np.array(weights, i64),
+        enable=np.array([1 if b else 0 for b in filters_on], i32),
+        aux_room=aux_room, aux_inc=np.int32(aux_inc_n),
+        nom_req=nom_req, nom_pods=nom_pods,
+        num_nodes=np.int32(n),
+        start_index=np.int32(start_index % max(1, n)),
+        to_find=np.int32(to_find),
+    )))
     return BatchPlan(
         features=feats,
         batch_pad=_batch_tier(batch_size),
@@ -1264,9 +1253,10 @@ class KeptPlan:
                start_index: int, nom_reqs, rows=None, shards: int = 1,
                percentage_of_nodes_to_score: int = 0) -> BatchPlan:
         """The plan `build_batch` would give now for `batch_size` pods of the
-        template, with `nom_reqs` (`lane_requests`) the nominated lane: a
-        few uploads and no pass over the nodes. The mirror is synced to the
-        `n` nodes. A node update may lie behind the plan (taints,
+        template, with `nom_reqs` (`lane_requests`) the nominated lane: one
+        transfer (`NodeStateMirror.upload`) and no pass over the nodes. The
+        mirror is synced to the `n` nodes. A node update may lie behind the
+        plan (taints,
         allocatable or the unschedulable flag of a row; labels, images and
         declared features intact): of the plan only `has_pns` reads those
         rows, and the synced mirror says it again as `build_batch` would.
@@ -1291,19 +1281,17 @@ class KeptPlan:
             if self._host_rows is None:
                 self._host_rows = {name: np.asarray(getattr(feats, name))
                                    for name in ROW_FIELDS}
-            again = {name: jnp.asarray(a[idx])
-                     for name, a in self._host_rows.items()}
+            again = {name: a[idx] for name, a in self._host_rows.items()}
             # the padding rows fail the static mask, whatever row 0 says
-            again["extra_ok"] = jnp.asarray(
-                self._host_rows["extra_ok"][idx] & (np.arange(len(idx)) < n))
+            again["extra_ok"] &= np.arange(len(idx)) < n
             if nom_reqs:
                 nom_req, nom_pods = nom_req[idx], nom_pods[idx]
-            again["num_nodes"] = jnp.asarray(np.int32(n))
-            again["to_find"] = jnp.asarray(np.int32(num_feasible_nodes_to_find(
-                n, percentage_of_nodes_to_score)))
-        again.update(
-            nom_req=jnp.asarray(nom_req), nom_pods=jnp.asarray(nom_pods),
-            start_index=jnp.asarray(np.int32(start_index % max(1, n))))
+            again["num_nodes"] = np.int32(n)
+            again["to_find"] = np.int32(num_feasible_nodes_to_find(
+                n, percentage_of_nodes_to_score))
+        again.update(nom_req=nom_req, nom_pods=nom_pods,
+                     start_index=np.int32(start_index % max(1, n)))
+        again = mirror.upload("derive", again)
         return replace(self.plan, features=feats._replace(**again),
                        has_nom=bool(nom_reqs),
                        has_pns=_has_pns(mirror, mirror.num_nodes),

@@ -12,6 +12,7 @@ may hold the TPU's library."""
 import collections
 import re
 
+import numpy as np
 import pytest
 
 import jax
@@ -231,7 +232,8 @@ def test_the_daemonset_cells_programs_compile_for_the_chip(one_chip):
     at any other tier."""
     from kubernetes_tpu.core import FakeClientset
     from kubernetes_tpu.models import TPUScheduler
-    from kubernetes_tpu.ops.device_state import _scatter_rows, patch_tier
+    from kubernetes_tpu.ops.device_state import (_scatter_rows, _unpacked,
+                                                 pack, patch_tier)
     from kubernetes_tpu.ops.kernel import schedule_batch
     from kubernetes_tpu.testing.wrappers import make_node, make_pod
 
@@ -264,10 +266,11 @@ def test_the_daemonset_cells_programs_compile_for_the_chip(one_chip):
             n_active=S((), jnp.int32), carry_in=None, has_pns=plan.has_pns,
             has_ipa_base=plan.has_ipa_base, anti_rowlocal=plan.anti_rowlocal,
             has_na_pref=plan.has_na_pref, port_selfblock=plan.port_selfblock,
-            has_aux=plan.has_aux, has_nom=plan.has_nom).compile().as_text(), st
+            has_aux=plan.has_aux, has_nom=plan.has_nom).compile().as_text(), \
+            st, f
 
     for rows in (64, 16384):
-        hlo, st = lap(state, plan, rows)
+        hlo, st, f16k = lap(state, plan, rows)
         body = _instructions(hlo, under="/while/body/")
         prims = _primitives(body)
         assert prims["div"] == 0 and prims["rem"] == 0, (rows, prims)
@@ -275,7 +278,14 @@ def test_the_daemonset_cells_programs_compile_for_the_chip(one_chip):
     # the mirror's flush at the 16,384-row tier: one width, 4,096 rows
     width = patch_tier(int(0.25 * 16384))
     assert width == 4096
-    rows_of = jax.tree_util.tree_map(
-        lambda x: S((width,) + x.shape[1:], x.dtype), st)._replace(
-        topo=S((st.topo.shape[0], width), st.topo.dtype))
-    _scatter_rows.lower(st, S((width,), jnp.int32), rows_of).compile()
+    # (its operand is one packed buffer since PR 48: the index and every
+    # column's rows, taken apart inside the scatter's program)
+    rows_of = {name: np.zeros((width,) + x.shape[1:], x.dtype)
+               for name, x in zip(st._fields[:-1], st[:-1])}
+    rows_of["topo"] = np.zeros((st.topo.shape[0], width), st.topo.dtype)
+    buf, layout = pack({"idx": np.zeros(width, np.int32), **rows_of})
+    _scatter_rows.lower(st, S(buf.shape, buf.dtype), layout=layout).compile()
+    # and the unpack of a full build's features at that tier
+    buf, layout = pack({name: np.zeros(x.shape, x.dtype) for name, x in
+                        zip(f16k._fields, f16k)})
+    _unpacked.lower(S(buf.shape, buf.dtype), layout=layout).compile()

@@ -36,9 +36,12 @@ def widths(monkeypatch):
     real = NodeStateMirror._dirty_payload
 
     def recording(self, dirty, width):
-        idx, rows = real(self, dirty, width)
-        seen.append(int(idx.shape[0]))
-        return idx, rows
+        packed, layout = real(self, dirty, width)
+        # the rows the one packed buffer carries: its index's length
+        (shape,) = [shape for name, _off, shape, _dt in layout
+                    if name == "idx"]
+        seen.append(int(shape[0]))
+        return packed, layout
 
     monkeypatch.setattr(NodeStateMirror, "_dirty_payload", recording)
     return seen
