@@ -11,17 +11,47 @@ cycles with a scan whose carry holds exactly the state one pod's placement
 changes for the next pod: per-node requested vectors, per-domain topology
 match counts, and inter-pod-affinity count tables.
 
-Performance shape (design assumption: each vector op in a sequential
-dependency chain pays a fixed issue latency regardless of width; per-op
-costs are not measured on this chip — see PERF.md):
-the scan body is written to MINIMIZE DEPENDENT STAGES, not op count —
+Performance shape (measured on a TPU v5e; PERF.md section 5 has the traces):
+a scan step is a chain of program events, each a fused elementwise pass or a
+reduction over the node axis, which the chip runs one after another, and
+what a step costs beyond its events' own time lies between them, where a
+value crosses to the scalar side and back. Until PR 49 a step
+was 65 events and a scalar detour (48.5 us: 21.9 us inside the events, 26.6
+us between them: the row index decoded from the reduction, ten one-row
+reads, the scores of one row on the scalar core, seven one-row writes); it
+is 22 events and 26.5 us now (21.9 inside, 4.6 between), the normalising
+scan 58 events and 51.7 us (21.2 + 30.5) before, 21 and 29.4 (22.7 + 6.7)
+now (PR 49's traces of 1,024 chained steps at 8,192 rows). So the scan body
+is written to keep the COUNT of events and the length of their chain down,
+not the instruction count —
+- the landed row never leaves the vector side: the packed selection key is
+  unique a row, so the rows that hold the best key ARE the landed row, as a
+  mask; everything a landing changes is a masked elementwise update over the
+  node axis or a reduction under the mask, and the loop body holds no dynamic
+  slice, dynamic update, gather or scatter at all (tests/test_kernel_aot.py).
+  The row's index is decoded for the results only;
+- the landed row's resource-derived values are re-evaluated at LANES columns,
+  not at one scalar row and not over all rows: the rows fold into
+  [NP / LANES, LANES], the row's inputs come down its column under the mask,
+  one evaluation runs over the columns (`_fit_scores`), the row takes its
+  column's result back. Requested lanes are kept as the call found them plus
+  the request times the pods a row took since (`landed_n`), so no [NP, R]
+  tensor is written a step;
 - per-step domain-count lookups ride the carry as per-NODE projections
   (mnum/scnt/acnt/fcnt/dproj) updated with elementwise compares against the
   landed row's topology value, instead of take_along_axis gathers (a TPU
-  gather serializes);
-- all windowed normalization min/max reductions collapse into ONE stacked
-  [k, NP] max-reduction (mins ride as negated lanes), and selection is a
-  second single reduction over a packed (score, rotation) key;
+  gather serializes); the count tables move by a compare against the same
+  value along their own axis;
+- reductions that read the same rows are written side by side (the compiler
+  makes sibling reductions one event; a stacked one costs an event more to
+  take its lanes apart), sums over a plan's few lanes are elementwise adds
+  (`_sum_lanes`), and what the NEXT step reads of the carry (the feasibility
+  mask where a landing reaches whole domains, the prefix count, its two
+  heads) is made at the step's tail, beside the updates it reads: events
+  fuse within an iteration, never across the loop's edge;
+- the prefix count of the feasibility mask is one int8 matrix product with a
+  triangle of ones over the folded rows (`_prefix_count`), exact in int32,
+  where a cumulative sum is five events;
 - no general integer `//` or `%` runs inside a scan or lap step (XLA:TPU
   expands an int64 one into a 64-step long division, ~1,900 scalar
   instructions): every quotient has a bound (a score 0..100, a fraction
@@ -66,6 +96,7 @@ the lap/scan restructuring (few dependent stages) addresses directly.
 
 from __future__ import annotations
 
+import functools
 from functools import partial
 from typing import NamedTuple, Optional, Tuple
 
@@ -211,6 +242,37 @@ def _unwrap(x, num):
     return jnp.where(x < 0, x + num, x)
 
 
+def _prefix_count(mask):
+    """Inclusive running count of a [NP] mask along the rows, exact: the rows
+    fold into columns, one int8 matrix product with a triangle of ones
+    counts along each fold (int32 accumulation), and the folds before a fold
+    add their totals."""
+    n = mask.shape[0]
+    w = min(n, 128)
+    folds = n // w
+    tri = (jnp.arange(w, dtype=jnp.int32)[:, None]
+           <= jnp.arange(w, dtype=jnp.int32)[None, :]).astype(jnp.int8)
+    within = lax.dot(mask.reshape(folds, w).astype(jnp.int8), tri,
+                     preferred_element_type=jnp.int32)
+    fold = jnp.arange(folds, dtype=jnp.int32)
+    before = jnp.where(fold[None, :] < fold[:, None], within[:, -1][None, :], 0
+                       ).sum(axis=1, dtype=jnp.int32)
+    return (within + before[:, None]).reshape(n)
+
+
+def _sum_lanes(x):
+    """x.sum(axis=0) of a [C, NP] stack of a few lanes (C is a plan's count
+    of constraints or terms), as C - 1 elementwise adds: it fuses with what
+    makes the lanes and what reads the sum, where a reduction is a program
+    event of its own."""
+    return functools.reduce(jnp.add, list(x))
+
+
+def _any_lanes(x):
+    """x.any(axis=0) of such a stack of masks, elementwise."""
+    return functools.reduce(jnp.logical_or, list(x))
+
+
 def _normalize_default_reverse(raw, mx):
     """default_normalize_score(max=100, reverse=True); mx precomputed over
     the kept set (one lane of the step's batched reduction)."""
@@ -226,30 +288,43 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
                    nom_r=None, nom_p=None):
     """Fit filter (fit.go:710) + LeastAllocated/MostAllocated score +
     integer-quantized BalancedAllocation for any leading shape (all nodes
-    pre-scan; a single updated row inside the scan — these values only change
-    at the row a pod landed on, so the scan carries them instead of
-    recomputing [NP, R] work per step).
+    before the scan and in every lap; the rows a delta patch names). Inside
+    the scan these values only change at the row a pod landed on, so the
+    scan carries them and feeds `_fit_scores` that row's columns itself.
 
     `nom_r`/`nom_p` (the nominated-pod lane): pass-1 of the two-pass filter
     (runtime/framework.go:1300-1317) counts nominated pods' requests/count
     against the FILTER only — scores stay pass-2 (real pods), exactly as the
     host computes them."""
     eff_count = pod_count if nom_p is None else pod_count + nom_p
-    pods_ok = (eff_count + 1).astype(jnp.int64) <= alloc_pods
     avail = alloc_r - req_r if nom_r is None else alloc_r - req_r - nom_r
     viol = ((f.request > 0) & (f.request > avail)).any(axis=-1)
-    fit_ok = (pods_ok & (~viol | (f.has_request == 0))) | (f.enable[4] == 0)
-    used0 = nonzero[..., 0] + f.nz_request[0]
-    used1 = nonzero[..., 1] + f.nz_request[1]
-    fit_num = jnp.zeros_like(used0)
-    fit_den = jnp.zeros_like(used0)
+    slots = []
     for j in range(f.fit_slots.shape[0]):
         slot = f.fit_slots[j]
-        w = f.fit_weights[j]
-        alloc = jnp.take(alloc_r, slot, axis=-1)
-        used = jnp.where(slot == 0, used0,
-                         jnp.where(slot == 1, used1,
-                                   jnp.take(req_r, slot, axis=-1) + f.request[slot]))
+        slots.append((slot, f.fit_weights[j], jnp.take(alloc_r, slot, axis=-1),
+                      jnp.take(req_r, slot, axis=-1) + f.request[slot]))
+    return _fit_scores(
+        fit_strategy, eff_count, alloc_pods, viol,
+        nonzero[..., 0] + f.nz_request[0], nonzero[..., 1] + f.nz_request[1],
+        slots, alloc_r[..., 0], alloc_r[..., 1],
+        f.has_request, f.enable[4], f.ba_skip)
+
+
+def _fit_scores(fit_strategy: int, eff_count, alloc_pods, viol, used0, used1,
+                slots, a_cpu, a_mem, has_request, fit_on, ba_skip):
+    """`_resource_eval` past its reads along the resource axis: elementwise
+    over whatever leading shape its operands share. `viol`: some requested
+    resource exceeds what is left; `used0` / `used1`: non-zero cpu / memory
+    with this pod; `slots`: per scored resource (slot, weight, allocatable,
+    requested with this pod); `a_cpu` / `a_mem`: allocatable cpu / memory.
+    The scan's step feeds it the landed row's columns directly."""
+    pods_ok = (eff_count + 1).astype(jnp.int64) <= alloc_pods
+    fit_ok = (pods_ok & (~viol | (has_request == 0))) | (fit_on == 0)
+    fit_num = jnp.zeros_like(used0)
+    fit_den = jnp.zeros_like(used0)
+    for slot, w, alloc, used_slot in slots:
+        used = jnp.where(slot == 0, used0, jnp.where(slot == 1, used1, used_slot))
         if fit_strategy == 0:  # LeastAllocated
             part = jnp.where((alloc > 0) & (used <= alloc), alloc - used, 0)
         else:  # MostAllocated
@@ -268,8 +343,6 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
         return jnp.where(used >= alloc, SCALE,
                          _bounded_div(used * SCALE, jnp.maximum(alloc, 1), _SCALE_BITS))
 
-    a_cpu = alloc_r[..., 0]
-    a_mem = alloc_r[..., 1]
     q_cpu = millionths(used0, a_cpu)
     q_mem = millionths(used1, a_mem)
     both = (a_cpu > 0) & (a_mem > 0)
@@ -277,14 +350,15 @@ def _resource_eval(f: BatchFeatures, fit_strategy: int,
                        _bounded_div(MAX_NODE_SCORE * SCALE - 50 * jnp.abs(q_cpu - q_mem),
                                     SCALE, _SCORE_BITS),
                        jnp.int64(MAX_NODE_SCORE))
-    ba = jnp.where(f.ba_skip == 1, 0, ba_val)
+    ba = jnp.where(ba_skip == 1, 0, ba_val)
     return fit_ok, fit_sc, ba
 
 
 # The largest batch_pad that stays on the scan whatever its coupling
-# (ops/features.py _batch_tier: the gang-sized tiers 8 and 64). The scan's
-# per-step body is ~6 fused ops against the lap's [LAP_MAX, NP] window
-# tensors, and a 4-member gang gets no lap parallelism anyway (with
+# (ops/features.py _batch_tier: the gang-sized tiers 8 and 64). A scan step
+# is 21-22 program events over [NP] lanes, 26-29 us at 8,192 rows (PERF.md
+# section 5; 58-65 events and 48-52 us until PR 49) against the lap's
+# [LAP_MAX, NP] window tensors, and a 4-member gang gets no lap parallelism anyway (with
 # truncation inactive every window spans the whole rotation, L=1).
 SCAN_MAX_BATCH = 64
 
@@ -376,11 +450,14 @@ def schedule_batch(
     A1 = f.anti_axis.shape[0]
     A2 = f.aff_axis.shape[0]
     KD = f.ipa_axis.shape[0]
+    R = f.request.shape[0]
     idx = jnp.arange(NP, dtype=jnp.int32)
     num = jnp.maximum(f.num_nodes, 1)
     # Radix of the packed (score, rotation) selection key: a power of two,
     # so the rotation comes back out with a mask.
     RADIX = _pow2(NP)
+    # Columns the rows fold into for the landed row's one evaluation (`step`).
+    LANES = min(NP, 128)
 
     incremental_feas, scores_carried, static_scores = coupling(
         f, batch_pad, has_pns=has_pns, has_ipa_base=has_ipa_base,
@@ -426,6 +503,15 @@ def schedule_batch(
 
     n_act = jnp.int32(batch_pad) if n_active is None else n_active.astype(jnp.int32)
 
+    # What a landing tells every lane family, lane by lane in the carry's
+    # order: the rows whose landing counts there (None: every row) and each
+    # row's topology value.
+    landing_lanes = (
+        [(dns_elig[c], dns_vid[c]) for c in range(C1)]
+        + [(~sa_ignored, sa_vid[c]) for c in range(C2)]
+        + [(None, vid[c]) for vid in (anti_vid, aff_vid, ipa_vid)
+           for c in range(vid.shape[0])])
+
     def feasibility_proj(fit_ok, dns_counts, mnum, acnt, fcnt, aff_total,
                          blocked, aux_cnt):
         """Per-node ok mask from the dynamic filters
@@ -447,76 +533,75 @@ def schedule_batch(
                 skew_bad = (mnum + f.dns_self[:, None] - min_match[:, None]
                             ) > jnp.minimum(f.dns_max_skew, _BIG)[:, None]
                 dns_reject = (f.dns_active[:, None] == 1) & (~(dns_vid > 0) | skew_bad)
-                ok &= ~dns_reject.any(axis=0)
+                ok &= ~_any_lanes(dns_reject)
         if A1:
-            ok &= ~((anti_vid > 0) & (acnt > 0)).any(axis=0)
+            ok &= ~_any_lanes((anti_vid > 0) & (acnt > 0))
         if A2:
             term_ok = (f.aff_active[:, None] == 0) | ((aff_vid > 0) & (fcnt > 0))
             bootstrap = (aff_total == 0) & (f.aff_own_all == 1) & aff_has_keys
-            ok &= term_ok.all(axis=0) | bootstrap
+            ok &= ~_any_lanes(~term_ok) | bootstrap
         return ok
 
+    def prefix_heads(F, start):
+        """The prefix sum's last value and its value before `start`, as
+        reductions, not reads at an address (F never falls along the rows,
+        so its maximum is its last value; no row is -1, so `start` 0 reads
+        0). Made at a step's tail for the next step's ranks."""
+        return jnp.max(F), jnp.max(jnp.where(idx == start - 1, F, 0))
+
     def step(carry):
-        (req_r, nonzero, pod_count, fit_ok, fit_sc, ba,
+        (landed_n, fit_ok, fit_sc, ba,
          dns_counts, sa_counts, anti_counts, aff_counts, ipa_delta, start,
-         blocked, aux_cnt, okd, F, total,
+         blocked, aux_cnt, okd, F, total_feas, f_start, total,
          mnum, scnt, acnt, fcnt, dproj, aff_total, t, out) = carry
         # True at every trip of the loop below; a step past n_act (the tests'
         # fixed-length reference runs them) lands nothing, moves no start.
         active = t < n_act
 
-        with jax.named_scope("feasibility"):
-            if not incremental_feas:
-                okd = feasibility_proj(fit_ok, dns_counts, mnum, acnt, fcnt,
-                                       aff_total, blocked, aux_cnt)
-                F = jnp.cumsum(okd.astype(jnp.int32))          # inclusive, row order
-
         with jax.named_scope("select"):
             # ---- sampling truncation + rotation (schedule_one.go:779-892) -----
             # Gather-free formulation: rank[row] = #feasible rows at rotation
             # positions <= rot(row), from the row-order prefix-sum with wrap
-            # adjustment (feasible count in [start..row] resp. wrapped).
-            total_feas = F[-1]
-            f_start = jnp.where(start > 0, F[jnp.maximum(start - 1, 0)], 0)
+            # adjustment (feasible count in [start..row] resp. wrapped); the
+            # prefix count's two heads ride the carry (`prefix_heads`).
             rank = jnp.where(idx >= start, F - f_start, F + total_feas - f_start)
             kept = okd & (rank <= f.to_find)
             rot_of_row = _unwrap(idx - start, num)             # row -> rotation pos
 
         with jax.named_scope("score_normalise"):
-            # ---- reductions: everything as stacked maxes (mins ride negated) --
-            # lane 0: window-boundary rotation (evaluated).
+            # ---- reductions: maxes side by side (mins ride negated), which the
+            # compiler runs as one event where they read the same rows ------
+            # the window-boundary rotation (evaluated)
             bound_lane = jnp.where(okd & (rank == f.to_find),
-                                   (num - 1 - rot_of_row).astype(jnp.int64), 0)
+                                   num - 1 - rot_of_row, 0)
             if scores_carried:
                 # total is already known: boundary + packed selection key
-                # (max-score-then-min-rotation; scores non-negative) collapse
-                # into ONE reduction round.
+                # (max-score-then-min-rotation; scores non-negative) are ONE
+                # reduction round.
                 key = total * RADIX + (jnp.int32(RADIX - 1) - rot_of_row)
-                red = jnp.max(jnp.stack(
-                    [jnp.where(kept, key, -1), bound_lane]), axis=1)
-                best_key = red[0]
-                evaluated = (num - red[1]).astype(jnp.int32)
+                best_key = jnp.max(jnp.where(kept, key, -1))
+                evaluated = (num - jnp.max(bound_lane)).astype(jnp.int32)
             else:
-                lanes = [bound_lane]
+                lanes = []
                 if has_pns:
                     lanes.append(jnp.where(kept, pns_cnt, 0))              # mx_pns
                 if C2:
-                    raw_sa = (scnt.astype(jnp.int64) * f.sa_wq[:, None] +
-                              (f.sa_skew[:, None] - 1) * 1024).sum(axis=0)
+                    raw_sa = _sum_lanes(scnt.astype(jnp.int64) * f.sa_wq[:, None] +
+                                        (f.sa_skew[:, None] - 1) * 1024)
                     live = kept & ~sa_ignored
                     lanes.append(jnp.where(live, raw_sa, 0))               # mx_sa
                     lanes.append(jnp.where(live, -raw_sa, -_INF64))        # -mn_sa
                 if KD or has_ipa_base:
                     raw_ipa = f.ipa_base
                     if KD:
-                        raw_ipa = raw_ipa + dproj.sum(axis=0)
+                        raw_ipa = raw_ipa + _sum_lanes(dproj)
                     lanes.append(jnp.where(kept, raw_ipa, -_INF64))        # mx_ipa
                     lanes.append(jnp.where(kept, -raw_ipa, -_INF64))       # -mn_ipa
                 if has_na_pref:
                     lanes.append(jnp.where(kept, f.na_raw, 0))             # mx_na
-                red = jnp.max(jnp.stack(lanes), axis=1)
-                evaluated = (num - red[0]).astype(jnp.int32)
-                li = 1
+                red = [jnp.max(lane) for lane in lanes]
+                evaluated = (num - jnp.max(bound_lane)).astype(jnp.int32)
+                li = 0
                 # ---- score assembly (runtime/framework.go:1526-1582) ----------
                 if has_pns:
                     tt = _normalize_default_reverse(pns_cnt, red[li]); li += 1
@@ -556,81 +641,122 @@ def schedule_batch(
                 best_key = jnp.max(jnp.where(kept, key, -1))
         with jax.named_scope("select"):
             any_kept = (best_key >= 0) & active
+            # The key is unique a row (the rotation is a permutation), so the
+            # rows that hold the best one ARE the landed row, as a mask; no
+            # row holds -1, so nothing is hit where nothing was kept.
+            hit = kept & (key == best_key) & active
+            # The row's index is a value for `out` (and one compare below),
+            # never an address.
             chosen_rot = jnp.int32(RADIX - 1) - (best_key & (RADIX - 1)).astype(jnp.int32)
             chosen = jnp.where(any_kept, _wrap(start + chosen_rot, num), -1).astype(jnp.int32)
 
         with jax.named_scope("carry_update"):
-            # ---- carry updates (inert when nothing was kept) ------------------
-            row = jnp.maximum(chosen, 0)
-            apply = jnp.where(any_kept, 1, 0).astype(jnp.int64)
-            req_r = req_r.at[row].add(f.request * apply)
-            nonzero = nonzero.at[row].add(f.nz_request * apply)
-            pod_count = pod_count.at[row].add(apply.astype(jnp.int32))
+            # ---- carry updates: everything a landing changes is a masked
+            # elementwise update over the node axis, or a reduction under
+            # the mask (inert when nothing was kept: `hit` is then empty) ----
+            h32 = hit.astype(jnp.int32)
+            landed_n = landed_n + h32
             with jax.named_scope("resource_fit"):
-                # Re-evaluate ONLY the landed row's resource-derived values (when
-                # nothing was applied the inputs are unchanged, so this is identity).
-                r_ok, r_fit, r_ba = _resource_eval(
-                    f, fit_strategy, state.alloc_r[row], state.alloc_pods[row],
-                    req_r[row], nonzero[row], pod_count[row],
-                    nom_r=f.nom_req[row] if has_nom else None,
-                    nom_p=f.nom_pods[row] if has_nom else None)
-            fit_ok = fit_ok.at[row].set(r_ok)
-            fit_sc = fit_sc.at[row].set(r_fit)
-            ba = ba.at[row].set(r_ba)
-            # All scatter/gather index operands stay int32 (matching `row` and
-            # the vid tables): with x64 enabled a bare arange defaults to int64,
-            # and mixed s64/s32 index tuples miscompile under GSPMD on this
-            # environment's XLA (compare(s64, s32) after spmd-partitioning —
-            # ROADMAP open item, fixed by this uniform-dtype normalization).
+                # Re-evaluate ONLY the landed row's resource-derived values,
+                # without leaving the vector side: the rows are folded into
+                # LANES columns, the landed row's inputs come down its column
+                # under the mask (every other column reads zeros), one
+                # LANES-wide evaluation runs, and the row takes its column's
+                # result back. A row's requested lanes are the call's own
+                # (`fit_cols`) plus the request times the pods it took since.
+                hit2 = hit.reshape(NP // LANES, LANES)
+                col = dict(zip(fit_rows, jnp.where(hit2[None], fit_cols, 0).sum(axis=1)))
+                took = jnp.where(hit2, landed_n.reshape(hit2.shape), 0).sum(
+                    axis=0, dtype=jnp.int64)
+                left = [col["alloc", j] - col["req", j] - took * request[j]
+                        - (col["nom", j] if has_nom else 0) for j in range(R)]
+                r_ok, r_fit, r_ba = _fit_scores(
+                    fit_strategy,
+                    col["pods"] + took + (col["nom_pods"] if has_nom else 0),
+                    col["room"],
+                    _any_lanes([(request[j] > 0) & (request[j] > left[j])
+                               for j in range(R)]),
+                    col["nz", 0] + (took + 1) * nz_request[0],
+                    col["nz", 1] + (took + 1) * nz_request[1],
+                    [(slot, w, col["slot_alloc", j],
+                      col["slot_req", j] + (took + 1) * slot_request)
+                     for j, (slot, w, slot_request) in enumerate(fit_slots)],
+                    col["alloc", 0], col["alloc", 1], f.has_request, fit_on,
+                    f.ba_skip)
+
+                def to_rows(x):
+                    return jnp.broadcast_to(x[None, :], hit2.shape).reshape(NP)
+
+                r_ok, r_fit, r_ba = to_rows(r_ok), to_rows(r_fit), to_rows(r_ba)
+            fit_ok = jnp.where(hit, r_ok, fit_ok)
+            fit_sc = jnp.where(hit, r_fit, fit_sc)
+            ba = jnp.where(hit, r_ba, ba)
+            if port_selfblock:
+                blocked = blocked | hit
+            if has_aux:
+                aux_cnt = aux_cnt + f.aux_inc * h32
+            # What the landed row says to every other row, as sums under the
+            # mask side by side (one row at most is hit, so a sum is its
+            # value): its topology value on every lane, 0 where the landing
+            # counts for nothing there (no value, ineligible, nothing
+            # landed), and whether it stays feasible.
+            lanes = [jnp.where(hit if counts is None else hit & counts, vid, 0)
+                     for counts, vid in landing_lanes]
+            if incremental_feas:
+                # Feasibility flips only at the landed row: each row as it
+                # would stand after a landing on it (its own count of a
+                # row-local anti term moves by the pod's own weight there;
+                # the tables themselves move below, after this reduction).
+                own = acnt + f.anti_self[:, None] * (anti_vid > 0) if A1 else acnt
+                new_ok = feasibility_proj(r_ok, dns_counts, mnum, own, fcnt,
+                                          aff_total, blocked, aux_cnt)
+                lanes.append(jnp.where(
+                    hit, new_ok.astype(jnp.int32) - okd.astype(jnp.int32), 0))
+            landed = iter([lane.sum(dtype=jnp.int32) for lane in lanes])
+
+            def land(counts, proj, vid, weight):
+                """One lane family after the landing: its count table and
+                its per-node projection, both by compares against the landed
+                row's value on each of its lanes."""
+                lv = jnp.stack([next(landed) for _ in range(vid.shape[0])])
+                upd = weight * (lv > 0)
+                values = jnp.arange(counts.shape[1], dtype=jnp.int32)
+                counts = counts + jnp.where(values[None, :] == lv[:, None],
+                                            upd[:, None], 0)
+                proj = proj + upd[:, None] * (vid == lv[:, None])
+                return counts, proj, upd
+
             with jax.named_scope("spread_lanes"):
                 if C1:
-                    c1i = jnp.arange(C1, dtype=jnp.int32)
-                    upd = (f.dns_self * dns_elig[c1i, row].astype(jnp.int32)
-                           * apply.astype(jnp.int32))
-                    dns_counts = dns_counts.at[c1i, dns_vid[:, row]].add(upd)
-                    mnum = mnum + upd[:, None] * (dns_vid == dns_vid[:, row][:, None])
+                    dns_counts, mnum, _ = land(dns_counts, mnum, dns_vid, f.dns_self)
                 if C2:
-                    upd = (f.sa_self * jnp.where(sa_ignored[row], 0, 1) * apply.astype(jnp.int32))
-                    sa_counts = sa_counts.at[jnp.arange(C2, dtype=jnp.int32),
-                                             sa_vid[:, row]].add(upd)
-                    scnt = scnt + upd[:, None] * (sa_vid == sa_vid[:, row][:, None])
+                    sa_counts, scnt, _ = land(sa_counts, scnt, sa_vid, f.sa_self)
             if A1:
-                upd = f.anti_self * (anti_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
-                anti_counts = anti_counts.at[jnp.arange(A1, dtype=jnp.int32),
-                                             anti_vid[:, row]].add(upd)
-                acnt = acnt + upd[:, None] * (anti_vid == anti_vid[:, row][:, None])
+                anti_counts, acnt, _ = land(anti_counts, acnt, anti_vid, f.anti_self)
             if A2:
-                upd = f.aff_self * (aff_vid[:, row] > 0).astype(jnp.int32) * apply.astype(jnp.int32)
-                aff_counts = aff_counts.at[jnp.arange(A2, dtype=jnp.int32),
-                                           aff_vid[:, row]].add(upd)
-                fcnt = fcnt + upd[:, None] * (aff_vid == aff_vid[:, row][:, None])
-                aff_total = aff_total + upd.sum()
+                aff_counts, fcnt, upd = land(aff_counts, fcnt, aff_vid, f.aff_self)
+                aff_total = aff_total + upd.sum(dtype=aff_total.dtype)
             if KD:
-                upd = f.ipa_wland * (ipa_vid[:, row] > 0) * apply
-                ipa_delta = ipa_delta.at[jnp.arange(KD, dtype=jnp.int32),
-                                         ipa_vid[:, row]].add(upd)
-                dproj = dproj + upd[:, None] * (ipa_vid == ipa_vid[:, row][:, None])
-            if port_selfblock:
-                blocked = blocked.at[row].set(blocked[row] | any_kept)
-            if has_aux:
-                aux_cnt = aux_cnt.at[row].add(f.aux_inc * apply.astype(jnp.int32))
+                ipa_delta, dproj, _ = land(ipa_delta, dproj, ipa_vid, f.ipa_wland)
             if incremental_feas:
-                # Feasibility flips only at the landed row: patch okd and shift
-                # the prefix-sum tail by the delta (replaces the full cumsum).
-                new_ok_row = static_ok[row] & r_ok & (row < num)
-                if A1:
-                    new_ok_row &= ~((anti_vid[:, row] > 0) & (acnt[:, row] > 0)).any()
-                if port_selfblock:
-                    new_ok_row &= ~blocked[row]
-                if has_aux:
-                    new_ok_row &= aux_cnt[row] + f.aux_inc <= f.aux_room[row]
-                delta = new_ok_row.astype(jnp.int32) - okd[row].astype(jnp.int32)
-                okd = okd.at[row].set(new_ok_row)
-                F = F + jnp.where(idx >= row, delta, 0)
+                # patch okd and shift the prefix-sum tail by the delta
+                # (replaces the full cumsum)
+                okd = jnp.where(hit, new_ok, okd)
+                F = F + jnp.where(idx >= chosen, next(landed), 0)
+            else:
+                with jax.named_scope("feasibility"):
+                    # A landing reaches whole domains: the next step's mask
+                    # and prefix sum, made here, beside the updates they
+                    # read (a step's tail fuses; a loop's edge does not).
+                    okd = feasibility_proj(fit_ok, dns_counts, mnum, acnt, fcnt,
+                                           aff_total, blocked, aux_cnt)
+                    F = _prefix_count(okd)                     # inclusive, row order
             if scores_carried:
-                total = total.at[row].set(
+                total = jnp.where(
+                    hit,
                     w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * r_fit + w_ba * r_ba
-                    + il_term[row])
+                    + il_term,
+                    total)
             start = jnp.where(active, _wrap(start + evaluated, num), start).astype(jnp.int32)
             # Results accumulate in the CARRY via a one-hot masked write at
             # the int32 step counter `t`, which also rides the carry and is
@@ -638,15 +764,15 @@ def schedule_batch(
             # mode, indexing the dynamic_update_slice of its ys-stacking) is
             # what this environment's XLA miscompiles under GSPMD —
             # compare(s64, s32) after spmd-partitioning, the ROADMAP open
-            # item. The elementwise write keeps the carry uniformly
-            # int32-indexed and is also exact under vmap (the cells axis),
-            # where a batched-index update slice is not.
-            out = jnp.where(jnp.arange(batch_pad, dtype=jnp.int32)[None, :] == t,
-                            jnp.stack([chosen, start])[:, None], out)
+            # item. The elementwise write is also exact under vmap (the cells
+            # axis), where a batched-index update slice is not.
+            out = jnp.where(jnp.arange(batch_pad, dtype=jnp.int32) == t,
+                            (chosen + 1).astype(out.dtype) * RADIX + start, out)
 
-        new_carry = (req_r, nonzero, pod_count, fit_ok, fit_sc, ba,
+        new_carry = (landed_n, fit_ok, fit_sc, ba,
                      dns_counts, sa_counts, anti_counts, aff_counts,
-                     ipa_delta, start, blocked, aux_cnt, okd, F, total,
+                     ipa_delta, start, blocked, aux_cnt, okd, F,
+                     *prefix_heads(F, start), total,
                      mnum, scnt, acnt, fcnt, dproj, aff_total,
                      t + jnp.int32(1), out)
         return new_carry
@@ -690,14 +816,51 @@ def schedule_batch(
     aff_total0 = (ext0.aff_counts * (f.aff_active[:, None] == 1)).sum()
     okd0 = feasibility_proj(ext0.fit_ok, ext0.dns_counts, mnum0, acnt0,
                             fcnt0, aff_total0, ext0.blocked, ext0.aux_cnt)
-    F0 = jnp.cumsum(okd0.astype(jnp.int32))
+    F0 = _prefix_count(okd0)
     if scores_carried:
         total0 = (w_tt * jnp.int64(MAX_NODE_SCORE) + w_fit * ext0.fit_sc
                   + w_ba * ext0.ba + il_term)
     else:
         total0 = jnp.zeros(NP, jnp.int64)
-    out0 = jnp.full((2, batch_pad), -1, jnp.int32)
-    carry0 = tuple(ext0) + (okd0, F0, total0,
+    # A step's result, the chosen row (or -1) and the start index after it,
+    # packed into one word of the narrowest type that holds both.
+    out0 = jnp.full(batch_pad, -1,
+                    jnp.int32 if (NP + 1) * RADIX < 2 ** 31 else jnp.int64)
+    # The landed row's resource lanes by name, one below the other for the
+    # step's one reduction under the mask and each folded into LANES columns:
+    # allocatable, requested and non-zero requested as the call found them,
+    # pod room and count, the nominated lane, and allocatable and requested
+    # of each scored resource. What the evaluation reads of the pod itself
+    # goes in as scalars.
+    fit_slots = [(f.fit_slots[j], f.fit_weights[j], f.request[f.fit_slots[j]])
+                 for j in range(f.fit_slots.shape[0])]
+    fit_rows = {}
+    for j in range(R):
+        fit_rows["alloc", j] = state.alloc_r[:, j]
+        fit_rows["req", j] = ext0.req_r[:, j]
+        if has_nom:
+            fit_rows["nom", j] = f.nom_req[:, j]
+    fit_rows["nz", 0], fit_rows["nz", 1] = ext0.nonzero[:, 0], ext0.nonzero[:, 1]
+    fit_rows["room"] = state.alloc_pods
+    fit_rows["pods"] = ext0.pod_count.astype(jnp.int64)
+    if has_nom:
+        fit_rows["nom_pods"] = f.nom_pods.astype(jnp.int64)
+    for j, (slot, _, _) in enumerate(fit_slots):
+        fit_rows["slot_alloc", j] = jnp.take(state.alloc_r, slot, axis=-1)
+        fit_rows["slot_req", j] = jnp.take(ext0.req_r, slot, axis=-1)
+    # The stack is this call's OWN copy of those lanes, made before the loop
+    # and kept opaque: the carry it returns is reckoned from the copy, so
+    # nothing of `state` is read once the results can be fetched. (On the
+    # CPU backend the host moves on as soon as it has them, and a carry
+    # reckoned from `state` after the loop was seen to count a landing
+    # twice: ROADMAP D20.)
+    fit_cols = lax.optimization_barrier(
+        jnp.stack(list(fit_rows.values())).reshape(-1, NP // LANES, LANES))
+    request = [f.request[j] for j in range(R)]
+    nz_request = [f.nz_request[0], f.nz_request[1]]
+    fit_on = f.enable[4]
+    carry0 = (jnp.zeros(NP, jnp.int32),) + tuple(ext0)[3:] + (
+        okd0, F0, *prefix_heads(F0, ext0.start), total0,
                             mnum0, scnt0, acnt0, fcnt0, dproj0, aff_total0,
                             jnp.int32(0), out0)
     # The loop stops at the pods it holds: `n_act` trips, not `batch_pad`.
@@ -711,7 +874,18 @@ def schedule_batch(
     # chain the next batch (carry_in) and keep the mirror resident
     # (NodeStateMirror.adopt) instead of re-uploading — the device-side
     # analogue of the incremental snapshot.
-    return final[-1], ScanCarry(*final[:14])
+    took = final[0]
+    took64 = took.astype(jnp.int64)[:, None]
+    found = dict(zip(fit_rows, fit_cols.reshape(-1, NP)))
+    out = final[-1]
+    results = jnp.where(out[None, :] < 0, -1, jnp.stack(
+        [(out >> RADIX.bit_length() - 1) - 1, out & (RADIX - 1)])).astype(jnp.int32)
+    return results, ScanCarry(
+        jnp.stack([found["req", j] for j in range(R)], axis=1)
+        + took64 * f.request[None, :],
+        jnp.stack([found["nz", 0], found["nz", 1]], axis=1)
+        + took64 * f.nz_request[None, :],
+        found["pods"].astype(jnp.int32) + took, *final[1:12])
 
 
 @partial(jax.jit, static_argnames=("fit_strategy", "has_nom"))

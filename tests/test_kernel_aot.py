@@ -17,17 +17,22 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.sharding import SingleDeviceSharding
 
-# Ceilings, with what was counted when they were set (PR 25; the parent of
-# that PR in brackets).
-RESOURCE_EVAL_CEILING = 2_500   # 1,766 LeastAllocated, 1,758 Most [11,493]
-# PR 32 (the scan loops `n_active` times, a `while` with no trip count known
-# to the compiler): 2,093 and 2,440.
-SCAN_BODY_CEILING = 3_000       # 2,095 [13,575]
-LAP_BODY_CEILING = 3_500        # 2,385 [21,147]
-NORMALISING_BODY_CEILING = 3_500  # 2,434 (PR 31: the scan that normalises)
+# Ceilings, with what was counted when they were set (PR 49, the landed row
+# kept on the vector side; in brackets the parent of that PR, whose own
+# brackets were PR 25's parent: 11,493, 13,575, 21,147).
+# `resource_fit` inside the scan's step: the landed row's evaluation at
+# LANES columns with the two reductions that bring its inputs down.
+RESOURCE_EVAL_CEILING = 2_500   # 2,144 LeastAllocated, 2,136 Most [1,766 / 1,758: one row, scalar]
+SCAN_BODY_CEILING = 3_000       # 2,570 [2,095]
+LAP_BODY_CEILING = 3_500        # 2,385 [2,385: the lap kernel is as it was]
+NORMALISING_BODY_CEILING = 3_500  # 3,058 [2,434]
+# Program events a step (instructions at the top of the loop body that the
+# chip runs as an event each: fusions, reductions, copies), at the two scan
+# cells' own shapes. Each costs about as much as the next whatever its size
+# (PERF.md section 5), so this is the count that keeps the step short.
+STEP_EVENTS_CEILING = {"spread-5k": 27, "prefaffinity-5k": 26}  # 22, 21 [58, 54]
 
 _INSTRUCTION = re.compile(r"\s+(ROOT )?%?[\w.\-]+ = ")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
@@ -69,46 +74,37 @@ def _primitives(lines):
     return collections.Counter(m.group(1).rsplit("/", 1)[-1] for m in names if m)
 
 
+def _lower_plan(one_chip, state, plan, **statics):
+    """`schedule_batch` lowered for the described chip at a built plan's
+    shapes and flags (`statics` override the plan's)."""
+    from kubernetes_tpu.ops.kernel import schedule_batch
+
+    def sds(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    flags = dict(has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
+                 anti_rowlocal=plan.anti_rowlocal, has_na_pref=plan.has_na_pref,
+                 port_selfblock=plan.port_selfblock, has_aux=plan.has_aux,
+                 has_nom=plan.has_nom, fit_strategy=plan.fit_strategy)
+    flags.update(statics)
+    fit_strategy = flags.pop("fit_strategy")
+    return schedule_batch.lower(
+        jax.tree_util.tree_map(sds, state), jax.tree_util.tree_map(sds, plan.features),
+        plan.batch_pad, fit_strategy, plan.vmax,
+        n_active=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
+        carry_in=None, **flags)
+
+
 @pytest.mark.parametrize("fit_strategy", [0, 1], ids=["LeastAllocated", "MostAllocated"])
 def test_landed_row_resource_eval_stays_small(one_chip, fit_strategy):
-    """The scan's per-step scalar work: `_resource_eval` on the one landed
-    row, inside a loop, as `step` calls it."""
-    from kubernetes_tpu.ops.kernel import _resource_eval
-    from kubernetes_tpu.ops.features import BatchFeatures
-
-    NP, R, STEPS = 8192, 8, 1024
-
-    def S(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    fields = dict(request=S((R,), jnp.int64), has_request=S((), jnp.int64),
-                  enable=S((5,), jnp.int32), nz_request=S((2,), jnp.int64),
-                  fit_slots=S((2,), jnp.int32), fit_weights=S((2,), jnp.int64),
-                  ba_skip=S((), jnp.int64))
-    f = BatchFeatures(**{k: fields.get(k) for k in BatchFeatures._fields})
-
-    def loop(f, alloc_r, alloc_pods, req_r, nonzero, pod_count, rows):
-        def body(i, c):
-            req_r, nonzero, pod_count, ok, sc, ba = c
-            row = rows[i]
-            req_r = req_r.at[row].add(f.request)
-            nonzero = nonzero.at[row].add(f.nz_request)
-            pod_count = pod_count.at[row].add(1)
-            with jax.named_scope("resource_fit"):
-                r_ok, r_sc, r_ba = _resource_eval(
-                    f, fit_strategy, alloc_r[row], alloc_pods[row],
-                    req_r[row], nonzero[row], pod_count[row])
-            return (req_r, nonzero, pod_count, ok.at[row].set(r_ok),
-                    sc.at[row].set(r_sc), ba.at[row].set(r_ba))
-        z = jnp.zeros(NP, jnp.int64)
-        return lax.fori_loop(0, STEPS, body,
-                             (req_r, nonzero, pod_count, jnp.zeros(NP, bool), z, z))
-
-    hlo = jax.jit(loop).lower(
-        f, S((NP, R), jnp.int64), S((NP,), jnp.int64), S((NP, R), jnp.int64),
-        S((NP, 2), jnp.int64), S((NP,), jnp.int32), S((STEPS,), jnp.int32),
-    ).compile().as_text()
-    scoped = _instructions(hlo, under="resource_fit")
+    """The scan's per-step resource work as `step` does it since PR 49: the
+    landed row's inputs brought down their columns under the mask and ONE
+    evaluation at LANES columns, in place of the one-row scalar
+    `_resource_eval` (which the step no longer holds). Counted in the real
+    program, under its `resource_fit` scope."""
+    state, plan = _small_plan(8, True)
+    hlo = _lower_plan(one_chip, state, plan, fit_strategy=fit_strategy).compile().as_text()
+    scoped = _instructions(hlo, under="/while/body/carry_update/resource_fit")
     assert 200 < len(scoped) <= RESOURCE_EVAL_CEILING, len(scoped)
     prims = _primitives(scoped)
     assert prims["div"] == 0 and prims["rem"] == 0, prims
@@ -148,24 +144,11 @@ def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, 
     the loop comes from a `div` or `rem`, the body stays under its
     ceiling, and the loop runs until a counter reaches the `n_active`
     argument: no program's trip count is its `batch_pad`."""
-    from kubernetes_tpu.ops.kernel import schedule_batch
-
     state, plan = _small_plan(batch, spread,
                               preferred=kernel == "scan_normalised")
     assert (plan.batch_pad > 64) == (kernel != "scan")
     assert plan.engine == {"scan": "scan_carried"}.get(kernel, kernel)
-
-    def sds(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
-
-    lowered = schedule_batch.lower(
-        jax.tree_util.tree_map(sds, state), jax.tree_util.tree_map(sds, plan.features),
-        plan.batch_pad, plan.fit_strategy, plan.vmax,
-        n_active=jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip),
-        carry_in=None, has_pns=plan.has_pns, has_ipa_base=plan.has_ipa_base,
-        anti_rowlocal=plan.anti_rowlocal, has_na_pref=plan.has_na_pref,
-        port_selfblock=plan.port_selfblock, has_aux=plan.has_aux,
-        has_nom=plan.has_nom)
+    lowered = _lower_plan(one_chip, state, plan)
     module = lowered.as_text(debug_info=True)
     n_active = _N_ACTIVE_ARG.search(module).group(1)
     (loop,) = _LOOP_COND.finditer(module)
@@ -179,6 +162,69 @@ def test_loop_body_holds_no_division_expansion(one_chip, kernel, batch, spread, 
     assert prims["div"] == 0 and prims["rem"] == 0, prims
     assert 200 < len(body) <= ceiling, len(body)
     assert any("resource_fit" in l for l in body), "the scope names left the HLO"
+
+
+def _cell_plan(cell):
+    """The plan of a scan cell's measured pods at its published size: 5,000
+    nodes (8,192 rows), batches of 1,024 (`benchmark/configs/<cell>.json`)."""
+    from kubernetes_tpu.core import FakeClientset
+    from kubernetes_tpu.models import TPUScheduler
+    from kubernetes_tpu.testing.wrappers import make_node, make_pod
+
+    cs = FakeClientset()
+    s = TPUScheduler(clientset=cs, max_batch=1024, mesh=None)
+    spread = cell == "spread-5k"
+    capacity = ({"cpu": "32", "memory": "256Gi", "pods": 110} if spread
+                else {"cpu": "4", "memory": "32Gi", "pods": 110})
+    for i in range(5000):
+        cs.create_node(make_node().name(f"node-{i}").capacity(capacity).zone(
+            f"zone-{i % 50 if spread else 0}").obj())
+    if spread:
+        pod = make_pod().name("probe").req({"cpu": "100m", "memory": "128Mi"}).labels(
+            {"app": "spread"}).spread_constraint(
+                1, "topology.kubernetes.io/zone", "DoNotSchedule", {"app": "spread"})
+    else:
+        pod = make_pod().name("probe").req({"cpu": "100m", "memory": "500Mi"}).labels(
+            {"color": "red"}).pod_affinity("kubernetes.io/hostname", {"color": "red"},
+                                           weight=1)
+    return s.build_plan(next(iter(s.profiles.values())), pod.obj(), 1024)
+
+
+# What the chip runs as an event of its own at the top of a loop body.
+_EVENT = re.compile(r" (fusion|reduce|reduce-window|copy|sort|gather|scatter|"
+                    r"dynamic-slice|dynamic-update-slice|convolution|transpose)\(")
+_ADDRESSED = ("dynamic-slice", "dynamic-update-slice", "gather", "scatter")
+
+
+@pytest.mark.parametrize("cell,engine", [
+    ("spread-5k", "scan_carried"),        # hard spread: feasibility + cumsum a step
+    ("prefaffinity-5k", "scan_normalised"),  # incremental feasibility, two rounds
+])
+def test_scan_step_addresses_nothing_by_a_value_of_its_own(one_chip, cell, engine):
+    """The two scan cells' programs at their own shapes (8,192 rows,
+    `batch_pad` 1,024): the compiled loop body holds NO dynamic slice,
+    dynamic update, gather or scatter at all (so none addressed by a value
+    the body computed: the landed row is a mask, its index only a value for
+    the results), no `div` / `rem`, and no more program events a step than
+    the ceiling."""
+    state, plan = _cell_plan(cell)
+    assert plan.engine == engine and state.valid.shape == (8192,)
+    assert plan.batch_pad == 1024
+    hlo = _lower_plan(one_chip, state, plan).compile().as_text()
+    body = _instructions(hlo, under="/while/body/")
+    assert len(body) > 200, len(body)
+    addressed = [l.strip()[:160] for l in body
+                 if any(f" {op}(" in l for op in _ADDRESSED)]
+    assert not addressed, addressed
+    prims = _primitives(body)
+    assert prims["div"] == 0 and prims["rem"] == 0, prims
+    # the loop body's own computation: the events of one step
+    (loop_line,) = [l for l in _instructions(hlo) if " while(" in l]
+    name = re.search(r"body=%?([\w.\-]+)", loop_line).group(1)
+    text = re.search(r"^%?" + re.escape(name) + r" [^\n]*\{\n(.*?)^\}", hlo,
+                     re.S | re.M).group(1)
+    events = [l for l in text.splitlines() if _EVENT.search(l)]
+    assert 10 <= len(events) <= STEP_EVENTS_CEILING[cell], len(events)
 
 
 def test_the_preemption_cells_programs_compile_for_the_chip(one_chip):
