@@ -501,6 +501,14 @@ class SchedulerMetrics:
             "'derive' = what a kept plan derives again. One a payload; the "
             "full upload after a restore (one array a transfer) is not "
             "among them.", ("payload",)))
+        self.plan_node_shapes = r(Gauge(
+            "scheduler_plan_node_shapes",
+            "Distinct allocatable shapes (cpu, memory, pod count) among the "
+            "nodes of the device path's node state as the newest session "
+            "acquired its plan (ops/device_state.py NodeStateMirror.shapes, "
+            "a census kept row by row as rows are encoded): 1 on a cluster "
+            "of equal nodes, one a node pool otherwise; a pool that joins "
+            "or leaves moves it at the next session.", ()))
         self.plan_anti_lane = r(Counter(
             "scheduler_plan_anti_lane_total",
             "Plans built whose anti-affinity filter had something to "

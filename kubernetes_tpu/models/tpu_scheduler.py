@@ -2455,7 +2455,8 @@ class TPUScheduler(Scheduler):
                       sampled=(), point: str = "", **attrs) -> _SessionDelta:
         """A session opens for `pod`'s template: `plan.build` (the caller's
         span contexts, extension point and attrs) round the acquisition,
-        which says `kind` and `cause` (a profiler event's stats).
+        which says `kind`, `cause`, `transfers` and `node_shapes` (a
+        profiler event's stats).
         ``neutral_ok``: sessions of the template's namespace-erased
         signature chain (plain pods only)."""
         sig = fw.sign_pod(pod)
@@ -2472,8 +2473,13 @@ class TPUScheduler(Scheduler):
             sent = transfers.total()
             kind = self._resume_or_rebuild(sd)
             sd.built = {"kind": kind, "cause": self.plan_build_cause}
+            # the cluster's allocatable shapes as the mirror's census has
+            # them (kept row by row where a row is encoded: no pass here)
+            shapes = len(self.mirror.shapes)
+            self.metrics.plan_node_shapes.set(shapes)
             st.say(**sd.built, **sd.plan.narrowed_attrs(),
-                   transfers=int(transfers.total() - sent))
+                   transfers=int(transfers.total() - sent),
+                   node_shapes=shapes)
         sd.start_seq = self.cluster_event_seq
         sd.start_unwinds = self.state_unwinds
         return sd

@@ -246,6 +246,11 @@ class NodeStateMirror:
         self._alloc_storage()
         self._row_names: List[str] = []
         self._row_gen: List[int] = []
+        # the census of allocatable shapes: (milli cpu, memory, pods) ->
+        # rows that hold a node of that shape, and each row's own; moved in
+        # `_note_shape` alone, where a row is encoded or leaves
+        self.shapes: Dict[tuple, int] = {}
+        self._row_shape: List[Optional[tuple]] = []
         self._dirty: set = set()
         self._full_flush = True
         self._device: Optional[DeviceNodeState] = None
@@ -314,6 +319,8 @@ class NodeStateMirror:
         self._alloc_storage()
         self._row_names = []
         self._row_gen = []
+        self.shapes = {}
+        self._row_shape = []
         self._full_flush = True
         self._device = None
 
@@ -358,10 +365,33 @@ class NodeStateMirror:
                 raise _Regrown()
             out[slot] = amount
 
+    def _note_shape(self, i: int, shape: Optional[tuple]) -> None:
+        """Row `i` holds a node of allocatable `shape` (None: no node) from
+        now on: the census moves only where that is news."""
+        known = self._row_shape
+        if i >= len(known):
+            known.extend([None] * (i + 1 - len(known)))
+        was = known[i]
+        if was == shape:
+            return
+        known[i] = shape
+        shapes = self.shapes
+        if was is not None:
+            left = shapes[was] - 1
+            if left:
+                shapes[was] = left
+            else:
+                del shapes[was]
+        if shape is not None:
+            shapes[shape] = shapes.get(shape, 0) + 1
+
     def _encode_row(self, i: int, ni: NodeInfo) -> None:
         node = ni.node
-        self._resource_vec(ni.allocatable, self.h_alloc_r[i])
-        self.h_alloc_pods[i] = ni.allocatable.allowed_pod_number
+        alloc = ni.allocatable
+        self._resource_vec(alloc, self.h_alloc_r[i])
+        self.h_alloc_pods[i] = alloc.allowed_pod_number
+        self._note_shape(i, None if node is None else (
+            alloc.milli_cpu, alloc.memory, alloc.allowed_pod_number))
         self._resource_vec(ni.requested, self.h_req_r[i])
         self.h_nonzero[i, 0] = ni.non_zero_requested.milli_cpu
         self.h_nonzero[i, 1] = ni.non_zero_requested.memory
@@ -418,6 +448,7 @@ class NodeStateMirror:
         if len(names) > n:  # shrink: invalidate tail rows
             for i in range(n, len(names)):
                 self.h_valid[i] = False
+                self._note_shape(i, None)
                 self._dirty.add(i)
             del names[n:]
             del gens[n:]

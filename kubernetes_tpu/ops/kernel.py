@@ -112,6 +112,7 @@ from .codebook import (
 )
 from .device_state import DeviceNodeState
 from .features import BatchFeatures, _pow2
+from ..plugins.interpodaffinity import float_shortfalls  # after .features: core first
 
 MAX_NODE_SCORE = 100
 _BIG = jnp.int32(1 << 30)
@@ -230,6 +231,18 @@ def _bounded_divmod(n, d, bits: int):
 
 def _bounded_div(n, d, bits: int):
     return _bounded_divmod(n, d, bits)[0]
+
+
+def _truncated_percent(a, b):
+    """InterPodAffinity's NormalizeScore as scoring.go computes it,
+    `int64(100 * (float64(a) / float64(b)))` for 0 <= a <= b, with no float:
+    the floor, less one where the quotient is exact and one of the whole
+    percentages that the float form falls short of
+    (plugins/interpodaffinity.py `float_shortfalls`: 29, 57, 58). Rows
+    outside the kept set carry other inputs and are masked by the caller."""
+    q, r = _bounded_divmod(MAX_NODE_SCORE * a, b, _SCORE_BITS)
+    short = _any_lanes([q == k for k in float_shortfalls()])
+    return q - ((r == 0) & short).astype(q.dtype)
 
 
 def _wrap(x, num):
@@ -621,8 +634,8 @@ def schedule_batch(
                     mx_i, mn_i = red[li], -red[li + 1]; li += 2
                     diff = mx_i - mn_i
                     ipa = jnp.where(diff > 0,
-                                    _bounded_div(MAX_NODE_SCORE * (raw_ipa - mn_i),
-                                                 jnp.maximum(diff, 1), _SCORE_BITS), 0)
+                                    _truncated_percent(raw_ipa - mn_i,
+                                                       jnp.maximum(diff, 1)), 0)
                 else:
                     ipa = jnp.int64(0)
                 if has_na_pref:

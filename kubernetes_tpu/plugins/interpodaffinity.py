@@ -32,6 +32,20 @@ from ..core.framework import (
 from ..core.node_info import NodeInfo, PodInfo
 from .helpers import AffinityTerm, compile_terms
 
+
+def float_shortfalls() -> Tuple[int, ...]:
+    """The whole percentages that scoring.go's NormalizeScore never yields
+    for an exact quotient: `int64(100 * (float64(a) / float64(b)))` is
+    `100 * a // b` except where `100 * a / b` is a whole number k and
+    `100.0 * (k / 100.0)` lands under k in float64, where it reads k - 1.
+    The correctly rounded quotient of a / b = k / 100 is the same float
+    whatever a and b, so the set depends on k alone (and a quotient that is
+    not whole stays clear of the next whole number by 1 / (100 b), far more
+    than float64's 2**-53 for any span of raw scores)."""
+    return tuple(k for k in range(MAX_NODE_SCORE + 1)
+                 if int(MAX_NODE_SCORE * (k / float(MAX_NODE_SCORE))) < k)
+
+
 ERR_EXISTING_ANTI = "node(s) didn't satisfy existing pods anti-affinity rules"
 ERR_ANTI = "node(s) didn't match pod anti-affinity rules"
 ERR_AFFINITY = "node(s) didn't match pod affinity rules"
@@ -356,23 +370,15 @@ class InterPodAffinity:
         min_count = min(s.score for s in scores)
         max_count = max(s.score for s in scores)
         diff = max_count - min_count
-        for s in scores:
-            if diff > 0:
-                # Floor division, exact on device int64 (ops/kernel.py does
-                # the same). NOT the reference's form in general: scoring.go
-                # computes int64(100 * (float64(a) / float64(b))), and the
-                # float quotient falls short of a/b at (a, b) = (29, 50),
-                # (29, 100), (57, 100), (58, 100), (87, 150), (58, 200),
-                # (114, 200), (116, 200) (every case up to b = 220), where it
-                # truncates to one less than the floor (57 against 58 at
-                # 29/50). None is reachable while every raw score is even and
-                # max - min <= 80 (weight-1 terms pulling both ways, at most
-                # 40 pods a node: benchmark prefaffinity-5k), which
-                # tests/test_ipa_normalise_forms.py enumerates; a node that
-                # holds 50 such pods reaches (58, 100).
-                s.score = MAX_NODE_SCORE * (s.score - min_count) // diff
-            else:
+        if diff <= 0:
+            for s in scores:
                 s.score = 0
+            return
+        # scoring.go's own form, float64 then truncated; the kernel reaches
+        # the same value in integers (ops/kernel.py `_truncated_percent`).
+        span = float(diff)
+        for s in scores:
+            s.score = int(MAX_NODE_SCORE * (float(s.score - min_count) / span))
 
     def sign(self, pod: Pod):
         aff = pod.affinity

@@ -327,6 +327,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_preemptor_plan_total",
                      "scheduler_prefilter_narrowed_pods_total",
                      "scheduler_host_to_device_transfers_total",
+                     "scheduler_plan_node_shapes",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",

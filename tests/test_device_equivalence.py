@@ -265,6 +265,59 @@ class TestAffinityEquivalence:
             build=lambda b: b.pod_affinity(ZONE, {"app": "spread-me"}, anti=True, weight=10)))
 
 
+    def test_preferred_affinity_on_four_node_sizes(self):
+        """The benchmark's `prefaffinity-pools-5k` at toy size: one node of
+        each pool's allocatable, its pods (100m / 500Mi, one preferred
+        hostname term of weight 1 over both namespaces), 29 bound on the
+        third node and 30 on the fourth. The fourth fills by pod count (its
+        raw score passes 100 and 200 while the third's stands at 58: the
+        pairs (58, 100) and (58, 200), where scoring.go's float form reads
+        one under an integer floor), then the third by pod count, then the
+        second by memory at 58 pods, and the first takes the rest."""
+        import dataclasses
+        shapes = [("3920m", "13621Mi"), ("7910m", "29022Mi"),
+                  ("15890m", "59824Mi"), ("31850m", "121428Mi")]
+
+        def cluster(sched, n_nodes, seed=0):
+            for i, (cpu, mem) in enumerate(shapes[:n_nodes]):
+                sched.clientset.create_node(
+                    make_node().name(f"node-{i}")
+                    .capacity({"cpu": cpu, "memory": mem, "pods": 110}).obj())
+
+        def pod(name, namespace, node=None):
+            b = (make_pod().name(name).namespace(namespace)
+                 .req({"cpu": "100m", "memory": "500Mi"})
+                 .labels({"color": "red"})
+                 .pod_affinity(HOSTNAME, {"color": "red"}, weight=1))
+            if node:
+                b = b.node(node)
+            p = b.obj()
+            aff = p.affinity.pod_affinity
+            last = aff.preferred[-1]
+            last = dataclasses.replace(last, term=dataclasses.replace(
+                last.term, namespaces=("sched-1", "sched-0")))
+            p.affinity = dataclasses.replace(
+                p.affinity, pod_affinity=dataclasses.replace(
+                    aff, preferred=aff.preferred[:-1] + (last,)))
+            return p
+
+        def pods():
+            return ([pod(f"init-{i}", "sched-0", "node-2") for i in range(29)]
+                    + [pod(f"init-{29 + i}", "sched-0", "node-3")
+                       for i in range(30)]
+                    + [pod(f"pod-{i}", "sched-1") for i in range(240)])
+
+        host, dev = _run_pair(4, pods, cluster=cluster)
+        held = {}
+        for p in dev.clientset.pods.values():
+            held[p.node_name] = held.get(p.node_name, 0) + 1
+        assert held == {"node-3": 110, "node-2": 110, "node-1": 58,
+                        "node-0": 21}
+        assert dev.host_path_pods == 0
+        assert dev.metrics.device_batches.value("scan_normalised") >= 1
+        assert len(dev.mirror.shapes) == 4
+
+
 class TestMixedWorkload:
     def test_mixed_signatures(self):
         """Multiple interleaved deployments → multiple batches per run."""

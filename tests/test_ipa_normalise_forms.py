@@ -1,13 +1,35 @@
-"""InterPodAffinity's NormalizeScore, two forms: the program floors
-(`100 * a // b`: plugins/interpodaffinity.py, ops/kernel.py), the reference
-scheduler computes in float64 and truncates (`int64(100 * (float64(a) /
-float64(b)))`, scoring.go), with a = raw - min and b = max - min. They are not
-the same function: this file lists where they part, and shows that the
-benchmark's `prefaffinity-5k` cannot reach such a pair, so the floor is exact
-THERE and only there by this argument."""
+"""InterPodAffinity's NormalizeScore: the reference scheduler computes in
+float64 and truncates (`int64(100 * (float64(a) / float64(b)))`, scoring.go),
+with a = raw - min and b = max - min, and so does the program on both of its
+paths: the host plugin in that very form (plugins/interpodaffinity.py), the
+kernel in integers (ops/kernel.py `_truncated_percent`: the floor, less one
+at an exact quotient of 29, 57 or 58 per cent), because the chip has no
+float64. A floor alone is another function; this file lists where the two
+part, holds the integer form to the float form pair by pair, and holds the
+host plugin, the kernel's lane and the benchmark's reference feature to one
+another on the pairs that the benchmark's node pools can reach
+(`prefaffinity-pools-5k`: nodes of 58 and 110 pods, even raws, spans to 220)."""
+
+import os
+import sys
+
+import numpy as np
+import pytest
+
+import kubernetes_tpu.core  # noqa: F401  (the plugins import after the core)
+from kubernetes_tpu.plugins.interpodaffinity import float_shortfalls
 
 MAX_PODS = 110                       # pods a node holds at most, every config
 REACH = 2 * MAX_PODS                 # a weight-1 term pulling both ways
+SPAN = 3_000                         # the spans the integer form is held over
+
+# (a, b) that the pools of `prefaffinity-pools-5k` reach (even raws, b <= 220)
+# and their neighbours: (pair, scoring.go's value). ISSUE 50 asks for "58 at
+# (59, 100)"; the float form reads 59 there (0.59 * 100.0 is 59.0), as a floor
+# does: the pairs at which the two part are the first, third, fourth and fifth.
+REACHED = [((58, 100), 57), ((59, 100), 59), ((58, 200), 28),
+           ((114, 200), 56), ((116, 200), 57), ((118, 200), 59),
+           ((60, 100), 60), ((56, 100), 56), ((220, 220), 100)]
 
 
 def _floor(a, b):
@@ -31,28 +53,33 @@ def test_where_floor_and_float_then_truncate_part():
     for a, b in parting:
         assert _floor(a, b) == _float_then_truncate(a, b) + 1
     assert (_floor(29, 50), _float_then_truncate(29, 50)) == (58, 57)
+    # every one of them at an exact quotient in the correction set, which is
+    # what one Python function states for the kernel and for this file
+    assert float_shortfalls() == (29, 57, 58)
+    assert all(100 * a % b == 0 and 100 * a // b in float_shortfalls()
+               for a, b in parting)
+    # nodes of 58 and 110 such pods (even raws) reach four of the eight
+    even = [(a, b) for a, b in parting if a % 2 == 0 and b % 2 == 0]
+    assert even == [(58, 100), (58, 200), (114, 200), (116, 200)]
 
 
-def test_prefaffinity_5k_cannot_reach_a_parting_pair():
-    """Every pod of `prefaffinity-5k` carries one weight-1 term that selects
-    every other, so a pod on a node adds 2 to its raw score (its term and the
-    incoming pod's), and 4 cpu / 100m caps a node at 40 pods: raws are even
-    and max - min <= 80."""
-    cap = 4000 // 100
-    assert cap == 40
-    reachable = {(a, b) for b in range(2, 2 * cap + 1, 2)
-                 for a in range(0, b + 1, 2)}
-    assert len(reachable) == 860
-    assert not reachable & set(_parting(REACH))
-    # and what would: the same pods on a node that holds 50 of them
-    even = [(a, b) for a, b in _parting(REACH) if a % 2 == 0 and b % 2 == 0]
-    assert even[0] == (58, 100)
-    # odd raws (a term that pulls one way only) part first at 25 pods' span
-    assert _parting(REACH)[0] == (29, 50)
+def test_the_integer_form_is_the_float_form_for_every_span_to_3000():
+    """4,504,500 pairs, as arrays: the kernel's rule (the floor, less one
+    where the remainder is 0 and the floor is in the set) against
+    numpy's float64 quotient, which is Python's and Go's."""
+    short = np.array(float_shortfalls())
+    pairs = 0
+    for b in range(1, SPAN + 1):
+        a = np.arange(b + 1, dtype=np.int64)
+        q, r = np.divmod(100 * a, b)
+        integer = q - ((r == 0) & np.isin(q, short))
+        floated = (100.0 * (a.astype(np.float64) / np.float64(b))).astype(np.int64)
+        assert (integer == floated).all(), (b, a[integer != floated][:5])
+        pairs += b + 1
+    assert pairs == 4_504_500
 
 
-def test_the_oracle_floors():
-    """The host plugin's form, so that the comment there stays true."""
+def _host_plugin(raws):
     from kubernetes_tpu.core.framework import NodeScore
     from kubernetes_tpu.plugins.interpodaffinity import InterPodAffinity
 
@@ -60,7 +87,60 @@ def test_the_oracle_floors():
         def read(self, key):
             return {"kubernetes.io/hostname": {"n": 1}}
 
-    scores = [NodeScore("a", 0), NodeScore("b", 29), NodeScore("c", 50)]
+    scores = [NodeScore(f"n{i}", int(r)) for i, r in enumerate(raws)]
     InterPodAffinity.normalize_score(
         InterPodAffinity.__new__(InterPodAffinity), _State(), None, scores)
-    assert [s.score for s in scores] == [0, 58, 100]
+    return [s.score for s in scores]
+
+
+def _kernel_lane(raws):
+    """The scan's `ipa` lane as the step computes it, a jitted call."""
+    import jax
+    import jax.numpy as jnp
+    from kubernetes_tpu.ops import kernel
+
+    @jax.jit
+    def lane(raw):
+        mn, diff = raw.min(), raw.max() - raw.min()
+        return jnp.where(diff > 0, kernel._truncated_percent(
+            raw - mn, jnp.maximum(diff, 1)), 0)
+
+    return [int(v) for v in lane(jnp.asarray(raws, jnp.int64))]
+
+
+def _reference_feature(raws):
+    """benchmark/reference_features/podAffinity.py's own normalise."""
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import features
+        module = features.load("reference", "podAffinity")
+    finally:
+        sys.path.remove(bench)
+    raw = np.asarray(raws, np.int64)
+    low, high = int(raw.min()), int(raw.max())
+    if high == low:              # `State.score`'s own guard
+        return [0] * len(raw)
+    sound = module.State.normalise(None, raw - low, high - low)
+    # and the control that floors is the OTHER function, on these pairs too
+    floored = module.CONTROLS["floor_not_float"].normalise(
+        None, raw - low, high - low)
+    assert [int(v) for v in floored] == [100 * int(a) // (high - low)
+                                         for a in raw - low]
+    return [int(v) for v in sound]
+
+
+@pytest.mark.parametrize("form", ["host plugin", "kernel lane",
+                                  "reference feature"])
+def test_every_path_truncates_as_the_source_does(form):
+    """Raws 0, a, b normalise a to scoring.go's value on every path: 57 at
+    (58, 100), where a floor gives 58, and 59 at (59, 100), where both do."""
+    normalise = {"host plugin": _host_plugin, "kernel lane": _kernel_lane,
+                 "reference feature": _reference_feature}[form]
+    for (a, b), want in REACHED:
+        assert want == _float_then_truncate(a, b)
+        low, mid, high = normalise([7, 7 + a, 7 + b])
+        assert (low, high) == (0, 100)
+        assert mid == want, (form, a, b, mid)
+    assert normalise([5, 5, 5]) == [0, 0, 0]     # no span: every node 0

@@ -288,6 +288,8 @@ def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
                for seq, d in enumerate(dispatches, 1))
     # what a stage says while it is open rides the annotation too: a plan's
     # kind, and why a full build is one (PR 40)
-    # (and, PR 48, the transfers of its acquisition: the features' one)
+    # (and, PR 48, the transfers of its acquisition: the features' one;
+    # PR 50, the allocatable shapes among its nodes: here all alike)
     assert ("sched.plan.build", {"batch": 3, "kind": "full",
-                                 "cause": "first", "transfers": 1}) in opened
+                                 "cause": "first", "transfers": 1,
+                                 "node_shapes": 1}) in opened
