@@ -501,6 +501,16 @@ class SchedulerMetrics:
             "'derive' = what a kept plan derives again. One a payload; the "
             "full upload after a restore (one array a transfer) is not "
             "among them.", ("payload",)))
+        self.mirror_rows = r(Counter(
+            "scheduler_mirror_rows_total",
+            "Rows of the device path's node state brought in line with "
+            "their NodeInfo (ops/device_state.py NodeStateMirror), by how: "
+            "'encoded' = the whole row, where the node itself is new to the "
+            "row or changed (a sync's, a session's row patch); 'by_column' "
+            "= the three columns a pod's arrival or departure moves, many "
+            "rows in one array pass, at a sync that finds the node it "
+            "encoded; 'adopted' = the same columns at a clean session's "
+            "end, from the live cache.", ("how",)))
         self.plan_node_shapes = r(Gauge(
             "scheduler_plan_node_shapes",
             "Distinct allocatable shapes (cpu, memory, pod count) among the "

@@ -63,7 +63,10 @@ class PodInfo:
 
 
 class NodeInfo:
-    """Aggregated node state. Mutable; every mutation bumps `generation`."""
+    """Aggregated node state. Mutable; every mutation bumps `generation`;
+    `node_generation` is the generation at which the node itself (its spec:
+    allocatable, labels, taints, images) was last set, so a reader that kept
+    it tells a change of the node from a change of its pods."""
 
     __slots__ = (
         "node",
@@ -77,6 +80,7 @@ class NodeInfo:
         "pvc_ref_counts",
         "image_states",
         "generation",
+        "node_generation",
     )
 
     # Default requests for the "non-zero" aggregate used by scoring
@@ -100,7 +104,7 @@ class NodeInfo:
             for img in node.images:
                 for name in img.names:
                     self.image_states[name] = img.size_bytes
-        self.generation = next_generation()
+        self.generation = self.node_generation = next_generation()
 
     # -- mutations ---------------------------------------------------------
 
@@ -111,7 +115,7 @@ class NodeInfo:
         for img in node.images:
             for name in img.names:
                 self.image_states[name] = img.size_bytes
-        self.generation = next_generation()
+        self.generation = self.node_generation = next_generation()
 
     def add_pod(self, pi: PodInfo) -> None:
         self.pods.append(pi)
@@ -179,4 +183,5 @@ class NodeInfo:
         c.pvc_ref_counts = dict(self.pvc_ref_counts)
         c.image_states = dict(self.image_states)
         c.generation = self.generation
+        c.node_generation = self.node_generation
         return c

@@ -185,6 +185,7 @@ class TPUScheduler(Scheduler):
             self.mesh = mesh  # explicit Mesh, or None to force single-device
         self.mirror = NodeStateMirror()
         self.mirror.transfers = self.metrics.host_to_device_transfers
+        self.mirror.rows = self.metrics.mirror_rows
         # the preemption what-if's victim tensors, kept from one preemptor
         # to the next and patched by the snapshot's generations
         self._victims = PreemptionVictims(self.mirror)
@@ -2455,8 +2456,9 @@ class TPUScheduler(Scheduler):
                       sampled=(), point: str = "", **attrs) -> _SessionDelta:
         """A session opens for `pod`'s template: `plan.build` (the caller's
         span contexts, extension point and attrs) round the acquisition,
-        which says `kind`, `cause`, `transfers` and `node_shapes` (a
-        profiler event's stats).
+        which says `kind`, `cause`, `transfers`, `node_shapes` and how many
+        mirror rows it brought in line whole and by column, `rows_encoded`
+        and `rows_by_column` (a profiler event's stats).
         ``neutral_ok``: sessions of the template's namespace-erased
         signature chain (plain pods only)."""
         sig = fw.sign_pod(pod)
@@ -2468,9 +2470,10 @@ class TPUScheduler(Scheduler):
             fw, pod, sig,
             self._neutral_sig(fw, pod, sig) if neutral_ok else None,
             neutral_ok, self._aux_shape(pod))
-        transfers = self.mirror.transfers
+        transfers, rows = self.mirror.transfers, self.mirror.rows
         with self.stages.stage("plan.build", sampled, point, **attrs) as st:
             sent = transfers.total()
+            encoded, by_column = rows.value("encoded"), rows.value("by_column")
             kind = self._resume_or_rebuild(sd)
             sd.built = {"kind": kind, "cause": self.plan_build_cause}
             # the cluster's allocatable shapes as the mirror's census has
@@ -2479,7 +2482,9 @@ class TPUScheduler(Scheduler):
             self.metrics.plan_node_shapes.set(shapes)
             st.say(**sd.built, **sd.plan.narrowed_attrs(),
                    transfers=int(transfers.total() - sent),
-                   node_shapes=shapes)
+                   node_shapes=shapes,
+                   rows_encoded=int(rows.value("encoded") - encoded),
+                   rows_by_column=int(rows.value("by_column") - by_column))
         sd.start_seq = self.cluster_event_seq
         sd.start_unwinds = self.state_unwinds
         return sd
@@ -2523,19 +2528,24 @@ class TPUScheduler(Scheduler):
 
     def _close_session(self, sd: _SessionDelta, invalidated: bool,
                        flushed: str, install_hint: bool = False) -> None:
-        """A session closes: `plan.adopt`, opened with its session's build.
+        """A session closes: `plan.adopt`, opened with its session's build,
+        says `rows_adopted` and `snapshot_refreshed` as it ends.
         An invalidated session's carry charged host-diverged placements, so
         staging is the authority again: a full re-encode + upload, counted
         as a flush (`flushed`). A clean one keeps the device state resident
         (the final carry holds every placement: the next flush uploads
-        nothing), hands its tail back and may install the score hint. A
+        nothing), hands its tail back and may install the score hint; the
+        mirror adopts the rows the session landed on from the LIVE cache, as
+        a row patch reads them, and nothing here refreshes the snapshot: the
+        cache keeps its dirty rows for the next reader's own refresh
+        (`_sync_mirror`, the host cycle's, the group paths'). A
         clean session over a narrowed row set leaves the resident state as
         it is: its carry holds other rows than the mirror's, so the rows it
         landed on go the ordinary way (their NodeInfo generations moved:
-        the next sync re-encodes them, the next flush scatters them, one
+        the next sync brings them in line, the next flush scatters them, one
         row a pinned node), and nobody resumes or is served from it."""
-        with self.stages.stage("plan.adopt", **sd.built):
-            self.cache.update_snapshot(self.snapshot)
+        with self.stages.stage("plan.adopt", **sd.built) as st:
+            seen, adopted = self.snapshot.generation, 0
             dirty_rows = sd.dirty_rows + sd.busy_patch_rows  # re-encoded
             if invalidated:
                 self.mirror.invalidate()
@@ -2543,9 +2553,9 @@ class TPUScheduler(Scheduler):
                 self._after_flush = True
             elif sd.plan.rows is None:
                 carry = sd.carry
-                self.mirror.adopt(self.snapshot.node_info_list, sd.ok_rows,
-                                  carry.req_r, carry.nonzero,
-                                  carry.pod_count, dirty_rows=dirty_rows)
+                adopted = self.mirror.adopt(
+                    self.cache.nodes, sd.ok_rows, carry.req_r, carry.nonzero,
+                    carry.pod_count, dirty_rows=dirty_rows)
                 if not dirty_rows:
                     self._hand_back_tail(sd)
                     if install_hint and self._hints.enabled and hint_eligible(
@@ -2554,6 +2564,8 @@ class TPUScheduler(Scheduler):
                             self.cache.affinity_pod_refs):
                         self._hints.install(sd.fw, sd.pod, sd.sig, sd.nsig,
                                             sd.plan, sd.node_names, carry)
+            st.say(rows_adopted=adopted,
+                   snapshot_refreshed=int(self.snapshot.generation != seen))
         # The session ran to completion (invalidation included — that is a
         # NORMAL end, not a device failure): a half-open breaker closes.
         self._note_device_success()
@@ -2867,6 +2879,9 @@ class TPUScheduler(Scheduler):
                 # per-node Filter, restricted to the winner). A miss means
                 # the carry diverged from live device state.
                 dra_state = CycleState()
+                # its own refresh: a resumed session made none, and the
+                # session before it no longer ends in one
+                self.cache.update_snapshot(self.snapshot)
                 ni = self.snapshot.get(node_name)
                 _r, st = dr.pre_filter(dra_state, pod,
                                        [ni] if ni is not None else [])

@@ -3,9 +3,12 @@
 This is the TPU-era equivalent of the reference's incremental snapshot refresh
 (pkg/scheduler/backend/cache/cache.go:206 UpdateSnapshot, generation walk at
 :236-262): the mirror keeps one row per node in `snapshot.node_info_list`
-order, re-encodes only rows whose NodeInfo.generation advanced (or whose list
-position changed), and flushes them to device with a scatter when few rows are
-dirty, a full upload otherwise.
+order, brings in line only rows whose NodeInfo.generation advanced (or whose
+list position changed), and flushes them to device with a scatter when few rows
+are dirty, a full upload otherwise. A row is encoded whole (`_encode_row`) when
+its node changed (NodeInfo.node_generation); a row of which only the pods moved
+has its three dynamic columns written, many rows in one array pass
+(`_write_pod_columns`).
 
 Row order == snapshot list order, so the kernel's rotation arithmetic
 (schedule_one.go:816 nextStartNodeIndex) operates directly on row indices.
@@ -19,7 +22,8 @@ and device agree bit-for-bit (see ops/kernel.py).
 from __future__ import annotations
 
 import math
-from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
+from typing import (Dict, List, Mapping, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import numpy as np
 
@@ -246,6 +250,8 @@ class NodeStateMirror:
         self._alloc_storage()
         self._row_names: List[str] = []
         self._row_gen: List[int] = []
+        # the NodeInfo.node_generation each row's whole encode was of
+        self._row_node: List[int] = []
         # the census of allocatable shapes: (milli cpu, memory, pods) ->
         # rows that hold a node of that shape, and each row's own; moved in
         # `_note_shape` alone, where a row is encoded or leaves
@@ -266,6 +272,10 @@ class NodeStateMirror:
         # scheduler that owns the mirror puts its registry's series here
         self.transfers = Counter(
             "scheduler_host_to_device_transfers_total", "", ("payload",))
+        # rows brought in line with their NodeInfo, by how (`encoded` whole,
+        # `by_column` at a sync, `adopted` at a session's end); the owner's
+        # registry likewise
+        self.rows = Counter("scheduler_mirror_rows_total", "", ("how",))
 
     # -- one transfer a payload --------------------------------------------
 
@@ -319,6 +329,7 @@ class NodeStateMirror:
         self._alloc_storage()
         self._row_names = []
         self._row_gen = []
+        self._row_node = []
         self.shapes = {}
         self._row_shape = []
         self._full_flush = True
@@ -337,7 +348,7 @@ class NodeStateMirror:
         self.axes[key] = ax
         # Existing rows lack the new axis column: force re-encode on next sync.
         self._full_flush = True
-        self._row_gen = [-1] * len(self._row_gen)
+        self._forget_rows()
         return ax
 
     def scalar_slot(self, resource_name: str) -> int:
@@ -433,18 +444,35 @@ class NodeStateMirror:
 
     def _sync_rows(self, node_info_list: Sequence[NodeInfo]) -> None:
         n = len(node_info_list)
-        names, gens = self._row_names, self._row_gen
+        names, gens, nodes = self._row_names, self._row_gen, self._row_node
+        known = len(names)
+        moved_rows, moved = [], []  # the node is the one encoded: pods moved
+        encoded = 0
         for i, ni in enumerate(node_info_list):
-            if i < len(names) and names[i] == ni.name and gens[i] == ni.generation:
-                continue
+            if i < known and names[i] == ni.name:
+                if gens[i] == ni.generation:
+                    continue
+                if nodes[i] == ni.node_generation:
+                    moved_rows.append(i)
+                    moved.append(ni)
+                    continue
             self._encode_row(i, ni)
-            if i < len(names):
+            if i < known:
                 names[i] = ni.name
                 gens[i] = ni.generation
+                nodes[i] = ni.node_generation
             else:
                 names.append(ni.name)
                 gens.append(ni.generation)
+                nodes.append(ni.node_generation)
             self._dirty.add(i)
+            encoded += 1
+        if moved_rows:
+            self._write_pod_columns(moved_rows, moved)
+            self._dirty.update(moved_rows)
+            self.rows.inc("by_column", value=float(len(moved_rows)))
+        if encoded:
+            self.rows.inc("encoded", value=float(encoded))
         if len(names) > n:  # shrink: invalidate tail rows
             for i in range(n, len(names)):
                 self.h_valid[i] = False
@@ -452,6 +480,41 @@ class NodeStateMirror:
                 self._dirty.add(i)
             del names[n:]
             del gens[n:]
+            del nodes[n:]
+
+    def _write_pod_columns(self, rows: List[int],
+                           infos: List[NodeInfo]) -> None:
+        """Staging rows `rows` brought in line with their NodeInfos' PODS:
+        the three columns that a pod's arrival or departure moves (`h_req_r`,
+        `h_nonzero`, `h_pod_count`), each written in one indexed assignment
+        with what `_encode_row` writes there, and the rows' generations
+        taken. The rest of each row is its node's already: the caller has
+        seen to that (`_row_node`). A requested vector with scalar resources
+        takes `_resource_vec`, which may raise `_Regrown`."""
+        if not rows:
+            return
+        idx = np.asarray(rows, np.intp)
+        requested = [ni.requested for ni in infos]
+        req = np.zeros((len(rows), self.r_slots), np.int64)
+        req[:, SLOT_CPU] = [r.milli_cpu for r in requested]
+        req[:, SLOT_MEMORY] = [r.memory for r in requested]
+        req[:, SLOT_EPHEMERAL] = [r.ephemeral_storage for r in requested]
+        self.h_req_r[idx] = req
+        for i, r in zip(rows, requested):
+            if r.scalar_resources:
+                self._resource_vec(r, self.h_req_r[i])
+        non_zero = [ni.non_zero_requested for ni in infos]
+        self.h_nonzero[idx, 0] = [r.milli_cpu for r in non_zero]
+        self.h_nonzero[idx, 1] = [r.memory for r in non_zero]
+        self.h_pod_count[idx] = [len(ni.pods) for ni in infos]
+        gens = self._row_gen
+        for i, ni in zip(rows, infos):
+            gens[i] = ni.generation
+
+    def _forget_rows(self) -> None:
+        """Every row is encoded whole at the next sync."""
+        self._row_gen = [-1] * len(self._row_gen)
+        self._row_node = [-1] * len(self._row_node)
 
     # -- flush -------------------------------------------------------------
 
@@ -596,8 +659,10 @@ class NodeStateMirror:
             for row, ni in updates:
                 self._encode_row(row, ni)
                 self._row_gen[row] = ni.generation
+                self._row_node[row] = ni.node_generation
         except _Regrown:
             return None  # staging reset: next flush rebuilds everything
+        self.rows.inc("encoded", value=float(len(updates)))
         dirty = sorted({row for row, _ in updates})
         packed, layout = self._dirty_payload(dirty, patch_tier(len(dirty)))
         if sharded_state is not None and sharded_state is self._device:
@@ -624,51 +689,56 @@ class NodeStateMirror:
         sync/flush (used when a device session diverged from the host: the
         carry can no longer be trusted as the device truth)."""
         self._full_flush = True
-        self._row_gen = [-1] * len(self._row_gen)
+        self._forget_rows()
 
     # -- carry adoption (device-resident steady state) ---------------------
 
     def adopt(
         self,
-        node_info_list: Sequence[NodeInfo],
+        nodes: Mapping[str, NodeInfo],
         rows: Sequence[int],
         req_r: jnp.ndarray,
         nonzero: jnp.ndarray,
         pod_count: jnp.ndarray,
         dirty_rows: Sequence[int] = (),
-    ) -> None:
+    ) -> int:
         """After a device batch: the kernel's final carry already holds the
         updated per-node aggregates, so install those arrays directly and
         bring the host staging + generations in line WITHOUT marking rows
         dirty — the next flush() then uploads nothing. Rows whose host commit
         failed (carry diverged from cache) go through the normal dirty path.
 
+        `nodes`: node name -> the NodeInfo that holds the truth of its pods,
+        the LIVE cache's (`Cache.nodes`) as `patch_rows` reads it, with no
+        snapshot refresh: a clone made later carries the generation taken
+        here, so the next sync skips the row unless something moved it. Only
+        the pod columns are written (`_write_pod_columns`): taints, labels
+        and topology cannot have moved without a node event, which a session
+        patches whole (`patch_rows`); a row whose node is not the one it
+        encoded is left to the next sync.
+
         This is the device-resident analogue of cache.go's incremental
         UpdateSnapshot: in steady state the only node changes are the batch's
-        own placements, which the device already has."""
+        own placements, which the device already has. Returns the rows whose
+        staging it brought in line."""
         if self._device is None or self._full_flush:
-            return  # a full upload from (authoritative) staging is pending
+            return 0  # a full upload from (authoritative) staging is pending
+        names, known = self._row_names, self._row_node
+        landed, infos = [], []
+        for i in set(rows).intersection(range(len(names))):
+            ni = nodes.get(names[i])
+            if ni is not None and ni.node_generation == known[i]:
+                landed.append(i)
+                infos.append(ni)
         try:
-            for i in rows:
-                if i < len(node_info_list):
-                    ni = node_info_list[i]
-                    # Only the resource aggregates change on our own
-                    # placements — re-encode just those columns (the full
-                    # row encode is ~3x the work and taints/labels/topology
-                    # can't have moved without a generation-bumping event,
-                    # which ends the session before adopt).
-                    self._resource_vec(ni.requested, self.h_req_r[i])
-                    self.h_nonzero[i, 0] = ni.non_zero_requested.milli_cpu
-                    self.h_nonzero[i, 1] = ni.non_zero_requested.memory
-                    self.h_pod_count[i] = len(ni.pods)
-                    if i < len(self._row_names):
-                        self._row_gen[i] = ni.generation
+            self._write_pod_columns(landed, infos)
         except _Regrown:
-            return  # staging reset; full flush will rebuild everything
+            return 0  # staging reset; full flush will rebuild everything
+        self.rows.inc("adopted", value=float(len(landed)))
         self._device = self._device._replace(
             req_r=req_r, nonzero=nonzero, pod_count=pod_count)
-        for i in dirty_rows:
-            self._dirty.add(i)
+        self._dirty.update(dirty_rows)
+        return len(landed)
 
 
 class _Regrown(Exception):

@@ -328,6 +328,7 @@ def test_metric_name_parity_with_reference():
                      "scheduler_prefilter_narrowed_pods_total",
                      "scheduler_host_to_device_transfers_total",
                      "scheduler_plan_node_shapes",
+                     "scheduler_mirror_rows_total",
                      "scheduler_shard_owned_shards",
                      "scheduler_shard_lease_renewals_total",
                      "scheduler_shard_adoptions_total",

@@ -289,7 +289,10 @@ def test_a_dispatch_carries_its_engine_into_the_profiler(monkeypatch):
     # what a stage says while it is open rides the annotation too: a plan's
     # kind, and why a full build is one (PR 40)
     # (and, PR 48, the transfers of its acquisition: the features' one;
-    # PR 50, the allocatable shapes among its nodes: here all alike)
+    # PR 50, the allocatable shapes among its nodes: here all alike;
+    # PR 51, the mirror rows it brought in line: the 24 nodes, all new to
+    # it, and once more when the build met the term's hostname axis)
     assert ("sched.plan.build", {"batch": 3, "kind": "full",
                                  "cause": "first", "transfers": 1,
-                                 "node_shapes": 1}) in opened
+                                 "node_shapes": 1, "rows_encoded": 48,
+                                 "rows_by_column": 0}) in opened
